@@ -1,0 +1,837 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, the only one that touches JAX, drives the main path once through
+the entry points a user calls and checks what comes out. It fails (exit code
+nonzero, no result line, the failing phase named on the last line) when JAX
+finds no TPU, when the device kind has no published peaks, or when any phase
+fails. On success the last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+    python chip_smoke.py            # one chip (the default, and the contract)
+    python chip_smoke.py --chips 4  # data-parallel ERNIE over a four-chip host
+
+Phases, one chip:
+
+  device   backend is "tpu" and `device_kind` is in calibrate.PEAKS; versions
+           and the compile-cache directory in effect are printed
+  trainer  ERNIE/BERT-base at full width and depth (b64 x 512, bf16 AMP Adam,
+           dropout 0.1, flash attention): startup + ten Executor.run steps on
+           one fixed batch; losses finite and falling, results resident on the
+           TPU, a Mosaic call in the compiled step, no compile after step two
+  kernels  every Pallas kernel a model can reach, compiled (never the
+           interpreter), run and compared on the chip with an XLA reference at
+           the shapes the models use
+  deepfm   three DeepFM steps at the benchmark's configuration (33.5M-row
+           packed table, batch 4096, exact Adagrad) on its default path
+
+`--chips 4` runs `device` and then `dp4`: the same ERNIE program under
+CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
+split, the state shardings, the memory spread, and five dp4 losses against
+five one-chip losses.
+
+Timings printed here (compile seconds, step ms) are set-up facts from one
+run, not benchmark numbers. Details too long for stdout go to
+``chiprun_out/chip_smoke_chips<N>.json``.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import importlib
+import importlib.metadata
+import json
+import os
+import sys
+import time
+import traceback
+from typing import Callable, NamedTuple, Optional
+
+import numpy as np
+
+OUT_DIR = "chiprun_out"
+
+# ERNIE/BERT-base exactly as bench.py's headline section builds it
+ERNIE_LAYERS = 12
+ERNIE_BATCH, ERNIE_SEQ = 64, 512
+ERNIE_STEPS = 10
+# DeepFM at bench.py's configuration
+DEEPFM_VOCAB, DEEPFM_BATCH, DEEPFM_STEPS = 33_554_432, 4096, 3
+# dp4: per-chip batch of the sharded run, and the dropout-free equality run
+DP4_PER_CHIP, DP4_EQ_BATCH, DP4_EQ_STEPS, DP4_EQ_RTOL = 64, 64, 5, 1e-2
+
+# jax.monitoring event fired around every backend compile (cache hit or miss)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold."""
+
+
+def _require(cond, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+class _CompileCounter:
+    """Counts backend compiles through jax.monitoring (every jit in the
+    process, not only the executor's own cache misses)."""
+
+    def __init__(self):
+        import jax
+
+        self.count, self.seconds = 0, 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def _on_event(self, event, duration, **_):
+        if event == _COMPILE_EVENT:
+            self.count += 1
+            self.seconds += duration
+
+
+def _registry_value(name: str) -> float:
+    from paddle_tpu.observability import get_registry
+    return float(get_registry().snapshot().get(name, 0.0))
+
+
+def _platforms(arr) -> set:
+    return {d.platform for d in arr.devices()}
+
+
+def _mem_stat(dev, name: str) -> int:
+    return int(dev.memory_stats()[name])
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+def phase_device(args):
+    import jax
+
+    backend = jax.default_backend()
+    _require(backend == "tpu",
+             f"chip_smoke needs a TPU: jax.default_backend() is {backend!r}")
+    devs = jax.devices()
+    from paddle_tpu.observability import calibrate
+
+    kind = devs[0].device_kind
+    _require(kind in calibrate.PEAKS,
+             f"device kind {kind!r} has no entry in calibrate.PEAKS "
+             f"(known: {sorted(calibrate.PEAKS)})")
+    _require(len(devs) >= args.chips,
+             f"--chips {args.chips} needs {args.chips} devices, "
+             f"jax.devices() has {len(devs)}")
+    versions = {p: importlib.metadata.version(p)
+                for p in ("jax", "jaxlib", "libtpu")}
+    out = {
+        "platform": devs[0].platform, "kind": kind, "count": len(devs),
+        "versions": versions,
+        "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        "compile_cache_entries_at_start": int(_registry_value(
+            "executor/compile_cache_entries_at_start")),
+    }
+    print(f"device: platform={out['platform']} kind={kind!r} "
+          f"count={out['count']} "
+          + " ".join(f"{k}={v}" for k, v in versions.items()))
+    print(f"device: compile cache {out['compile_cache_dir']} held "
+          f"{out['compile_cache_entries_at_start']} entries at start")
+    return out
+
+
+# ---------------------------------------------------------------------------
+# trainer: ERNIE/BERT-base through Program -> Executor
+# ---------------------------------------------------------------------------
+
+def ernie_program(batch: int, seq: int, dropout: float):
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import bert
+
+    cfg = bert.BertConfig(num_layers=ERNIE_LAYERS, hidden_size=768,
+                          num_heads=12, ffn_size=3072, vocab_size=30522,
+                          hidden_dropout=dropout, attn_dropout=dropout)
+
+    def opt():
+        return mp.decorate(fluid.optimizer.Adam(1e-4), dtype="bfloat16",
+                           use_dynamic_loss_scaling=False)
+
+    main, startup, _, loss = bert.build_pretrain_program(
+        cfg, batch, seq, optimizer_factory=opt)
+    return cfg, main, startup, loss
+
+
+def ernie_feed(cfg, batch: int, seq: int) -> dict:
+    rng = np.random.RandomState(0)
+    return {
+        "src_ids": rng.randint(0, cfg.vocab_size, (batch, seq)).astype("int32"),
+        "pos_ids": np.tile(np.arange(seq), (batch, 1)).astype("int32"),
+        "sent_ids": np.zeros((batch, seq), dtype="int32"),
+        "input_mask": np.ones((batch, seq), dtype="float32"),
+        "mlm_labels": rng.randint(
+            0, cfg.vocab_size, (batch, seq, 1)).astype("int32"),
+    }
+
+
+def _compiled_step(exe, program):
+    """The executable `exe` compiled for `program`. Reads the executor's
+    cache because nothing public hands it out; the AUTO-layout AOT step must
+    exist on a TPU (its absence would mean the executor quietly fell back to
+    the plain jit)."""
+    steps = [fn for key, fn in exe._cache.items() if key[0] == id(program)]
+    _require(len(steps) == 1,
+             f"expected one compiled step for the program, found {len(steps)}")
+    _require(steps[0]._compiled is not None,
+             "the executor holds no AUTO-layout executable for the step")
+    return steps[0]._compiled
+
+
+def _hbm_by_xla(compiled) -> dict:
+    """XLA's own account of what the executable needs in HBM. On this
+    runtime memory_stats()' peak_bytes_in_use counts live arrays only, not
+    the program's temporaries, so it understates a training step several
+    times over."""
+    ma = compiled.memory_analysis()
+    return {"argument_bytes": int(ma.argument_size_in_bytes),
+            "output_bytes": int(ma.output_size_in_bytes),
+            "alias_bytes": int(ma.alias_size_in_bytes),
+            "temp_bytes": int(ma.temp_size_in_bytes),
+            "live_bytes": int(ma.argument_size_in_bytes
+                              + ma.output_size_in_bytes
+                              - ma.alias_size_in_bytes
+                              + ma.temp_size_in_bytes)}
+
+
+def phase_trainer(args):
+    import jax
+
+    import paddle_tpu as fluid
+
+    compiles = _CompileCounter()
+    cfg, main, startup, loss = ernie_program(ERNIE_BATCH, ERNIE_SEQ, 0.1)
+    feed = ernie_feed(cfg, ERNIE_BATCH, ERNIE_SEQ)
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        startup_s = time.perf_counter() - t0
+
+        losses, first_step_s, steady_t0 = [], None, None
+        compiles_after_2 = misses_after_2 = None
+        for step in range(ERNIE_STEPS):
+            t0 = time.perf_counter()
+            (lv,) = exe.run(main, feed=feed, fetch_list=[loss],
+                            return_numpy=False)
+            if step == 0:
+                lv.block_until_ready()
+                first_step_s = time.perf_counter() - t0
+                _require(compiles.count > 0,
+                         f"no {_COMPILE_EVENT} event seen during the first "
+                         f"step: the compile counter is blind")
+            if step == 1:
+                lv.block_until_ready()
+                compiles_after_2 = compiles.count
+                misses_after_2 = _registry_value("executor/cache_misses")
+                steady_t0 = time.perf_counter()
+            losses.append(lv)
+        losses[-1].block_until_ready()
+        steady_ms = ((time.perf_counter() - steady_t0)
+                     / (ERNIE_STEPS - 2) * 1e3)
+
+        _require(_platforms(losses[-1]) == {"tpu"},
+                 f"fetched loss lives on {_platforms(losses[-1])}, not tpu")
+        param = scope.find_var("word_embedding")
+        _require(param is not None and _platforms(param) == {"tpu"},
+                 "parameter 'word_embedding' is not resident on the tpu")
+        vals = [float(np.asarray(v)) for v in losses]
+        _require(all(np.isfinite(vals)), f"non-finite loss in {vals}")
+        _require(vals[-1] < vals[0],
+                 f"loss did not fall over {ERNIE_STEPS} steps: {vals}")
+        late = compiles.count - compiles_after_2
+        late_misses = _registry_value("executor/cache_misses") - misses_after_2
+        _require(late == 0 and late_misses == 0,
+                 f"{late} backend compiles / {late_misses} executor cache "
+                 f"misses after step two")
+        compiled = _compiled_step(exe, main)
+        n_mosaic = compiled.as_text().count("tpu_custom_call")
+        _require(n_mosaic > 0,
+                 "no tpu_custom_call in the compiled ERNIE step: attention "
+                 "did not take the Pallas kernel")
+        hbm = _hbm_by_xla(compiled)
+        peak = _mem_stat(jax.devices()[0], "peak_bytes_in_use")
+    out = {
+        "config": {"layers": ERNIE_LAYERS, "batch": ERNIE_BATCH,
+                   "seq": ERNIE_SEQ, "dropout": 0.1},
+        "losses": [round(v, 4) for v in vals],
+        "mosaic_calls_in_step": n_mosaic,
+        "compiles_total": compiles.count,
+        "compiles_after_step_two": late,
+        "peak_bytes_in_use": peak,
+        "hbm_by_xla_memory_analysis": hbm,
+        "setup": {"startup_s": round(startup_s, 2),
+                  "compile_plus_first_step_s": round(first_step_s, 2),
+                  "backend_compile_s": round(compiles.seconds, 2),
+                  "step_ms_one_run": round(steady_ms, 1)},
+    }
+    print(f"trainer: ERNIE-base L{ERNIE_LAYERS} b{ERNIE_BATCH}x{ERNIE_SEQ} "
+          f"loss {vals[0]:.4f} -> {vals[-1]:.4f} over {ERNIE_STEPS} steps, "
+          f"{n_mosaic} Mosaic calls in the step, {late} compiles after step "
+          f"two")
+    print(f"trainer: HBM the step needs by XLA's memory analysis "
+          f"{hbm['live_bytes'] / 2**30:.2f} GiB (arguments "
+          f"{hbm['argument_bytes'] / 2**30:.2f} + temporaries "
+          f"{hbm['temp_bytes'] / 2**30:.2f}); memory_stats "
+          f"peak_bytes_in_use {peak / 2**30:.2f} GiB (live arrays only)")
+    print(f"trainer: set-up facts (one run, not a benchmark): startup "
+          f"{startup_s:.1f} s, compile+first step {first_step_s:.1f} s, "
+          f"then {steady_ms:.0f} ms/step")
+    del losses, param, scope, exe, compiled
+    gc.collect()
+    return out
+
+
+# ---------------------------------------------------------------------------
+# kernels: each Pallas entry point against an XLA reference, on the chip
+# ---------------------------------------------------------------------------
+
+class KernelCase(NamedTuple):
+    """`kernel(*args)` and `reference(*args)` return the same tuple of
+    arrays (outputs, then gradients); `make_args(rng)` draws the inputs.
+    `tol` bounds ||kernel - reference|| / ||reference|| (Frobenius) per
+    array: a max-norm would be set by the few elements whose relu mask
+    flips when two bf16 roundings of the same value straddle zero."""
+
+    name: str
+    make_args: Callable
+    kernel: Callable
+    reference: Callable
+    tol: float
+
+
+def _fa():
+    # the package re-exports the flash_attention *function* under the same
+    # name, shadowing the submodule
+    return importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.flash_attention")
+
+
+def _with_grads(f, n_diff: int):
+    """(args) -> (out, d/d(args[:n_diff]) of sum(out * w)) with a fixed
+    pseudo-random cotangent w, so forward and backward are both compared."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(*args):
+        def scalar(*diff):
+            out = f(*diff, *args[n_diff:])
+            w = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+                out.shape)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        grads, out = jax.grad(scalar, argnums=tuple(range(n_diff)),
+                              has_aux=True)(*args[:n_diff])
+        return (out, *grads)
+    return run
+
+
+def _attention_reference(q, k, v, nh, mask=None, bias=None):
+    """Plain softmax attention on packed [B, T, H] tensors in f32 with exact
+    matmuls. `mask` is a bool [B, 1|nh, Tq, Tk] (True = visible); rows with
+    nothing visible return 0, as the kernels do."""
+    import jax
+    import jax.numpy as jnp
+
+    b, tq, h = q.shape
+    d = h // nh
+    hp = jax.lax.Precision.HIGHEST
+
+    def heads(x):
+        return x.astype(jnp.float32).reshape(
+            b, x.shape[1], nh, d).transpose(0, 2, 1, 3)
+
+    s = jnp.einsum("bhqd,bhkd->bhqk", heads(q), heads(k),
+                   precision=hp) / np.sqrt(d)
+    if bias is not None:
+        s = s + bias[:, None].astype(jnp.float32)      # [B,1,1,Tk]
+    if mask is not None:
+        s = jnp.where(mask, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, heads(v), precision=hp)
+    return o.transpose(0, 2, 1, 3).reshape(b, tq, h).astype(q.dtype)
+
+
+def _causal(tq, tk):
+    return (np.arange(tq)[:, None] >= np.arange(tk)[None, :])[None, None]
+
+
+def _qkv(rng, b, tq, tk, h, dtype="bfloat16"):
+    import jax.numpy as jnp
+    return tuple(jnp.asarray(rng.standard_normal((b, t, h)) * 0.5, dtype)
+                 for t in (tq, tk, tk))
+
+
+def _segments(rng, b, t, avg):
+    """Packed segment-id rows as reader.pack_by_tokens lays them out:
+    1-based ascending ids, 0 = pad tail on some rows."""
+    seg = np.zeros((b, t), "int32")
+    for r in range(b):
+        p, sid = 0, 1
+        while p < t:
+            ln = min(int(rng.randint(avg // 2, avg * 2)), t - p)
+            seg[r, p:p + ln] = sid
+            p, sid = p + ln, sid + 1
+            if p > t // 2 and rng.rand() < 0.25:
+                break
+    return seg
+
+
+def _dense_case(name, b, t, nh, causal, with_bias):
+    import jax.numpy as jnp
+    h = nh * 64
+
+    def make_args(rng):
+        args = _qkv(rng, b, t, t, h)
+        if with_bias:
+            # the BERT additive mask: 0 = keep, -10000 = pad (last eighth)
+            keep = np.ones((b, 1, t), "float32")
+            keep[b // 2:, :, t - t // 8:] = 0.0
+            args += (jnp.asarray((keep - 1.0) * 10000.0),)
+        return args
+
+    def kernel(q, k, v, *bias):
+        return _fa().flash_attention_packed(
+            q, k, v, nh, bias=bias[0] if bias else None, causal=causal)
+
+    def reference(q, k, v, *bias):
+        return _attention_reference(
+            q, k, v, nh, mask=_causal(t, t) if causal else None,
+            bias=bias[0] if bias else None)
+
+    return KernelCase(name, make_args, _with_grads(kernel, 3),
+                      _with_grads(reference, 3), 2e-2)
+
+
+def _sparse_case(name, b, tq, tk, nh, causal):
+    import jax.numpy as jnp
+    h = nh * 64
+
+    def make_args(rng):
+        k_seg = _segments(rng, b, tk, 48)
+        if tq == tk:
+            q_seg = k_seg
+        else:
+            # cross attention: every query segment id exists on the key side
+            q_seg = np.minimum(_segments(rng, b, tq, 48),
+                               k_seg.max(axis=1, keepdims=True))
+        return _qkv(rng, b, tq, tk, h) + (jnp.asarray(q_seg),
+                                          jnp.asarray(k_seg))
+
+    def kernel(q, k, v, q_seg, k_seg):
+        return _fa().flash_attention_packed_sparse(
+            q, k, v, nh, q_seg, k_seg, causal=causal)
+
+    def reference(q, k, v, q_seg, k_seg):
+        mask = ((q_seg[:, :, None] == k_seg[:, None, :])
+                & (q_seg[:, :, None] > 0))[:, None]
+        if causal:
+            mask = mask & _causal(tq, tk)
+        return _attention_reference(q, k, v, nh, mask=mask)
+
+    return KernelCase(name, make_args, _with_grads(kernel, 3),
+                      _with_grads(reference, 3), 2e-2)
+
+
+def _bn_args(rng, n, c, hw, co=None, residual=False):
+    import jax.numpy as jnp
+    cc = co or c
+    args = [jnp.asarray(rng.standard_normal((n, c, hw, hw)), jnp.bfloat16)]
+    if co is not None:
+        args.append(jnp.asarray(
+            rng.standard_normal((co, c, 1, 1)) / np.sqrt(c), jnp.bfloat16))
+    args += [jnp.asarray(rng.rand(cc) + 0.5, jnp.float32),
+             jnp.asarray(rng.standard_normal(cc) * 0.1, jnp.float32)]
+    if residual:
+        args.append(jnp.asarray(rng.standard_normal((n, cc, hw, hw)),
+                                jnp.bfloat16))
+    return tuple(args)
+
+
+def _bn_case(name, n, c, hw):
+    """fused_bn_act (+relu) against the XLA batch-norm math."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import fused_bn
+    _require(fused_bn.supports((n, c, hw, hw), jnp.bfloat16),
+             f"fused_bn.supports rejects {(n, c, hw, hw)}")
+
+    def kernel(x, scale, bias):
+        return fused_bn.fused_bn_act(x, scale, bias, 1e-5, "relu", False)[0]
+
+    def reference(x, scale, bias):
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=(0, 2, 3), keepdims=True)
+        var = jnp.mean(xf * xf, axis=(0, 2, 3), keepdims=True) - mean * mean
+        y = ((xf - mean) * jax.lax.rsqrt(jnp.maximum(var, 0.0) + 1e-5)
+             * scale.reshape(1, -1, 1, 1) + bias.reshape(1, -1, 1, 1))
+        return jax.nn.relu(y).astype(x.dtype)
+
+    return KernelCase(name, lambda rng: _bn_args(rng, n, c, hw),
+                      _with_grads(kernel, 3), _with_grads(reference, 3), 2e-2)
+
+
+def _conv_bn_case(name, n, ci, hw, co):
+    """fused_conv_bn_act, the bottleneck tail (1x1 conv + BN + residual +
+    relu), against the repo's own XLA composition `conv_bn_xla`."""
+    from paddle_tpu.ops.pallas_kernels import fused_bn
+    _require(fused_bn.conv_bn_supports((n, ci, hw, hw), (co, ci, 1, 1), 1),
+             f"conv_bn_supports rejects {(n, ci, hw, hw)} -> {co}")
+
+    def kernel(x, w, scale, bias, res):
+        return fused_bn.fused_conv_bn_act(x, w, scale, bias, 1e-5, "relu", 1,
+                                          True, res)[0]
+
+    def reference(x, w, scale, bias, res):
+        return fused_bn.conv_bn_xla(x, w, scale, bias, 1e-5, "relu", 1,
+                                    res)[0]
+
+    # looser than the rest: the two sides round the conv output to bf16
+    # after different accumulation orders, so a fraction f of relu masks
+    # flips and the masked gradients differ by ~sqrt(f) (exact f32 parity
+    # is tests/test_fused_bn.py's job, under the interpreter)
+    return KernelCase(
+        name, lambda rng: _bn_args(rng, n, ci, hw, co=co, residual=True),
+        _with_grads(kernel, 5), _with_grads(reference, 5), 6e-2)
+
+
+def kernel_cases(batch: Optional[int] = None):
+    """The shapes the four models put through each kernel: ERNIE (b64, T=512,
+    12 heads, [B,1,T] bias), NMT-big (16 heads; causal decoder, block-sparse
+    packed self and cross attention), ring attention's causal T=4096 block,
+    ResNet-50's bottleneck tails at batch 128. `batch` overrides every batch
+    size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
+    images fused_bn's own shape gate needs (1024 rows, a multiple of 8)."""
+    b = (lambda default, least=1: max(batch, least) if batch else default)
+    return [
+        _dense_case("flash_dense_t512_bias_onepass", b(64), 512, 12,
+                    causal=False, with_bias=True),
+        _dense_case("flash_dense_t256_causal_onepass", b(16), 256, 16,
+                    causal=True, with_bias=False),
+        _dense_case("flash_dense_t1024_causal_tiled", b(4), 1024, 16,
+                    causal=True, with_bias=False),
+        _dense_case("flash_dense_t4096_causal_tiled", b(1), 4096, 16,
+                    causal=True, with_bias=False),
+        _sparse_case("flash_sparse_self_t256_causal", b(16), 256, 256, 16,
+                     causal=True),
+        _sparse_case("flash_sparse_cross_tq256_tk384", b(16), 256, 384, 16,
+                     causal=False),
+        _bn_case("fused_bn_act_128x64x56x56", b(128), 64, 56),
+        _bn_case("fused_bn_act_128x2048x7x7", b(128, 24), 2048, 7),
+        _conv_bn_case("fused_conv_bn_act_128x64x56x56_to_256", b(128), 64,
+                      56, 256),
+        _conv_bn_case("fused_conv_bn_act_128x512x7x7_to_2048", b(128, 24),
+                      512, 7, 2048),
+    ]
+
+
+def _rel_err(got, want) -> float:
+    got = np.asarray(got, np.float64)
+    want = np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
+
+
+def run_kernel_case(case: KernelCase) -> dict:
+    import jax
+
+    args = case.make_args(np.random.RandomState(0))
+    lowered = jax.jit(case.kernel).lower(*args)
+    n_mosaic = lowered.as_text().count("tpu_custom_call")
+    got = lowered.compile()(*args)
+    want = jax.jit(case.reference)(*args)
+    errs = [_rel_err(g, w) for g, w in zip(got, want)]
+    finite = all(bool(np.isfinite(np.asarray(g, np.float32)).all())
+                 for g in got)
+    ok = n_mosaic > 0 and finite and max(errs) <= case.tol
+    return {"name": case.name, "ok": ok, "mosaic_calls": n_mosaic,
+            "finite": finite, "rel_err": [round(e, 5) for e in errs],
+            "tol": case.tol}
+
+
+def dropout_check() -> dict:
+    """The in-kernel PRNG exists only on the TPU, so dropout has no XLA twin
+    to compare with. With q = k = 0 every probability is 1/T, and with
+    v = 1 each output element is (kept keys) / (T (1 - rate)): its mean
+    gives the keep fraction. With a cotangent of ones, sum(dv) over keys
+    equals sum(out) over queries only if the backward kernel regenerated the
+    forward's masks."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, nh, rate = 8, 512, 12, 0.1
+    h = nh * 64
+    q = jnp.zeros((b, t, h), jnp.float32)
+    v = jnp.ones((b, t, h), jnp.float32)
+    bias = jnp.zeros((b, 1, t), jnp.float32)
+
+    def f(v, key):
+        return _fa().flash_attention_packed(
+            q, q, v, nh, bias=bias, dropout_rate=rate, dropout_key=key)
+
+    fwd = jax.jit(f)
+    fwd_bwd = jax.jit(lambda v, key: jax.value_and_grad(
+        lambda v: jnp.sum(f(v, key)))(v))
+    n_mosaic = fwd_bwd.lower(v, jax.random.key(0)).as_text().count(
+        "tpu_custom_call")
+    out = fwd(v, jax.random.key(0))
+    total, dv = fwd_bwd(v, jax.random.key(0))
+    out2 = fwd(v, jax.random.key(1))
+    keep = float(jnp.mean(out)) * (1.0 - rate)
+    per_head = np.asarray(out[:, :, ::64]).reshape(b, t, nh)
+    fwd_bwd_gap = abs(float(jnp.sum(dv)) - float(total)) / float(total)
+    ok = (n_mosaic > 0 and abs(keep - (1.0 - rate)) < 5e-3
+          and per_head.std() > 0 and fwd_bwd_gap < 1e-4
+          and not bool(jnp.array_equal(out, out2)))
+    return {"name": "flash_dense_t512_dropout0.1", "ok": ok,
+            "mosaic_calls": n_mosaic, "keep_fraction": round(keep, 5),
+            "expected": 1.0 - rate, "mask_varies": bool(per_head.std() > 0),
+            "fwd_bwd_mask_gap": fwd_bwd_gap,
+            "key_changes_mask": not bool(jnp.array_equal(out, out2))}
+
+
+def phase_kernels(args):
+    fa = _fa()
+    from paddle_tpu.ops.pallas_kernels import fused_bn
+    _require(not fa.FORCE_PALLAS_INTERPRET
+             and not fused_bn.FORCE_PALLAS_INTERPRET,
+             "a FORCE_PALLAS_INTERPRET flag is set: kernels would not compile")
+    results = []
+    for case in kernel_cases():
+        res = run_kernel_case(case)
+        results.append(res)
+        print(f"kernel {res['name']}: {'PASS' if res['ok'] else 'FAIL'} "
+              f"compiled ({res['mosaic_calls']} Mosaic calls), worst rel err "
+              f"{max(res['rel_err']):.2e} (tol {res['tol']:.0e})",
+              flush=True)
+        gc.collect()
+    res = dropout_check()
+    results.append(res)
+    print(f"kernel {res['name']}: {'PASS' if res['ok'] else 'FAIL'} compiled "
+          f"({res['mosaic_calls']} Mosaic calls), keep fraction "
+          f"{res['keep_fraction']} (want {res['expected']}), fwd/bwd mask gap "
+          f"{res['fwd_bwd_mask_gap']:.1e}", flush=True)
+    bad = [r["name"] for r in results if not r["ok"]]
+    _require(not bad, f"kernels failed: {', '.join(bad)}")
+    return {"kernels": results}
+
+
+# ---------------------------------------------------------------------------
+# deepfm: the second model, on its default (XLA gather/scatter) path
+# ---------------------------------------------------------------------------
+
+def phase_deepfm(args):
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import deepfm
+
+    vocab, batch = DEEPFM_VOCAB, DEEPFM_BATCH
+    main, startup, _, loss, _ = deepfm.build_train_program(
+        vocab_size=vocab, is_sparse=True, fused_table=True,
+        embedding_optimizer="adagrad",
+        packed_rows={"rows_per_step": batch * 26})
+    rng = np.random.RandomState(0)
+    feed = {
+        "sparse_ids": jnp.asarray(
+            rng.randint(0, vocab, (batch, 26)).astype("int32")),
+        "dense": jnp.asarray(rng.rand(batch, 13).astype("float32")),
+        "label": jnp.asarray(rng.randint(0, 2, (batch, 1)).astype("float32")),
+    }
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        losses = [exe.run(main, feed=feed, fetch_list=[loss],
+                          return_numpy=False)[0]
+                  for _ in range(DEEPFM_STEPS)]
+        vals = [float(np.asarray(v)) for v in losses]
+        wall_s = time.perf_counter() - t0
+        _require(all(np.isfinite(vals)), f"non-finite DeepFM loss in {vals}")
+        _require(vals[-1] < vals[0],
+                 f"DeepFM loss did not fall on a fixed batch: {vals}")
+        _require(_platforms(losses[-1]) == {"tpu"},
+                 f"DeepFM loss lives on {_platforms(losses[-1])}, not tpu")
+        tables = [(n, v) for n, v in
+                  ((n, scope.find_var(n)) for n in scope.var_names())
+                  if getattr(v, "shape", ())[:1] == (vocab,)]
+        _require(tables and all(_platforms(v) == {"tpu"} for _, v in tables),
+                 "no [vocab, ...] table resident on the tpu")
+        peak = _mem_stat(jax.devices()[0], "peak_bytes_in_use")
+    out = {"config": {"vocab": vocab, "batch": batch,
+                      "optimizer": "adagrad, packed rows"},
+           "losses": [round(v, 5) for v in vals],
+           "tables": {n: [list(v.shape), str(v.dtype)] for n, v in tables},
+           "peak_bytes_in_use_process": peak,
+           "setup": {"startup_compile_and_steps_s": round(wall_s, 2)}}
+    print(f"deepfm: {vocab} rows b{batch} packed Adagrad, loss "
+          f"{vals[0]:.5f} -> {vals[-1]:.5f} over {DEEPFM_STEPS} steps "
+          f"(startup+compile+steps {wall_s:.1f} s, set-up fact)")
+    del losses, tables, scope, exe
+    gc.collect()
+    return out
+
+
+# ---------------------------------------------------------------------------
+# dp4: the same ERNIE program, data-parallel over four chips
+# ---------------------------------------------------------------------------
+
+def _run_steps(exe, program, feed, loss, steps):
+    vals = []
+    for _ in range(steps):
+        (lv,) = exe.run(program, feed=feed, fetch_list=[loss])
+        vals.append(float(lv))
+    return vals
+
+
+def phase_dp4(args):
+    import jax
+
+    import paddle_tpu as fluid
+
+    devs = jax.devices()[:4]
+    n = len(devs)
+    exe = fluid.Executor(fluid.TPUPlace())
+
+    # (a) 64 per chip, dropout on: split, shardings, memory spread
+    batch = DP4_PER_CHIP * n
+    cfg, main, startup, loss = ernie_program(batch, ERNIE_SEQ, 0.1)
+    feed = ernie_feed(cfg, batch, ERNIE_SEQ)
+    cp = fluid.CompiledProgram(main).with_data_parallel(loss_name=loss.name)
+    _require(list(cp._mesh.devices.flat) == devs,
+             f"mesh devices {list(cp._mesh.devices.flat)} != {devs}")
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        t0 = time.perf_counter()
+        vals = _run_steps(exe, cp, feed, loss, 3)
+        wall_s = time.perf_counter() - t0
+        _require(all(np.isfinite(vals)), f"non-finite dp4 loss in {vals}")
+
+        unsharded = [
+            name for name in scope.var_names()
+            if isinstance(scope.find_var(name), jax.Array)
+            and len(scope.find_var(name).sharding.device_set) != n]
+        _require(not unsharded,
+                 f"state not placed on all {n} devices: {unsharded[:8]}")
+        n_state = len(scope.var_names())
+
+        # the compiled executable says how the feed is split: re-lower the
+        # cached jit on the live state (the compile itself is a cache hit)
+        (step_fn,) = cp._cache.values()
+        state = {v.name: scope.find_var(v.name)
+                 for v in main.list_vars()
+                 if v.persistable and scope.has_var(v.name)}
+        feed_arrays = {k: np.asarray(v) for k, v in feed.items()}
+        compiled = step_fn.lower(
+            state, feed_arrays, scope.find_var("@RNG_STATE@")).compile()
+        feed_sh = compiled.input_shardings[0][1]
+        split = {k: list(feed_sh[k].shard_shape(np.shape(v)))
+                 for k, v in feed_arrays.items()}
+        bad = {k: s for k, s in split.items()
+               if s[0] * n != np.shape(feed_arrays[k])[0]
+               or len(feed_sh[k].device_set) != n}
+        _require(not bad, f"feeds not split {n} ways on the batch dim: {bad}")
+        n_mosaic = compiled.as_text().count("tpu_custom_call")
+        _require(n_mosaic > 0, "no tpu_custom_call in the compiled dp4 step")
+        hbm = _hbm_by_xla(compiled)
+
+        in_use = [_mem_stat(d, "bytes_in_use") for d in devs]
+        _require(min(in_use) > 0 and max(in_use) <= 1.5 * min(in_use),
+                 f"bytes_in_use not spread evenly over the chips: {in_use}")
+        peaks = [_mem_stat(d, "peak_bytes_in_use") for d in devs]
+    print(f"dp4: ERNIE-base b{batch} ({DP4_PER_CHIP}/chip) loss "
+          f"{vals[0]:.4f} -> {vals[-1]:.4f}; feed shards {split['src_ids']} "
+          f"of {list(np.shape(feed['src_ids']))}; {n_state} state arrays on "
+          f"all {n} devices; {n_mosaic} Mosaic calls")
+    print("dp4: bytes_in_use per chip "
+          + ", ".join(f"{b / 2**30:.2f}" for b in in_use) + " GiB (max/min "
+          f"{max(in_use) / min(in_use):.2f}); peak "
+          + ", ".join(f"{b / 2**30:.2f}" for b in peaks) + " GiB; "
+          f"compile + 3 steps {wall_s:.1f} s (set-up fact); XLA's memory "
+          f"analysis: {hbm['live_bytes'] / 2**30:.2f} GiB per chip")
+    out = {"per_chip_batch": DP4_PER_CHIP, "losses": vals,
+           "feed_shard_shapes": split, "state_arrays": n_state,
+           "mosaic_calls_in_step": n_mosaic, "bytes_in_use": in_use,
+           "peak_bytes_in_use": peaks, "hbm_by_xla_memory_analysis": hbm}
+    del compiled, state, step_fn, scope, cp
+    gc.collect()
+
+    # (b) dropout off, global batch 64: dp4 against one chip. The startup
+    # program draws from its own seed, so two scopes start identical.
+    cfg, main, startup, loss = ernie_program(DP4_EQ_BATCH, ERNIE_SEQ, 0.0)
+    feed = ernie_feed(cfg, DP4_EQ_BATCH, ERNIE_SEQ)
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        one = _run_steps(exe, main, feed, loss, DP4_EQ_STEPS)
+    gc.collect()
+    cp = fluid.CompiledProgram(main).with_data_parallel(loss_name=loss.name)
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        dp = _run_steps(exe, cp, feed, loss, DP4_EQ_STEPS)
+    rel = [abs(a - b) / abs(b) for a, b in zip(dp, one)]
+    print(f"dp4: dropout off, global b{DP4_EQ_BATCH}: dp4 losses "
+          f"{[round(v, 4) for v in dp]} vs one chip "
+          f"{[round(v, 4) for v in one]} (max rel diff {max(rel):.1e}, "
+          f"tol {DP4_EQ_RTOL:.0e})")
+    _require(all(np.isfinite(dp)) and max(rel) <= DP4_EQ_RTOL,
+             f"dp4 losses {dp} differ from one-chip losses {one}")
+    out["equality"] = {"dp4": dp, "one_chip": one, "max_rel_diff": max(rel)}
+    return out
+
+
+# ---------------------------------------------------------------------------
+
+PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
+                   ("kernels", phase_kernels), ("deepfm", phase_deepfm)]
+PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
+
+
+def main(argv: Optional[list] = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+    phases = PHASES_FOUR_CHIPS if args.chips == 4 else PHASES_ONE_CHIP
+    facts = {}
+    t_start = time.perf_counter()
+    for name, fn in phases:
+        print(f"== phase {name}", flush=True)
+        t0 = time.perf_counter()
+        try:
+            facts[name] = fn(args)
+        except Exception as e:
+            # the run ends here: nothing carries on past a failed phase
+            traceback.print_exc()
+            sys.stderr.flush()
+            print(f"chip_smoke: FAILED in phase {name}: "
+                  f"{type(e).__name__}: {str(e)[:400]}", flush=True)
+            return 1
+        facts[name]["phase_seconds"] = round(time.perf_counter() - t0, 1)
+    facts["total_seconds"] = round(time.perf_counter() - t_start, 1)
+    os.makedirs(OUT_DIR, exist_ok=True)
+    path = os.path.join(OUT_DIR, f"chip_smoke_chips{args.chips}.json")
+    with open(path, "w") as f:
+        json.dump(facts, f, indent=1)
+    print(f"chip_smoke: all phases passed in {facts['total_seconds']} s; "
+          f"details in {path}")
+    dev = facts["device"]
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev["platform"], "kind": dev["kind"],
+        "count": dev["count"]}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

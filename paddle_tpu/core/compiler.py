@@ -451,6 +451,7 @@ class CompiledProgram:
         shared by _build and Executor.run_batched's scan carry."""
         block = self._program.global_block()
         mesh = self._mesh
+        data_axis = self._data_axis
         amp = getattr(self._program, "_amp", None)
         remat_spec = self._remat_spec()
         shard_grad = self._grad_shard_fn()
@@ -483,7 +484,8 @@ class CompiledProgram:
             ctx = ExecContext(key, mesh=mesh, amp=amp,
                               remat=remat_spec.op_set,
                               remat_units=remat_spec,
-                              shard_grad=shard_grad)
+                              shard_grad=shard_grad,
+                              data_axis=data_axis)
             _run_block(block, env, ctx)
             fetches = [env[n] for n in fetch_names]
             new_state = {}

@@ -85,40 +85,39 @@ register_dump_section("compiled_signatures", _compiled_signatures_section)
 
 
 # -- persistent compilation cache -------------------------------------------
-_COMPILE_CACHE_ENABLED = [False]
-
-
-def _maybe_enable_compile_cache(cache_dir: Optional[str] = None) -> bool:
-    """Enable jax's on-disk compilation cache once per process when
-    ``compile_cache_dir`` (env: PDTPU_COMPILE_CACHE_DIR) is set — warm
-    process restarts then deserialize XLA executables instead of
-    recompiling. The entry count at enable time lands in the registry so
-    exports distinguish cold (0 entries) from warm starts."""
-    if _COMPILE_CACHE_ENABLED[0]:
-        return True
-    from ..flags import flag
-    d = cache_dir or flag("compile_cache_dir")
-    if not d:
-        return False
+def _default_compile_cache_dir() -> str:
+    """``<checkout>/.jax_cache``, derived from this package's own path: the
+    directory is part of jax's cache key, so it must not move between runs
+    (no tempfile, pid or clock)."""
     import os
-    os.makedirs(d, exist_ok=True)
-    entries = sum(1 for f in os.listdir(d) if not f.startswith("."))
-    try:
+    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def _enable_compile_cache() -> str:
+    """Turn on jax's on-disk compilation cache for this process; runs once,
+    when the package is imported, so static-graph, dygraph and bare
+    ``jax.jit`` users all compile under it. Where JAX_COMPILATION_CACHE_DIR
+    is set jax has already read it and no directory is set here; otherwise
+    the cache lives in `_default_compile_cache_dir`. The entry count at
+    start lands in the registry so exports tell a cold start (0) from a
+    warm one. Returns the directory in effect."""
+    import os
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = _default_compile_cache_dir()
         jax.config.update("jax_compilation_cache_dir", d)
-    except Exception:  # jaxlib without persistent-cache support
-        return False
     # default thresholds skip small/fast compiles — exactly the programs
     # a restarted trainer recompiles most often; cache everything
-    for k, v in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                 ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(k, v)
-        except Exception:
-            pass
-    _COMPILE_CACHE_ENABLED[0] = True
-    _OBS.gauge("executor/compile_cache_enabled").set(1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    entries = (sum(1 for f in os.listdir(d) if f.endswith("-cache"))
+               if os.path.isdir(d) else 0)
     _OBS.gauge("executor/compile_cache_entries_at_start").set(entries)
-    return True
+    return d
+
+
+_enable_compile_cache()
 
 
 # -- FLAGS_check_nan_inf device-side probe ----------------------------------
@@ -230,11 +229,8 @@ def _make_key(seed: int):
     recipe); XLA's hardware RngBitGenerator ("rbg") is an order of magnitude
     cheaper and statistically fine for dropout."""
     if jax.default_backend() == "tpu":
-        try:
-            # typed key so split()/bernoulli() dispatch on the rbg impl
-            return jax.random.key(seed, impl="rbg")
-        except TypeError:  # older jax without impl kwarg
-            pass
+        # typed key so split()/bernoulli() dispatch on the rbg impl
+        return jax.random.key(seed, impl="rbg")
     return jax.random.PRNGKey(seed)
 
 
@@ -242,10 +238,15 @@ class ExecContext:
     """Per-trace context handed to op implementations."""
 
     def __init__(self, key, is_test: bool = False, mesh=None, amp=None,
-                 remat: bool = False, shard_grad=None, remat_units=None):
+                 remat: bool = False, shard_grad=None, remat_units=None,
+                 data_axis=None):
         self._key = key
         self.is_test = is_test
         self.mesh = mesh
+        # the mesh axis the batch dim of the feeds is sharded over (None
+        # without a mesh): ops GSPMD cannot partition (Mosaic kernels) run
+        # per shard of it
+        self.data_axis = data_axis
         self.amp = amp  # {'dtype', 'white_list', 'black_list'} or None
         # ShardingStrategy.stage2 hook (CompiledProgram._grad_shard_fn):
         # (target_name, grad) -> grad with a dp sharding constraint, making
@@ -797,7 +798,8 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
     def fwd(*vals):
         sub = ExecContext(gkey, is_test=ctx.is_test, mesh=ctx.mesh,
                           amp=ctx.amp, remat=False,
-                          shard_grad=ctx.shard_grad)
+                          shard_grad=ctx.shard_grad,
+                          data_axis=ctx.data_axis)
         sub.group_forward = True
         local = dict(zip(in_names, vals))
         for op in ops:
@@ -1096,7 +1098,6 @@ class Executor:
         # a finished loop's loader can die without waiting for close()
         self._loaders: "weakref.WeakSet" = weakref.WeakSet()
         _LIVE_EXECUTORS.add(self)
-        _maybe_enable_compile_cache()
         # live introspection plane: PDTPU_INTROSPECT_PORT alone makes
         # any training process scrapeable (/metrics, /healthz, /debug)
         maybe_serve_from_env()
@@ -1332,8 +1333,7 @@ class Executor:
         The TPU analog of the reference's in-C++ trainer hot loop
         (hogwild_worker.cc:163 via Executor::RunFromDataset): there the
         per-step loop never re-enters Python; here the per-dispatch
-        runtime cost (host Python + transport, ~ms-scale on tunneled
-        runtimes) is paid once per N steps instead of per step. Feeds
+        host cost is paid once per N steps instead of per step. Feeds
         must share shapes/dtypes across the N steps (one compiled scan).
 
         Requires every persistable the program writes to already exist in

@@ -19,10 +19,6 @@ _FLAGS: Dict[str, Any] = {
     # train_from_dataset keeps in flight (1 = sync every step; 2 = classic
     # double buffering — host prepares N+1 while the device runs N)
     "max_inflight_steps": 2,
-    # non-empty: enable jax's persistent on-disk compilation cache at
-    # first Executor construction (warm process restarts skip XLA
-    # compiles; see executor._maybe_enable_compile_cache)
-    "compile_cache_dir": "",
     # inert reference-compat knobs
     "fraction_of_gpu_memory_to_use": 0.92,
     "allocator_strategy": "auto_growth",
@@ -65,7 +61,6 @@ def flag(key: str):
 # documented alongside PDTPU_FUSE_UPDATES / PDTPU_REMAT_OPS)
 _ENV_ALIASES = {
     "PDTPU_MAX_INFLIGHT_STEPS": "max_inflight_steps",
-    "PDTPU_COMPILE_CACHE_DIR": "compile_cache_dir",
 }
 
 

@@ -1195,9 +1195,8 @@ def py_func(func, x, out, backward_func=None, skip_vars_in_backward_input=None):
     (output grads can never be skipped).
 
     Runtime support: host callbacks need a PJRT runtime with host
-    send/recv (CPU and standard TPU runtimes have it; tunneled/proxied
-    runtimes may raise UNIMPLEMENTED at execution — the reference's
-    py_func was CPU-kernel-only too, py_func_op.cc)."""
+    send/recv (the CPU and TPU runtimes have it; the reference's py_func
+    was CPU-kernel-only too, py_func_op.cc)."""
     xs = x if isinstance(x, (list, tuple)) else [x]
     outs = out if isinstance(out, (list, tuple)) else [out]
     from ..core.dtypes import dtype_str

@@ -100,17 +100,14 @@ class ProgramCost:
 
 def cost_from_executable(executable) -> Optional[dict]:
     """flops / bytes_accessed / transcendentals from an XLA ``Compiled``
-    or ``Lowered`` object, or None when the backend returns nothing
-    (TPU PJRT raises Unimplemented on some versions; older jax returns a
-    list of per-partition dicts)."""
+    or ``Lowered`` object, or None when the backend returns nothing (a
+    runtime may raise Unimplemented)."""
     if executable is None:
         return None
     try:
         ca = executable.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out = {"flops": float(ca.get("flops", 0.0) or 0.0),

@@ -27,7 +27,8 @@ def _lower_subblock(ctx, block, env_names: List[str]):
 
     def fn(vals):
         env = dict(zip(env_names, vals))
-        sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh)
+        sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh,
+                          data_axis=ctx.data_axis)
         _run_block(block, env, sub)
         return tuple(env[n] for n in env_names)
 
@@ -112,7 +113,8 @@ def _static_rnn(ctx, inputs, attrs):
         env = dict(zip(state_names, carry))
         env.update(zip(seq_in_names, xt))
         env.update(zip(param_names, params))
-        sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh)
+        sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh,
+                          data_axis=ctx.data_axis)
         _run_block(block, env, sub)
         new_carry = tuple(env[n] for n in state_out_names)
         ys = tuple(env[n] for n in out_names)
@@ -142,7 +144,8 @@ def _cond(ctx, inputs, attrs):
     def mk(block, env_names, out_names, vals):
         def fn(_):
             env = dict(zip(env_names, vals))
-            sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh)
+            sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh,
+                              data_axis=ctx.data_axis)
             _run_block(block, env, sub)
             return tuple(env[n] for n in out_names)
         return fn
@@ -172,7 +175,8 @@ def _switch(ctx, inputs, attrs):
             if block is None:
                 return tuple(vals)
             env = dict(zip(var_names, vals))
-            sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh)
+            sub = ExecContext(None, is_test=ctx.is_test, mesh=ctx.mesh,
+                              data_axis=ctx.data_axis)
             _run_block(block, env, sub)
             return tuple(env[n] for n in var_names)
         return fn

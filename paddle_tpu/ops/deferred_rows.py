@@ -48,19 +48,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..core.registry import register_op
 from ..core.selected_rows import SelectedRows
-from ..observability.registry import get_registry
-from .pallas_kernels import sparse_adagrad as _fused_adagrad
 
 SENTINEL = 2**31 - 1
-
-# Trace-time counter (one inc per compile of a program that took the fused
-# branch): lets tests and production assert the Pallas path did not silently
-# deactivate — an env flip or a shape outside `supports()` would otherwise
-# degrade deepfm back to the scatter path with no signal.
-_FUSED_SPARSE = get_registry().counter("optimizer/fused_sparse_updates")
 
 
 # ---------------------------------------------------------------------------
@@ -371,6 +364,21 @@ def unpack_rows(u, d):
         u[:, :2 * d].reshape(n, d, 2), jnp.float32)
 
 
+def _row_major(chunk):
+    """Pin a [rows, lanes] chunk cut from (or bound for) a packed table to
+    the table's row-major layout, so that only the chunk is relaid out. The
+    u16<->f32 bitcast prefers the transposed layout, and through
+    dynamic_slice / dynamic_update_slice XLA gives that layout to the whole
+    loop-carried table. On the v5e without this pin (PR 21 chip run) the
+    AUTO-layout startup step handed the 33.5M-row table out transposed
+    (major_to_minor=(1, 0)), its rows read back NaN and every DeepFM loss
+    was NaN, with no error anywhere. Compiled ahead of time the layout
+    cannot be afforded either way: a default-layout startup ends in an
+    8 GiB transposing copy, and a train step handed the transposed table
+    begins with one (RESOURCE_EXHAUSTED, 16.01G of 15.75G hbm)."""
+    return with_layout_constraint(chunk, Layout(major_to_minor=(0, 1)))
+
+
 @register_op("rowpack_init", differentiable=False)
 def _rowpack_init(ctx, inputs, attrs):
     """Initialize a packed table: visible columns ~ U(low, high), state
@@ -402,7 +410,7 @@ def _rowpack_init(ctx, inputs, attrs):
     def body(i, acc):
         start = jnp.minimum(i * cs, v - cs).astype(jnp.int32)
         return lax.dynamic_update_slice(
-            acc, chunk(i), (start, jnp.zeros((), jnp.int32)))
+            acc, _row_major(chunk(i)), (start, jnp.zeros((), jnp.int32)))
 
     return {"Out": [lax.fori_loop(0, n_chunks, body, out)]}
 
@@ -426,12 +434,14 @@ def _rowpack_init_state_cols(ctx, inputs, attrs):
     def body(i, acc):
         start = jnp.minimum(i * cs, v - cs).astype(jnp.int32)
         z = jnp.zeros((), jnp.int32)
-        chunk = lax.dynamic_slice(acc, (start, z), (cs, acc.shape[1]))
+        chunk = _row_major(
+            lax.dynamic_slice(acc, (start, z), (cs, acc.shape[1])))
         rows = unpack_rows(chunk, dt)
         rows = jnp.concatenate(
             [rows[:, :vis], jnp.full((cs, dt - vis), val, jnp.float32)],
             axis=-1)
-        return lax.dynamic_update_slice(acc, pack_rows(rows), (start, z))
+        return lax.dynamic_update_slice(
+            acc, _row_major(pack_rows(rows)), (start, z))
 
     return {"ParamOut": [lax.fori_loop(0, n_chunks, body, p)]}
 
@@ -471,29 +481,9 @@ def _adagrad_row_packed(ctx, inputs, attrs):
     """adagrad_op.cc SparseAdagradFunctor on a packed table: G rides in
     the state columns; touched rows advance G += g^2,
     p -= lr*g/(sqrt(G)+eps); one gather (forward, reused) + one
-    scatter-set per step.
-
-    When the fused Pallas kernel is available (TPU, or the interpreter
-    under test) and the op was not built with ``fused=False``, the whole
-    gather→update→scatter round trip collapses into one
-    `sparse_adagrad.fused_adagrad_update` pass: the kernel reads each
-    touched packed row straight from the table (same bytes FwdRows was
-    gathered from — the table is unmodified between forward and
-    optimizer within a step), applies the identical Adagrad math, and
-    writes it back through an input/output alias instead of an XLA
-    scatter. Bitwise-identical to the branch below."""
+    scatter-set per step."""
     (p,) = inputs["Param"]
     eps = attrs.get("epsilon", 1e-6)
-    vis = int(attrs["vis"])
-    if attrs.get("fused", True) and _fused_adagrad.enabled(vis, p.shape[-1]):
-        (g,) = inputs["Grad"]
-        r = int(attrs["rows_per_step"])
-        ids, grows = _grad_rows(g)
-        uids, utot, _rep = uniq_merge(
-            ids, grows[:, :vis].astype(jnp.float32), r)
-        _FUSED_SPARSE.inc()
-        return {"ParamOut": [_fused_adagrad.fused_adagrad_update(
-            p, uids, utot, _lr(inputs), vis=vis, eps=eps)]}
     uids, utot, cur_u, valid, vis, dt = _packed_common(inputs, attrs)
     g_new = cur_u[:, vis:2 * vis] + utot * utot
     p_new = cur_u[:, :vis] - _lr(inputs) * utot / (jnp.sqrt(g_new) + eps)

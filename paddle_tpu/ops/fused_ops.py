@@ -105,6 +105,36 @@ def _fused_conv_bn(ctx, inputs, attrs):
     }
 
 
+def _per_data_shard(ctx, fn, arrays, key):
+    """``fn(*arrays, key)`` for a Mosaic kernel under a mesh. GSPMD cannot
+    partition a Mosaic call (the lowering refuses it outright), so the call
+    runs inside a shard_map over the whole mesh: dim 0 of every array (the
+    batch) is split over the data axis when it divides, everything else is
+    replicated, and each data shard folds its index into the dropout key so
+    shards do not repeat each other's masks."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.collective import shard_map
+
+    mesh, axis = ctx.mesh, ctx.data_axis
+    if axis is not None and any(
+            a.shape[0] % mesh.shape[axis] for a in arrays):
+        axis = None
+    spec = P(axis) if axis is not None else P()
+
+    def body(key, *arrays):
+        if key is not None and axis is not None:
+            key = jax.random.fold_in(key, jax.lax.axis_index(axis))
+        return fn(*arrays, key)
+
+    return shard_map(body, mesh, in_specs=(P(),) + (spec,) * len(arrays),
+                     out_specs=spec)(key, *arrays)
+
+
+def _under_mesh(ctx) -> bool:
+    return ctx.mesh is not None and ctx.mesh.size > 1
+
+
 @register_op("flash_attention", nondiff_inputs=["BiasQK"])
 def _flash_attention(ctx, inputs, attrs):
     """Memory-efficient fused attention (Pallas on TPU, blockwise JAX
@@ -125,6 +155,8 @@ def _flash_attention(ctx, inputs, attrs):
     key = None
     if rate > 0.0 and not is_test:
         key = ctx.rng()
+    causal = attrs.get("causal", False)
+    rate = 0.0 if is_test else rate
     if q.ndim == 3:
         # packed [B, T, H] layout — adapted to the folded kernel layout
         # (see the layout note in pallas_kernels/flash_attention.py)
@@ -132,13 +164,26 @@ def _flash_attention(ctx, inputs, attrs):
             raise ValueError(
                 "flash_attention: 3D (packed [B,T,H]) q/k/v requires the "
                 "num_heads attr — pass num_heads= to layers.flash_attention")
-        return one(_fa.flash_attention_packed(
-            q, k, v, attrs["num_heads"], bias=bias,
-            causal=attrs.get("causal", False),
-            dropout_rate=0.0 if is_test else rate, dropout_key=key))
-    return one(_fa.flash_attention(
-        q, k, v, bias=bias, causal=attrs.get("causal", False),
-        dropout_rate=0.0 if is_test else rate, dropout_key=key))
+        nh = attrs["num_heads"]
+        t, d = q.shape[1], q.shape[2] // nh
+
+        def attend(q, k, v, *rest):
+            *bias, key = rest
+            return _fa.flash_attention_packed(
+                q, k, v, nh, bias=bias[0] if bias else None, causal=causal,
+                dropout_rate=rate, dropout_key=key)
+    else:
+        t, d = q.shape[2], q.shape[3]
+
+        def attend(q, k, v, *rest):
+            *bias, key = rest
+            return _fa.flash_attention(
+                q, k, v, bias=bias[0] if bias else None, causal=causal,
+                dropout_rate=rate, dropout_key=key)
+    arrays = (q, k, v) if bias is None else (q, k, v, bias)
+    if _under_mesh(ctx) and _fa._pallas_ok(t, d):
+        return one(_per_data_shard(ctx, attend, arrays, key))
+    return one(attend(*arrays, key))
 
 
 @register_op("flash_attention_sparse", nondiff_inputs=["QSeg", "KSeg"])
@@ -161,7 +206,15 @@ def _flash_attention_sparse(ctx, inputs, attrs):
     key = None
     if rate > 0.0 and not is_test:
         key = ctx.rng()
-    return one(_fa.flash_attention_packed_sparse(
-        q, k, v, attrs["num_heads"], q_seg, k_seg,
-        causal=attrs.get("causal", False),
-        dropout_rate=0.0 if is_test else rate, dropout_key=key))
+    nh = attrs["num_heads"]
+
+    def attend(q, k, v, q_seg, k_seg, key):
+        return _fa.flash_attention_packed_sparse(
+            q, k, v, nh, q_seg, k_seg, causal=attrs.get("causal", False),
+            dropout_rate=0.0 if is_test else rate, dropout_key=key)
+
+    if _under_mesh(ctx) and _fa._sparse_pallas_ok(
+            q.shape[1], k.shape[1], q.shape[2] // nh):
+        return one(_per_data_shard(ctx, attend, (q, k, v, q_seg, k_seg),
+                                   key))
+    return one(attend(q, k, v, q_seg, k_seg, key))

@@ -9,4 +9,3 @@ as: pallas kernel on TPU when its constraints hold, blockwise-JAX fallback
 everywhere else.
 """
 from .flash_attention import flash_attention  # noqa: F401
-from .sparse_adagrad import fused_adagrad_update  # noqa: F401

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # 512² blocks keep the whole [T,T] score tile in VMEM for BERT-scale
 # sequence lengths: measured on v5e, bq=bk=512 runs the forward ~2.5× faster
@@ -45,31 +47,15 @@ DEFAULT_BLOCK_K = 512
 _LANES = 128  # TPU lane width: scratch stats are kept lane-replicated
 _NEG_INF = -1e30
 
-# Tests may set this to run the Pallas kernels on CPU through the
-# interpreter (dropout kernels need pltpu.InterpretParams; the interpreter's
-# PRNG returns zeros, so dropout-path numerics are TPU-only).
+# Tests set this to run the Pallas kernels on CPU through the interpreter
+# (dropout kernels need pltpu.InterpretParams; the interpreter's PRNG
+# returns zeros, so dropout-path numerics are TPU-only). Nothing else turns
+# the interpreter on.
 FORCE_PALLAS_INTERPRET = False
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-try:  # pallas import is deferred-safe: CPU-only envs still import this module
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = pltpu = None
-    _HAVE_PALLAS = False
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or getattr(pltpu, "TPUCompilerParams", None)
-                   if _HAVE_PALLAS else None)
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +221,7 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, *args)
@@ -340,7 +326,7 @@ def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(seed, *args)
@@ -469,7 +455,7 @@ def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
             out_specs=out_specs,
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(seed, *args)
@@ -725,7 +711,7 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, *args)
@@ -801,7 +787,7 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             scratch_shapes=scratch2,
         ),
         out_shape=out_shape2,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, *args2)
@@ -1007,7 +993,7 @@ def _pallas_ok(t: int, d: int) -> bool:
     """Static dispatch decision — must be identical in fwd and bwd so the
     in-kernel dropout masks regenerate consistently."""
     bq, _ = _pick_blocks(t)
-    return (_HAVE_PALLAS and (_on_tpu() or FORCE_PALLAS_INTERPRET)
+    return ((_on_tpu() or FORCE_PALLAS_INTERPRET)
             and bq is not None and bq >= 64 and d % 64 == 0)
 
 
@@ -1016,10 +1002,7 @@ def _interpret_arg(dropout_rate: float):
         return False
     # dropout kernels call pltpu.prng_*, which only the TPU-semantics
     # interpreter accepts (it returns zero bits — numerics are TPU-only)
-    if dropout_rate > 0.0:
-        ip = getattr(pltpu, "InterpretParams", None)
-        return ip() if ip is not None else True
-    return True
+    return pltpu.InterpretParams() if dropout_rate > 0.0 else True
 
 
 def _flash_bwd_block_dispatch(q, k, v, g, lse, out, sm_scale, causal):
@@ -1289,7 +1272,7 @@ def _flash_fwd_pallas_sparse(q, k, v, se_rep, vis, nh, sm_scale, causal,
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, vis, q, k, v, se_rep)
@@ -1435,7 +1418,7 @@ def _flash_bwd_pallas_sparse(q, k, v, se_rep, vis, nh, g, lse, out, sm_scale,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, vis, q, k, v, se_rep, gf, lse_r, delta_r)
@@ -1471,7 +1454,7 @@ def _flash_bwd_pallas_sparse(q, k, v, se_rep, vis, nh, g, lse, out, sm_scale,
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(seed, vis, q, k, v, se_rep, gf, lse_r, delta_r)
@@ -1580,17 +1563,10 @@ def _flash_bwd_jax_sparse(res, g, *, sm_scale, causal, block_k,
 
 # ---- dispatch + custom_vjp ------------------------------------------------
 
-def _sparse_pallas_ok(tq: int, tk: int, d: int,
-                      dropout_rate: float = 0.0) -> bool:
+def _sparse_pallas_ok(tq: int, tk: int, d: int) -> bool:
     bq, _ = _pick_blocks(tq)
     bk, _ = _pick_blocks(tk)
-    if dropout_rate > 0.0 and not _on_tpu() and not hasattr(
-            pltpu, "InterpretParams"):
-        # the dropout kernels call pltpu.prng_*, which off-TPU needs the
-        # TPU-semantics interpreter; older jax doesn't expose it — use the
-        # jax fallback there (fwd and bwd agree: both see dropout_rate)
-        return False
-    return (_HAVE_PALLAS and (_on_tpu() or FORCE_PALLAS_INTERPRET)
+    return ((_on_tpu() or FORCE_PALLAS_INTERPRET)
             and bq is not None and bk is not None
             and bq >= 64 and bk >= 64 and d % 64 == 0)
 
@@ -1617,7 +1593,7 @@ def _flash_sparse_fwd_dispatch(q, k, v, se, dropout_key, nh, sm_scale,
     tk = k.shape[1]
     bq, _ = _pick_blocks(tq)
     bk, _ = _pick_blocks(tk)
-    if _sparse_pallas_ok(tq, tk, d, dropout_rate):
+    if _sparse_pallas_ok(tq, tk, d):
         vis = _compute_block_vis(se, tq, tk, bq, bk, causal).reshape(-1)
         se_rep = jnp.broadcast_to(se[:, :, None],
                                   (se.shape[0], tq, _LANES))
@@ -1650,7 +1626,7 @@ def _flash_sparse_core_bwd(nh, sm_scale, causal, dropout_rate, res, g):
     tk = k.shape[1]
     bq, _ = _pick_blocks(tq)
     bk, _ = _pick_blocks(tk)
-    if _sparse_pallas_ok(tq, tk, d, dropout_rate):
+    if _sparse_pallas_ok(tq, tk, d):
         vis = _compute_block_vis(se, tq, tk, bq, bk, causal).reshape(-1)
         se_rep = jnp.broadcast_to(se[:, :, None],
                                   (se.shape[0], tq, _LANES))

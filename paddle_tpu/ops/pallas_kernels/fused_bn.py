@@ -36,24 +36,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:  # pallas import is deferred-safe: CPU-only envs still import this module
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    pl = pltpu = None
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
-# Tests may set this to run the kernels on CPU through the interpreter.
+# Tests set this to run the kernels on CPU through the interpreter; nothing
+# else turns the interpreter on.
 FORCE_PALLAS_INTERPRET = False
 
 # Per-operand VMEM budget per grid step (bytes); the widest backward pass
@@ -65,8 +56,6 @@ _MAX_BLOCK_BYTES = 1024 * 1024
 def supports(x_shape, dtype) -> bool:
     """Static gate for the pallas path: 4-D, lane-friendly C, and enough
     rows that kernel launch overhead amortizes."""
-    if not _HAVE_PALLAS:
-        return False
     if len(x_shape) != 4:
         return False
     n, c, h, w = x_shape
@@ -378,8 +367,6 @@ _MAX_W_BYTES = 8 * 1024 * 1024
 def conv_bn_supports(x_shape, w_shape, stride) -> bool:
     """Static gate for the fused conv+BN pallas path: 1×1 kernel, stride
     1/2, lane-friendly channel counts, enough output rows to tile."""
-    if not _HAVE_PALLAS:
-        return False
     if len(x_shape) != 4 or len(w_shape) != 4:
         return False
     n, ci, h, w = x_shape

@@ -86,7 +86,7 @@ def _pipeline(ctx, inputs, attrs):
         env.update(zip(bc_names, bcaps))
         env[in_name] = inp
         sub = ExecContext(stage_key, is_test=ctx.is_test, mesh=ctx.mesh,
-                          amp=ctx.amp)
+                          amp=ctx.amp, data_axis=ctx.data_axis)
         _run_block(block, env, sub)
         return (env[out_name], *bcaps)
 
@@ -203,7 +203,7 @@ def _pipeline_hetero(ctx, inputs, attrs):
             env.update(zip(bnames, cap_vals))
             env[names[k]] = xin
             sub = ExecContext(mkey, is_test=ctx.is_test, mesh=ctx.mesh,
-                              amp=ctx.amp)
+                              amp=ctx.amp, data_axis=ctx.data_axis)
             _run_block(blocks[k], env, sub)
             return env[names[k + 1]]
         micro_keys = _jax.random.split(key_k, m) if keyed else None

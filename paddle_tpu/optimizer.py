@@ -153,11 +153,7 @@ class Optimizer:
                   "LearningRate": [self._lr_var.name]}
         outputs = {"ParamOut": [p.name]}
         attrs = {"vis": dt // mult,
-                 "rows_per_step": int(self._packed_rows["rows_per_step"]),
-                 # opt-out knob for the fused Pallas update path
-                 # (adagrad_row_packed): packed_rows={"fused": False} pins
-                 # the unfused gather+scatter branch regardless of backend
-                 "fused": bool(self._packed_rows.get("fused", True))}
+                 "rows_per_step": int(self._packed_rows["rows_per_step"])}
         return inputs, outputs, attrs
 
     # how many column groups the table row carries per optimizer type:

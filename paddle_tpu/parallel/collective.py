@@ -18,12 +18,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # JAX 0.9: jax.shard_map; older: jax.experimental.shard_map
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False,

@@ -49,10 +49,8 @@ Write-backs ride the tier's ``_Pusher`` and therefore the push journal:
 cache write-backs as ordinary pushes — crash recovery stays lossless and
 bitwise with zero new machinery.
 
-Device ops (gather for write-back, scatter for admission) go through the
-Pallas row kernels in ``ops.pallas_kernels.sparse_adagrad`` when the
-backend can run them, else a jitted XLA gather/scatter producing the
-same bytes. All index vectors are padded to power-of-two buckets by
+Device ops (gather for write-back, scatter for admission) are a jitted
+XLA gather/scatter. All index vectors are padded to power-of-two buckets by
 repeating their last element — identical-value duplicate writes keep the
 scatter deterministic while the executable set stays O(log slab).
 
@@ -376,18 +374,12 @@ class HotRowCache:
     def _bind_ops(self):
         import jax
         import jax.numpy as jnp
-        from ..ops.pallas_kernels import sparse_adagrad as fsa
 
-        if fsa.rows_enabled(self.lanes):
-            self._gather_fn = fsa.fused_row_gather
-            self._scatter_fn = fsa.fused_row_scatter
-        else:
-            self._gather_fn = jax.jit(
-                lambda t, i: jnp.take(t, i, axis=0))
-            # padded duplicate targets carry identical bytes, so the
-            # scatter stays deterministic despite non-unique indices
-            self._scatter_fn = jax.jit(
-                lambda t, tgt, rows, src: t.at[tgt].set(rows[src]))
+        self._gather_fn = jax.jit(lambda t, i: jnp.take(t, i, axis=0))
+        # padded duplicate targets carry identical bytes, so the scatter
+        # stays deterministic despite non-unique indices
+        self._scatter_fn = jax.jit(
+            lambda t, tgt, rows, src: t.at[tgt].set(rows[src]))
 
     def take_rows(self, slots: np.ndarray):
         """Gather ``slab[slots]`` -> device ``[bucket(n), lanes]``; pad

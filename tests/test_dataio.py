@@ -1,6 +1,7 @@
 """Async input pipeline: DeviceLoader prefetch, FetchHandle fetches,
 in-flight train_from_dataset, PyReader double buffering, and the
 device-side FLAGS_check_nan_inf path."""
+import os
 import threading
 import time
 
@@ -353,44 +354,73 @@ class TestFlagsAndCompileCache:
         from paddle_tpu import flags as flags_mod
         old = dict(flags_mod._FLAGS)
         monkeypatch.setenv("PDTPU_MAX_INFLIGHT_STEPS", "4")
-        monkeypatch.setenv("PDTPU_COMPILE_CACHE_DIR", "/tmp/xyz")
         try:
             flags_mod._bootstrap_from_env()
             assert flags_mod.flag("max_inflight_steps") == 4
-            assert flags_mod.flag("compile_cache_dir") == "/tmp/xyz"
         finally:
             flags_mod._FLAGS.update(old)
 
-    def test_compile_cache_enable_records_entry_count(self, tmp_path,
-                                                      monkeypatch):
-        from paddle_tpu.core import executor as exe_mod
-        (tmp_path / "entry0").write_bytes(b"x")
-        calls = {}
-        monkeypatch.setattr(jax.config, "update",
-                            lambda k, v: calls.setdefault(k, v))
-        was = exe_mod._COMPILE_CACHE_ENABLED[0]
-        exe_mod._COMPILE_CACHE_ENABLED[0] = False
-        try:
-            assert exe_mod._maybe_enable_compile_cache(str(tmp_path))
-            assert calls["jax_compilation_cache_dir"] == str(tmp_path)
-            from paddle_tpu.observability import get_registry
-            snap = get_registry().snapshot()
-            assert snap["executor/compile_cache_enabled"] == 1
-            assert snap["executor/compile_cache_entries_at_start"] == 1
-            # and it is once-per-process from here on
-            assert exe_mod._maybe_enable_compile_cache("/elsewhere")
-            assert calls["jax_compilation_cache_dir"] == str(tmp_path)
-        finally:
-            exe_mod._COMPILE_CACHE_ENABLED[0] = was
+    @staticmethod
+    def _repo_cache_dir():
+        import paddle_tpu
+        return os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(paddle_tpu.__file__))), ".jax_cache")
 
-    def test_disabled_without_flag(self):
+    def test_cache_dir_in_effect_after_import(self):
+        """This process imported paddle_tpu long ago: the cache directory
+        is the one the environment names, else <repo>/.jax_cache — and an
+        Executor() does not move it."""
+        want = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                or self._repo_cache_dir())
+        assert jax.config.jax_compilation_cache_dir == want
+        fluid.Executor()
+        assert jax.config.jax_compilation_cache_dir == want
+
+    def test_default_dir_is_set_when_env_is_not(self, monkeypatch):
         from paddle_tpu.core import executor as exe_mod
-        was = exe_mod._COMPILE_CACHE_ENABLED[0]
-        exe_mod._COMPILE_CACHE_ENABLED[0] = False
-        try:
-            assert not exe_mod._maybe_enable_compile_cache("")
-        finally:
-            exe_mod._COMPILE_CACHE_ENABLED[0] = was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        calls = {}
+        monkeypatch.setattr(jax.config, "update", calls.__setitem__)
+        assert exe_mod._enable_compile_cache() == self._repo_cache_dir()
+        assert calls["jax_compilation_cache_dir"] == self._repo_cache_dir()
+        # small and fast compiles are cached too
+        assert calls["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+    def test_env_dir_is_left_to_jax(self, tmp_path, monkeypatch):
+        """Where JAX_COMPILATION_CACHE_DIR is set the code sets no
+        directory, and the entry count at start lands in the registry."""
+        from paddle_tpu.core import executor as exe_mod
+        (tmp_path / "jit_f-0123-cache").write_bytes(b"x")
+        (tmp_path / "jit_f-0123-atime").write_bytes(b"x")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        calls = {}
+        monkeypatch.setattr(jax.config, "update", calls.__setitem__)
+        assert exe_mod._enable_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in calls
+        from paddle_tpu.observability import get_registry
+        snap = get_registry().snapshot()
+        assert snap["executor/compile_cache_entries_at_start"] == 1
+
+    def test_env_dir_wins_in_a_fresh_interpreter(self, tmp_path):
+        """End to end: jax reads the variable itself, entries land there
+        and <repo>/.jax_cache is not what the config names."""
+        import subprocess
+        import sys
+        code = (
+            "import jax, paddle_tpu as fluid\n"
+            "fluid.Executor()\n"
+            "import jax.numpy as jnp\n"
+            "jax.jit(lambda x: x + 1)(jnp.ones(3)).block_until_ready()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, capture_output=True,
+            text=True, timeout=300, cwd=os.path.dirname(
+                self._repo_cache_dir()))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
+        assert any(f.endswith("-cache") for f in os.listdir(tmp_path))
 
 
 # ---------------------------------------------------------------------------

@@ -61,10 +61,10 @@ def test_cost_from_executable_cpu_matmul():
     assert mem == (64 * 32 + 32 * 16 + 64 * 16) * 4
 
 
-def test_cost_from_executable_normalizes_list_and_rejects_empty():
-    class ListExe:
+def test_cost_from_executable_normalizes_keys_and_rejects_empty():
+    class DictExe:
         def cost_analysis(self):
-            return [{"flops": 5.0, "bytes accessed": 7.0}]
+            return {"flops": 5.0, "bytes accessed": 7.0}
 
     class RaisingExe:
         def cost_analysis(self):
@@ -74,7 +74,7 @@ def test_cost_from_executable_normalizes_list_and_rejects_empty():
         def cost_analysis(self):
             return {"flops": 0.0, "bytes accessed": 0.0}
 
-    assert perf.cost_from_executable(ListExe()) == {
+    assert perf.cost_from_executable(DictExe()) == {
         "flops": 5.0, "bytes_accessed": 7.0, "transcendentals": 0.0}
     assert perf.cost_from_executable(RaisingExe()) is None
     assert perf.cost_from_executable(ZeroExe()) is None

@@ -8,9 +8,8 @@ baseline): every shard count, any prefetch/push depth, cache smaller
 OR larger than the working set, and straight through a SIGKILLed
 pserver. Plus: the shared slab bookkeeping (ps.slab), the plan/commit
 concurrency rules (dirty-at-commit, in-flight slot pinning, pending
-evictions in flush), the checkpoint flush hook, the Pallas
-row-maintenance kernels under the interpreter, and the ps_admin
-hot-cache block.
+evictions in flush), the checkpoint flush hook, the slab's device
+gather/scatter, and the ps_admin hot-cache block.
 """
 import json
 import threading
@@ -21,7 +20,6 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.observability.registry import get_registry
-from paddle_tpu.ops.pallas_kernels import sparse_adagrad as fsa
 from paddle_tpu.parallel.checkpoint import Checkpointer
 from paddle_tpu.ps import (FreqSketch, HotRowCache, LruOrder,
                            PsEmbeddingTier, PsTableBinding, RangeSpec,
@@ -39,14 +37,6 @@ def ref():
     feeds = tpe._feeds()
     losses, final = tpe._packed_baseline(feeds)
     return feeds, losses, final
-
-
-@pytest.fixture
-def interpret_kernel():
-    old = fsa.FORCE_PALLAS_INTERPRET
-    fsa.FORCE_PALLAS_INTERPRET = True
-    yield
-    fsa.FORCE_PALLAS_INTERPRET = old
 
 
 # ------------------------------------------------------------- slab core
@@ -211,39 +201,10 @@ def test_flush_rows_dirty_at_commit_plus_pending_evicts():
     hc.commit(p2)
 
 
-# ------------------------------------------- Pallas row kernels (interpret)
+# ------------------------------------------------ slab device ops
 
-def test_row_gather_matches_take(interpret_kernel):
+def test_hot_cache_device_ops_roundtrip():
     import jax.numpy as jnp
-    rng = np.random.RandomState(0)
-    table = rng.randint(0, 2 ** 16, (10, LANES)).astype(np.uint16)
-    # duplicates allowed on the read path; tail repeats the last slot
-    slots = np.array([3, 3, 0, 9, 9, 9, 9, 9], np.int32)
-    out = np.asarray(fsa.fused_row_gather(jnp.asarray(table),
-                                          jnp.asarray(slots)))
-    np.testing.assert_array_equal(out, table[slots])
-
-
-def test_row_scatter_matches_assign_and_aliases(interpret_kernel):
-    import jax.numpy as jnp
-    rng = np.random.RandomState(1)
-    table = rng.randint(0, 2 ** 16, (10, LANES)).astype(np.uint16)
-    rows = rng.randint(0, 2 ** 16, (4, LANES)).astype(np.uint16)
-    # distinct prefix [7, 2, 5], padded by repeating the last (tgt, src)
-    # pair — the contract every caller follows
-    slots = np.array([7, 2, 5, 5], np.int32)
-    src = np.array([0, 1, 2, 2], np.int32)
-    out = np.asarray(fsa.fused_row_scatter(
-        jnp.asarray(table), jnp.asarray(slots), jnp.asarray(rows),
-        jnp.asarray(src)))
-    want = table.copy()
-    want[[7, 2, 5]] = rows[[0, 1, 2]]
-    np.testing.assert_array_equal(out, want)  # untouched rows bitwise
-
-
-def test_hot_cache_device_ops_roundtrip_via_pallas(interpret_kernel):
-    import jax.numpy as jnp
-    assert fsa.rows_enabled(LANES)   # interpreter forced by the fixture
     hc = _mk_cache(capacity=4, step_rows=4)
     rng = np.random.RandomState(2)
     rows = jnp.asarray(rng.randint(0, 2 ** 16, (3, LANES))
