@@ -70,6 +70,7 @@ from .core import (  # noqa: F401
     in_dygraph_mode,
     program_guard,
     remat_unit,
+    unit,
     scope_guard,
 )
 from .param_attr import ParamAttr, WeightNormParamAttr  # noqa: F401

@@ -15,6 +15,7 @@ from .program import (  # noqa: F401
     in_dygraph_mode,
     program_guard,
     remat_unit,
+    unit,
 )
 from .registry import get_op, has_op, register_op, registered_ops  # noqa: F401
 from .scope import Scope, global_scope, scope_guard  # noqa: F401

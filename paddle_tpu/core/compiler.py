@@ -24,11 +24,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import jax.numpy as jnp
 
 from .executor import (_RNG_STATE, _CACHE_HITS, _CACHE_MISSES, _EXECUTE_MS,
-                       _OBS, _WATCHDOG, _sig_digest, ExecContext, _run_block)
+                       _OBS, _WATCHDOG, _sig_digest, _Step, ExecContext,
+                       _run_block)
 from .program import Program, Variable
+from ..observability import scopes as _scopes
 from ..observability.tracer import trace_span
 
-import time
 import weakref
 
 
@@ -500,6 +501,8 @@ class CompiledProgram:
                 new_state[n] = v
             return fetches, new_state, ctx.final_key()
 
+        # as on the plain path: the compile cache hashes the name
+        step.__name__ = _scopes.scheme_name("step", self._program)
         return step
 
     def _build(self, feed_names, fetch_names, state_names, out_state_names,
@@ -516,28 +519,117 @@ class CompiledProgram:
         # fetches are replicated so every process can np.asarray() them
         # (a partially-addressable fetch would fail on multi-host)
         fetch_sh = [NamedSharding(mesh, P()) for _ in fetch_names]
-        return jax.jit(
+        return _Step(jax.jit(
             step,
             in_shardings=(state_sh, feed_sh, key_sh),
             out_shardings=(fetch_sh, out_state_sh, key_sh),
             donate_argnums=(0,),
-        )
+        ))
 
     # -- execution (called by Executor.run) --------------------------------
     def _run(self, exe, feed, fetch_list, scope, return_numpy):
+        """One step under the mesh. Called inside `Executor.run`'s
+        `executor/step` span, and records the same children as the plain
+        path: executor/feed, executor/state_in, compiled_program/run,
+        executor/telemetry, executor/state_out, executor/fetch."""
         from .scope import _scope
 
-        if self._mesh is None:
-            self.with_data_parallel()
-        program = self._program
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = scope or _scope()
-        fetch_names = [f.name if isinstance(f, Variable) else f for f in fetch_list]
+        with trace_span("executor/feed"):
+            if self._mesh is None:
+                self.with_data_parallel()
+            program = self._program
+            feed = feed or {}
+            fetch_list = list(fetch_list or [])
+            scope = scope or _scope()
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
+            multiproc = jax.process_count() > 1
+            feed_vals = self._convert_feeds(program.global_block(), feed,
+                                            multiproc)
+            feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
+                                    for n, v in feed_vals.items()))
+            sig = _sig_digest(feed_sig)
 
-        multiproc = jax.process_count() > 1
+        with trace_span("executor/state_in"):
+            state_names = sorted(
+                v.name for v in program.list_vars()
+                if v.persistable and scope.has_var(v.name))
+            out_state_names = sorted({v.name for v in program.list_vars()
+                                      if v.persistable})
+            key_sig = (program._version, feed_sig, tuple(fetch_names),
+                       tuple(state_names),
+                       self._remat_spec().token,
+                       self._zero_stage(),
+                       id(self._mesh), self._data_axis,
+                       getattr(self, "_seq_axis", None))
+            fn = self._cache.get(key_sig)
+            compiling = fn is None
+            if compiling:
+                _CACHE_MISSES.inc()
+                wd_key = (id(self._program), program._version, "mesh",
+                          tuple(fetch_names))
+                if _WATCHDOG.record_compile(
+                        wd_key, feed_sig,
+                        label=f"CompiledProgram 0x{id(self._program):x}"):
+                    weakref.finalize(self._program, _WATCHDOG.forget, wd_key)
+                fn = self._build(
+                    sorted(feed_vals), fetch_names, state_names,
+                    out_state_names,
+                    {n: np.asarray(v).ndim if not isinstance(v, jax.Array)
+                     else v.ndim for n, v in feed_vals.items()})
+                self._cache[key_sig] = fn
+            else:
+                _CACHE_HITS.inc()
+            state, key = self._state_in(program, scope, state_names,
+                                        multiproc)
 
-        block = program.global_block()
+        from ..observability.flight import get_flight_recorder
+        from ..observability.steps import get_step_profiler
+        with get_flight_recorder().guard(
+                "CompiledProgram._run",
+                program=f"0x{id(self._program):x}",
+                sig=sig, compiling=compiling), \
+                trace_span("compiled_program/compile+run" if compiling
+                           else "compiled_program/run", sig=sig) as call:
+            fetches, new_state, new_key = fn(state, feed_vals, key)
+        dt_ms = call.dur_ms
+
+        with trace_span("executor/telemetry"):    # the instrument, timed
+            if compiling:
+                # perf ledger for the mesh executable: trace-only lower on
+                # the avals of the call for XLA's cost numbers (the mesh jit
+                # is lazy — there is no AOT Compiled to ask), analytic IR
+                # walk otherwise
+                from ..observability import perf as _perf
+                lowered = None
+                if _perf.trace_cost_enabled():
+                    try:
+                        lowered = fn.lower(*fn._avals)
+                    except Exception:
+                        lowered = None
+                _perf.get_ledger().register(
+                    id(self._program), sig, executable=lowered,
+                    program=program, feed=feed_vals)
+                _OBS.histogram("executor/compile_ms", sig=sig).observe(dt_ms)
+                # gauge the state footprint once per compiled signature —
+                # the number ShardingStrategy shrinks — plus allocator
+                # occupancy
+                from ..observability.memory import record_state_memory
+                record_state_memory(new_state.values())
+            else:
+                _EXECUTE_MS.observe(dt_ms)
+            get_step_profiler().record(dt_ms, program_id=id(self._program),
+                                       sig=sig, compiled=compiling)
+        with trace_span("executor/state_out"):
+            for n, v in new_state.items():
+                scope.set_var(n, v)
+            scope.set_var(_RNG_STATE, new_key)
+        if return_numpy:
+            with trace_span("executor/fetch"):    # waits for the device
+                return [np.asarray(f) for f in fetches]
+        return list(fetches)
+
+    def _convert_feeds(self, block, feed, multiproc):
         feed_vals = {}
         for name, val in feed.items():
             var = block._find_var_recursive(name)
@@ -562,36 +654,10 @@ class CompiledProgram:
             else:
                 from .executor import convert_feed_value
                 feed_vals[name] = convert_feed_value(block, name, val)
+        return feed_vals
 
-        state_names = sorted(
-            v.name for v in program.list_vars()
-            if v.persistable and scope.has_var(v.name))
-        out_state_names = sorted({v.name for v in program.list_vars() if v.persistable})
-        feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype)) for n, v in feed_vals.items()))
-        key_sig = (program._version, feed_sig, tuple(fetch_names),
-                   tuple(state_names),
-                   self._remat_spec().token,
-                   self._zero_stage(),
-                   id(self._mesh), self._data_axis,
-                   getattr(self, "_seq_axis", None))
-        fn = self._cache.get(key_sig)
-        compiling = fn is None
-        if compiling:
-            _CACHE_MISSES.inc()
-            wd_key = (id(self._program), program._version, "mesh",
-                      tuple(fetch_names))
-            if _WATCHDOG.record_compile(
-                    wd_key, feed_sig,
-                    label=f"CompiledProgram 0x{id(self._program):x}"):
-                weakref.finalize(self._program, _WATCHDOG.forget, wd_key)
-            fn = self._build(sorted(feed_vals), fetch_names, state_names,
-                             out_state_names,
-                             {n: np.asarray(v).ndim if not isinstance(v, jax.Array) else v.ndim
-                              for n, v in feed_vals.items()})
-            self._cache[key_sig] = fn
-        else:
-            _CACHE_HITS.inc()
-
+    def _state_in(self, program, scope, state_names, multiproc):
+        """The state leaves in their compiled layout, and the RNG key."""
         pads = self._zero_pad_map()
         state = {}
         for n in state_names:
@@ -645,49 +711,4 @@ class CompiledProgram:
             else:
                 key = jax.make_array_from_process_local_data(
                     sh, np.asarray(key))
-
-        from ..observability.flight import get_flight_recorder
-        from ..observability.steps import get_step_profiler
-        if compiling:
-            # perf ledger for the mesh executable: trace-only lower for
-            # XLA's cost numbers (the mesh jit is lazy — there is no AOT
-            # Compiled to ask), analytic IR walk otherwise
-            from ..observability import perf as _perf
-            lowered = None
-            if _perf.trace_cost_enabled():
-                try:
-                    lowered = fn.lower(state, feed_vals, key)
-                except Exception:
-                    lowered = None
-            _perf.get_ledger().register(
-                id(self._program), _sig_digest(feed_sig),
-                executable=lowered, program=program, feed=feed_vals)
-        t0 = time.perf_counter()
-        with get_flight_recorder().guard(
-                "CompiledProgram._run",
-                program=f"0x{id(self._program):x}",
-                sig=_sig_digest(feed_sig), compiling=compiling), \
-                trace_span("compiled_program/compile+run" if compiling
-                           else "compiled_program/run",
-                           sig=_sig_digest(feed_sig)):
-            fetches, new_state, new_key = fn(state, feed_vals, key)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        if compiling:
-            _OBS.histogram("executor/compile_ms",
-                           sig=_sig_digest(feed_sig)).observe(dt_ms)
-        else:
-            _EXECUTE_MS.observe(dt_ms)
-        get_step_profiler().record(dt_ms, program_id=id(self._program),
-                                   sig=_sig_digest(feed_sig),
-                                   compiled=compiling)
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        scope.set_var(_RNG_STATE, new_key)
-        if compiling:
-            # gauge the state footprint once per compiled signature — the
-            # number ShardingStrategy shrinks — plus allocator occupancy
-            from ..observability.memory import record_state_memory
-            record_state_memory(new_state.values())
-        if return_numpy:
-            return [np.asarray(f) for f in fetches]
-        return list(fetches)
+        return state, key

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import registry
-from .program import Block, Program, Variable, default_main_program, grad_var_name
+from .program import (UNIT_ATTR, Block, Program, Variable,
+                      default_main_program, grad_var_name)
 from .scope import Scope, _scope, global_scope
 
 from ..dataio.handle import FetchHandle
@@ -35,12 +36,13 @@ from ..observability.flight import (get_flight_recorder,
                                     register_dump_section)
 from ..observability.http import maybe_serve_from_env
 from ..observability.registry import get_registry
+from ..observability import scopes as _scopes
 from ..observability.steps import get_step_profiler
-from ..observability.tracer import trace_span
+from ..observability.tracer import step_span, trace_span
 from ..observability.watchdog import get_watchdog
 
 import collections
-import time
+import itertools
 import weakref
 
 _RNG_STATE = "@RNG_STATE@"
@@ -59,6 +61,8 @@ _INFLIGHT = _OBS.gauge("executor/inflight_steps")
 _WATCHDOG = get_watchdog()
 _STEPS = get_step_profiler()
 _FLIGHT = get_flight_recorder()
+# the ordinal every `executor/step` span carries (plain and mesh path alike)
+_STEP_ORDINAL = itertools.count()
 
 # live executors, so the flight recorder can dump which compiled
 # signatures were resident when a run died (weak: a GC'd executor's
@@ -101,7 +105,16 @@ def _enable_compile_cache() -> str:
     is set jax has already read it and no directory is set here; otherwise
     the cache lives in `_default_compile_cache_dir`. The entry count at
     start lands in the registry so exports tell a cold start (0) from a
-    warm one. Returns the directory in effect."""
+    warm one. Returns the directory in effect.
+
+    jax strips locations from the cache's key, and the name scopes the
+    lowering writes (observability/scopes.py) live in locations: a step whose
+    scopes changed would be handed the executable an earlier build cached,
+    with that build's names. Metadata stays out of the key all the same (with
+    it in, every edit that moves a source line under a step's trace, and
+    every other script that calls the step, compiles cold: PERF.md section 6,
+    PR 24); what is hashed instead is the scope scheme itself, through the
+    step's name (`scopes.scheme_name`)."""
     import os
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not d:
@@ -258,12 +271,16 @@ class ExecContext:
         # elementwise-heavy ops). True = all ops, or a set of op types.
         self.remat = remat
         # RematSpec (compiler.resolve_remat) — when its unit_policy is set,
-        # consecutive ops tagged with the same `__remat_unit__` attr run as
+        # consecutive ops of one remat block (`program.remat_unit_of`) run as
         # ONE jax.checkpoint region (_run_remat_group)
         self.remat_units = remat_units
         # True while tracing the forward of a remat group: ops run their
         # plain forward (the group's single jax.vjp owns differentiation)
         self.group_forward = False
+        # True once the block's `autodiff` op has run: what is lowered after
+        # it (everything optimizer.py appends) is the optimizer's, and its
+        # name scopes start with `opt/`
+        self.after_autodiff = False
         self.tape: List[TapeEntry] = []
         # declared output arity of the op currently being run ({slot: n}) —
         # lets arity-driven kernels (reference: split_ids_op.cc sizes N from
@@ -397,6 +414,14 @@ def convert_feed_value(block, name: str, val):
     return arr
 
 
+def _op_scope(op, ctx: ExecContext):
+    """The name scope every instruction lowered from `op` carries:
+    `[opt/][u.<unit>/]op.<op type>` (observability/scopes.py)."""
+    return _scopes.op_scope(
+        op.type, op.attrs.get(UNIT_ATTR),
+        opt=ctx.after_autodiff or op.type in _FUSABLE_UPDATES)
+
+
 def _run_op(op, env: Dict[str, object], ctx: ExecContext):
     opdef = registry.get_op(op.type)
     ctx.out_arity = {slot: len(names) for slot, names in op.outputs.items()}
@@ -415,9 +440,10 @@ def _run_op(op, env: Dict[str, object], ctx: ExecContext):
     if custom_grad is not None:
         # hand-written gradient (GradOpMaker analog): used where the
         # cotangent is not a dense array — e.g. SelectedRows embedding rows
-        ins_c = _amp_cast({s: list(v) for s, v in in_vals.items()},
-                          op.type, ctx.amp)
-        out = opdef.fn(ctx, ins_c, op.attrs)
+        with _op_scope(op, ctx):
+            ins_c = _amp_cast({s: list(v) for s, v in in_vals.items()},
+                              op.type, ctx.amp)
+            out = opdef.fn(ctx, ins_c, op.attrs)
         out_names, flat_out_vals = [], []
         for slot in sorted(op.outputs):
             vals = out.get(slot, [])
@@ -440,7 +466,9 @@ def _run_op(op, env: Dict[str, object], ctx: ExecContext):
             for s, c in zip(out_slots, out_counts):
                 by_slot[s] = list(out_cots[i:i + c])
                 i += c
-            in_cots = custom_grad(_ctx, _ins, _op.attrs, _out, by_slot)
+            # called from the autodiff walk: the scope names the op there
+            with _op_scope(_op, _ctx):
+                in_cots = custom_grad(_ctx, _ins, _op.attrs, _out, by_slot)
             flat = []
             for s in in_slots:
                 got = in_cots.get(s)
@@ -466,9 +494,13 @@ def _run_op(op, env: Dict[str, object], ctx: ExecContext):
                 ins[s] = list(flat_vals[pos:pos + c])
                 pos += c
             # AMP casts live INSIDE the differentiated fn so vjp converts
-            # cotangent dtypes through the cast automatically
-            ins = _amp_cast(ins, op.type, ctx.amp)
-            out = opdef.fn(ctx, ins, op.attrs)
+            # cotangent dtypes through the cast automatically. So does the
+            # name scope: entered around jax.vjp it would be lost from the
+            # backward operations (`transpose(jvp())`), entered here they
+            # read `transpose(jvp(u.<unit>/op.<op type>))`
+            with _op_scope(op, ctx):
+                ins = _amp_cast(ins, op.type, ctx.amp)
+                out = opdef.fn(ctx, ins, op.attrs)
             flat_out = []
             for slot in sorted(op.outputs):
                 vals = out.get(slot, [])
@@ -500,7 +532,9 @@ def _run_op(op, env: Dict[str, object], ctx: ExecContext):
         ctx.tape.append(TapeEntry(flat_in_names, out_names, vjp_fn,
                                   list(flat_out_vals), nondiff_in))
     else:
-        out = opdef.fn(ctx, _amp_cast(in_vals, op.type, ctx.amp), op.attrs)
+        with _op_scope(op, ctx):
+            out = opdef.fn(ctx, _amp_cast(in_vals, op.type, ctx.amp),
+                           op.attrs)
         for slot in sorted(op.outputs):
             vals = out.get(slot, [])
             names = op.outputs[slot]
@@ -517,7 +551,15 @@ def _run_autodiff(op, env, ctx: ExecContext):
 
     Equivalent of reference append_backward's generated grad-op sequence
     (backward.py:558, accumulation rule _addup_repetitive_outputs_:135),
-    executed functionally."""
+    executed functionally. The walk runs under the `autodiff` name scope
+    (cotangent sums and custom gradients are backward work too); what the
+    block lowers after it is the optimizer's."""
+    with _scopes.autodiff_scope():
+        _walk_tape(op, env, ctx)
+    ctx.after_autodiff = True
+
+
+def _walk_tape(op, env, ctx: ExecContext):
     loss_name = op.attrs["loss_name"]
     targets: Sequence[str] = op.attrs["targets"]
     block = op.block
@@ -660,19 +702,20 @@ def _run_update_group(ops, env, ctx: ExecContext):
     spec = _FUSABLE_UPDATES[ops[0].type]
     shapes = [jnp.shape(env[op.inputs["Param"][0]]) for op in ops]
     sizes = [int(np.prod(s)) if s else 1 for s in shapes]
-    ins = {}
-    for slot in spec["flat_in"]:
-        ins[slot] = [jnp.concatenate(
-            [jnp.ravel(env[op.inputs[slot][0]]) for op in ops])]
-    for slot in spec["scalar_in"]:
-        if slot in ops[0].inputs:
-            ins[slot] = [env[ops[0].inputs[slot][0]]]
-    out = opdef.fn(ctx, ins, ops[0].attrs)
     offsets = list(np.cumsum(sizes)[:-1])
-    for slot in spec["flat_out"]:
-        parts = jnp.split(out[slot][0], offsets)
-        for op, part, shp in zip(ops, parts, shapes):
-            env[op.outputs[slot][0]] = part.reshape(shp)
+    with _op_scope(ops[0], ctx):    # the flats and the splits are its work
+        ins = {}
+        for slot in spec["flat_in"]:
+            ins[slot] = [jnp.concatenate(
+                [jnp.ravel(env[op.inputs[slot][0]]) for op in ops])]
+        for slot in spec["scalar_in"]:
+            if slot in ops[0].inputs:
+                ins[slot] = [env[ops[0].inputs[slot][0]]]
+        out = opdef.fn(ctx, ins, ops[0].attrs)
+        for slot in spec["flat_out"]:
+            parts = jnp.split(out[slot][0], offsets)
+            for op, part, shp in zip(ops, parts, shapes):
+                env[op.outputs[slot][0]] = part.reshape(shp)
     for slot in spec["scalar_out"]:
         if slot in ops[0].outputs and slot in out:
             for op in ops:
@@ -726,9 +769,9 @@ def _remat_group_eligible(op) -> bool:
 def _plan_remat_items(block: Block, ctx: ExecContext):
     """Partition block.ops into ("op", None, op) singles and
     ("group", decision, [ops]) maximal runs of consecutive ops sharing a
-    `__remat_unit__` tag whose unit decision (RematSpec.unit_policy) is
+    remat block (`program.remat_unit_of`) whose unit decision (RematSpec.unit_policy) is
     truthy. Cheap when no policy is active (the common path)."""
-    from .program import REMAT_UNIT_ATTR
+    from .program import remat_unit_of
 
     spec = ctx.remat_units
     pred = getattr(spec, "unit_policy", None) if spec is not None else None
@@ -745,12 +788,13 @@ def _plan_remat_items(block: Block, ctx: ExecContext):
         cur_unit, cur_dec, cur_ops = None, None, []
 
     for op in block.ops:
-        unit = op.attrs.get(REMAT_UNIT_ATTR)
+        unit = remat_unit_of(op)
         dec = None
         if unit is not None and _remat_group_eligible(op):
             if unit not in decisions:
                 try:
-                    decisions[unit] = pred(unit)
+                    # the policy is asked by the name `remat_unit` was given
+                    decisions[unit] = pred(unit.rsplit("/", 1)[-1])
                 except Exception:
                     decisions[unit] = False
             dec = decisions[unit]
@@ -915,7 +959,45 @@ def _run_block(block: Block, env: Dict[str, object], ctx: ExecContext):
     flush()
 
 
-class _AutoLayoutStep:
+class _Step:
+    """What `Executor.compiled_step` and `scopes.hottest_step` need of a
+    step: how often it was dispatched, and its executable. A jitted function
+    keeps no executable to hand out, so the step remembers the avals of its
+    first call and lowers on them again (the same trace, so a hit of the
+    compile cache). The mesh path's step is this class as it is."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self.calls = 0
+        self._avals = None
+        self._lowered_again = None
+        _scopes.track_step(self)
+
+    def _count(self, *args):
+        self.calls += 1
+        if self._avals is None:
+            self._avals = jax.tree.map(
+                lambda v: jax.ShapeDtypeStruct(jnp.shape(v), v.dtype), args)
+
+    def __call__(self, state, feed, key):
+        self._count(state, feed, key)
+        return self._jitted(state, feed, key)
+
+    def __getattr__(self, name):
+        # what else callers ask of a jitted function (`lower`, `trace`)
+        if name == "_jitted":
+            raise AttributeError(name)
+        return getattr(self._jitted, name)
+
+    def compiled(self):
+        if self._lowered_again is None:
+            if self._avals is None:
+                raise RuntimeError("the step has not been dispatched yet")
+            self._lowered_again = self._jitted.lower(*self._avals).compile()
+        return self._lowered_again
+
+
+class _AutoLayoutStep(_Step):
     """jit wrapper that lets XLA choose (and keep) the parameter layouts.
 
     With default row-major entry layouts, every conv/matmul weight is
@@ -932,6 +1014,7 @@ class _AutoLayoutStep:
     def __init__(self, step):
         self._step = step
         self._plain = jax.jit(step, donate_argnums=(0,))
+        super().__init__(self._plain)
         # previous step's output state (name -> array), retained so the
         # steady-state path can verify leaves BY IDENTITY — `.format`
         # builds a Format object per access, ~0.5 µs/leaf, which at
@@ -1014,7 +1097,15 @@ class _AutoLayoutStep:
         self._compiled = relayout.lower(state, feed, key).compile()
         self._in_format = self._compiled.input_formats[0][0]
 
+    def compiled(self):
+        """The AUTO-layout executable where there is one, else the plain
+        jit's."""
+        if self._compiled is not None:
+            return self._compiled
+        return super().compiled()
+
     def __call__(self, state, feed, key):
+        self._count(state, feed, key)
         if self._auto is not None and self._compiled is None:
             # huge state leaves (Criteo-scale embedding tables): a layout
             # disagreement between the AUTO solver and the producing
@@ -1157,7 +1248,29 @@ class Executor:
             new_state = {n: env[n] for n in out_state_names if n in env}
             return fetches, new_state, ctx.final_key()
 
+        # the compile cache hashes the step's name and not its metadata: the
+        # name carries the names the lowering will write
+        step.__name__ = _scopes.scheme_name("step", program)
         return _AutoLayoutStep(step)
+
+    def compiled_step(self, program=None):
+        """The compiled executable (`jax.stages.Compiled`) behind
+        `run(program, ...)`: of the signatures this executor has run the
+        program with, the one dispatched most often. For its
+        `memory_analysis()`, `cost_analysis()` and `as_text()`;
+        `observability.scopes.op_scopes` reads the last."""
+        from .compiler import CompiledProgram
+
+        if isinstance(program, CompiledProgram):
+            steps = list(program._cache.values())
+        else:
+            program = program or default_main_program()
+            steps = [fn for key, fn in self._cache.items()
+                     if key[0] == id(program) and hasattr(fn, "compiled")]
+        steps = [fn for fn in steps if fn.calls]
+        if not steps:
+            raise RuntimeError("this executor has not run the program yet")
+        return max(steps, key=lambda fn: fn.calls).compiled()
 
     def run(
         self,
@@ -1177,131 +1290,152 @@ class Executor:
         point. Results are bitwise-identical to return_numpy=True."""
         from .compiler import CompiledProgram
 
-        if isinstance(program, CompiledProgram):
-            # chaos probe: one hit per training-step dispatch — a spec
-            # like exec.dispatch:crash@7 kills exactly step 7's dispatch
-            fault_point("exec.dispatch")
-            out = program._run(self, feed, fetch_list, scope,
-                               return_numpy and not return_handle)
-            # maintenance epilogues must fire under the mesh too — the
-            # deferred-row fold is cadence-critical (the append log
-            # overflows silently if it never runs)
+        # one root span per call, carrying the step's ordinal; its children
+        # (the same names on the mesh path, in CompiledProgram._run) are the
+        # phases of the call: feed, state_in, run, telemetry, state_out,
+        # epilogue, fetch
+        with step_span("executor/step", next(_STEP_ORDINAL)):
+            if isinstance(program, CompiledProgram):
+                return self._run_compiled(program, feed, fetch_list, scope,
+                                          return_numpy, return_handle)
+            return self._run_program(program, feed, fetch_list, scope,
+                                     return_numpy, return_handle)
+
+    def _run_compiled(self, program, feed, fetch_list, scope, return_numpy,
+                      return_handle):
+        # chaos probe: one hit per training-step dispatch — a spec
+        # like exec.dispatch:crash@7 kills exactly step 7's dispatch
+        fault_point("exec.dispatch")
+        out = program._run(self, feed, fetch_list, scope,
+                           return_numpy and not return_handle)
+        # maintenance epilogues must fire under the mesh too — the
+        # deferred-row fold is cadence-critical (the append log
+        # overflows silently if it never runs)
+        with trace_span("executor/epilogue"):
             self._advance_epilogues(program._program, scope or _scope(), 1,
                                     compiled=program)
-            if return_handle:
-                names = [f.name if isinstance(f, Variable) else f
-                         for f in (fetch_list or [])]
-                return FetchHandle(names, out)
-            return out
-        program = program or default_main_program()
-        feed = feed or {}
-        fetch_list = list(fetch_list or [])
-        scope = scope or _scope()
+        if return_handle:
+            names = [f.name if isinstance(f, Variable) else f
+                     for f in (fetch_list or [])]
+            return FetchHandle(names, out)
+        return out
 
-        fetch_names = [f.name if isinstance(f, Variable) else f for f in fetch_list]
-        block = program.global_block()
-        feed_vals = {name: convert_feed_value(block, name, val)
-                     for name, val in feed.items()}
+    def _run_program(self, program, feed, fetch_list, scope, return_numpy,
+                     return_handle):
+        with trace_span("executor/feed"):
+            program = program or default_main_program()
+            feed = feed or {}
+            fetch_list = list(fetch_list or [])
+            scope = scope or _scope()
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in fetch_list]
+            block = program.global_block()
+            feed_vals = {name: convert_feed_value(block, name, val)
+                         for name, val in feed.items()}
+            feed_sig = feed_signature(feed_vals)
+            sig = _sig_digest(feed_sig)
 
-        state_names = self._state_names(program, scope)
-        out_state_names = sorted({v.name for v in program.list_vars() if v.persistable})
-        feed_sig = feed_signature(feed_vals)
-        key_sig = (id(program), program._version, feed_sig, tuple(fetch_names),
-                   tuple(state_names))
-        fn = self._cache.get(key_sig)
-        compiling = fn is None
-        if compiling:
-            _CACHE_MISSES.inc()
-            # every cache miss is one XLA trace+compile: count it per
-            # program and let the watchdog diagnose shape-churn storms
-            if _WATCHDOG.record_compile(
-                    (id(program), program._version, tuple(fetch_names)),
-                    feed_sig, label=f"Executor program 0x{id(program):x}"):
-                weakref.finalize(
-                    program, _WATCHDOG.forget,
-                    (id(program), program._version, tuple(fetch_names)))
-            fn = self._build(program, sorted(feed_vals), fetch_names,
-                             state_names, out_state_names)
-            self._cache[key_sig] = fn
-        else:
-            _CACHE_HITS.inc()
+        with trace_span("executor/state_in"):
+            state_names = self._state_names(program, scope)
+            out_state_names = sorted({v.name for v in program.list_vars()
+                                      if v.persistable})
+            key_sig = (id(program), program._version, feed_sig,
+                       tuple(fetch_names), tuple(state_names))
+            fn = self._cache.get(key_sig)
+            compiling = fn is None
+            if compiling:
+                _CACHE_MISSES.inc()
+                # every cache miss is one XLA trace+compile: count it per
+                # program and let the watchdog diagnose shape-churn storms
+                if _WATCHDOG.record_compile(
+                        (id(program), program._version, tuple(fetch_names)),
+                        feed_sig, label=f"Executor program 0x{id(program):x}"):
+                    weakref.finalize(
+                        program, _WATCHDOG.forget,
+                        (id(program), program._version, tuple(fetch_names)))
+                fn = self._build(program, sorted(feed_vals), fetch_names,
+                                 state_names, out_state_names)
+                self._cache[key_sig] = fn
+            else:
+                _CACHE_HITS.inc()
 
-        state = {n: scope.find_var(n) for n in state_names}
-        key = scope.find_var(_RNG_STATE)
-        if key is None:
-            key = _make_key(program.random_seed or 0)
-        # a scope that last ran through a ZeRO-padded CompiledProgram
-        # boundary holds some leaves padded past their declared shape —
-        # slice the pad off before tracing the unsharded step
-        zero_pads = getattr(program, "_zero_padded", None)
-        if zero_pads:
-            for n, shp in zero_pads.items():
-                v = state.get(n)
-                if (v is not None and shp and getattr(v, "shape", None)
-                        and tuple(v.shape) != tuple(shp)
-                        and v.shape[0] > shp[0]):
-                    state[n] = jnp.asarray(v)[:shp[0]]
-        state = {n: (v if isinstance(v, jax.Array) else jnp.asarray(v))
-                 for n, v in state.items()}
+            state = {n: scope.find_var(n) for n in state_names}
+            key = scope.find_var(_RNG_STATE)
+            if key is None:
+                key = _make_key(program.random_seed or 0)
+            # a scope that last ran through a ZeRO-padded CompiledProgram
+            # boundary holds some leaves padded past their declared shape —
+            # slice the pad off before tracing the unsharded step
+            zero_pads = getattr(program, "_zero_padded", None)
+            if zero_pads:
+                for n, shp in zero_pads.items():
+                    v = state.get(n)
+                    if (v is not None and shp and getattr(v, "shape", None)
+                            and tuple(v.shape) != tuple(shp)
+                            and v.shape[0] > shp[0]):
+                        state[n] = jnp.asarray(v)[:shp[0]]
+            state = {n: (v if isinstance(v, jax.Array) else jnp.asarray(v))
+                     for n, v in state.items()}
 
-        t0 = time.perf_counter()
         with _FLIGHT.guard("Executor.run", program=f"0x{id(program):x}",
-                           sig=_sig_digest(feed_sig), compiling=compiling), \
+                           sig=sig, compiling=compiling), \
                 trace_span("executor/compile+run" if compiling
-                           else "executor/run", sig=_sig_digest(feed_sig)):
+                           else "executor/run", sig=sig) as call:
             # chaos probe: one hit per training-step dispatch
             # (exec.dispatch:crash@7 kills exactly step 7). Inside the
             # timed region on purpose — a delay_ms fault here IS a slow
             # step, so the StepProfiler's straggler detector must see it
             fault_point("exec.dispatch")
             fetches, new_state, new_key = fn(state, feed_vals, key)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        if compiling:
-            # the first call pays trace+compile (+ the first dispatch);
-            # labeled per signature so a shape-churning feed shows up as
-            # many one-count compile histograms
-            _OBS.histogram("executor/compile_ms",
-                           sig=_sig_digest(feed_sig)).observe(dt_ms)
-        else:
-            # steady-state host dispatch time (device work is async on
-            # real accelerators; on CPU this is the full step)
-            _EXECUTE_MS.observe(dt_ms)
-        if compiling:
-            # perf ledger: one cost entry per (program, signature). The
-            # AUTO-layout AOT executable gives XLA's cost/memory analysis
-            # for free; the plain-jit fallback pays one trace-only lower
-            # (or falls back to the analytic IR walk). Registered before
-            # the profiler record so even the compile dispatch can see it.
-            from ..observability import perf as _perf
-            executable = getattr(fn, "_compiled", None)
-            if executable is None and _perf.trace_cost_enabled():
-                try:
-                    structs = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
-                               for n, v in state.items()}
-                    executable = fn._plain.lower(structs, feed_vals, key)
-                except Exception:
-                    executable = None
-            _perf.get_ledger().register(
-                id(program), _sig_digest(feed_sig), executable=executable,
-                program=program, feed=feed_vals)
-        _STEPS.record(dt_ms, program_id=id(program),
-                      sig=_sig_digest(feed_sig), compiled=compiling)
+        dt_ms = call.dur_ms
 
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        scope.set_var(_RNG_STATE, new_key)
+        with trace_span("executor/telemetry"):    # the instrument, timed
+            if compiling:
+                # the first call pays trace+compile (+ the first dispatch);
+                # labeled per signature so a shape-churning feed shows up as
+                # many one-count compile histograms
+                _OBS.histogram("executor/compile_ms", sig=sig).observe(dt_ms)
+                # perf ledger: one cost entry per (program, signature). The
+                # AUTO-layout AOT executable gives XLA's cost/memory
+                # analysis for free; the plain-jit fallback pays one
+                # trace-only lower on the avals of the call (or falls back
+                # to the analytic IR walk). Registered before the profiler
+                # record so even the compile dispatch can see it.
+                from ..observability import perf as _perf
+                executable = getattr(fn, "_compiled", None)
+                if executable is None and _perf.trace_cost_enabled():
+                    try:
+                        executable = fn.lower(*fn._avals)
+                    except Exception:
+                        executable = None
+                _perf.get_ledger().register(
+                    id(program), sig, executable=executable,
+                    program=program, feed=feed_vals)
+            else:
+                # steady-state host dispatch time (device work is async on
+                # real accelerators; on CPU this is the full step)
+                _EXECUTE_MS.observe(dt_ms)
+            _STEPS.record(dt_ms, program_id=id(program), sig=sig,
+                          compiled=compiling)
 
-        # maintenance epilogues (e.g. the deferred-row fold program,
-        # optimizer.py _build_deferred_fold — pserver communicator-cadence
-        # analog): run attached programs every `every` runs of this program
-        self._advance_epilogues(program, scope, 1)
+        with trace_span("executor/state_out"):
+            for n, v in new_state.items():
+                scope.set_var(n, v)
+            scope.set_var(_RNG_STATE, new_key)
 
-        from ..flags import flag
-        if flag("check_nan_inf"):
-            # validate every fetched value and updated state var on
-            # device; the host pays one scalar readback unless it trips
-            _check_finite(list(zip(fetch_names, fetches))
-                          + list(new_state.items()))
+        with trace_span("executor/epilogue"):
+            # maintenance epilogues (e.g. the deferred-row fold program,
+            # optimizer.py _build_deferred_fold — pserver communicator-
+            # cadence analog): run attached programs every `every` runs of
+            # this program
+            self._advance_epilogues(program, scope, 1)
+
+            from ..flags import flag
+            if flag("check_nan_inf"):
+                # validate every fetched value and updated state var on
+                # device; the host pays one scalar readback unless it trips
+                _check_finite(list(zip(fetch_names, fetches))
+                              + list(new_state.items()))
 
         if return_handle:
             # fetch-less steps still need something to block on for
@@ -1316,7 +1450,8 @@ class Executor:
                     probe = jnp.ravel(leaf)[:1]
             return FetchHandle(fetch_names, fetches, probe=probe)
         if return_numpy:
-            return [np.asarray(f) for f in fetches]
+            with trace_span("executor/fetch"):    # waits for the device
+                return [np.asarray(f) for f in fetches]
         return list(fetches)
 
     def run_batched(
@@ -1460,6 +1595,8 @@ class Executor:
                 (st, k2), ys = _lax.scan(body, (state, key), feeds)
                 return ys, st, k2
 
+            scan_fn.__name__ = _scopes.scheme_name("scan", program)
+
             if compiled is not None:
                 # pin the scan carry to the compiled layout: ZeRO-sharded
                 # state enters sharded, is donated, and leaves sharded —
@@ -1540,15 +1677,14 @@ class Executor:
             _perf.get_ledger().register(
                 id(program), _sig_digest(stacked_sig), executable=lowered,
                 program=program, feed=per_step_feed, steps=n)
-        t0 = time.perf_counter()
         with _FLIGHT.guard(site,
                            program=f"0x{id(program):x}",
                            sig=_sig_digest(stacked_sig), steps=n,
                            compiling=compiling), \
                 trace_span(site.replace("Executor.", "executor/"), steps=n,
-                           sig=_sig_digest(stacked_sig)):
+                           sig=_sig_digest(stacked_sig)) as call:
             ys, new_state, new_key = fn(state, stacked, key)
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        dt_ms = call.dur_ms
         if compiling:
             _OBS.histogram("executor/compile_ms",
                            sig=_sig_digest(stacked_sig)).observe(dt_ms)

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import copy
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -226,8 +226,7 @@ class Block:
             return out
 
         op = Operator(self, type, _norm(inputs), _norm(outputs), attrs)
-        if _REMAT_UNIT_STACK and REMAT_UNIT_ATTR not in op.attrs:
-            op.attrs[REMAT_UNIT_ATTR] = _REMAT_UNIT_STACK[-1]
+        _tag_units(op.attrs)
         self.ops.append(op)
         for name in op.output_names():
             if name in self.vars:
@@ -400,31 +399,61 @@ def grad_var_name(name: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Remat units: model-block boundaries for the remat policy surface
-# (BuildStrategy.remat_policy). Ops appended inside `remat_unit(name)` are
-# tagged with `__remat_unit__ = name`; the executor groups consecutive
-# same-unit ops into ONE jax.checkpoint region so a whole transformer layer
-# recomputes from its entry activations instead of saving per-op residuals.
-# The reference expressed the same boundary through RecomputeOptimizer's
-# checkpoints=[...] var list (fleet meta optimizer); here it is a trace-time
-# scope, nested scopes keep the innermost name.
-_REMAT_UNIT_STACK: List[str] = []
+# Units: the model parts a Program is made of (an encoder layer, the
+# embeddings, the loss head, the embedding rows). Ops appended inside
+# `unit(name)` are tagged `__unit__ = <path>`, nested units joined by "/";
+# the lowering writes the path into every instruction's metadata
+# (observability/scopes.py), so a device trace names the part it is in. A
+# unit may also be a remat block: `remat_unit(name)` is `unit(name,
+# remat=True)`, and its ops carry the flag `__remat__` as well: how many
+# leading components of their path name the remat block (`remat_unit_of`).
+# That is what the remat policy surface (BuildStrategy.remat_policy) reads:
+# the executor groups consecutive ops of one remat block into ONE
+# jax.checkpoint region so a whole transformer layer recomputes from its
+# entry activations instead of saving per-op residuals. The reference
+# expressed the same boundary through RecomputeOptimizer's checkpoints=[...]
+# var list (fleet meta optimizer); here it is a trace-time scope, nested
+# remat scopes keep the innermost. A unit that is only a name never changes
+# what is rematerialised.
+_UNIT_STACK: List[Tuple[str, bool]] = []
 
-REMAT_UNIT_ATTR = "__remat_unit__"
+UNIT_ATTR = "__unit__"
+REMAT_ATTR = "__remat__"
 
 
 @contextlib.contextmanager
-def remat_unit(name: str):
-    """Tag every op appended in this scope as part of remat block `name`."""
-    _REMAT_UNIT_STACK.append(str(name))
+def unit(name: str, remat: bool = False):
+    """Tag every op appended in this scope as part of model part `name`;
+    with `remat=True` also as part of remat block `name`."""
+    _UNIT_STACK.append((str(name), bool(remat)))
     try:
         yield
     finally:
-        _REMAT_UNIT_STACK.pop()
+        _UNIT_STACK.pop()
 
 
-def current_remat_unit() -> Optional[str]:
-    return _REMAT_UNIT_STACK[-1] if _REMAT_UNIT_STACK else None
+def remat_unit(name: str):
+    """Tag every op appended in this scope as part of remat block `name`."""
+    return unit(name, remat=True)
+
+
+def remat_unit_of(op) -> Optional[str]:
+    """The unit path of the remat block `op` was built in, or None. Its last
+    component is the name `remat_unit` was given."""
+    depth = op.attrs.get(REMAT_ATTR)
+    if not depth:
+        return None
+    return "/".join(op.attrs[UNIT_ATTR].split("/")[:depth])
+
+
+def _tag_units(attrs: dict) -> None:
+    if not _UNIT_STACK or UNIT_ATTR in attrs:
+        return
+    attrs[UNIT_ATTR] = "/".join(n for n, _ in _UNIT_STACK)
+    depth = max((i + 1 for i, (_, remat) in enumerate(_UNIT_STACK) if remat),
+                default=0)
+    if depth:
+        attrs[REMAT_ATTR] = depth
 
 
 _dygraph_tracer = None

@@ -149,18 +149,23 @@ def embeddings(cfg: BertConfig, src_ids, pos_ids, sent_ids, is_test=False):
 def bert_encoder(cfg: BertConfig, src_ids, pos_ids, sent_ids, input_mask,
                  is_test=False):
     """input_mask: [B, T] float (1 = token). Returns sequence output [B,T,H]."""
-    emb = embeddings(cfg, src_ids, pos_ids, sent_ids, is_test)
-    # additive mask [B,1,T]: (mask-1)*10000 → 0 for keep, -10000 for pad
-    # (the packed flash path consumes [B,1,T]; the dense path re-expands)
-    neg = layers.scale(layers.elementwise_add(input_mask,
-                                              layers.fill_constant([1], "float32", -1.0)),
-                       scale=10000.0)
-    mask4 = layers.unsqueeze(neg, [1])
+    # every part of the model is a unit (core.program.unit): the compiled
+    # step's operations carry its name, and a trace says where the time is
+    from ..core.program import remat_unit, unit
+    with unit("embed"):
+        emb = embeddings(cfg, src_ids, pos_ids, sent_ids, is_test)
+        # additive mask [B,1,T]: (mask-1)*10000 → 0 for keep, -10000 for
+        # pad (the packed flash path consumes [B,1,T]; the dense path
+        # re-expands)
+        neg = layers.scale(
+            layers.elementwise_add(input_mask,
+                                   layers.fill_constant([1], "float32", -1.0)),
+            scale=10000.0)
+        mask4 = layers.unsqueeze(neg, [1])
     x = emb
-    # each transformer block is one remat unit: under remat_policy
+    # each transformer block is also one remat unit: under remat_policy
     # "minimal"/"full" the whole block's forward is recomputed in the
     # backward pass instead of keeping its activations resident
-    from ..core.program import remat_unit
     for i in range(cfg.num_layers):
         with remat_unit(f"bert_layer_{i}"):
             x = encoder_layer(cfg, x, mask4, i, is_test)
@@ -170,18 +175,23 @@ def bert_encoder(cfg: BertConfig, src_ids, pos_ids, sent_ids, input_mask,
 def bert_pretrain_loss(cfg: BertConfig, seq_out, mlm_labels, input_mask):
     """Masked-LM loss over all positions (labels = -100 to ignore), plus
     tied-embedding decoding is approximated with its own output matrix."""
-    logits = layers.fc(seq_out, cfg.vocab_size, num_flatten_dims=2,
-                       param_attr=_attr(cfg, "mlm_out.w", _tp(cfg, None, "tp")),
-                       bias_attr=ParamAttr(name="mlm_out.b",
-                                           initializer=ConstantInitializer(0.0),
-                                           shard_spec=_tp(cfg, "tp")))
-    loss = layers.softmax_with_cross_entropy(logits, mlm_labels, ignore_index=-100)
-    # mean over non-ignored tokens
-    valid = layers.cast(layers.not_equal(
-        mlm_labels, layers.fill_constant([1], "int64", -100)), "float32")
-    total = layers.reduce_sum(layers.elementwise_mul(loss, valid))
-    denom = layers.reduce_sum(valid)
-    return layers.elementwise_div(total, denom)
+    from ..core.program import unit
+    with unit("mlm_head"):
+        logits = layers.fc(
+            seq_out, cfg.vocab_size, num_flatten_dims=2,
+            param_attr=_attr(cfg, "mlm_out.w", _tp(cfg, None, "tp")),
+            bias_attr=ParamAttr(name="mlm_out.b",
+                                initializer=ConstantInitializer(0.0),
+                                shard_spec=_tp(cfg, "tp")))
+    with unit("loss"):
+        loss = layers.softmax_with_cross_entropy(logits, mlm_labels,
+                                                 ignore_index=-100)
+        # mean over non-ignored tokens
+        valid = layers.cast(layers.not_equal(
+            mlm_labels, layers.fill_constant([1], "int64", -100)), "float32")
+        total = layers.reduce_sum(layers.elementwise_mul(loss, valid))
+        denom = layers.reduce_sum(valid)
+        return layers.elementwise_div(total, denom)
 
 
 def build_pretrain_program(cfg: BertConfig, batch_size: int, seq_len: int,

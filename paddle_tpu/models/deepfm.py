@@ -44,51 +44,56 @@ def deepfm(sparse_ids, dense_feats, vocab_size: int, num_fields: int,
     if state_mult > 1 and not fused_table:
         raise ValueError("state_mult>1 (deferred moment state) requires "
                          "fused_table=True")
-    if fused_table:
-        from paddle_tpu.initializer import RowPackInitializer
-        vis = embed_dim + 1
-        init = (RowPackInitializer(vis, vis * state_mult, -1e-2, 1e-2)
-                if row_packed else UniformInitializer(-1e-2, 1e-2))
-        both = layers.embedding(
-            sparse_ids, [vocab_size, vis * state_mult], is_sparse=is_sparse,
-            row_pack=row_packed,
-            param_attr=ParamAttr(name="fm_t", initializer=init,
-                                 shard_spec=spec))
-        if state_mult > 1:
-            both = layers.slice(both, axes=[2], starts=[0], ends=[vis])
-        w1 = layers.slice(both, axes=[2], starts=[embed_dim],
-                          ends=[embed_dim + 1])
-        emb = layers.slice(both, axes=[2], starts=[0], ends=[embed_dim])
-    else:
-        # first-order weights
-        w1 = layers.embedding(sparse_ids, [vocab_size, 1], is_sparse=is_sparse,
-                              param_attr=ParamAttr(name="fm_w1",
-                                                   initializer=UniformInitializer(-1e-4, 1e-4),
-                                                   shard_spec=spec))
-        emb = layers.embedding(sparse_ids, [vocab_size, embed_dim],
-                               is_sparse=is_sparse,
-                               param_attr=ParamAttr(name="fm_emb",
-                                                    initializer=UniformInitializer(-1e-2, 1e-2),
-                                                    shard_spec=spec))
-    first_order = layers.reduce_sum(w1, dim=[1, 2], keep_dim=False)
+    # two units (`fluid.unit`): the compiled step's operations carry
+    # the name of their part, `rows` (with the table's optimizer, below) or
+    # `dense`, and a trace says where the time is
+    with fluid.unit("rows"):
+        if fused_table:
+            from paddle_tpu.initializer import RowPackInitializer
+            vis = embed_dim + 1
+            init = (RowPackInitializer(vis, vis * state_mult, -1e-2, 1e-2)
+                    if row_packed else UniformInitializer(-1e-2, 1e-2))
+            both = layers.embedding(
+                sparse_ids, [vocab_size, vis * state_mult], is_sparse=is_sparse,
+                row_pack=row_packed,
+                param_attr=ParamAttr(name="fm_t", initializer=init,
+                                     shard_spec=spec))
+            if state_mult > 1:
+                both = layers.slice(both, axes=[2], starts=[0], ends=[vis])
+            w1 = layers.slice(both, axes=[2], starts=[embed_dim],
+                              ends=[embed_dim + 1])
+            emb = layers.slice(both, axes=[2], starts=[0], ends=[embed_dim])
+        else:
+            # first-order weights
+            w1 = layers.embedding(sparse_ids, [vocab_size, 1], is_sparse=is_sparse,
+                                  param_attr=ParamAttr(name="fm_w1",
+                                                       initializer=UniformInitializer(-1e-4, 1e-4),
+                                                       shard_spec=spec))
+            emb = layers.embedding(sparse_ids, [vocab_size, embed_dim],
+                                   is_sparse=is_sparse,
+                                   param_attr=ParamAttr(name="fm_emb",
+                                                        initializer=UniformInitializer(-1e-2, 1e-2),
+                                                        shard_spec=spec))
+    with fluid.unit("dense"):
+        first_order = layers.reduce_sum(w1, dim=[1, 2], keep_dim=False)
 
-    # second-order: embeddings [B, F, D]
-    sum_sq = layers.square(layers.reduce_sum(emb, dim=[1]))
-    sq_sum = layers.reduce_sum(layers.square(emb), dim=[1])
-    second_order = layers.scale(
-        layers.reduce_sum(layers.elementwise_sub(sum_sq, sq_sum), dim=[1]), scale=0.5)
+        # second-order: embeddings [B, F, D]
+        sum_sq = layers.square(layers.reduce_sum(emb, dim=[1]))
+        sq_sum = layers.reduce_sum(layers.square(emb), dim=[1])
+        second_order = layers.scale(
+            layers.reduce_sum(layers.elementwise_sub(sum_sq, sq_sum), dim=[1]), scale=0.5)
 
-    # deep part
-    deep = layers.reshape(emb, [0, num_fields * embed_dim])
-    deep = layers.concat([deep, dense_feats], axis=1)
-    for i, hs in enumerate(hidden_sizes):
-        deep = layers.fc(deep, hs, act="relu", name=f"deep_{i}")
-    deep_out = layers.fc(deep, 1, name="deep_out")
+        # deep part
+        deep = layers.reshape(emb, [0, num_fields * embed_dim])
+        deep = layers.concat([deep, dense_feats], axis=1)
+        for i, hs in enumerate(hidden_sizes):
+            deep = layers.fc(deep, hs, act="relu", name=f"deep_{i}")
+        deep_out = layers.fc(deep, 1, name="deep_out")
 
-    logit = layers.elementwise_add(
-        layers.elementwise_add(layers.unsqueeze(first_order, [1]),
-                               layers.unsqueeze(second_order, [1])),
-        deep_out)
+        logit = layers.elementwise_add(
+            layers.elementwise_add(layers.unsqueeze(first_order, [1]),
+                                   layers.unsqueeze(second_order, [1])),
+            deep_out)
     return logit
 
 
@@ -145,9 +150,10 @@ def build_train_program(vocab_size=100000, num_fields=26, num_dense=13,
                        shard_axis=shard_axis, is_sparse=is_sparse,
                        fused_table=fused_table, state_mult=state_mult,
                        row_packed=packed_rows is not None)
-        loss = layers.mean(
-            layers.sigmoid_cross_entropy_with_logits(logit, label))
-        prob = layers.sigmoid(logit)
+        with fluid.unit("dense"):
+            loss = layers.mean(
+                layers.sigmoid_cross_entropy_with_logits(logit, label))
+            prob = layers.sigmoid(logit)
         if embedding_optimizer is None:
             if deferred_rows is not None or packed_rows is not None:
                 raise ValueError(
@@ -163,7 +169,9 @@ def build_train_program(vocab_size=100000, num_fields=26, num_dense=13,
                         if pg[0].name in _TABLE_NAMES]
             dense_pg = [pg for pg in params_grads
                         if pg[0].name not in _TABLE_NAMES]
-            adam.apply_gradients(dense_pg)
-            table_opt.apply_gradients(table_pg)
+            with fluid.unit("dense"):
+                adam.apply_gradients(dense_pg)
+            with fluid.unit("rows"):
+                table_opt.apply_gradients(table_pg)
             main._deferred_table_optimizer = table_opt
     return main, startup, ["sparse_ids", "dense", "label"], loss, prob

@@ -15,9 +15,18 @@ capability along its natural seam:
 - **trace_span / Tracer** (tracer.py) — host-side nested wall-clock
   spans per thread, exported as chrome-trace JSON (chrome://tracing /
   Perfetto). Device-side tracing stays with jax.profiler (XPlane);
-  ``paddle_tpu.profiler.record_event`` records into BOTH so host spans
-  and XPlane annotations line up, and
-  ``python -m paddle_tpu.tools.timeline`` merges/summarizes the files.
+  every span is also a ``TraceAnnotation`` there, so under a profiler
+  session the program's spans lie beside the device operations on one
+  clock (``paddle_tpu.profiler.record_event`` is ``trace_span``).
+  ``Executor.run`` records ``executor/step`` with its phases as
+  children; ``get_tracer().spans()`` gives spans back with parent and
+  self time, and ``python -m paddle_tpu.tools.timeline``
+  merges/summarizes exported files.
+- **scopes** (scopes.py) — the names inside the compiled step: the
+  lowering writes ``[opt/]u.<unit>/op.<op type>`` into every instruction's
+  metadata from the Program IR, ``op_scopes(exe.compiled_step(main))``
+  reads phase, unit and op types back per HLO instruction, and
+  ``hottest_step()`` hands out the step this process runs.
 - **RecompileWatchdog** (watchdog.py) — the executor reports every
   executable-cache miss; past a threshold the watchdog warns once,
   naming exactly which feed's shape/dtype diverged between the cached
@@ -75,6 +84,7 @@ from . import calibrate  # noqa: F401
 from . import context  # noqa: F401
 from . import federate  # noqa: F401
 from . import perf  # noqa: F401
+from . import scopes  # noqa: F401
 from .alerts import (Alert, AlertFiringError, AlertManager,  # noqa: F401
                      FileSink, WebhookSink, get_alert_manager,
                      install_alert_manager)
@@ -102,8 +112,8 @@ from .registry import (Counter, Gauge, Histogram, Registry,  # noqa: F401
 from .slo import (BURN_RATE_WINDOWS, SloEngine, SloSpec,  # noqa: F401
                   default_slos)
 from .steps import StepProfiler, get_step_profiler  # noqa: F401
-from .tracer import (Tracer, get_tracer, server_span,  # noqa: F401
-                     start_trace, trace_span)
+from .tracer import (Tracer, get_tracer, pair_spans,  # noqa: F401
+                     server_span, start_trace, step_span, trace_span)
 from .watchdog import (RecompileWarning, RecompileWatchdog,  # noqa: F401
                        diff_signatures, get_watchdog)
 
@@ -115,7 +125,8 @@ __all__ = [
     "TraceContext", "context",
     "FederatedScraper", "ScrapeTarget", "install_scraper", "get_scraper",
     "device_memory_stats", "per_device_state_bytes", "record_state_memory",
-    "Tracer", "get_tracer", "trace_span", "start_trace", "server_span",
+    "Tracer", "get_tracer", "trace_span", "step_span", "start_trace",
+    "server_span", "pair_spans", "scopes",
     "RecompileWarning", "RecompileWatchdog", "diff_signatures",
     "get_watchdog",
     "FlightRecorder", "get_flight_recorder", "is_oom",

@@ -52,6 +52,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..core.registry import register_op
 from ..core.selected_rows import SelectedRows
+from ..observability.scopes import unit_scope
 
 SENTINEL = 2**31 - 1
 
@@ -113,17 +114,21 @@ def uniq_merge(ids, rows, r):
         return (jnp.full((r,), SENTINEL, jnp.int32),
                 jnp.zeros((r, d), rows.dtype),
                 jnp.zeros((r,), jnp.int32))
-    order = jnp.argsort(ids)
-    sids = ids[order]
-    srows = rows[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), sids[1:] != sids[:-1]])
-    seg = (jnp.cumsum(first) - 1).astype(jnp.int32)
-    nu = seg[-1] + 1
-    utot = jnp.zeros((qn, d), srows.dtype).at[seg].add(srows)
-    rep = jnp.full((qn,), 0, jnp.int32).at[seg].max(order.astype(jnp.int32))
-    # unique ids via the representative positions — an O(r) element gather
-    # from the small id array instead of a second O(r) scatter
-    uids = jnp.where(jnp.arange(qn) < nu, ids[rep], SENTINEL)
+    # the sort and the merge are a part of the row update with a name of
+    # its own in the compiled step (`rows/merge` under the model's `rows`)
+    with unit_scope("merge"):
+        order = jnp.argsort(ids)
+        sids = ids[order]
+        srows = rows[order]
+        first = jnp.concatenate([jnp.ones((1,), bool), sids[1:] != sids[:-1]])
+        seg = (jnp.cumsum(first) - 1).astype(jnp.int32)
+        nu = seg[-1] + 1
+        utot = jnp.zeros((qn, d), srows.dtype).at[seg].add(srows)
+        rep = jnp.full((qn,), 0, jnp.int32).at[seg].max(
+            order.astype(jnp.int32))
+        # unique ids via the representative positions — an O(r) element
+        # gather from the small id array instead of a second O(r) scatter
+        uids = jnp.where(jnp.arange(qn) < nu, ids[rep], SENTINEL)
     if qn < r:
         uids = jnp.concatenate([uids, jnp.full((r - qn,), SENTINEL, jnp.int32)])
         utot = jnp.concatenate([utot, jnp.zeros((r - qn, d), utot.dtype)])

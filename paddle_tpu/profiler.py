@@ -7,7 +7,10 @@ chrome://tracing by tools/timeline.py.
 
 TPU-native: jax.profiler captures an XPlane trace viewable in
 TensorBoard/Perfetto (the chrome-trace analog); RecordEvent becomes
-TraceAnnotation (named scopes visible in the trace and in HLO metadata).
+`observability.trace_span`, which is both a host span of the program's
+tracer and a TraceAnnotation in the XPlane trace. What names the device
+operations is not an annotation but the name scopes the lowering writes into
+the compiled step's metadata (observability/scopes.py).
 """
 from __future__ import annotations
 
@@ -18,6 +21,8 @@ from collections import defaultdict
 from typing import Optional
 
 import jax
+
+from .observability.tracer import trace_span
 
 
 _active = {}
@@ -66,18 +71,12 @@ def profiler(state: str = "All", sorted_key: Optional[str] = None,
         stop_profiler(sorted_key, profile_path)
 
 
-@contextlib.contextmanager
-def record_event(name: str, **args):
-    """RecordEvent RAII parity (platform/profiler.h:81): annotates the
-    device trace AND the compiled HLO (jax.profiler.TraceAnnotation,
-    visible per-fusion in XLA tooling) AND records a host-side span in
-    `observability.get_tracer()` — so the same named region lines up in
-    the XPlane trace and the chrome-trace export of the host tracer.
-    Extra kwargs become chrome-trace span args."""
-    from .observability.tracer import trace_span
-
-    with trace_span(name, **args), jax.profiler.TraceAnnotation(name):
-        yield
+# RecordEvent RAII parity (platform/profiler.h:81) is `trace_span` itself: a
+# host-side span in `observability.get_tracer()` and, under a profiler
+# session, a jax.profiler.TraceAnnotation of the same name on a host line of
+# the XPlane trace, so the region lines up with the device operations. It
+# annotates nothing in the compiled HLO: only a name scope at trace time does.
+record_event = trace_span
 
 
 class _OpTimer:

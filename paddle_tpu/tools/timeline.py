@@ -37,6 +37,8 @@ import json
 import os
 from typing import Dict, List, Optional
 
+from ..observability.tracer import pair_spans
+
 __all__ = ["find_xplanes", "xplane_to_chrome_trace", "load_trace",
            "merge_traces", "merge_fleet_traces", "summarize",
            "format_summary", "format_flight", "main"]
@@ -130,37 +132,11 @@ def merge_traces(traces: List[dict],
 # -- fleet merge -------------------------------------------------------------
 
 def _spans(trace: dict, index: int) -> List[dict]:
-    """Pair B/E events per (pid, tid) into spans: {name, ts, dur, args,
-    pid, tid, trace: index}. Stray E events are dropped; an unclosed B
-    becomes a zero-duration span (a process that died mid-span still
-    shows where it was)."""
-    stacks: Dict[tuple, list] = {}
-    spans: List[dict] = []
-    events = [ev for ev in trace.get("traceEvents", [])
-              if ev.get("ph") in ("B", "E")]
-    events.sort(key=lambda ev: ev.get("ts", 0))
-    for ev in events:
-        key = (ev.get("pid"), ev.get("tid"))
-        stack = stacks.setdefault(key, [])
-        if ev.get("ph") == "B":
-            stack.append(ev)
-        elif stack:
-            b = stack.pop()
-            spans.append({"name": b.get("name", "?"),
-                          "ts": float(b.get("ts", 0)),
-                          "dur": float(ev.get("ts", 0)) - float(
-                              b.get("ts", 0)),
-                          "args": b.get("args") or {},
-                          "pid": b.get("pid"), "tid": b.get("tid"),
-                          "trace": index})
-    for stack in stacks.values():
-        for b in stack:
-            spans.append({"name": b.get("name", "?"),
-                          "ts": float(b.get("ts", 0)), "dur": 0.0,
-                          "args": b.get("args") or {},
-                          "pid": b.get("pid"), "tid": b.get("tid"),
-                          "trace": index})
-    return spans
+    """The trace's B/E events paired into spans (`tracer.pair_spans`: per
+    (pid, tid), stray E events dropped, an unclosed B kept as a
+    zero-duration span), each tagged with the trace's index."""
+    return [dict(span, trace=index) for span in
+            pair_spans(trace.get("traceEvents", []), keep_open=True)]
 
 
 def _rpc_pairs(all_spans: List[dict]) -> List[tuple]:
@@ -292,9 +268,9 @@ def merge_fleet_traces(traces: List[dict],
 def summarize(trace: dict) -> Dict[str, dict]:
     """Per-span-name totals: {"name": {count, total_ms, avg_ms, max_ms}}.
 
-    Handles both duration forms: B/E pairs (matched per pid/tid with a
-    stack, so nesting is honored and stray E events are ignored) and
-    complete "X" events carrying an explicit dur."""
+    Handles both duration forms: B/E pairs (`tracer.pair_spans`: matched per
+    pid/tid with a stack, so nesting is honored and stray E events are
+    ignored) and complete "X" events carrying an explicit dur."""
     stats: Dict[str, dict] = {}
 
     def add(name, dur_us):
@@ -305,22 +281,12 @@ def summarize(trace: dict) -> Dict[str, dict]:
         s["total_ms"] += ms
         s["max_ms"] = max(s["max_ms"], ms)
 
-    stacks: Dict[tuple, list] = {}
-    events = [ev for ev in trace.get("traceEvents", [])
-              if ev.get("ph") in ("B", "E", "X")]
-    events.sort(key=lambda ev: ev.get("ts", 0))
+    events = trace.get("traceEvents", [])
     for ev in events:
-        ph = ev.get("ph")
-        if ph == "X":
+        if ev.get("ph") == "X":
             add(ev.get("name", "?"), float(ev.get("dur", 0)))
-            continue
-        key = (ev.get("pid"), ev.get("tid"))
-        stack = stacks.setdefault(key, [])
-        if ph == "B":
-            stack.append((ev.get("name", "?"), float(ev.get("ts", 0))))
-        elif stack:  # E closes the innermost open B on this thread
-            name, ts0 = stack.pop()
-            add(name, float(ev.get("ts", 0)) - ts0)
+    for span in pair_spans(events):
+        add(span["name"], span["dur"])
     for s in stats.values():
         s["avg_ms"] = s["total_ms"] / max(s["count"], 1)
     return stats

@@ -294,6 +294,14 @@ def test_federated_scraper_merges_and_derives_signals():
         float(s.get("value") or 0.0)
         for s in get_registry().series(deep=True)
         if s.get("name") == "steps/anomalies")
+    # the same for shard 0's pull latency: the signal is the worst p99 over
+    # the targets, and which ps tests ran in this process first depends on
+    # how the run's files were dealt out
+    pre_pull_p99 = max(
+        (float((s.get("summary") or {}).get("p99") or 0.0)
+         for s in get_registry().series(deep=True)
+         if s.get("name") == "ps/shard_pull_ms"
+         and (s.get("labels") or {}).get("shard") == "0"), default=0.0)
     stub = [{"name": "ps/shard_pull_ms", "type": "summary",
              "labels": {"shard": "0"},
              "summary": {"count": 4, "sum": 8.0, "p50": 2.0, "p95": 3.0,
@@ -317,14 +325,16 @@ def test_federated_scraper_merges_and_derives_signals():
                    for s in ps_t["series"])
         sig = doc["signals"]
         # per-key: the pserver target may carry real shard_pull/queue
-        # series from earlier in-process tests alongside the stub's
-        assert sig["ps_pull_p99_ms"]["0"] == 3.5
+        # series from earlier in-process tests alongside the stub's, and the
+        # signal is the worst of them
+        expected_pull = max(3.5, pre_pull_p99)
+        assert sig["ps_pull_p99_ms"]["0"] == expected_pull
         assert sig["queue_depth"]["w0"] == 7.0
         assert sig["stragglers"] == 2.0 + pre_anomalies
         assert sig["targets_unreachable"] == 1
         reg = get_registry()
         assert reg.gauge("autoscale/ps_pull_p99_ms",
-                         shard="0").value == 3.5
+                         shard="0").value == expected_pull
         assert reg.gauge("autoscale/queue_depth",
                          process="w0").value == 7.0
         assert reg.gauge("autoscale/targets_unreachable").value == 1.0

@@ -262,7 +262,8 @@ def test_tracer_event_cap_drops_and_counts():
     for i in range(4):
         with _span_into(t, f"s{i}"):
             pass
-    assert len(t) == 4 and t.dropped == 4  # first 2 spans kept, rest dropped
+    assert len(t) == 4 and t.dropped == 4  # a ring: the newest 2 spans stay
+    assert [s["name"] for s in t.spans()] == ["s2", "s3"]
 
 
 class _span_into:
@@ -277,6 +278,110 @@ class _span_into:
 
     def __exit__(self, *exc):
         self.tracer.end(self.name)
+
+
+def test_the_ring_keeps_the_newest_events_of_a_long_run():
+    t = obs.Tracer(max_events=100)
+    for i in range(1000):
+        with _span_into(t, f"step{i}"):
+            with _span_into(t, "leaf"):
+                pass
+    assert len(t) == 100 and t.dropped == 4 * 1000 - 100
+    names = [s["name"] for s in t.spans() if s["name"] != "leaf"]
+    assert names[-1] == "step999" and names[0] == "step975"
+    # the E of a span whose B fell out of the ring closes nothing
+    assert all(s["dur"] >= 0 for s in t.spans())
+
+
+def test_spans_come_back_with_parent_and_self_time():
+    import time
+
+    with obs.trace_span("root", step=7):
+        with obs.trace_span("root/a"):
+            time.sleep(0.002)
+            with obs.trace_span("root/a/leaf"):
+                time.sleep(0.002)
+        with obs.trace_span("root/b"):
+            time.sleep(0.001)
+    with obs.trace_span("other"):
+        pass
+    spans = obs.get_tracer().spans()
+    assert [s["name"] for s in spans] == ["root", "root/a", "root/a/leaf",
+                                          "root/b", "other"]
+    root, a, leaf, b, other = spans
+    assert root["parent"] is None and other["parent"] is None
+    assert (a["parent"], leaf["parent"], b["parent"]) == (0, 1, 0)
+    assert root["args"] == {"step": 7}
+    # self time: the duration less what the child spans cover
+    assert a["self"] == pytest.approx(a["dur"] - leaf["dur"])
+    assert root["self"] == pytest.approx(root["dur"] - a["dur"] - b["dur"])
+    assert leaf["self"] == leaf["dur"] >= 2000 and root["self"] >= 0
+    assert sum(s["self"] for s in spans[:4]) == pytest.approx(root["dur"])
+
+
+def test_a_span_whose_body_raises_still_closes_and_reads_its_duration():
+    span = obs.trace_span("doomed")
+    with pytest.raises(ValueError):
+        with obs.trace_span("outer"):
+            with span:
+                raise ValueError("boom")
+    spans = obs.get_tracer().spans()
+    assert [(s["name"], s["parent"]) for s in spans] == [("outer", None),
+                                                         ("doomed", 0)]
+    assert span.dur_ms == pytest.approx(spans[1]["dur"] * 1e-3)
+    # with the tracer off a span records nothing and still times itself
+    tracer = obs.get_tracer()
+    tracer.enabled = False
+    try:
+        with obs.trace_span("unseen") as quiet:
+            pass
+    finally:
+        tracer.enabled = True
+    assert quiet.dur_ms >= 0 and len(tracer.spans()) == 2
+
+
+PHASES = ["executor/feed", "executor/state_in", "executor/telemetry",
+          "executor/state_out", "executor/epilogue"]
+
+
+@pytest.mark.parametrize("path,call", [
+    ("plain", "executor/run"), ("mesh", "compiled_program/run")])
+def test_a_run_records_its_phases_under_one_step_span(path, call):
+    import jax
+
+    import paddle_tpu as fluid
+
+    main, startup, y = _tiny_program()
+    program = main
+    if path == "mesh":
+        program = fluid.CompiledProgram(main).with_data_parallel(
+            places=jax.devices()[:2])
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(startup)
+    feed = {"x": np.zeros((2, 3), np.float32)}
+    for _ in range(3):
+        exe.run(program, feed=feed, fetch_list=[y], return_numpy=False)
+    spans = obs.get_tracer().spans()
+    roots = [i for i, s in enumerate(spans) if s["name"] == "executor/step"]
+    assert len(roots) == 4 and all(spans[i]["parent"] is None for i in roots)
+    ordinals = [spans[i]["args"]["step"] for i in roots]
+    assert ordinals == list(range(ordinals[0], ordinals[0] + 4))
+    last = roots[-1]
+    children = [s for s in spans if s["parent"] == last]
+    # the same child names on both paths, the jitted call under its own
+    assert sorted(s["name"] for s in children) == sorted(PHASES + [call])
+    assert sum(s["dur"] for s in children) <= spans[last]["dur"]
+    assert spans[last]["self"] == pytest.approx(
+        spans[last]["dur"] - sum(s["dur"] for s in children))
+    compiled = [s["name"] for s in spans if s["parent"] == roots[1]]
+    assert call.replace("run", "compile+run") in compiled
+    # a blocking fetch is a phase of its own
+    exe.run(program, feed=feed, fetch_list=[y])
+    spans = obs.get_tracer().spans()
+    fetch = [s for s in spans if s["name"] == "executor/fetch"][-1]
+    assert spans[fetch["parent"]]["name"] == "executor/step"
+    assert fetch["parent"] == max(i for i, s in enumerate(spans)
+                                  if s["name"] == "executor/step")
 
 
 # -- timeline CLI ----------------------------------------------------------
@@ -384,6 +489,7 @@ def test_executor_cache_and_compile_metrics():
 def test_record_event_routes_to_host_tracer():
     from paddle_tpu import profiler
 
+    assert profiler.record_event is obs.trace_span
     with profiler.record_event("annotated/region", tag=3):
         pass
     evs = _span_events(obs.get_tracer().export_chrome_trace())

@@ -213,8 +213,8 @@ def test_remat_unit_attr_tagging():
         with fluid.remat_unit("u0"):
             h = fluid.layers.fc(x, 4, act="relu")
         fluid.layers.fc(h, 1)
-    tagged = [op.attrs.get("__remat_unit__")
-              for op in main.global_block().ops]
+    from paddle_tpu.core.program import remat_unit_of
+    tagged = [remat_unit_of(op) for op in main.global_block().ops]
     assert "u0" in tagged            # ops inside the scope are tagged
     assert tagged[-1] is None        # ops outside are not
 
