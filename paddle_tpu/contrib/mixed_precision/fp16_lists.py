@@ -20,6 +20,12 @@ WHITE_LIST = {"conv2d", "conv3d", "depthwise_conv2d", "conv2d_transpose",
 # layer_norm is gray (not listed): its kernel takes bf16 activations and
 # does the statistics in f32 internally (nn_ops._layer_norm) — black-listing
 # it would bounce the residual stream through f32 HBM at every layer.
+# linear_softmax_with_cross_entropy is gray (not listed): it multiplies in
+# the dtype its activations arrive in (bf16 after a bf16 trunk) with float32
+# accumulation, keeps the logits, the softmax and the loss in float32 and
+# sums dW / dBias in float32 straight into the float32 masters
+# (nn_ops._linear_ce) — white-listing it would round Bias and both
+# gradients to bf16, black-listing it would run the three products in f32.
 BLACK_LIST = {"cross_entropy", "mean",
               "reduce_mean", "softmax", "sum",
               "exp", "log", "rsqrt", "sqrt"}

@@ -376,6 +376,41 @@ def softmax_with_cross_entropy(logits: Variable, label: Variable,
     return loss
 
 
+def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
+                                      size: int, ignore_index: int = -100,
+                                      param_attr=None, bias_attr=None,
+                                      return_rows: bool = False, name=None):
+    """`softmax_with_cross_entropy(fc(input, size, num_flatten_dims=rank-1),
+    label, ignore_index=ignore_index)` as one op (TPU extension, no
+    reference analog): the same per-position loss [..., 1] and the same
+    gradients, but only the positions whose label is not `ignore_index` are
+    projected onto the [hidden, size] matrix, a chunk at a time, so the
+    [positions, size] logits never exist (ops/nn_ops.py `_linear_ce`). For a
+    masked-LM head, where 85% of the labels are ignored. The parameters are
+    those `fc` would create (weight [hidden, size], then bias [size]).
+    `return_rows=True` also returns the rows the op projected (whole
+    chunks) and the labelled count, both int32 scalars."""
+    helper = LayerHelper("fc", name=name)
+    w = helper.create_parameter(param_attr, shape=[input.shape[-1], size],
+                                dtype=input.dtype)
+    b = helper.create_parameter(bias_attr, shape=[size], dtype=input.dtype,
+                                is_bias=True)
+    loss = helper.create_variable_for_type_inference(
+        input.dtype, shape=tuple(input.shape[:-1]) + (1,))
+    rows = helper.create_variable_for_type_inference("int32", shape=())
+    labelled = helper.create_variable_for_type_inference("int32", shape=())
+    helper.append_op(type="linear_softmax_with_cross_entropy",
+                     inputs={"X": [input.name], "W": [w.name],
+                             "Bias": [b.name], "Label": [label.name]},
+                     outputs={"Loss": [loss.name],
+                              "RowsComputed": [rows.name],
+                              "Labelled": [labelled.name]},
+                     attrs={"ignore_index": ignore_index})
+    if return_rows:
+        return loss, rows, labelled
+    return loss
+
+
 def sigmoid_cross_entropy_with_logits(x, label, ignore_index=-100, name=None,
                                       normalize=False) -> Variable:
     helper = LayerHelper("sigmoid_cross_entropy_with_logits", name=name)

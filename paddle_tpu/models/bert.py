@@ -173,19 +173,44 @@ def bert_encoder(cfg: BertConfig, src_ids, pos_ids, sent_ids, input_mask,
 
 
 def bert_pretrain_loss(cfg: BertConfig, seq_out, mlm_labels, input_mask):
-    """Masked-LM loss over all positions (labels = -100 to ignore), plus
-    tied-embedding decoding is approximated with its own output matrix."""
+    """Masked-LM loss: the mean, over the positions whose label is not -100,
+    of the softmax cross-entropy of an untied [hidden, vocab] output matrix
+    (`mlm_out.w`, `mlm_out.b`; tied-embedding decoding is approximated with
+    it).
+
+    Without a tensor-parallel axis the head is one op,
+    `layers.linear_softmax_with_cross_entropy`: it projects the labelled
+    positions only (BERT labels 15%), a chunk at a time, and is exact — the
+    loss and every gradient are the numbers the dense pair gives, because
+    `softmax_with_cross_entropy(ignore_index=-100)` gives an ignored
+    position loss 0 and gradient exactly 0. Under a data-parallel mesh it
+    runs per data shard. With `cfg.tp_axis` set the output matrix is
+    sharded over the vocabulary and the head stays the dense
+    `fc` -> `softmax_with_cross_entropy` pair over all positions, which
+    GSPMD partitions and `parallel/tensor_parallel.py` recognises: a
+    vocabulary-sharded softmax over compacted rows needs collectives the
+    fused op does not have."""
     from ..core.program import unit
-    with unit("mlm_head"):
-        logits = layers.fc(
-            seq_out, cfg.vocab_size, num_flatten_dims=2,
-            param_attr=_attr(cfg, "mlm_out.w", _tp(cfg, None, "tp")),
-            bias_attr=ParamAttr(name="mlm_out.b",
-                                initializer=ConstantInitializer(0.0),
-                                shard_spec=_tp(cfg, "tp")))
+    from ..observability import get_registry
+    get_registry().counter(
+        "models/bert_head_built",
+        path="dense" if cfg.tp_axis else "labelled_rows").inc()
+    w_attr = _attr(cfg, "mlm_out.w", _tp(cfg, None, "tp"))
+    b_attr = ParamAttr(name="mlm_out.b", initializer=ConstantInitializer(0.0),
+                       shard_spec=_tp(cfg, "tp"))
+    if cfg.tp_axis is None:
+        with unit("mlm_head"):
+            loss = layers.linear_softmax_with_cross_entropy(
+                seq_out, mlm_labels, cfg.vocab_size, ignore_index=-100,
+                param_attr=w_attr, bias_attr=b_attr)
+    else:
+        with unit("mlm_head"):
+            logits = layers.fc(seq_out, cfg.vocab_size, num_flatten_dims=2,
+                               param_attr=w_attr, bias_attr=b_attr)
+        with unit("loss"):
+            loss = layers.softmax_with_cross_entropy(logits, mlm_labels,
+                                                     ignore_index=-100)
     with unit("loss"):
-        loss = layers.softmax_with_cross_entropy(logits, mlm_labels,
-                                                 ignore_index=-100)
         # mean over non-ignored tokens
         valid = layers.cast(layers.not_equal(
             mlm_labels, layers.fill_constant([1], "int64", -100)), "float32")

@@ -105,13 +105,16 @@ def _fused_conv_bn(ctx, inputs, attrs):
     }
 
 
-def _per_data_shard(ctx, fn, arrays, key):
-    """``fn(*arrays, key)`` for a Mosaic kernel under a mesh. GSPMD cannot
-    partition a Mosaic call (the lowering refuses it outright), so the call
-    runs inside a shard_map over the whole mesh: dim 0 of every array (the
-    batch) is split over the data axis when it divides, everything else is
-    replicated, and each data shard folds its index into the dropout key so
-    shards do not repeat each other's masks."""
+def _per_data_shard(ctx, fn, arrays, key, replicated=()):
+    """``fn(*arrays, *replicated, key)`` for what GSPMD cannot partition
+    under a mesh: a Mosaic kernel (the lowering refuses it outright), a
+    compaction of each shard's own rows. The call runs inside a shard_map
+    over the whole mesh: dim 0 of every one of `arrays` and of every result
+    (the batch) is split over the data axis when it divides, everything else
+    — `replicated` whole: parameters, whose gradients the shard_map's
+    transpose sums over the shards — is replicated, and each data shard
+    folds its index into the dropout key so shards do not repeat each
+    other's masks."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.collective import shard_map
@@ -127,8 +130,10 @@ def _per_data_shard(ctx, fn, arrays, key):
             key = jax.random.fold_in(key, jax.lax.axis_index(axis))
         return fn(*arrays, key)
 
-    return shard_map(body, mesh, in_specs=(P(),) + (spec,) * len(arrays),
-                     out_specs=spec)(key, *arrays)
+    return shard_map(
+        body, mesh,
+        in_specs=(P(),) + (spec,) * len(arrays) + (P(),) * len(replicated),
+        out_specs=spec)(key, *arrays, *replicated)
 
 
 def _under_mesh(ctx) -> bool:

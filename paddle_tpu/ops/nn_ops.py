@@ -625,6 +625,168 @@ def _softmax_with_cross_entropy(ctx, inputs, attrs):
     return {"Loss": [loss], "Softmax": [jnp.exp(logp)]}
 
 
+# a loop iteration's float32 logits stay under this many bytes
+_CE_CHUNK_LOGITS_BYTES = 128 << 20
+
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def linear_ce_chunk_rows(n_pos: int, vocab: int) -> int:
+    """Rows one iteration of the labelled-rows loop projects: the largest
+    power of two whose float32 [rows, vocab] logits stay under
+    _CE_CHUNK_LOGITS_BYTES (1024 rows at BERT's 30,522: eight MXU tiles
+    high), never more than the positions there are, rounded up to a
+    sublane multiple."""
+    fit = max(8, _CE_CHUNK_LOGITS_BYTES // (4 * vocab))
+    return min(1 << (fit.bit_length() - 1), _cdiv(n_pos, 8) * 8)
+
+
+def _labelled_first(valid, chunk):
+    """Order the positions with the labelled ones first, both kept in their
+    own order. Returns `rank` [n_pos] (position -> slot), `order` (slot ->
+    position, padded to whole chunks with 0) and the labelled count."""
+    n_pos = valid.shape[0]
+    seen = jnp.cumsum(valid.astype(jnp.int32))
+    n = seen[-1]
+    pos = jnp.arange(n_pos, dtype=jnp.int32)
+    rank = jnp.where(valid, seen - 1, n + pos - seen)
+    order = jnp.zeros((_cdiv(n_pos, chunk) * chunk,), jnp.int32)
+    order = order.at[rank].set(pos, unique_indices=True,
+                               mode="promise_in_bounds")
+    return rank, order, n
+
+
+def _rows(a, idx):
+    return a.at[idx].get(mode="promise_in_bounds")
+
+
+def _chunk_logits(x, w, b, order, lbl, at, chunk):
+    """The `chunk` ordered positions from slot `at`: which they are, their
+    rows of x, the float32 logits of those rows and a one-hot mask of their
+    labels."""
+    slots = lax.dynamic_slice(order, (at,), (chunk,))
+    xc = _rows(x, slots)
+    logits = jnp.dot(xc, w, preferred_element_type=jnp.float32) + b
+    hot = (lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+           == _rows(lbl, slots)[:, None])
+    return slots, xc, logits, hot
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _linear_ce(x, w, b, lbl, ignore, chunk):
+    """Per-position softmax cross-entropy of `x @ w + b` against hard labels
+    `lbl`, 0 where the label is `ignore` — computed on the labelled rows
+    only, `chunk` of them at a time, so that [positions, vocab] logits never
+    exist. x [N, H]; w [H, V] and b [V] are rounded to x's dtype for the
+    products, which accumulate in float32 like everything after them; lbl
+    [N] int. Exact for any number of labelled rows: the loop runs
+    ceil(labelled / chunk) times, in the backward pass too (jax does not
+    reverse a loop of dynamic length by itself, hence the custom_vjp)."""
+    loss, _ = _linear_ce_fwd(x, w, b, lbl, ignore, chunk)
+    return loss
+
+
+def _linear_ce_fwd(x, w, b, lbl, ignore, chunk):
+    valid = lbl != ignore
+    rank, order, n = _labelled_first(valid, chunk)
+    w_lo, bf = w.astype(x.dtype), b.astype(jnp.float32)
+
+    def body(i, carry):
+        loss_c, lse_c = carry
+        at = i * chunk
+        _, _, logits, hot = _chunk_logits(x, w_lo, bf, order, lbl, at, chunk)
+        m = jnp.max(logits, axis=1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=1))
+        picked = jnp.sum(jnp.where(hot, logits, 0.0), axis=1)
+        return (lax.dynamic_update_slice(loss_c, lse - picked, (at,)),
+                lax.dynamic_update_slice(lse_c, lse, (at,)))
+
+    zeros = jnp.zeros(order.shape, jnp.float32)
+    loss_c, lse_c = lax.fori_loop(0, _cdiv(n, chunk), body, (zeros, zeros))
+    loss = jnp.where(valid, _rows(loss_c, rank), 0.0)
+    return loss, (x, w, b, lbl, rank, order, n, lse_c)
+
+
+def _linear_ce_bwd(ignore, chunk, res, g):
+    x, w, b, lbl, rank, order, n, lse_c = res
+    valid = lbl != ignore
+    w_lo, bf = w.astype(x.dtype), b.astype(jnp.float32)
+    g = g.astype(jnp.float32)
+    row = jnp.arange(chunk, dtype=jnp.int32)
+
+    def body(i, carry):
+        dx_c, dw, db = carry
+        at = i * chunk
+        slots, xc, logits, hot = _chunk_logits(x, w_lo, bf, order, lbl, at,
+                                               chunk)
+        # slots from the labelled count on hold ignored positions
+        gc = jnp.where(at + row < n, _rows(g, slots), 0.0)
+        lse = lax.dynamic_slice(lse_c, (at,), (chunk,))
+        dl = (jnp.exp(logits - lse[:, None]) - hot) * gc[:, None]
+        dl_lo = dl.astype(x.dtype)
+        dxc = lax.dot_general(dl_lo, w_lo, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dw = dw + lax.dot_general(xc, dl_lo, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return (lax.dynamic_update_slice(dx_c, dxc.astype(x.dtype), (at, 0)),
+                dw, db + jnp.sum(dl, axis=0))
+
+    dx_c, dw, db = lax.fori_loop(
+        0, _cdiv(n, chunk), body,
+        (jnp.zeros((order.shape[0], x.shape[1]), x.dtype),
+         jnp.zeros(w.shape, jnp.float32), jnp.zeros(b.shape, jnp.float32)))
+    dx = jnp.where(valid[:, None], _rows(dx_c, rank), 0)
+    return dx, dw.astype(w.dtype), db.astype(b.dtype), None
+
+
+_linear_ce.defvjp(_linear_ce_fwd, _linear_ce_bwd)
+
+
+@register_op("linear_softmax_with_cross_entropy", nondiff_inputs=["Label"])
+def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
+    """softmax_with_cross_entropy(X @ W + Bias, Label, ignore_index) in one
+    op that projects the labelled positions only (`_linear_ce`): the loss
+    and every gradient are those of the pair, the positions whose label is
+    ignore_index (loss 0, gradient exactly 0 there too) are never
+    multiplied. Under a mesh each data shard compacts its own positions (a
+    global compaction would make GSPMD gather the batch) and the gradients
+    of W and Bias are summed over the shards once, by the shard_map's own
+    transpose. Also returns the rows it projected (whole chunks) and the
+    labelled count, summed over the shards."""
+    from ..observability import get_registry
+    from .fused_ops import _per_data_shard, _under_mesh
+
+    (x,) = inputs["X"]
+    (w,) = inputs["W"]
+    (label,) = inputs["Label"]
+    (b,) = inputs["Bias"]
+    ignore = attrs.get("ignore_index", -100)
+    path = "per_data_shard" if _under_mesh(ctx) else "whole"
+
+    def head(x, idx, w, b, key=None):
+        flat = idx.reshape(-1)
+        chunk = linear_ce_chunk_rows(flat.shape[0], w.shape[1])
+        obs = get_registry()
+        obs.counter("ops/linear_ce_lowered", path=path).inc()
+        obs.gauge("ops/linear_ce_chunk_rows").set(chunk)
+        loss = _linear_ce(x.reshape(flat.shape[0], -1), w, b, flat, ignore,
+                          chunk)
+        n = jnp.sum(flat != ignore, dtype=jnp.int32)
+        rows = _cdiv(n, chunk) * chunk
+        return loss.reshape(idx.shape), jnp.stack([rows, n])[None]
+
+    idx = label.reshape(x.shape[:-1] + (1,))
+    if path == "whole":
+        loss, counts = head(x, idx, w, b)
+    else:
+        loss, counts = _per_data_shard(ctx, head, (x, idx), None,
+                                       replicated=(w, b))
+    rows, n = jnp.sum(counts, axis=0)
+    return {"Loss": [loss], "RowsComputed": [rows], "Labelled": [n]}
+
+
 @register_op("sigmoid_cross_entropy_with_logits", nondiff_inputs=["Label"])
 def _sigmoid_ce(ctx, inputs, attrs):
     (x,) = inputs["X"]

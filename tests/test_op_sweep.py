@@ -877,6 +877,15 @@ for _k in ["hard_shrink", "softshrink", "thresholded_relu", "maxout",
         FIXTURES[_k].grad = None
 
 
+# the masked-LM head on the labelled rows only (drawn last, so that the
+# fixtures above keep the values the seed has always given them); its
+# parity with the dense pair is tests/test_linear_ce.py's
+FIXTURES["linear_softmax_with_cross_entropy"] = Fx(
+    {"X": sym(2, 6, 8), "W": sym(8, 10), "Bias": sym(10, scale=0.1),
+     "Label": np.array([3, -100, 9, -100, -100, 0, -100, -100, 7, 7, -100,
+                        1], "int64").reshape(2, 6, 1)},
+    {"ignore_index": -100}, outs=("Loss", "RowsComputed", "Labelled"),
+    grad="X", gout="Loss", delta=1e-3)
 # long-tail ops that are smooth W.R.T. THE PERTURBED SLOT under the
 # harness's fixed PRNG key: sampled ops (nce, sample_logits) draw the
 # same samples on every FD evaluation, and selection ops (multiplex,

@@ -417,12 +417,15 @@ def test_structural_tp_derivation_matches_hand_rules():
     def derived(program):
         return {k: tuple(v) for k, v in tp.derive_tp_specs(program).items()}
 
-    # BERT-base shapes (hand rules live in MEGATRON_RULES; build without
-    # build-time shard_spec so only the rules speak)
+    # BERT-base shapes (hand rules live in MEGATRON_RULES; the build-time
+    # shard_specs are cleared below so only the rules speak). Built as the
+    # tensor-parallel program it is derived for: that one has the dense
+    # `mul` -> `softmax_with_cross_entropy` head the derivation recognises,
+    # a program without `tp_axis` has the fused labelled-rows head
     cfg = bert.BertConfig(vocab_size=30522, hidden_size=768, num_layers=2,
                           num_heads=12, ffn_size=3072, max_position=512,
                           hidden_dropout=0.1, attn_dropout=0.1,
-                          use_flash_attention=False)
+                          use_flash_attention=False, tp_axis="tp")
     main, _, _, _ = bert.build_pretrain_program(cfg, 2, 16)
     for p in main.all_parameters():   # clear any build-time annotations
         p.shard_spec = None

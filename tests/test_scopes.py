@@ -65,12 +65,19 @@ def test_every_dot_names_its_layer_and_its_phase(bert_scopes):
         # attention's own two; backward matmuls keep their layer's name
         assert by_unit[(f"bert_layer_{i}", "fwd")] >= 4
         assert by_unit[(f"bert_layer_{i}", "bwd")] >= 8
+    # the head is one op with a loop in each pass: the chunk's logits
+    # forward; dX and dW backward, and the logits again unless XLA finds the
+    # forward's (here one chunk covers every position)
     assert by_unit[("mlm_head", "fwd")] == 1
-    assert by_unit[("mlm_head", "bwd")] == 2
+    assert by_unit[("mlm_head", "bwd")] in (2, 3)
     assert {u for u, _ in by_unit} == {"mlm_head"} | {
         f"bert_layer_{i}" for i in range(LAYERS)}
-    assert all("mul" in s.op_types or "flash_attention" in s.op_types
+    assert all({"mul", "flash_attention",
+                "linear_softmax_with_cross_entropy"} & set(s.op_types)
                for s in dots)
+    head = [s for s in dots if s.unit == "mlm_head"]
+    assert all(s.op_types == ("linear_softmax_with_cross_entropy",)
+               for s in head)
 
 
 def test_all_three_phases_occur_and_most_instructions_resolve(bert_scopes):
@@ -245,7 +252,7 @@ def test_a_fusion_of_an_update_with_other_work_is_mixed():
 %fused_computation (p0: f32[8,8], p1: f32[8,8]) -> f32[8,8] {
   %p0 = f32[8,8]{1,0} parameter(0)
   %p1 = f32[8,8]{1,0} parameter(1)
-  %dot.1 = f32[8,8]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/autodiff/transpose(jvp(u.mlm_head/op.mul))/dot_general"}
+  %dot.1 = f32[8,8]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/autodiff/transpose(jvp(u.bert_layer_1/op.mul))/dot_general"}
   %mul.8 = f32[8,8]{1,0} multiply(%p0, %p0), metadata={op_name="jit(step)/autodiff/transpose(jvp(u.bert_layer_0/op.layer_norm))/mul"}
   %mul.9 = f32[8,8]{1,0} multiply(%mul.8, %p0), metadata={op_name="jit(step)/autodiff/transpose(jvp(u.bert_layer_0/op.layer_norm))/mul"}
   ROOT %sub.1 = f32[8,8]{1,0} subtract(%p1, %dot.1), metadata={op_name="jit(step)/opt/op.adam/sub"}
@@ -271,7 +278,8 @@ ENTRY %main (a: f32[8,8], b: f32[8,8]) -> f32[8,8] {
     head = found["fusion.1"]
     # the matrix product names the kernel's unit, however many small
     # operations of another unit are fused around it
-    assert (head.phase, head.unit, head.has_dot) == ("mixed", "mlm_head", True)
+    assert (head.phase, head.unit, head.has_dot) == ("mixed", "bert_layer_1",
+                                                     True)
     assert head.op_types == ("adam", "layer_norm", "mul")
     # forward-named work fused into a backward kernel is spent in backward
     gelu = found["fusion.2"]

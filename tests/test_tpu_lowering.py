@@ -58,29 +58,92 @@ def test_flash_dropout_kernels_lower_for_tpu():
     assert text.count("tpu_custom_call") >= 2
 
 
-def test_data_parallel_flash_attention_lowers_for_tpu():
-    """Under a mesh the lowering refuses a bare Mosaic call; the
-    flash_attention op runs it per data shard inside a shard_map."""
+@pytest.fixture(scope="module")
+def dp_step_text():
+    """The data-parallel pretraining step, lowered for the TPU."""
     from paddle_tpu.core.executor import convert_feed_value
     from paddle_tpu.models import bert
 
-    cfg = bert.BertConfig(num_layers=1, hidden_size=128, num_heads=2,
-                          ffn_size=256, vocab_size=100, max_position=128)
-    main, startup, _, loss = bert.build_pretrain_program(
-        cfg, 8, 128,
-        optimizer_factory=lambda: fluid.optimizer.SGD(0.1))
-    cp = fluid.CompiledProgram(main).with_data_parallel(loss_name=loss.name)
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-    feed = {k: convert_feed_value(main.global_block(), k, v)
-            for k, v in chip_smoke.ernie_feed(cfg, 8, 128).items()}
-    names = sorted(v.name for v in main.list_vars()
-                   if v.persistable and scope.has_var(v.name))
-    step = cp._build(sorted(feed), [loss.name], names, names,
-                     {k: v.ndim for k, v in feed.items()})
-    text = step.trace(
-        {n: scope.find_var(n) for n in names}, feed,
-        jax.random.key(0)).lower(lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
+    mp = pytest.MonkeyPatch()
+    mp.setattr(fa, "_on_tpu", lambda: True)
+    try:
+        cfg = bert.BertConfig(num_layers=1, hidden_size=128, num_heads=2,
+                              ffn_size=256, vocab_size=100,
+                              max_position=128)
+        with fluid.unique_name.guard():
+            main, startup, _, loss = bert.build_pretrain_program(
+                cfg, 8, 128,
+                optimizer_factory=lambda: fluid.optimizer.SGD(0.1))
+        cp = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name)
+        exe = fluid.Executor(fluid.CPUPlace())
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+        feed = {k: convert_feed_value(main.global_block(), k, v)
+                for k, v in chip_smoke.ernie_feed(cfg, 8, 128).items()}
+        names = sorted(v.name for v in main.list_vars()
+                       if v.persistable and scope.has_var(v.name))
+        step = cp._build(sorted(feed), [loss.name], names, names,
+                         {k: v.ndim for k, v in feed.items()})
+        return step.trace(
+            {n: scope.find_var(n) for n in names}, feed,
+            jax.random.key(0)).lower(lowering_platforms=("tpu",)).as_text()
+    finally:
+        mp.undo()
+
+
+def test_data_parallel_flash_attention_lowers_for_tpu(dp_step_text):
+    """Under a mesh the lowering refuses a bare Mosaic call; the
+    flash_attention op runs it per data shard inside a shard_map."""
+    assert "tpu_custom_call" in dp_step_text
+
+
+def test_data_parallel_labelled_rows_head_lowers_for_tpu(dp_step_text):
+    """The fused masked-LM head runs per data shard too: its loops of
+    dynamic length (forward and backward) sit inside a shard_map, each shard
+    compacting its own 128 positions, and no logits of all positions
+    exist."""
+    text = dp_step_text
+    assert text.count("stablehlo.while") >= 2
+    assert "sdy.manual_computation" in text or "shard_map" in text
+    assert "1024x100x" not in text and "8x128x100x" not in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_labelled_rows_head_compiles_for_v5e_at_ernie_width(one_chip):
+    """The head of `ernie_base.seq512` (64 x 512 positions, bf16
+    activations, the float32 768 x 30,522 matrix), loss and gradients,
+    through the TPU's own compiler: the loops keep their dynamic length and
+    the step's temporaries stay far under the 1.86 GiB that bf16 logits of
+    all positions would take."""
+    from paddle_tpu.ops import nn_ops
+
+    n, h, v = 64 * 512, 768, 30522
+    chunk = nn_ops.linear_ce_chunk_rows(n, v)
+
+    def loss(x, w, b, lbl):
+        return jnp.sum(nn_ops._linear_ce(x, w, b, lbl, -100, chunk))
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((n, h), jnp.bfloat16),
+                                 ((h, v), jnp.float32), ((v,), jnp.float32),
+                                 ((n,), jnp.int32))]
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).trace(
+        *args).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2
+    assert f"[{n},{v}]" not in text and f"[{v},{n}]" not in text
+    dense_logits = n * v * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < dense_logits / 2
