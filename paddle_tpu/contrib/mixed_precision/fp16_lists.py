@@ -26,6 +26,18 @@ WHITE_LIST = {"conv2d", "conv3d", "depthwise_conv2d", "conv2d_transpose",
 # sums dW / dBias in float32 straight into the float32 masters
 # (nn_ops._linear_ce) — white-listing it would round Bias and both
 # gradients to bf16, black-listing it would run the three products in f32.
+# rms_norm is gray (not listed), like layer_norm: activations in whatever
+# dtype, the mean square and the gate's silu in float32, the input dtype
+# back. relu2 is gray: elementwise, in the dtype it is given.
+# causal_conv1d and ssd_scan are gray: they take the bf16 activations a bf16
+# projection hands them and keep in float32 what needs it (the convolution's
+# sum; the scan's time steps, decays and states, ops/ssm_ops.py) —
+# white-listing the scan would round A_log, dt_bias and D to bf16,
+# black-listing it would run its chunk products in float32.
+# moe_ffn is gray: its router runs in float32 at full matmul precision
+# whatever arrives (a near-tie between two experts flips on rounding), its
+# expert products take the activations' dtype with float32 accumulation, and
+# the experts' weight gradients are summed in float32 (parallel/moe.py).
 BLACK_LIST = {"cross_entropy", "mean",
               "reduce_mean", "softmax", "sum",
               "exp", "log", "rsqrt", "sqrt"}

@@ -129,11 +129,16 @@ class RematSpec:
 REMAT_POLICIES = ("none", "minimal", "full")
 
 
-def resolve_remat(policy=None, legacy_remat=False, saveable_names=None):
+def resolve_remat(policy=None, legacy_remat=False, saveable_names=None,
+                  program=None):
     """Map the remat policy surface (BuildStrategy.remat_policy /
     DistributedStrategy.remat_policy / legacy boolean-or-set
-    BuildStrategy.remat) onto a RematSpec."""
+    BuildStrategy.remat) onto a RematSpec. Where none of them gives a
+    policy, `program.remat_policy` (what the model's builder asked for its
+    own remat units) is taken."""
     names = tuple(saveable_names) if saveable_names else None
+    if policy is None and program is not None:
+        policy = getattr(program, "remat_policy", None)
     if policy is None:
         # legacy knob: True = per-op checkpoint everywhere, a set = only
         # those op types; no unit grouping (exact pre-policy behavior)
@@ -313,7 +318,7 @@ class CompiledProgram:
                   or getattr(self, "_strategy_remat", False))
         names = (getattr(bs, "remat_saveable_names", None)
                  if bs is not None else None)
-        return resolve_remat(policy, legacy, names)
+        return resolve_remat(policy, legacy, names, self._program)
 
     def _zero_plan(self, var):
         """(axis, pad_to) sharding plan for `var` over the data axis under

@@ -1228,7 +1228,8 @@ class Executor:
         # jax.checkpoint on the plain-Executor path (the CompiledProgram
         # path takes the same knob through BuildStrategy.remat);
         # PDTPU_REMAT_POLICY="minimal"|"full" maps onto the policy surface
-        # (remat units included) for scripts without a CompiledProgram
+        # (remat units included) for scripts without a CompiledProgram;
+        # without either, `program.remat_policy` is taken (resolve_remat)
         import os as _os
         from .compiler import resolve_remat
         remat_env = _os.environ.get("PDTPU_REMAT_OPS", "")
@@ -1236,7 +1237,7 @@ class Executor:
                   else frozenset(t for t in remat_env.split(",") if t)
                   if remat_env else False)
         spec = resolve_remat(_os.environ.get("PDTPU_REMAT_POLICY") or None,
-                             legacy)
+                             legacy, program=program)
 
         def step(state, feed, key):
             env = dict(state)

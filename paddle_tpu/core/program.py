@@ -266,6 +266,11 @@ class Program:
         self.random_seed = 0
         # op_role bookkeeping kept minimal: backward insertion point markers
         self._appended_backward = False
+        # what the builder asks for its `unit(..., remat=True)` blocks:
+        # None | "none" | "minimal" | "full" | unit_name -> bool|"minimal"|
+        # "full". Taken where neither BuildStrategy.remat_policy nor
+        # PDTPU_REMAT_POLICY gives one (compiler.resolve_remat)
+        self.remat_policy = None
 
     def _bump_version(self):
         self._version += 1

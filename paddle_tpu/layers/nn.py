@@ -273,6 +273,76 @@ def layer_norm(input: Variable, scale: bool = True, shift: bool = True,
     return helper.append_activation(out, act)
 
 
+def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
+             group_size: int = 0, param_attr=None, name=None) -> Variable:
+    """RMS normalisation over the last axis with a learned scale (TPU
+    extension, no reference analog): `x / sqrt(mean(x^2) + eps) * scale`.
+    With `gate` the input is `x * silu(gate)` first; with `group_size` each
+    consecutive group of that many channels is normalised by itself
+    (Mamba-2's gated norm). Statistics in float32 under AMP."""
+    helper = LayerHelper("rms_norm", name=name)
+    s = helper.create_parameter(param_attr, shape=[int(input.shape[-1])],
+                                dtype=input.dtype,
+                                default_initializer=ConstantInitializer(1.0))
+    ins = {"X": [input.name], "Scale": [s.name]}
+    if gate is not None:
+        ins["Gate"] = [gate.name]
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    helper.append_op(type="rms_norm", inputs=ins, outputs={"Out": [out.name]},
+                     attrs={"epsilon": epsilon, "group_size": int(group_size)})
+    return out
+
+
+def causal_conv1d(input: Variable, filter_size: int, act: str = "",
+                  param_attr=None, bias_attr=None, name=None) -> Variable:
+    """Depthwise causal convolution along the time axis of [B, T, C] (TPU
+    extension): channel c at time t sees its own last `filter_size` inputs.
+    Filter [C, filter_size], bias [C] (`bias_attr=False`: none); `act` ""
+    or "silu" is applied inside the op."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    c = int(input.shape[-1])
+    w = helper.create_parameter(param_attr, shape=[c, filter_size],
+                                dtype=input.dtype)
+    ins = {"X": [input.name], "Filter": [w.name]}
+    if bias_attr is not False:
+        b = helper.create_parameter(bias_attr, shape=[c], dtype=input.dtype,
+                                    is_bias=True)
+        ins["Bias"] = [b.name]
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    helper.append_op(type="causal_conv1d", inputs=ins,
+                     outputs={"Out": [out.name]}, attrs={"activation": act})
+    return out
+
+
+def ssd_scan(x: Variable, dt: Variable, b: Variable, c: Variable,
+             num_heads: int, n_groups: int, chunk: int = 128,
+             a_log_attr=None, d_attr=None, dt_bias_attr=None,
+             name=None) -> Variable:
+    """Mamba-2's state-space scan (TPU extension; ops/ssm_ops.py). x
+    [B, T, H*P], dt [B, T, H] (raw: the op adds `dt_bias` and applies
+    softplus), b, c [B, T, G*N]. Creates the per-head parameters A_log
+    (A = -exp(A_log)), D (the skip) and dt_bias, each [H]. T must be a
+    multiple of `chunk`."""
+    helper = LayerHelper("ssd_scan", name=name)
+    a_log = helper.create_parameter(a_log_attr, shape=[num_heads],
+                                    dtype=x.dtype,
+                                    default_initializer=ConstantInitializer(0.0))
+    d = helper.create_parameter(d_attr, shape=[num_heads], dtype=x.dtype,
+                                default_initializer=ConstantInitializer(1.0))
+    dt_bias = helper.create_parameter(dt_bias_attr, shape=[num_heads],
+                                      dtype=x.dtype, is_bias=True)
+    out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"X": [x.name], "Dt": [dt.name], "ALog": [a_log.name],
+                "B": [b.name], "C": [c.name], "D": [d.name],
+                "DtBias": [dt_bias.name]},
+        outputs={"Out": [out.name]},
+        attrs={"num_heads": int(num_heads), "n_groups": int(n_groups),
+               "chunk": int(chunk)})
+    return out
+
+
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,
                act=None, name=None) -> Variable:
     helper = LayerHelper("group_norm", name=name)
@@ -387,21 +457,23 @@ def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
     projected onto the [hidden, size] matrix, a chunk at a time, so the
     [positions, size] logits never exist (ops/nn_ops.py `_linear_ce`). For a
     masked-LM head, where 85% of the labels are ignored. The parameters are
-    those `fc` would create (weight [hidden, size], then bias [size]).
-    `return_rows=True` also returns the rows the op projected (whole
+    those `fc` would create (weight [hidden, size], then bias [size];
+    `bias_attr=False`: no bias). `return_rows=True` also returns the rows the op projected (whole
     chunks) and the labelled count, both int32 scalars."""
     helper = LayerHelper("fc", name=name)
     w = helper.create_parameter(param_attr, shape=[input.shape[-1], size],
                                 dtype=input.dtype)
-    b = helper.create_parameter(bias_attr, shape=[size], dtype=input.dtype,
-                                is_bias=True)
+    ins = {"X": [input.name], "W": [w.name], "Label": [label.name]}
+    if bias_attr is not False:
+        b = helper.create_parameter(bias_attr, shape=[size],
+                                    dtype=input.dtype, is_bias=True)
+        ins["Bias"] = [b.name]
     loss = helper.create_variable_for_type_inference(
         input.dtype, shape=tuple(input.shape[:-1]) + (1,))
     rows = helper.create_variable_for_type_inference("int32", shape=())
     labelled = helper.create_variable_for_type_inference("int32", shape=())
     helper.append_op(type="linear_softmax_with_cross_entropy",
-                     inputs={"X": [input.name], "W": [w.name],
-                             "Bias": [b.name], "Label": [label.name]},
+                     inputs=ins,
                      outputs={"Loss": [loss.name],
                               "RowsComputed": [rows.name],
                               "Labelled": [labelled.name]},
@@ -560,6 +632,7 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
                     attn_bias: Optional[Variable] = None,
                     causal: bool = False, dropout_prob: float = 0.0,
                     is_test: bool = False, num_heads: Optional[int] = None,
+                    num_kv_heads: Optional[int] = None,
                     name=None) -> Variable:
     """Fused memory-efficient attention.
 
@@ -571,7 +644,12 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
     - [B, H, T, D] 4D q/k/v; `attn_bias` broadcastable to [B, H, T, T].
     - packed [B, T, H·D] 3D q/k/v with `num_heads` (required for 3D) — the
       convenience form for fused-qkv models; adapted internally to the
-      folded kernel layout. `attn_bias` is the [B, 1, T] mask."""
+      folded kernel layout. `attn_bias` is the [B, 1, T] mask.
+
+    Grouped-query attention: k and v may have fewer heads than q, a divisor
+    of q's count (4D: their head dim says so; packed: `num_kv_heads`, with
+    k, v [B, T, num_kv_heads·D]). Query head h reads key/value head
+    h // (H / Hkv); the kernels index the shared head, nothing is copied."""
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
@@ -581,6 +659,8 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
              "is_test": is_test}
     if num_heads is not None:
         attrs["num_heads"] = int(num_heads)
+    if num_kv_heads is not None and num_kv_heads != num_heads:
+        attrs["num_kv_heads"] = int(num_kv_heads)
     helper.append_op(type="flash_attention", inputs=inputs,
                      outputs={"Out": [out.name]}, attrs=attrs)
     return out
@@ -667,48 +747,85 @@ def flash_attention_sparse(q: Variable, k: Variable, v: Variable,
 
 
 def moe_ffn(input: Variable, num_experts: int, hidden_size: int, k: int = 2,
-            capacity_factor: float = 1.25, act: str = "gelu",
-            ep_axis: str = "ep", param_attr=None, name=None):
+            act: str = "gelu", ep_axis: str = "ep", param_attr=None,
+            bias_attr=None, experts_held=None, scoring: str = "softmax",
+            correction_bias: bool = False, norm_topk: bool = True,
+            routed_scaling: float = 1.0, return_counts: bool = False,
+            name=None):
     """Mixture-of-Experts feed-forward block (no reference analog — the
     reference predates MoE; exposed like its fused composite ops).
 
-    Top-k routed, static-capacity dispatch; under a compiled mesh with an
-    `ep` axis the tokens travel to their experts by all-to-all (expert
-    parallelism, parallel/moe.py), otherwise the identical dense path runs.
+    Top-k routing over all `num_experts` in float32 (`scoring` "softmax", or
+    "sigmoid" with, under `correction_bias=True`, a selection-only bias
+    parameter `<name>.corr_bias`; the chosen scores divided by their sum
+    under `norm_topk`, times `routed_scaling`). Dropless: the (token,
+    expert) pairs are sorted by expert and each expert multiplies exactly
+    the tokens routed to it (parallel/moe.py) — no capacity, no dropped
+    token. `experts_held = (first, count)` makes the layer hold that range
+    of the experts only (a chip's share of an expert-parallel deployment):
+    it creates their weights alone, and pairs on absent experts add
+    nothing. Under a compiled mesh with an `ep` axis a layer that holds all
+    its experts shards them over the axis. `bias_attr=False` leaves the
+    experts without biases.
+
     Returns (out, aux_loss): add `aux_loss` (Switch load-balance term,
-    scaled by your coefficient) to the training loss."""
+    scaled by your coefficient) to the training loss. `return_counts=True`
+    also returns the pairs on each held expert [count] and the pairs held
+    (int32) — fetch them where the loss is fetched."""
     helper = LayerHelper("moe_ffn", name=name)
     d = input.shape[-1]
+    first, held = experts_held or (0, num_experts)
 
-    def _attr(suffix):
-        # five distinct parameters: clone the user attr per param (a shared
-        # ParamAttr instance would be renamed on first use and alias all five)
-        base = ParamAttr._to_attr(param_attr)
+    def _attr(base, suffix):
+        # distinct parameters: clone the user attr per param (a shared
+        # ParamAttr instance would be renamed on first use and alias them)
         import copy
-        a = copy.copy(base)
+        a = copy.copy(ParamAttr._to_attr(base))
         if a.name is not None:
             a.name = f"{a.name}.{suffix}"
         return a
 
-    gate = helper.create_parameter(_attr("gate"), shape=[d, num_experts],
-                                   dtype=input.dtype)
-    w1 = helper.create_parameter(_attr("w1"), shape=[num_experts, d, hidden_size],
+    gate = helper.create_parameter(_attr(param_attr, "gate"),
+                                   shape=[d, num_experts], dtype=input.dtype)
+    w1 = helper.create_parameter(_attr(param_attr, "w1"),
+                                 shape=[held, d, hidden_size],
                                  dtype=input.dtype)
-    b1 = helper.create_parameter(_attr("b1"), shape=[num_experts, hidden_size],
-                                 dtype=input.dtype, is_bias=True)
-    w2 = helper.create_parameter(_attr("w2"), shape=[num_experts, hidden_size, d],
+    w2 = helper.create_parameter(_attr(param_attr, "w2"),
+                                 shape=[held, hidden_size, d],
                                  dtype=input.dtype)
-    b2 = helper.create_parameter(_attr("b2"), shape=[num_experts, d],
-                                 dtype=input.dtype, is_bias=True)
+    ins = {"X": [input.name], "GateW": [gate.name], "W1": [w1.name],
+           "W2": [w2.name]}
+    if bias_attr is not False:
+        base = param_attr if bias_attr is None else bias_attr
+        b1 = helper.create_parameter(_attr(base, "b1"),
+                                     shape=[held, hidden_size],
+                                     dtype=input.dtype, is_bias=True)
+        b2 = helper.create_parameter(_attr(base, "b2"), shape=[held, d],
+                                     dtype=input.dtype, is_bias=True)
+        ins.update(B1=[b1.name], B2=[b2.name])
+    if correction_bias:
+        cb_attr = _attr(param_attr, "corr_bias")
+        cb_attr.trainable = False       # balanced outside the gradient
+        cb_attr.initializer = ConstantInitializer(0.0)
+        cb = helper.create_parameter(cb_attr, shape=[num_experts],
+                                     dtype=input.dtype, is_bias=True)
+        ins["CorrectionBias"] = [cb.name]
     out = helper.create_variable_for_type_inference(input.dtype, shape=input.shape)
     aux = helper.create_variable_for_type_inference(input.dtype, shape=())
+    tokens = helper.create_variable_for_type_inference(
+        "int32", shape=(held,), stop_gradient=True)
+    pairs = helper.create_variable_for_type_inference(
+        "int32", shape=(), stop_gradient=True)
     helper.append_op(
-        type="moe_ffn",
-        inputs={"X": [input.name], "GateW": [gate.name], "W1": [w1.name],
-                "B1": [b1.name], "W2": [w2.name], "B2": [b2.name]},
-        outputs={"Out": [out.name], "AuxLoss": [aux.name]},
-        attrs={"k": k, "capacity_factor": capacity_factor, "act": act,
-               "ep_axis": ep_axis})
+        type="moe_ffn", inputs=ins,
+        outputs={"Out": [out.name], "AuxLoss": [aux.name],
+                 "TokensPerExpert": [tokens.name], "PairsHeld": [pairs.name]},
+        attrs={"k": k, "act": act, "ep_axis": ep_axis,
+               "experts_first": int(first), "scoring": scoring,
+               "norm_topk": bool(norm_topk),
+               "routed_scaling": float(routed_scaling)})
+    if return_counts:
+        return out, aux, tokens, pairs
     return out, aux
 
 

@@ -48,6 +48,12 @@ def gelu(x, approximate=False, name=None):
     return _activation_layer("gelu", x, {"approximate": approximate}, name)
 
 
+def relu2(x, name=None):
+    """Squared ReLU, max(x, 0)^2 (TPU extension; also `act="relu2"` of
+    `fc`)."""
+    return _activation_layer("relu2", x, {}, name)
+
+
 def relu6(x, threshold=6.0, name=None):
     return _activation_layer("relu6", x, {"threshold": threshold}, name)
 

@@ -33,6 +33,7 @@ from . import (  # noqa: F401
     rnn_ops,
     sampled_ops,
     sequence_ops,
+    ssm_ops,
     tensor_ops,
     vision_ops,
 )

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from .common import one
+from .common import one, relu2
 
 
 def _act(name, fn):
@@ -22,6 +22,7 @@ def _act(name, fn):
 
 
 _act("relu", lambda x, a: jax.nn.relu(x))
+_act("relu2", lambda x, a: relu2(x))
 _act("sigmoid", lambda x, a: jax.nn.sigmoid(x))
 _act("tanh", lambda x, a: jnp.tanh(x))
 _act("softplus", lambda x, a: jax.nn.softplus(x))

@@ -43,6 +43,13 @@ def _identity(x):
     return x
 
 
+def relu2(x):
+    """Squared ReLU (Primer, So et al. 2021; the expert activation of
+    Nemotron-H): max(x, 0)^2."""
+    import jax
+    return jnp.square(jax.nn.relu(x))
+
+
 def act_map():
     import jax
     return {
@@ -52,4 +59,6 @@ def act_map():
         "tanh": jnp.tanh,
         "sigmoid": jax.nn.sigmoid,
         "gelu": jax.nn.gelu,
+        "silu": jax.nn.silu,
+        "relu2": relu2,
     }

@@ -170,13 +170,14 @@ def _flash_attention(ctx, inputs, attrs):
                 "flash_attention: 3D (packed [B,T,H]) q/k/v requires the "
                 "num_heads attr — pass num_heads= to layers.flash_attention")
         nh = attrs["num_heads"]
+        nkv = attrs.get("num_kv_heads", nh)
         t, d = q.shape[1], q.shape[2] // nh
 
         def attend(q, k, v, *rest):
             *bias, key = rest
             return _fa.flash_attention_packed(
                 q, k, v, nh, bias=bias[0] if bias else None, causal=causal,
-                dropout_rate=rate, dropout_key=key)
+                dropout_rate=rate, dropout_key=key, num_kv_heads=nkv)
     else:
         t, d = q.shape[2], q.shape[3]
 

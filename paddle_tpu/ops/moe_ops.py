@@ -1,47 +1,60 @@
 """Mixture-of-Experts framework op.
 
 No reference analog (barrierye/Paddle predates MoE) — this exposes the
-expert-parallel machinery of parallel/moe.py to static-graph programs as a
-single `moe_ffn` op, the same way the reference exposes composite blocks as
-fused ops (e.g. fused_embedding_seq_pool_op.cc). Under a compiled mesh with
-an `ep` axis the op dispatches tokens via all-to-all expert parallelism;
-otherwise it computes the identical dense path. Fully differentiable via
-the executor's vjp tape.
+machinery of parallel/moe.py to static-graph programs as a single `moe_ffn`
+op, the same way the reference exposes composite blocks as fused ops (e.g.
+fused_embedding_seq_pool_op.cc): a float32 router over all the layer's
+experts, dropless sort-and-segment dispatch, one grouped product over the
+experts held. Under a compiled mesh with an `ep` axis the experts are sharded
+over it and the tokens gathered and reduce-scattered; otherwise the op
+computes the part of the result its `experts_held` give. Differentiable
+through the executor's vjp tape (the grouped product brings its own
+backward).
+
+Gray under AMP (not listed in contrib/mixed_precision/fp16_lists.py): the
+router runs in float32 whatever dtype the activations arrive in, the expert
+products take the activations' dtype with float32 accumulation, and the
+weight gradients are summed in float32 straight into the float32 masters.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
 from ..parallel import moe as _moe
+from .common import act_map, opt_input
 
 
-@register_op("moe_ffn")
+@register_op("moe_ffn", nondiff_inputs=["CorrectionBias"])
 def _moe_ffn(ctx, inputs, attrs):
     (x,) = inputs["X"]                 # [B, T, D] or [N, D]
     (gate_w,) = inputs["GateW"]        # [D, E]
-    (w1,) = inputs["W1"]               # [E, D, H]
-    (b1,) = inputs["B1"]               # [E, H]
-    (w2,) = inputs["W2"]               # [E, H, D]
-    (b2,) = inputs["B2"]               # [E, D]
-    k = int(attrs.get("k", 2))
-    cf = float(attrs.get("capacity_factor", 1.25))
+    (w1,) = inputs["W1"]               # [E_held, D, H]
+    (w2,) = inputs["W2"]               # [E_held, H, D]
+    b1 = opt_input(inputs, "B1")       # [E_held, H]
+    b2 = opt_input(inputs, "B2")       # [E_held, D]
+    bias = opt_input(inputs, "CorrectionBias")     # [E]
     axis = attrs.get("ep_axis", "ep")
-    act = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
-           "silu": jax.nn.silu}[attrs.get("act", "gelu")]
+    act = act_map()[attrs.get("act", "gelu")]
+    kw = dict(k=int(attrs.get("k", 2)), act=act,
+              scoring=attrs.get("scoring", "softmax"), correction_bias=bias,
+              norm_topk=bool(attrs.get("norm_topk", True)),
+              routed_scaling=float(attrs.get("routed_scaling", 1.0)))
+    e = gate_w.shape[1]
+    first = int(attrs.get("experts_first", 0))
 
     shape = x.shape
     flat = x.reshape(-1, shape[-1])
 
     mesh = ctx.mesh
     if mesh is not None and axis in mesh.axis_names \
-            and gate_w.shape[1] % mesh.shape[axis] == 0 \
+            and w1.shape[0] == e and e % mesh.shape[axis] == 0 \
             and flat.shape[0] % mesh.shape[axis] == 0:
-        y, aux = _moe.moe_ffn_expert_parallel(
-            flat, gate_w, w1, b1, w2, b2, mesh, axis=axis, k=k,
-            capacity_factor=cf, act=act)
+        out = _moe.moe_ffn_expert_parallel(flat, gate_w, w1, b1, w2, b2,
+                                           mesh, axis=axis, **kw)
     else:
-        y, aux = _moe.moe_ffn(flat, gate_w, w1, b1, w2, b2, k=k,
-                              capacity_factor=cf, act=act)
-    return {"Out": [y.reshape(shape)], "AuxLoss": [aux]}
+        out = _moe.moe_ffn(flat, gate_w, w1, b1, w2, b2,
+                           experts_held=(first, w1.shape[0]), **kw)
+    return {"Out": [out.y.reshape(shape)], "AuxLoss": [out.aux_loss],
+            "TokensPerExpert": [out.tokens_per_expert],
+            "PairsHeld": [out.pairs_held.astype(jnp.int32)]}

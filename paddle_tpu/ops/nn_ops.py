@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_op
-from .common import one
+from .common import one, opt_input
 
 
 def _pair(v, n=2):
@@ -339,6 +339,31 @@ def _layer_norm(ctx, inputs, attrs):
         y = y + bias.astype(jnp.float32).reshape(norm_shape)
     return {"Y": [y.astype(x.dtype)], "Mean": [mean.squeeze(axes)],
             "Variance": [var.squeeze(axes)]}
+
+
+@register_op("rms_norm")
+def _rms_norm(ctx, inputs, attrs):
+    """Root-mean-square normalisation over the last axis (Zhang & Sennrich
+    2019): y = x / sqrt(mean(x^2) + eps) * Scale. With a Gate input z (same
+    shape as X) the input is x * silu(z) first, and with `group_size` the
+    mean is taken over consecutive groups of that many channels, each
+    normalised by itself (Mamba-2's gated norm). Gray under AMP, like
+    layer_norm: activations in whatever dtype, statistics in float32, the
+    input dtype back."""
+    (x,) = inputs["X"]
+    scale = inputs.get("Scale", [None])[0]
+    gate = inputs.get("Gate", [None])[0]
+    eps = attrs.get("epsilon", 1e-5)
+    group = int(attrs.get("group_size", 0)) or x.shape[-1]
+    xf = x.astype(jnp.float32)
+    if gate is not None:
+        xf = xf * jax.nn.silu(gate.astype(jnp.float32))
+    grouped = xf.reshape(x.shape[:-1] + (x.shape[-1] // group, group))
+    ms = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+    y = (grouped * lax.rsqrt(ms + eps)).reshape(x.shape)
+    if scale is not None:
+        y = y * scale.astype(jnp.float32)
+    return one(y.astype(x.dtype))
 
 
 @register_op("group_norm")
@@ -761,7 +786,9 @@ def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
     (x,) = inputs["X"]
     (w,) = inputs["W"]
     (label,) = inputs["Label"]
-    (b,) = inputs["Bias"]
+    b = opt_input(inputs, "Bias")
+    if b is None:          # a head without a bias: a zero one, never learnt
+        b = jnp.zeros((w.shape[1],), jnp.float32)
     ignore = attrs.get("ignore_index", -100)
     path = "per_data_shard" if _under_mesh(ctx) else "whole"
 

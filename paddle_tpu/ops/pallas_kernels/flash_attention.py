@@ -155,14 +155,27 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         lse_ref[0] = (m_ref[...] + jnp.log(l_safe)).astype(jnp.float32)
 
 
+def _kv_row(kv_group: int):
+    """Folded q row -> folded k/v row for the kernels' block index maps:
+    with `kv_group` query heads to a key/value head (grouped-query
+    attention) q row b*Hq + h reads k/v row b*Hkv + h // kv_group, which is
+    (q row) // kv_group. One head each is the identity, so that the kernels
+    of a model without grouped heads are built as before."""
+    if kv_group == 1:
+        return lambda b: b
+    return lambda b: b // kv_group
+
+
 def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
-                      interpret=False, dropout_rate=0.0, seed=None):
-    """q,k,v: [BH, T, D] (heads folded); bias: [BH, Tq_or_1, Tk] or None.
-    Returns (out [BH,T,D], lse [BH,T])."""
+                      interpret=False, dropout_rate=0.0, seed=None,
+                      kv_group=1):
+    """q: [BHq, T, D], k, v: [BHq / kv_group, T, D] (heads folded); bias:
+    [BHq, Tq_or_1, Tk] or None. Returns (out [BHq,T,D], lse [BHq,T])."""
     bh, t, d = q.shape
     block_q, block_k = min(block_q, t), min(block_k, t)
     nq, nk = t // block_q, t // block_k
-    if nq == 1 and nk == 1:
+    kv = _kv_row(kv_group)
+    if nq == 1 and nk == 1 and kv_group == 1:
         per_q_bias = bias is not None and bias.shape[1] != 1
         group = _pick_group(
             bh, t, d, _tt_bytes_per_head(1, per_q_bias, dropout_rate, t))
@@ -174,8 +187,8 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -633,13 +646,17 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
 
 def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
                       block_q, block_k, dropout_rate=0.0, seed=None,
-                      interpret=False):
+                      interpret=False, kv_group=1):
     """Returns (dq, dk, dv, dbias). dbias is [BH,Tq,Tk] f32 for a per-q bias,
-    [BH,1,Tk] f32 for a broadcast (mask-like) bias, or None."""
+    [BH,1,Tk] f32 for a broadcast (mask-like) bias, or None. With
+    `kv_group` query heads to a key/value head, k and v are
+    [BH / kv_group, T, D] and dk, dv come back per QUERY head ([BH, T, D]):
+    the caller sums them over the group."""
     bh, t, d = q.shape
     block_q, block_k = min(block_q, t), min(block_k, t)
     nq, nk = t // block_q, t // block_k
-    if nq == 1 and nk == 1:
+    kv = _kv_row(kv_group)
+    if nq == 1 and nk == 1 and kv_group == 1:
         per_q_bias = bias is not None and bias.shape[1] != 1
         group = _pick_group(
             bh, t, d, _tt_bytes_per_head(3, per_q_bias, dropout_rate, t))
@@ -658,8 +675,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     # ---- dq kernel: grid (bh, nq, nk) --------------------------------------
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),   # v
+        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
     ]
     args = [q, k, v]
     if has_bias:
@@ -723,8 +740,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     # ---- dk/dv kernel: grid (bh, nk, nq) -----------------------------------
     in_specs2 = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0)),   # v
+        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (kv(b), j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (kv(b), j, 0)),
     ]
     args2 = [q, k, v]
     if has_bias:
@@ -943,17 +960,26 @@ def _folded_to_pack(x, b_):
 def flash_attention_packed(q, k, v, num_heads: int, bias=None,
                            causal: bool = False,
                            sm_scale: Optional[float] = None,
-                           dropout_rate: float = 0.0, dropout_key=None):
+                           dropout_rate: float = 0.0, dropout_key=None,
+                           num_kv_heads: Optional[int] = None):
     """Memory-efficient attention on packed [B, T, H] tensors (H = nh·d).
 
     Adapts to the folded [B·nh, T, d] kernel layout; XLA inserts the
     head-split transposes (see the layout note above — measured optimum for
     d=64 heads on v5e). bias (optional) is the additive [B, 1, T] mask.
-    Returns [B, T, H]."""
+    `num_kv_heads` (a divisor of `num_heads`; default: the same) is the head
+    count of k and v ([B, T, nkv·d]): query head h reads key/value head
+    h // (nh / nkv). Returns [B, T, H]."""
     b_, t, hdim = q.shape
+    num_kv_heads = num_heads if num_kv_heads is None else num_kv_heads
     if hdim % num_heads:
         raise ValueError(f"hidden {hdim} not divisible by heads {num_heads}")
     d = hdim // num_heads
+    if num_heads % num_kv_heads or k.shape[2] != num_kv_heads * d:
+        raise ValueError(
+            f"flash_attention: {num_kv_heads} key/value heads must divide "
+            f"{num_heads} query heads and k, v be [B, T, {num_kv_heads * d}]"
+            f", got {k.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if not 0.0 <= dropout_rate < 1.0:
@@ -973,7 +999,8 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
             b_ * num_heads, 1, t)
     if dropout_rate == 0.0:
         dropout_key = None
-    qf, kf, vf = (_pack_to_folded(x, num_heads) for x in (q, k, v))
+    qf = _pack_to_folded(q, num_heads)
+    kf, vf = (_pack_to_folded(x, num_kv_heads) for x in (k, v))
     out = _flash_core(qf, kf, vf, bias, dropout_key, float(sm_scale),
                       bool(causal), float(dropout_rate))
     return _folded_to_pack(out, b_)
@@ -1030,21 +1057,44 @@ def _flash_core(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
     return out
 
 
+def _kv_group(q, k) -> int:
+    """Query heads to a key/value head, from the folded shapes."""
+    return q.shape[0] // k.shape[0]
+
+
+def _repeat_kv(x, group: int):
+    """[BHkv, T, D] -> [BHq, T, D]: each k/v head once per query head of its
+    group (the blockwise-JAX path; the kernels index instead)."""
+    return x if group == 1 else jnp.repeat(x, group, axis=0)
+
+
+def _sum_kv_group(dx, group: int, dtype):
+    """Per-query-head dk or dv [BHq, T, D] -> [BHkv, T, D], summed in
+    float32 over the heads that read the same key/value head."""
+    if group == 1:
+        return dx
+    bh, t, d = dx.shape
+    return jnp.sum(dx.astype(jnp.float32).reshape(bh // group, group, t, d),
+                   axis=1).astype(dtype)
+
+
 def _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale, causal,
                         dropout_rate):
     t, d = q.shape[1], q.shape[2]
     bq, bk = _pick_blocks(t)
+    group = _kv_group(q, k)
     if _pallas_ok(t, d):
         seed = (_seed_from_key(dropout_key) if dropout_rate > 0.0 else None)
         return _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, bq, bk,
                                  dropout_rate=dropout_rate, seed=seed,
-                                 interpret=_interpret_arg(dropout_rate))
+                                 interpret=_interpret_arg(dropout_rate),
+                                 kv_group=group)
     if bq is None:
         raise ValueError(f"flash_attention: seq len {t} has no power-of-two "
                          f"block divisor ≥8; pad the sequence")
     key = dropout_key if dropout_rate > 0.0 else None
-    return _flash_fwd_jax(q, k, v, bias, sm_scale, causal, bk,
-                          dropout_rate, key)
+    return _flash_fwd_jax(q, _repeat_kv(k, group), _repeat_kv(v, group), bias,
+                          sm_scale, causal, bk, dropout_rate, key)
 
 
 def _flash_core_fwd(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
@@ -1059,16 +1109,20 @@ def _flash_core_bwd(sm_scale, causal, dropout_rate, res, g):
     t, d = q.shape[1], q.shape[2]
     bq, bk = _pick_blocks(t)
     has_bias = bias is not None
+    group = _kv_group(q, k)
     if _pallas_ok(t, d):
         seed = (_seed_from_key(key) if dropout_rate > 0.0 else None)
         dq, dk, dv, dbias = _flash_bwd_pallas(
             q, k, v, bias, g, lse, out, sm_scale, causal, bq, bk,
             dropout_rate=dropout_rate, seed=seed,
-            interpret=_interpret_arg(dropout_rate))
+            interpret=_interpret_arg(dropout_rate), kv_group=group)
     else:
+        res = (q, _repeat_kv(k, group), _repeat_kv(v, group)) + res[3:]
         dq, dk, dv, dbias = _flash_bwd_jax(
             res, g, sm_scale=sm_scale, causal=causal, block_k=bk,
             dropout_rate=dropout_rate, has_bias=has_bias)
+    dk = _sum_kv_group(dk, group, k.dtype)
+    dv = _sum_kv_group(dv, group, v.dtype)
     if has_bias:
         # reduce over broadcast dims back to the bias shape (the pallas
         # col-sum path has already reduced the q axis)
@@ -1697,10 +1751,16 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
                     dropout_rate: float = 0.0, dropout_key=None):
     """Memory-efficient multi-head attention.
 
-    q, k, v: [B, H, T, D]. bias: additive, broadcastable to [B, H, T, T]
-    (e.g. the BERT mask [B,1,1,T]). Returns [B, H, T, D].
+    q: [B, H, T, D]; k, v: [B, Hkv, T, D] with Hkv dividing H (query head h
+    reads key/value head h // (H / Hkv); Hkv == H is plain multi-head
+    attention). bias: additive, broadcastable to [B, H, T, T] (e.g. the
+    BERT mask [B,1,1,T]). Returns [B, H, T, D].
     """
     b, h, t, d = q.shape
+    if h % k.shape[1] or k.shape != v.shape:
+        raise ValueError(
+            f"flash_attention: the key/value head count must divide the "
+            f"query head count; got q {q.shape}, k {k.shape}, v {v.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if not 0.0 <= dropout_rate < 1.0:
@@ -1712,7 +1772,7 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
             "flash_attention: dropout_rate > 0 requires a dropout_key; "
             "pass one or set dropout_rate=0 for inference")
 
-    fold = lambda x: x.reshape(b * h, *x.shape[2:])
+    fold = lambda x: x.reshape(x.shape[0] * x.shape[1], *x.shape[2:])
     qf, kf, vf = fold(q), fold(k), fold(v)
     bias_f = None
     if bias is not None:

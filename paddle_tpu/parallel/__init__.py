@@ -23,7 +23,7 @@ from .dgc import dgc_allreduce, sparse_allgather_exchange, top_k_sparsify  # noq
 from .local_sgd import (  # noqa: F401
     average_params, local_sgd_step, replicate_params)
 from .moe import (  # noqa: F401
-    init_moe_params, moe_ffn, moe_ffn_expert_parallel, top_k_gating)
+    experts_ffn, init_moe_params, moe_ffn, moe_ffn_expert_parallel, route)
 from .pipeline import GPipe, pipeline_step  # noqa: F401
 from .ring_attention import ring_attention, ring_self_attention  # noqa: F401
 from .tensor_parallel import (MEGATRON_RULES, annotate_tp,  # noqa: F401

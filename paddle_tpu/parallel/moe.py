@@ -1,154 +1,346 @@
-"""Mixture-of-Experts with expert parallelism over a named mesh axis.
+"""Mixture-of-Experts: dropless sort-and-segment routing, a layer that is told
+which experts it holds, and expert parallelism over a named mesh axis.
 
 No reference analog: barrierye/Paddle has no MoE/expert-parallel machinery
 (its closest sparse-capacity idea is the pserver-sharded embedding,
-operators/distributed/parameter_prefetch.cc). This is a new first-class
-parallel axis of the TPU build (SURVEY §5 "long-context/parallelism" gap),
-designed XLA-first:
+operators/distributed/parameter_prefetch.cc).
 
-- Static capacity dispatch (GShard/Switch style): every shape is fixed at
-  trace time — tokens route into an [E, C, D] buffer via one-hot einsums, so
-  the MXU does the dispatch and no dynamic shapes leak into the graph.
-- Expert parallelism via `lax.all_to_all` inside `shard_map`: tokens are
-  sharded over the `ep` axis (the data axis doubles as the expert axis, the
-  standard TPU layout), experts are sharded over the same axis; one
-  all-to-all sends token slices to their experts' hosts, a second brings
-  results home. Both ride ICI.
-- Load-balance aux loss (Switch: E * Σ_e f_e·P_e) with globally-psummed
-  statistics so the loss is identical no matter how the batch is sharded.
-
-The dense path (`moe_ffn`) and the expert-parallel path
-(`moe_ffn_expert_parallel`) compute identical results when capacity is not
-exceeded — tested in tests/test_moe.py.
+- **Routing** (`route`) is over ALL experts of the layer, in float32 at
+  full matmul precision: softmax scores (Switch/GShard) or sigmoid scores
+  with a selection-only correction bias, the chosen scores renormalised and
+  scaled (DeepSeek-V3 / Nemotron-H style). Near-ties between experts flip on
+  rounding, so the router never runs in the AMP dtype.
+- **The experts held** are a contiguous range `(first, count)` of the
+  layer's experts: the weights passed in are those experts' only. A
+  (token, expert) pair whose expert is held is computed; a pair on an absent
+  expert adds nothing here (on a deployment another chip adds it). Holding
+  all of them is the whole layer.
+- **Dispatch** sorts the held pairs by expert and cuts each expert's run
+  into tiles of `TILE` rows. **The grouped product** (`_grouped_ffn`) walks
+  the live tiles only — a loop whose trip count is the number of tiles the
+  routing made, each tile one [TILE, D] x [D, H] x [H, D] expert MLP on
+  gathered rows, weighted and scatter-added back to its tokens — so the work
+  follows the pairs held, there is no capacity, no token is ever dropped
+  (all tokens on one expert just make more tiles), and nothing of size
+  [N, E, C] or [N*k, D] exists. Its backward walks the same tiles again
+  (jax cannot reverse a loop of dynamic length, hence the custom_vjp),
+  recomputing each tile's hidden activations.
+- **Expert parallelism** (`moe_ffn_expert_parallel`): tokens sharded over the
+  axis, experts sharded over the same axis. Each device gathers the tokens
+  (all-gather), computes its held experts' part for all of them, and a
+  reduce-scatter sums the parts and hands each device its own tokens'
+  rows. Both ride ICI; nothing is dropped, whatever the routing.
+- Load-balance aux loss (Switch: E * Σ_e f_e·P_e), psum-averaged across the
+  axis so it matches the unsharded run's.
 """
 from __future__ import annotations
 
+import functools
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..observability.scopes import unit_scope
 from .collective import shard_map
 
+_HI = lax.Precision.HIGHEST
+# rows of one expert handled per step of the grouped product. 256 rows
+# against a [2688, 1856] expert read its weights about as fast as it
+# multiplies by them on a v5e; an expert's last tile is part empty, which
+# costs E_held * TILE / 2 rows a layer on average
+TILE = 256
 
-class GateOutput(NamedTuple):
-    combine: jax.Array   # [N, E, C] float — combine weights (0 where dropped)
-    dispatch: jax.Array  # [N, E, C] bool  — dispatch mask
-    aux_loss: jax.Array  # []  load-balance loss
-    probs: jax.Array     # [N, E] softmax router probabilities
+
+class Routing(NamedTuple):
+    idx: jax.Array       # [N, k] int32 — chosen experts, best first
+    weight: jax.Array    # [N, k] float32 — their combine weights
+    aux_loss: jax.Array  # [] load-balance loss
 
 
-def top_k_gating(x, gate_w, k: int = 2, capacity: int = 0,
-                 capacity_factor: float = 1.25, renormalize: bool = True,
-                 axis: Optional[str] = None) -> GateOutput:
-    """Static-capacity top-k router.
+class MoEOutput(NamedTuple):
+    y: jax.Array                  # [N, D] — the held experts' part
+    aux_loss: jax.Array           # []
+    tokens_per_expert: jax.Array  # [E_held] int32 — pairs on each held expert
+    pairs_held: jax.Array         # [] int32 — pairs on held experts, of N*k
 
-    x: [N, D] tokens, gate_w: [D, E]. Returns combine/dispatch tensors with a
-    fixed per-expert capacity C (computed from capacity_factor if capacity is
-    0). When `axis` is given (inside shard_map), aux-loss statistics are
-    psum-averaged across the axis so the loss matches the unsharded run.
-    """
-    n, _ = x.shape
+
+def route(x, gate_w, k: int = 2, scoring: str = "softmax",
+          correction_bias=None, norm_topk: bool = True,
+          routed_scaling: float = 1.0, axis: Optional[str] = None) -> Routing:
+    """Top-k router over all `gate_w.shape[1]` experts. x: [N, D]; gate_w:
+    [D, E]. `scoring` "softmax": scores = softmax(x·W); "sigmoid": scores =
+    sigmoid(x·W), the choice made on scores + `correction_bias` [E] (which
+    carries no gradient and no weight), the weights being the plain scores.
+    `norm_topk` divides the chosen scores by their sum; `routed_scaling`
+    multiplies them. With `axis` (inside shard_map) the aux-loss statistics
+    are averaged over the axis."""
     e = gate_w.shape[1]
-    if capacity <= 0:
-        capacity = max(1, int(math.ceil(k * n / e * capacity_factor)))
-    c = capacity
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=_HI)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"route: scoring must be 'softmax' or 'sigmoid', "
+                         f"got {scoring!r}")
+    choose = scores
+    if correction_bias is not None:
+        choose = scores + lax.stop_gradient(
+            correction_bias.astype(jnp.float32))
+    _, idx = lax.top_k(choose, k)                                 # [N, k]
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        weight = weight / jnp.maximum(
+            jnp.sum(weight, axis=-1, keepdims=True), 1e-20)
+    weight = weight * routed_scaling
 
-    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                      # [N, E]
-
-    gate_vals, gate_idx = lax.top_k(probs, k)                    # [N, k]
-    if renormalize:
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-
-    # Slot-major priority: all slot-0 assignments claim capacity before any
-    # slot-1 assignment (GShard ordering).
-    combine = jnp.zeros((n, e, c), dtype=jnp.float32)
-    counts = jnp.zeros((e,), dtype=jnp.int32)   # tokens already placed per expert
-    for j in range(k):
-        onehot = jax.nn.one_hot(gate_idx[:, j], e, dtype=jnp.int32)  # [N, E]
-        pos = jnp.cumsum(onehot, axis=0) - onehot + counts[None, :]  # [N, E]
-        pos_j = jnp.sum(pos * onehot, axis=1)                        # [N]
-        keep = pos_j < c
-        counts = counts + jnp.sum(onehot, axis=0)
-        pos_oh = jax.nn.one_hot(pos_j, c, dtype=jnp.float32)         # [N, C]
-        combine = combine + (gate_vals[:, j] * keep)[:, None, None] \
-            * onehot.astype(jnp.float32)[:, :, None] * pos_oh[:, None, :]
-
-    dispatch = combine > 0.0
-
-    # Switch load-balance loss on the top-1 assignment.
-    top1 = jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32)
-    frac_tokens = jnp.mean(top1, axis=0)       # f_e
-    frac_probs = jnp.mean(probs, axis=0)       # P_e
+    # Switch load-balance loss on the top-1 assignment
+    top1 = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32)
+    frac_tokens = jnp.mean(top1, axis=0)                          # f_e
+    share = scores / jnp.maximum(jnp.sum(scores, -1, keepdims=True), 1e-20)
+    frac_probs = jnp.mean(share, axis=0)                          # P_e
     if axis is not None:
         frac_tokens = lax.pmean(frac_tokens, axis)
         frac_probs = lax.pmean(frac_probs, axis)
     aux = e * jnp.sum(frac_tokens * frac_probs)
-    return GateOutput(combine.astype(x.dtype), dispatch, aux, probs)
+    return Routing(idx.astype(jnp.int32), weight, aux)
 
 
-def _expert_ffn(h, w1, b1, w2, b2, act):
-    """h: [E_local, C', D]; w1: [E_local, D, H]; w2: [E_local, H, D]."""
-    u = jnp.einsum("ecd,edh->ech", h, w1) + b1[:, None, :]
-    u = act(u)
-    return jnp.einsum("ech,ehd->ecd", u, w2) + b2[:, None, :]
+# ---------------------------------------------------------------------------
+# dispatch: the held pairs sorted by expert, cut into tiles
+# ---------------------------------------------------------------------------
+
+class _Plan(NamedTuple):
+    order: jax.Array        # [N*k] pair ids, held pairs first, by expert
+    tile_expert: jax.Array  # [max_tiles] held-expert index of each tile
+    tile_lo: jax.Array      # [max_tiles] first sorted position of the tile
+    tile_hi: jax.Array      # [max_tiles] end of its expert's run
+    n_tiles: jax.Array      # [] live tiles
+    counts: jax.Array       # [E_held] pairs on each held expert
 
 
-def moe_ffn(x, gate_w, w1, b1, w2, b2, k: int = 2,
-            capacity_factor: float = 1.25, act=jax.nn.gelu):
-    """Dense (single-device) MoE FFN. x: [N, D] → [N, D], plus aux loss.
+def _dispatch(idx, first, count: int, tile: int) -> _Plan:
+    """idx: [N, k] chosen experts (of all the layer's); the held experts are
+    `first .. first + count - 1` (`first` may be traced: a device's own range
+    under expert parallelism)."""
+    flat = idx.reshape(-1) - first
+    held = (flat >= 0) & (flat < count)
+    key = jnp.where(held, flat, count)          # absent experts sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    tiles = (counts + tile - 1) // tile
+    tile_ends = jnp.cumsum(tiles)
+    max_tiles = -(-flat.shape[0] // tile) + count
+    i = jnp.arange(max_tiles, dtype=jnp.int32)
+    e = jnp.minimum(jnp.searchsorted(tile_ends, i, side="right"),
+                    count - 1).astype(jnp.int32)
+    within = i - (tile_ends[e] - tiles[e])
+    return _Plan(order, e, starts[e] + within * tile, ends[e],
+                 tile_ends[-1], counts)
 
-    gate_w: [D, E]; w1: [E, D, H]; b1: [E, H]; w2: [E, H, D]; b2: [E, D].
-    """
-    gate = top_k_gating(x, gate_w, k=k, capacity_factor=capacity_factor)
-    expert_in = jnp.einsum(
-        "nec,nd->ecd", gate.dispatch.astype(x.dtype), x)         # [E, C, D]
-    expert_out = _expert_ffn(expert_in, w1, b1, w2, b2, act)     # [E, C, D]
-    y = jnp.einsum("nec,ecd->nd", gate.combine, expert_out)
-    return y, gate.aux_loss
+
+# ---------------------------------------------------------------------------
+# the grouped product
+# ---------------------------------------------------------------------------
+
+def _tile_rows(plan: _Plan, weight_flat, i, k: int, tile: int):
+    """Tile i: its expert, the tokens of its rows, their combine weights
+    (0 for the empty rows of an expert's last tile) and their pair ids."""
+    e = plan.tile_expert[i]
+    pos = plan.tile_lo[i] + jnp.arange(tile, dtype=jnp.int32)
+    live = pos < plan.tile_hi[i]
+    pair = plan.order.at[jnp.where(live, pos, 0)].get(
+        mode="promise_in_bounds")
+    wgt = jnp.where(live, weight_flat.at[pair].get(mode="promise_in_bounds"),
+                    0.0)
+    return e, pair // k, wgt, pair, live
+
+
+def _expert_tile(xt, w1e, b1e, w2e, b2e, wgt, act):
+    """One expert on one tile of rows: act(xt·W1 + b1)·W2 + b2, weighted.
+    Products take operands in xt's dtype and accumulate in float32."""
+    h = jnp.dot(xt, w1e, preferred_element_type=jnp.float32)
+    if b1e is not None:
+        h = h + b1e
+    h = act(h).astype(xt.dtype)
+    o = jnp.dot(h, w2e, preferred_element_type=jnp.float32)
+    if b2e is not None:
+        o = o + b2e
+    return o * wgt[:, None]
+
+
+def _at(a, e):
+    return None if a is None else lax.dynamic_index_in_dim(a, e, 0, False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _grouped_ffn(x, weight, w1, b1, w2, b2, plan, act, k, tile):
+    """Σ over the held (token, expert) pairs of weight · expert(x[token]),
+    as [N, D] float32. x: [N, D]; weight: [N, k] float32; w1: [E_held, D, H];
+    w2: [E_held, H, D]; b1, b2: [E_held, H], [E_held, D] or None."""
+    return _grouped_fwd(x, weight, w1, b1, w2, b2, plan, act, k, tile)[0]
+
+
+def _lo(a, dtype):
+    return None if a is None else a.astype(dtype)
+
+
+def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, act, k, tile):
+    w1c, w2c = w1.astype(x.dtype), w2.astype(x.dtype)
+    b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
+    weight_flat = weight.reshape(-1)
+
+    def body(i, y):
+        e, token, wgt, _, _ = _tile_rows(plan, weight_flat, i, k, tile)
+        xt = x.at[token].get(mode="promise_in_bounds")
+        o = _expert_tile(xt, _at(w1c, e), _at(b1f, e), _at(w2c, e),
+                         _at(b2f, e), wgt, act)
+        return y.at[token].add(o, mode="promise_in_bounds")
+
+    y = lax.fori_loop(0, plan.n_tiles, body,
+                      jnp.zeros(x.shape, jnp.float32))
+    return y, (x, weight, w1, b1, w2, b2, plan)
+
+
+def _grouped_bwd(act, k, tile, res, g):
+    x, weight, w1, b1, w2, b2, plan = res
+    w1c, w2c = w1.astype(x.dtype), w2.astype(x.dtype)
+    b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
+    weight_flat = weight.reshape(-1)
+    g = g.astype(jnp.float32)
+
+    def add_at(acc, e, d):
+        if acc is None:
+            return None
+        return lax.dynamic_update_index_in_dim(
+            acc, lax.dynamic_index_in_dim(acc, e, 0, False)
+            + d.astype(jnp.float32), e, 0)
+
+    def body(i, carry):
+        dx, dwgt, dw1, db1, dw2, db2 = carry
+        e, token, wgt, pair, live = _tile_rows(plan, weight_flat, i, k, tile)
+        xt = x.at[token].get(mode="promise_in_bounds")
+        gt = jnp.where(live[:, None],
+                       g.at[token].get(mode="promise_in_bounds"), 0.0)
+        args = (xt, _at(w1c, e), _at(b1f, e), _at(w2c, e), _at(b2f, e), wgt)
+        _, vjp = jax.vjp(functools.partial(_expert_tile, act=act), *args)
+        dxt, d1, dbias1, d2, dbias2, dw = vjp(gt)
+        dx = dx.at[token].add(dxt.astype(jnp.float32),
+                              mode="promise_in_bounds")
+        # an empty row's pair id is a live pair's: it must add nothing
+        dwgt = dwgt.at[pair].add(jnp.where(live, dw, 0.0),
+                                 mode="promise_in_bounds")
+        return (dx, dwgt, add_at(dw1, e, d1), add_at(db1, e, dbias1),
+                add_at(dw2, e, d2), add_at(db2, e, dbias2))
+
+    def zeros(a):
+        return None if a is None else jnp.zeros(a.shape, jnp.float32)
+
+    dx, dwgt, dw1, db1, dw2, db2 = lax.fori_loop(
+        0, plan.n_tiles, body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(weight_flat.shape, jnp.float32),
+         zeros(w1), zeros(b1), zeros(w2), zeros(b2)))
+
+    def like(d, a):
+        return None if a is None else d.astype(a.dtype)
+
+    plan_ct = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, jax.dtypes.float0), plan)
+    return (dx.astype(x.dtype), dwgt.reshape(weight.shape).astype(weight.dtype),
+            like(dw1, w1), like(db1, b1), like(dw2, w2), like(db2, b2),
+            plan_ct)
+
+
+_grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def experts_ffn(x, routing: Routing, w1, b1, w2, b2, first=0,
+                act=jax.nn.gelu, tile: Optional[int] = None):
+    """The held experts' part of the layer for routed tokens x [N, D]: the
+    experts `first .. first + w1.shape[0] - 1` of those `routing` chose
+    among. Returns (y [N, D] in x's dtype, tokens on each held expert,
+    pairs held)."""
+    count, k = w1.shape[0], routing.idx.shape[1]
+    tile = tile or TILE
+    with unit_scope("dispatch"):
+        plan = _dispatch(routing.idx, first, count, tile)
+    with unit_scope("experts"):
+        y = _grouped_ffn(x, routing.weight, w1, b1, w2, b2, plan, act, k,
+                         tile)
+    with unit_scope("combine"):
+        y = y.astype(x.dtype)
+    return y, plan.counts, jnp.sum(plan.counts)
+
+
+def moe_ffn(x, gate_w, w1, b1, w2, b2, k: int = 2, act=jax.nn.gelu,
+            experts_held: Optional[Tuple[int, int]] = None,
+            scoring: str = "softmax", correction_bias=None,
+            norm_topk: bool = True, routed_scaling: float = 1.0) -> MoEOutput:
+    """Single-device MoE FFN. x: [N, D]. gate_w: [D, E] routes over all E
+    experts; w1: [E_held, D, H], b1: [E_held, H] or None, w2: [E_held, H, D],
+    b2: [E_held, D] or None are the weights of the experts held,
+    `experts_held = (first, count)` (default: all E). Pairs on experts that
+    are not held add nothing."""
+    first, count = experts_held or (0, gate_w.shape[1])
+    if w1.shape[0] != count:
+        raise ValueError(f"moe_ffn: {count} experts held but w1 has "
+                         f"{w1.shape[0]}")
+    with unit_scope("router"):
+        routing = route(x, gate_w, k, scoring, correction_bias, norm_topk,
+                        routed_scaling)
+    y, tokens, pairs = experts_ffn(x, routing, w1, b1, w2, b2, first, act)
+    return MoEOutput(y, routing.aux_loss, tokens, pairs)
 
 
 def moe_ffn_expert_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
-                            axis: str = "ep", k: int = 2,
-                            capacity_factor: float = 1.25, act=jax.nn.gelu):
-    """Expert-parallel MoE FFN over `axis`.
-
-    x is sharded on tokens along `axis` ([N, D] global, N/ep per device);
-    expert weights are sharded on the expert dim. Two all-to-alls move token
-    slices to expert hosts and back. Per-device capacity is computed from
-    the *local* token count, so the result equals the dense path run on each
-    shard's tokens independently (same router, same weights).
-    """
+                            axis: str = "ep", k: int = 2, act=jax.nn.gelu,
+                            scoring: str = "softmax", correction_bias=None,
+                            norm_topk: bool = True,
+                            routed_scaling: float = 1.0) -> MoEOutput:
+    """Expert-parallel MoE FFN over `axis`: x [N, D] sharded on tokens, the
+    E experts' weights sharded on the expert dim (E / ep held a device).
+    Every device routes its own tokens, the tokens and their routing are
+    all-gathered, each device computes its experts' part for all tokens and
+    a reduce-scatter sums the parts back to the tokens' owners. Equal to
+    `moe_ffn` over the whole batch, whatever the routing."""
     ep = mesh.shape[axis]
     e = gate_w.shape[1]
     if e % ep != 0:
         raise ValueError(f"num experts {e} not divisible by mesh axis {ep}")
+    held = e // ep
+    has_bias = b1 is not None
 
-    def local(xs, gw, w1s, b1s, w2s, b2s):
-        # xs: [N/ep, D]; expert weights: local shard [E/ep, ...]
-        gate = top_k_gating(xs, gw, k=k, capacity_factor=capacity_factor,
-                            axis=axis)
-        exp_in = jnp.einsum("nec,nd->ecd", gate.dispatch.astype(xs.dtype), xs)
-        # [E, C, D] → each device keeps its E/ep experts, gathering every
-        # device's token slice along capacity: [E/ep, C*ep, D]
-        exp_in = lax.all_to_all(exp_in, axis, split_axis=0, concat_axis=1,
-                                tiled=True)
-        exp_out = _expert_ffn(exp_in, w1s, b1s, w2s, b2s, act)
-        # route results home: [E/ep, C*ep, D] → [E, C, D]
-        exp_out = lax.all_to_all(exp_out, axis, split_axis=1, concat_axis=0,
-                                 tiled=True)
-        y = jnp.einsum("nec,ecd->nd", gate.combine, exp_out)
-        return y, gate.aux_loss
+    def local(xs, gw, cb, w1s, w2s, *biases):
+        b1s, b2s = biases if has_bias else (None, None)
+        with unit_scope("router"):
+            r = route(xs, gw, k, scoring, cb, norm_topk, routed_scaling,
+                      axis=axis)
+        x_all, idx, weight = (lax.all_gather(a, axis, axis=0, tiled=True)
+                              for a in (xs, r.idx, r.weight))
+        first = lax.axis_index(axis) * held
+        y, tokens, pairs = experts_ffn(
+            x_all, Routing(idx, weight, r.aux_loss), w1s, b1s, w2s, b2s,
+            first, act)
+        y = lax.psum_scatter(y, axis, scatter_dimension=0, tiled=True)
+        return (y, r.aux_loss, lax.all_gather(tokens, axis, axis=0, tiled=True),
+                lax.psum(pairs, axis))
 
-    f = shard_map(local, mesh,
-                  in_specs=(P(axis), P(), P(axis), P(axis), P(axis), P(axis)),
-                  out_specs=(P(axis), P()))
-    return f(x, gate_w, w1, b1, w2, b2)
+    cb = (jnp.zeros((e,), jnp.float32) if correction_bias is None
+          else correction_bias)
+    args = (x, gate_w, cb, w1, w2) + ((b1, b2) if has_bias else ())
+    specs = (P(axis), P(), P(), P(axis), P(axis)) + \
+        ((P(axis), P(axis)) if has_bias else ())
+    f = shard_map(local, mesh, in_specs=specs,
+                  out_specs=(P(axis), P(), P(), P()))
+    return MoEOutput(*f(*args))
 
 
 def init_moe_params(rng, d_model: int, d_hidden: int, num_experts: int,

@@ -1,8 +1,10 @@
 """MoE + expert parallelism (new capability — no reference analog; the
 reference's sparse story is pserver embeddings, parameter_prefetch.cc).
 
-Checks: static-capacity router invariants, dense == expert-parallel outputs
-and gradients on the 8-device CPU mesh, balance loss behavior."""
+Checks: the router's invariants, the dropless sort-and-segment layer against
+a plain loop over the experts (values and gradients, all experts or a held
+range, all tokens on one expert), single-device == expert-parallel outputs
+and gradients on the 8-device CPU mesh, the static-graph layer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,112 +15,145 @@ from paddle_tpu.parallel import moe
 
 
 def _params(d=16, h=32, e=8, seed=0):
-    return moe.init_moe_params(jax.random.PRNGKey(seed), d, h, e)
+    gw, w1, b1, w2, b2 = moe.init_moe_params(jax.random.PRNGKey(seed), d, h, e)
+    return gw, w1, b1 + 0.1, w2, b2 - 0.05
 
 
-def test_gating_capacity_and_weights():
-    d, e, n = 16, 8, 64
+def _loop_over_experts(x, gw, w1, b1, w2, b2, k, first=0, act=jax.nn.gelu,
+                       scoring="softmax", scale=1.0):
+    """The layer written plainly: every held expert over every token,
+    weighted by the token's (renormalised) score for it, 0 where it was not
+    among the token's k."""
+    logits = x @ gw
+    s = (jax.nn.softmax(logits, -1) if scoring == "softmax"
+         else jax.nn.sigmoid(logits))
+    _, idx = jax.lax.top_k(s, k)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = w / jnp.sum(w, -1, keepdims=True) * scale
+    y = jnp.zeros_like(x)
+    for e in range(w1.shape[0]):
+        out = act(x @ w1[e] + (0 if b1 is None else b1[e])) @ w2[e] \
+            + (0 if b2 is None else b2[e])
+        y = y + out * jnp.sum(jnp.where(idx == e + first, w, 0.0), -1)[:, None]
+    return y
+
+
+def test_router_invariants():
+    d, e, n, k = 16, 8, 64, 2
     x = jax.random.normal(jax.random.PRNGKey(1), (n, d))
     gw = jax.random.normal(jax.random.PRNGKey(2), (d, e)) * 0.2
-    out = moe.top_k_gating(x, gw, k=2, capacity_factor=1.0)
-    nc = out.dispatch.shape[2]
-    # no expert slot double-booked: each (e, c) pair holds at most one token
-    per_slot = np.asarray(out.dispatch).sum(axis=0)
-    assert per_slot.max() <= 1
-    # combine weights of a kept token sum to ≤ 1 (renormalized top-k)
-    tok_mass = np.asarray(out.combine).sum(axis=(1, 2))
-    assert tok_mass.max() <= 1.0 + 1e-5
-    # capacity = ceil(k*n/e * 1.0)
-    assert nc == int(np.ceil(2 * n / e))
-    assert np.isfinite(float(out.aux_loss))
+    r = moe.route(x, gw, k=k)
+    idx, w = np.asarray(r.idx), np.asarray(r.weight)
+    assert idx.shape == w.shape == (n, k) and idx.dtype == np.int32
+    assert (idx[:, 0] != idx[:, 1]).all() and idx.min() >= 0 and idx.max() < e
+    # best first, renormalised to one
+    assert (w[:, 0] >= w[:, 1]).all()
+    np.testing.assert_allclose(w.sum(-1), 1.0, rtol=1e-6)
+    assert np.isfinite(float(r.aux_loss))
+    # sigmoid scoring: the correction bias moves the choice, not the weights
+    bias = jnp.zeros((e,)).at[5].set(10.0)
+    rs = moe.route(x, gw, k=k, scoring="sigmoid", correction_bias=bias,
+                   routed_scaling=2.5)
+    assert (np.asarray(rs.idx)[:, 0] == 5).all()
+    np.testing.assert_allclose(np.asarray(rs.weight).sum(-1), 2.5, rtol=1e-6)
+    s5 = jax.nn.sigmoid(x @ gw)[:, 5]
+    s_other = jnp.take_along_axis(jax.nn.sigmoid(x @ gw), rs.idx[:, 1:], -1)
+    np.testing.assert_allclose(np.asarray(rs.weight[:, 0]),
+                               np.asarray(2.5 * s5 / (s5 + s_other[:, 0])),
+                               rtol=1e-5)
 
 
-def test_dense_moe_shapes_and_grads():
-    d, h, e, n = 16, 32, 8, 32
-    gw, w1, b1, w2, b2 = _params(d, h, e)
+@pytest.mark.parametrize("held", [(0, 8), (2, 3)])
+def test_dense_moe_matches_a_loop_over_the_experts(held, monkeypatch):
+    monkeypatch.setattr(moe, "TILE", 16)     # several tiles an expert
+    d, h, e, n = 16, 32, 8, 200
+    p = _params(d, h, e)
     x = jax.random.normal(jax.random.PRNGKey(3), (n, d))
+    sl = slice(held[0], held[0] + held[1])
 
-    def loss_fn(params):
-        y, aux = moe.moe_ffn(x, *params, k=2, capacity_factor=2.0)
-        return jnp.mean(y ** 2) + 0.01 * aux
+    def cut(p):
+        return (p[0],) + tuple(a[sl] for a in p[1:])
 
-    loss, grads = jax.value_and_grad(loss_fn)((gw, w1, b1, w2, b2))
-    assert np.isfinite(float(loss))
-    for g in grads:
-        assert np.isfinite(np.asarray(g)).all()
-        assert float(jnp.abs(g).max()) > 0.0
+    def run(x, p):
+        return moe.moe_ffn(x, *cut(p), k=2, experts_held=held)
+
+    out = run(x, p)
+    ref = _loop_over_experts(x, *cut(p), k=2, first=held[0])
+    np.testing.assert_allclose(np.asarray(out.y), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    # what the op counted is what the router chose
+    idx = np.asarray(moe.route(x, p[0], k=2).idx)
+    counts = np.bincount(idx.ravel(), minlength=e)[sl]
+    assert np.array_equal(np.asarray(out.tokens_per_expert), counts)
+    assert int(out.pairs_held) == counts.sum()
+    g = jax.grad(lambda a: jnp.sum(jnp.sin(run(*a).y)))((x, p))
+    g_ref = jax.grad(lambda a: jnp.sum(jnp.sin(_loop_over_experts(
+        a[0], *cut(a[1]), k=2, first=held[0]))))((x, p))
+    for a, b in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_ref)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    assert float(jnp.abs(g[1][0]).max()) > 0.0      # the router learns
+
+
+def test_no_token_is_dropped_when_all_choose_one_expert(monkeypatch):
+    monkeypatch.setattr(moe, "TILE", 8)
+    d, h, e, n = 8, 16, 4, 100
+    _, w1, b1, w2, b2 = _params(d, h, e, seed=3)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (n, d))) + 0.1
+    gw = jnp.zeros((d, e)).at[:, 2].set(5.0)        # every token: expert 2
+    out = moe.moe_ffn(x, gw, w1, b1, w2, b2, k=1)
+    assert np.array_equal(np.asarray(out.tokens_per_expert), [0, 0, n, 0])
+    assert int(out.pairs_held) == n
+    one = jax.nn.gelu(x @ w1[2] + b1[2]) @ w2[2] + b2[2]
+    np.testing.assert_allclose(np.asarray(out.y), np.asarray(one),
+                               rtol=1e-5, atol=1e-5)
+    # and a layer that holds none of the chosen experts adds nothing
+    none = moe.moe_ffn(x, gw, w1[:2], b1[:2], w2[:2], b2[:2], k=1,
+                       experts_held=(0, 2))
+    assert int(none.pairs_held) == 0 and not np.asarray(none.y).any()
 
 
 @pytest.mark.parametrize("ep", [4, 8])
 def test_expert_parallel_matches_dense(ep):
     d, h, e = 16, 32, 8
     n = 8 * 16  # divisible by ep
-    gw, w1, b1, w2, b2 = _params(d, h, e)
+    p = _params(d, h, e)
     x = jax.random.normal(jax.random.PRNGKey(4), (n, d))
     mesh = Mesh(np.array(jax.devices()[:ep]), ("ep",))
-
-    y_ep, aux_ep = moe.moe_ffn_expert_parallel(
-        x, gw, w1, b1, w2, b2, mesh, axis="ep", k=2, capacity_factor=8.0)
-
-    # dense reference on each shard's tokens independently (the EP router
-    # runs per-shard); ample capacity → no drops → results equal
-    ys = []
-    auxs = []
-    for s in range(ep):
-        xs = x[s * (n // ep):(s + 1) * (n // ep)]
-        y, aux = moe.moe_ffn(xs, gw, w1, b1, w2, b2, k=2, capacity_factor=8.0)
-        ys.append(y)
-        auxs.append(aux)
-    y_ref = jnp.concatenate(ys)
-    np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
+    got = moe.moe_ffn_expert_parallel(x, *p, mesh, axis="ep", k=2)
+    ref = moe.moe_ffn(x, *p, k=2)
+    # whatever the routing: nothing is dropped, so the whole batch agrees
+    np.testing.assert_allclose(np.asarray(got.y), np.asarray(ref.y),
                                rtol=2e-5, atol=2e-5)
-    # EP aux loss is the pmean of per-shard stats; compare to the average
-    np.testing.assert_allclose(
-        float(aux_ep),
-        float(e * jnp.sum(
-            jnp.mean(jnp.stack([_top1_frac(xs_i, gw, e) for xs_i in
-                                jnp.split(x, ep)]), 0)
-            * jnp.mean(jnp.stack([_prob_frac(xs_i, gw) for xs_i in
-                                  jnp.split(x, ep)]), 0))),
-        rtol=1e-4)
-
-
-def _top1_frac(xs, gw, e):
-    p = jax.nn.softmax(xs.astype(jnp.float32) @ gw, -1)
-    return jnp.mean(jax.nn.one_hot(jnp.argmax(p, -1), e), axis=0)
-
-
-def _prob_frac(xs, gw):
-    return jnp.mean(jax.nn.softmax(xs.astype(jnp.float32) @ gw, -1), axis=0)
+    np.testing.assert_allclose(float(got.aux_loss), float(ref.aux_loss),
+                               rtol=1e-5)
+    assert np.array_equal(np.asarray(got.tokens_per_expert),
+                          np.asarray(ref.tokens_per_expert))
+    assert int(got.pairs_held) == int(ref.pairs_held) == 2 * n
 
 
 def test_expert_parallel_grads_match_dense():
     d, h, e, ep = 8, 16, 4, 4
     n = 4 * 8
-    gw, w1, b1, w2, b2 = _params(d, h, e, seed=7)
+    p = _params(d, h, e, seed=7)
     x = jax.random.normal(jax.random.PRNGKey(5), (n, d))
     mesh = Mesh(np.array(jax.devices()[:ep]), ("ep",))
 
-    def loss_ep(params):
-        y, aux = moe.moe_ffn_expert_parallel(
-            x, *params, mesh=mesh, axis="ep", k=1, capacity_factor=8.0)
-        return jnp.sum(y ** 2) + 0.1 * aux
+    def loss_ep(a):
+        out = moe.moe_ffn_expert_parallel(a[0], *a[1], mesh=mesh, axis="ep",
+                                          k=1)
+        return jnp.sum(out.y ** 2) + 0.1 * out.aux_loss
 
-    def loss_dense(params):
-        gw = params[0]
-        tot = 0.0
-        for xs in jnp.split(x, ep):
-            y, _ = moe.moe_ffn(xs, *params, k=1, capacity_factor=8.0)
-            tot = tot + jnp.sum(y ** 2)
-        # EP aux pools f/P stats across shards BEFORE the product
-        shards = jnp.split(x, ep)
-        f = jnp.mean(jnp.stack([_top1_frac(s, gw, e) for s in shards]), 0)
-        p = jnp.mean(jnp.stack([_prob_frac(s, gw) for s in shards]), 0)
-        return tot + 0.1 * (e * jnp.sum(f * p))
+    def loss_dense(a):
+        out = moe.moe_ffn(a[0], *a[1], k=1)
+        return jnp.sum(out.y ** 2) + 0.1 * out.aux_loss
 
-    g_ep = jax.grad(loss_ep)((gw, w1, b1, w2, b2))
-    g_dn = jax.grad(loss_dense)((gw, w1, b1, w2, b2))
-    for a, b in zip(g_ep, g_dn):
+    g_ep = jax.grad(loss_ep)((x, p))
+    g_dn = jax.grad(loss_dense)((x, p))
+    for a, b in zip(jax.tree_util.tree_leaves(g_ep),
+                    jax.tree_util.tree_leaves(g_dn)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-5)
 
@@ -137,9 +172,9 @@ def test_moe_under_jit_train_step():
     @jax.jit
     def step(params, state, x):
         def loss_fn(p):
-            y, aux = moe.moe_ffn_expert_parallel(
-                x, *p, mesh=mesh, axis="ep", k=2, capacity_factor=2.0)
-            return jnp.mean((y - x) ** 2) + 0.01 * aux
+            out = moe.moe_ffn_expert_parallel(x, *p, mesh=mesh, axis="ep",
+                                              k=2)
+            return jnp.mean((out.y - x) ** 2) + 0.01 * out.aux_loss
         loss, grads = jax.value_and_grad(loss_fn)(params)
         upd, state = opt.update(grads, state)
         return optax.apply_updates(params, upd), state, loss
@@ -151,7 +186,8 @@ def test_moe_under_jit_train_step():
 
 
 def test_moe_layer_static_graph_trains():
-    """layers.moe_ffn in a static program: trains dense, loss decreases."""
+    """layers.moe_ffn in a static program: trains dense, loss decreases, and
+    the counters come out with the loss."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
 
@@ -159,8 +195,8 @@ def test_moe_layer_static_graph_trains():
     with fluid.program_guard(main, startup):
         x = layers.data("x", [16])
         y = layers.data("y", [16])
-        h, aux = layers.moe_ffn(x, num_experts=4, hidden_size=32, k=2,
-                                capacity_factor=4.0)
+        h, aux, tokens, pairs = layers.moe_ffn(
+            x, num_experts=4, hidden_size=32, k=2, return_counts=True)
         mse = layers.reduce_mean(layers.square(layers.elementwise_sub(h, y)))
         loss = layers.elementwise_add(mse, layers.scale(aux, scale=0.01))
         fluid.optimizer.Adam(0.01).minimize(loss)
@@ -171,9 +207,12 @@ def test_moe_layer_static_graph_trains():
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.TPUPlace())
         exe.run(startup)
-        losses = [float(exe.run(main, feed=feed, fetch_list=[loss])[0])
-                  for _ in range(15)]
+        outs = [exe.run(main, feed=feed, fetch_list=[loss, tokens, pairs])
+                for _ in range(15)]
+    losses = [float(o[0]) for o in outs]
     assert losses[-1] < losses[0] * 0.8, (losses[0], losses[-1])
+    assert outs[-1][1].shape == (4,) and outs[-1][1].sum() == 64
+    assert int(outs[-1][2]) == 64               # nothing dropped, ever
 
 
 def test_moe_layer_expert_parallel_matches_dense():
@@ -188,8 +227,7 @@ def test_moe_layer_expert_parallel_matches_dense():
             main.random_seed = startup.random_seed = 7
             x = layers.data("x", [16])
             y = layers.data("y", [16])
-            h, aux = layers.moe_ffn(x, num_experts=8, hidden_size=32, k=1,
-                                    capacity_factor=8.0)
+            h, aux = layers.moe_ffn(x, num_experts=8, hidden_size=32, k=1)
             mse = layers.reduce_mean(
                 layers.square(layers.elementwise_sub(h, y)))
             loss = layers.elementwise_add(mse, layers.scale(aux, scale=0.01))
@@ -216,16 +254,16 @@ def test_moe_layer_expert_parallel_matches_dense():
         got = [float(exe.run(prog, feed=feed, fetch_list=[loss])[0])
                for _ in range(4)]
 
-    # EP router runs per-shard (local capacity/cumsum); with ample capacity
-    # no tokens drop, so combine weights — and losses — match the dense run.
-    # aux differs only by stat pooling order, covered by the tolerance on
-    # the 0.01-scaled term.
+    # the mesh run routes every shard's tokens with the same router and drops
+    # nothing, so the losses are the single-device run's
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
 
 
-def test_moe_layer_custom_param_attr_distinct_params():
-    """A user-supplied param_attr must yield five distinct parameters (a
-    shared attr would alias all five under one name)."""
+@pytest.mark.parametrize("bias,expected", [(None, 5), (False, 3)])
+def test_moe_layer_custom_param_attr_distinct_params(bias, expected):
+    """A user-supplied param_attr must yield distinct parameters (a shared
+    attr would alias them all under one name); `bias_attr=False` leaves the
+    experts without biases; a held range sizes the experts' weights."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
     from paddle_tpu.param_attr import ParamAttr
@@ -233,11 +271,14 @@ def test_moe_layer_custom_param_attr_distinct_params():
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         x = layers.data("x", [8])
-        h, aux = layers.moe_ffn(x, num_experts=2, hidden_size=4,
+        h, aux = layers.moe_ffn(x, num_experts=4, hidden_size=4,
                                 param_attr=ParamAttr(name="moe0",
-                                                     learning_rate=0.5))
-    names = [v.name for v in main.global_block().all_parameters()]
-    assert len(names) == len(set(names)) == 5, names
+                                                     learning_rate=0.5),
+                                bias_attr=bias, experts_held=(2, 2))
+    params = {v.name: v for v in main.global_block().all_parameters()}
+    assert len(params) == expected, sorted(params)
+    assert tuple(params["moe0.gate"].shape) == (8, 4)
+    assert tuple(params["moe0.w1"].shape) == (2, 8, 4)
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.TPUPlace())
         exe.run(startup)

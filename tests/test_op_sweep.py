@@ -716,8 +716,9 @@ FIXTURES["filter_by_instag"] = Fx(
 FIXTURES["moe_ffn"] = Fx(
     {"X": f32(4, 8), "GateW": sym(8, 2), "W1": sym(2, 8, 16),
      "B1": sym(2, 16), "W2": sym(2, 16, 8), "B2": sym(2, 8)},
-    {"k": 1, "capacity_factor": 2.0, "act": "relu"},
-    outs=("Out", "AuxLoss"), grad=None)
+    {"k": 1, "act": "gelu"},
+    outs=("Out", "AuxLoss", "TokensPerExpert", "PairsHeld"),
+    grad="W1", gout="Out", delta=1e-3)
 
 # ------------------------------------------------------- sampled / sparse
 FIXTURES["nce"] = Fx(
@@ -886,6 +887,21 @@ FIXTURES["linear_softmax_with_cross_entropy"] = Fx(
                         1], "int64").reshape(2, 6, 1)},
     {"ignore_index": -100}, outs=("Loss", "RowsComputed", "Labelled"),
     grad="X", gout="Loss", delta=1e-3)
+# PR 26's ops (drawn after everything above, for the same reason): RMS
+# norm gated by group, the causal depthwise convolution, the state-space
+# scan (parity with the literal recurrence: tests/test_nemotron_h.py) and
+# squared ReLU (kinked at 0: no finite-difference check)
+FIXTURES["rms_norm"] = Fx(
+    {"X": sym(3, 5, 8), "Scale": f32(8), "Gate": sym(3, 5, 8)},
+    {"epsilon": 1e-5, "group_size": 4}, delta=1e-3)
+FIXTURES["causal_conv1d"] = Fx(
+    {"X": sym(2, 6, 4), "Filter": sym(4, 3), "Bias": sym(4)},
+    {"activation": "silu"}, delta=1e-3)
+FIXTURES["ssd_scan"] = Fx(
+    {"X": sym(2, 8, 2 * 3), "Dt": sym(2, 8, 2), "ALog": sym(2),
+     "B": sym(2, 8, 4), "C": sym(2, 8, 4), "D": f32(2), "DtBias": sym(2)},
+    {"num_heads": 2, "n_groups": 1, "chunk": 4}, delta=1e-3)
+FIXTURES["relu2"] = Fx({"X": sym(3, 8) + 0.05}, grad=None)
 # long-tail ops that are smooth W.R.T. THE PERTURBED SLOT under the
 # harness's fixed PRNG key: sampled ops (nce, sample_logits) draw the
 # same samples on every FD evaluation, and selection ops (multiplex,
@@ -1076,6 +1092,7 @@ GOLDEN = {
     "logsigmoid": lambda x: -np.log1p(np.exp(-x)),
     "tanh_shrink": lambda x: x - np.tanh(x),
     "relu6": lambda x: np.clip(x, 0, 6),
+    "relu2": lambda x: np.maximum(x, 0) ** 2,
     "leaky_relu": lambda x: np.where(x >= 0, x, 0.02 * x),
     "elu": lambda x: np.where(x >= 0, x, np.exp(x) - 1),
     "softmax": _np_softmax,

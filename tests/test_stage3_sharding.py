@@ -206,6 +206,19 @@ def test_remat_policy_rejects_unknown_string():
         resolve_remat("everything")
 
 
+def test_a_programs_own_remat_policy_is_taken_last():
+    """`Program.remat_policy` (a model builder's request) is what
+    resolve_remat takes where no strategy and no environment gives one."""
+    from paddle_tpu.core.compiler import resolve_remat
+    main = fluid.Program()
+    assert main.remat_policy is None
+    assert resolve_remat(program=main).token == ("none",)
+    main.remat_policy = "full"
+    assert resolve_remat(program=main).token == ("full", None)
+    assert resolve_remat("minimal", program=main).token == ("minimal", None)
+    assert main.clone().remat_policy == "full"
+
+
 def test_remat_unit_attr_tagging():
     main = fluid.Program()
     with fluid.program_guard(main, fluid.Program()):

@@ -147,3 +147,63 @@ def test_labelled_rows_head_compiles_for_v5e_at_ernie_width(one_chip):
     assert f"[{n},{v}]" not in text and f"[{v},{n}]" not in text
     dense_logits = n * v * 2
     assert compiled.memory_analysis().temp_size_in_bytes < dense_logits / 2
+
+
+def test_gqa_flash_attention_compiles_for_v5e_at_nemotron_width(one_chip):
+    """The attention block of `nemotron3_nano.train8k`: 32 query heads on 2
+    key/value heads of 128, causal, T 8,192, bf16, forward and backward,
+    through the TPU's own compiler (Mosaic's tiling and VMEM limits): three
+    kernels, k and v never copied per query head, dk and dv summed over the
+    group to the two heads they belong to."""
+    b, t, hq, hkv, d = 2, 8192, 32, 2, 128
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_packed(
+            q, k, v, hq, causal=True, num_kv_heads=hkv).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((b, t, n * d), jnp.bfloat16,
+                                 sharding=one_chip) for n in (hq, hkv, hkv)]
+    # the suite asks for float32-exact products; the chip's kernels take
+    # their bf16 operands as they are
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    dq, dk, dv = jax.eval_shape(jax.grad(loss, (0, 1, 2)), *args)
+    assert dk.shape == dv.shape == (b, t, hkv * d) and dq.shape[2] == hq * d
+    # nothing the size of k or v repeated for all 32 query heads is an
+    # operand of a kernel: the kernels' k/v operands are [B*2, T, 128]
+    assert f"bf16[{b * hkv},{t},{d}]" in text
+
+
+def test_grouped_expert_product_compiles_for_v5e_at_nemotron_width(one_chip):
+    """The routed experts of one block of `nemotron3_nano.train8k` (16,384
+    tokens, top-6 of 128, 8 experts of 2688 x 1856 held, bf16 activations
+    over float32 masters), loss and gradients: the two loops keep their
+    dynamic length, and nothing of the size of all 98,304 pairs' rows (0.5
+    GB in bf16) exists."""
+    from paddle_tpu.ops.activation_ops import relu2
+    from paddle_tpu.parallel import moe
+
+    n, d, h, e, held, k = 16384, 2688, 1856, 128, 8, 6
+
+    def loss(x, gate, w1, w2):
+        out = moe.moe_ffn(x, gate, w1, None, w2, None, k=k, act=relu2,
+                          experts_held=(0, held), scoring="sigmoid",
+                          routed_scaling=2.5)
+        return jnp.sum(out.y.astype(jnp.float32)), out.pairs_held
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((n, d), jnp.bfloat16),
+                                 ((d, e), jnp.float32),
+                                 ((held, d, h), jnp.float32),
+                                 ((held, h, d), jnp.float32))]
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)
+                       ).trace(*args).lower(
+                           lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2
+    assert f"[{n * k},{d}]" not in text and f"[{n * k},{h}]" not in text
+    all_pairs_rows = n * k * d * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * all_pairs_rows
