@@ -506,11 +506,39 @@ def _conv_bn_case(name, n, ci, hw, co):
         _with_grads(kernel, 5), _with_grads(reference, 5), 6e-2)
 
 
+def _ssd_case(name, b, t, heads=64, p=64, groups=8, n=128, chunk=128):
+    """The Mamba-2 scan's forward and backward kernels against the einsum
+    form (ops/ssm_ops.py), at Nemotron's widths: bf16 x, B, C, time steps
+    over the published [1e-3, 1e-1], A = -1..-H as the mixer starts."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas_kernels import ssd_scan
+    _require(ssd_scan.supports(t, heads, p, groups, n, chunk),
+             f"ssd_scan.supports rejects T {t}, {heads} heads of {p}, "
+             f"{groups} groups, state {n}")
+
+    def make_args(rng):
+        def normal(*shape):
+            return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (b, t, heads)))
+        return (normal(b, t, heads, p), jnp.asarray(dt, jnp.float32),
+                -jnp.arange(1, heads + 1, dtype=jnp.float32),
+                normal(b, t, groups, n), normal(b, t, groups, n),
+                jnp.ones((heads,), jnp.float32))
+
+    return KernelCase(
+        name, make_args,
+        _with_grads(lambda *a: ssd_scan.ssd_scan(*a, chunk), 6),
+        _with_grads(lambda *a: ssm_ops.ssd_scan_einsum(*a, chunk), 6), 2e-2)
+
+
 def kernel_cases(batch: Optional[int] = None):
     """The shapes the four models put through each kernel: ERNIE (b64, T=512,
     12 heads, [B,1,T] bias), NMT-big (16 heads; causal decoder, block-sparse
     packed self and cross attention), ring attention's causal T=4096 block,
-    ResNet-50's bottleneck tails at batch 128. `batch` overrides every batch
+    ResNet-50's bottleneck tails at batch 128, Nemotron's Mamba-2 scan at
+    the benchmark cell's own shape (b2 x T8192). `batch` overrides every batch
     size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
     images fused_bn's own shape gate needs (1024 rows, a multiple of 8)."""
     b = (lambda default, least=1: max(batch, least) if batch else default)
@@ -533,6 +561,7 @@ def kernel_cases(batch: Optional[int] = None):
                       56, 256),
         _conv_bn_case("fused_conv_bn_act_128x512x7x7_to_2048", b(128, 24),
                       512, 7, 2048),
+        _ssd_case("ssd_scan_t8192_h64x64_g8_n128", b(2), 8192),
     ]
 
 

@@ -322,7 +322,13 @@ def ssd_scan(x: Variable, dt: Variable, b: Variable, c: Variable,
     [B, T, H*P], dt [B, T, H] (raw: the op adds `dt_bias` and applies
     softplus), b, c [B, T, G*N]. Creates the per-head parameters A_log
     (A = -exp(A_log)), D (the skip) and dt_bias, each [H]. T must be a
-    multiple of `chunk`."""
+    multiple of `chunk`.
+
+    Which form computes it is decided when the op is lowered, by the backend
+    and the shapes alone: on a TPU, with `chunk`, N and a group's
+    (H / G)·P multiples of 128 and P a divisor of 128, two Pallas kernels
+    (ops/pallas_kernels/ssd_scan.py; per data shard under a mesh); anywhere
+    else XLA einsums (ops/ssm_ops.py). Both give the same numbers."""
     helper = LayerHelper("ssd_scan", name=name)
     a_log = helper.create_parameter(a_log_attr, shape=[num_heads],
                                     dtype=x.dtype,

@@ -10,11 +10,28 @@ recurrence, per head h with state size N and head dim P (Dao & Gu 2024,
 
 `ssd_scan` computes it in chunks of `chunk` positions: inside a chunk as a
 masked [chunk, chunk] product (the dual, attention-like form), between chunks
-through the chunk states, which are combined by one [chunks, chunks] decay
-matrix — einsums throughout, no loop, and nothing of size [T, T]. Decays, dt
-and the states are float32; the products take their operands in x's dtype
-(bf16 under AMP) and accumulate in float32. Its backward (`custom_vjp`) keeps
-the op's inputs only and recomputes the chunk-local terms and the states.
+through the chunk states; nothing of size [T, T]. Decays, dt and the states
+are float32; the products take their operands in x's dtype (bf16 under AMP)
+and accumulate in float32. Two forms compute the same numbers:
+
+- *the kernels* (ops/pallas_kernels/ssd_scan.py): a Pallas forward and a
+  Pallas backward kernel that carry the state from chunk to chunk in VMEM
+  and keep each head's [chunk, chunk] decays and weights there. They run
+  where `pallas_kernels.ssd_scan.takes` says: on a TPU backend, with
+  `chunk`, N and a group's R·P multiples of 128, a head size P that
+  divides 128, at most 40 heads a group, and T whole chunks. Under a mesh
+  the op runs them per data shard (a Mosaic call cannot be partitioned by
+  GSPMD).
+- *the einsum form* (`_ssd`, here): whole-array einsums, the chunk states
+  combined by one [chunks, chunks] decay matrix, no loop. Its backward
+  (`custom_vjp`) keeps the op's inputs only and recomputes the chunk-local
+  terms and the states. It runs everywhere else (off the TPU, and for the
+  shapes the kernels do not take), and it is the kernels' oracle in the
+  tests.
+
+The shapes and the backend choose; no flag, attribute or argument does. The
+counter `ops/ssd_scan_lowered{path="pallas"|"einsum"}` says which form an op
+was lowered to.
 
 Both ops are gray under AMP (not listed in fp16_lists.py): they take the
 activations in the dtype they arrive in and keep what is sensitive in float32
@@ -117,24 +134,51 @@ def _between_chunks(total):
     return jnp.exp(jnp.where(keep, diff, -jnp.inf))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def ssd_scan(x, dt, a, b, c, chunk):
-    """The chunked state-space-dual scan (see the module docstring)."""
-    return _ssd(x, dt, a, b, c, chunk)
+def _ssd_skip(x, dt, a, b, c, d, chunk):
+    """The einsum form whole: the scan, + d ∘ x, in x's dtype."""
+    y = _ssd(x, dt, a, b, c, chunk)
+    y = y + d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
-def _ssd_fwd(x, dt, a, b, c, chunk):
-    return _ssd(x, dt, a, b, c, chunk), (x, dt, a, b, c)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def ssd_scan_einsum(x, dt, a, b, c, d, chunk):
+    """`ssd_scan` by the einsum form, whatever the backend and the shapes."""
+    return _ssd_skip(x, dt, a, b, c, d, chunk)
+
+
+def _ssd_fwd(x, dt, a, b, c, d, chunk):
+    return _ssd_skip(x, dt, a, b, c, d, chunk), (x, dt, a, b, c, d)
 
 
 def _ssd_bwd(chunk, res, g):
     # the inputs are all that is kept: the chunk-local products and the
     # chunk states are made again here, then differentiated
-    _, vjp = jax.vjp(functools.partial(_ssd, chunk=chunk), *res)
+    _, vjp = jax.vjp(functools.partial(_ssd_skip, chunk=chunk), *res)
     return vjp(g)
 
 
-ssd_scan.defvjp(_ssd_fwd, _ssd_bwd)
+ssd_scan_einsum.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def scan_path(t: int, h: int, p: int, g: int, n: int, chunk: int) -> str:
+    """"pallas" where the kernels take a scan of T positions, H heads of P
+    in G groups and a state of N, else "einsum" (the module docstring has
+    the rule)."""
+    from .pallas_kernels import ssd_scan as kernels
+    return "pallas" if kernels.takes(t, h, p, g, n, chunk) else "einsum"
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk):
+    """The chunked state-space-dual scan with its skip (see the module
+    docstring). x [B, T, H, P]; dt [B, T, H] float32 (> 0); a [H] float32
+    (< 0); b, c [B, T, G, N] with H a multiple of G; d [H]. Returns
+    [B, T, H, P] in x's dtype, by the kernels where they take the shapes and
+    by the einsum form elsewhere."""
+    from .pallas_kernels import ssd_scan as kernels
+    if kernels.takes(*x.shape[1:], *b.shape[2:], chunk):
+        return kernels.ssd_scan(x, dt, a, b, c, d, chunk)
+    return ssd_scan_einsum(x, dt, a, b, c, d, chunk)
 
 
 @register_op("ssd_scan")
@@ -142,7 +186,11 @@ def _ssd_scan(ctx, inputs, attrs):
     """Mamba-2's mixer core. X [B, T, H*P], Dt [B, T, H] (raw), ALog [H],
     B, C [B, T, G*N], D [H], DtBias [H]:
     dt = softplus(Dt + DtBias), A = -exp(ALog), the scan, + D * x. T must be
-    a multiple of `chunk`. Out [B, T, H*P] in X's dtype."""
+    a multiple of `chunk`. Out [B, T, H*P] in X's dtype. Under a mesh the
+    kernels run on each data shard's own sequences."""
+    from ..observability import get_registry
+    from .fused_ops import _per_data_shard, _under_mesh
+
     (x,) = inputs["X"]
     (dt,) = inputs["Dt"]
     (a_log,) = inputs["ALog"]
@@ -152,15 +200,25 @@ def _ssd_scan(ctx, inputs, attrs):
     (dt_bias,) = inputs["DtBias"]
     h, g = int(attrs["num_heads"]), int(attrs["n_groups"])
     chunk = int(attrs.get("chunk", 128))
-    bsz, t = x.shape[0], x.shape[1]
-    if t % chunk:
-        raise ValueError(f"ssd_scan: sequence length {t} is not a multiple "
-                         f"of the chunk {chunk}")
+    if x.shape[1] % chunk:
+        raise ValueError(f"ssd_scan: sequence length {x.shape[1]} is not a "
+                         f"multiple of the chunk {chunk}")
     f32 = jnp.float32
-    xh = x.reshape(bsz, t, h, -1)
-    dtf = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
-    y = ssd_scan(xh, dtf, -jnp.exp(a_log.astype(f32)),
-                 b.reshape(bsz, t, g, -1).astype(x.dtype),
-                 c.reshape(bsz, t, g, -1).astype(x.dtype), chunk)
-    y = y + d.astype(f32)[:, None] * xh.astype(f32)
-    return one(y.reshape(x.shape).astype(x.dtype))
+    path = scan_path(x.shape[1], h, x.shape[2] // h, g, b.shape[2] // g,
+                     chunk)
+
+    def scan(x, dt, b, c, a_log, d, dt_bias, key=None):
+        bsz, t = x.shape[0], x.shape[1]
+        get_registry().counter("ops/ssd_scan_lowered", path=path).inc()
+        dtf = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        y = ssd_scan(x.reshape(bsz, t, h, -1), dtf,
+                     -jnp.exp(a_log.astype(f32)),
+                     b.reshape(bsz, t, g, -1).astype(x.dtype),
+                     c.reshape(bsz, t, g, -1).astype(x.dtype),
+                     d.astype(f32), chunk)
+        return y.reshape(x.shape)
+
+    if path == "pallas" and _under_mesh(ctx):
+        return one(_per_data_shard(ctx, scan, (x, dt, b, c), None,
+                                   replicated=(a_log, d, dt_bias)))
+    return one(scan(x, dt, b, c, a_log, d, dt_bias))

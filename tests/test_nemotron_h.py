@@ -20,6 +20,8 @@ from paddle_tpu.ops.activation_ops import relu2
 from paddle_tpu.parallel import moe
 
 fa = importlib.import_module("paddle_tpu.ops.pallas_kernels.flash_attention")
+ssd_kernels = importlib.import_module(
+    "paddle_tpu.ops.pallas_kernels.ssd_scan")
 
 
 def _cfg(pattern="MEMEM*EME", experts=8, held=(2, 4), **over):
@@ -75,36 +77,150 @@ def _batches(cfg, n, b=2, t=64, seed=0):
 # the scan
 # ---------------------------------------------------------------------------
 
-def _scan_inputs(t, h, p, g, n, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    return (jax.random.normal(ks[0], (2, t, h, p)),
+def _scan_inputs(t, h, p, g, n, seed=0, dtype=jnp.float32):
+    """x, dt, A, B, C, D and a cotangent."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    return (jax.random.normal(ks[0], (2, t, h, p)).astype(dtype),
             jax.nn.softplus(jax.random.normal(ks[1], (2, t, h))),
             -jnp.exp(0.5 * jax.random.normal(ks[2], (h,))),
-            jax.random.normal(ks[3], (2, t, g, n)),
-            jax.random.normal(ks[4], (2, t, g, n)),
+            jax.random.normal(ks[3], (2, t, g, n)).astype(dtype),
+            jax.random.normal(ks[4], (2, t, g, n)).astype(dtype),
+            jax.random.normal(ks[6], (h,)),
             jax.random.normal(ks[5], (2, t, h, p)))
 
 
-@pytest.mark.parametrize("t,h,p,g,n,chunk", [
-    (64, 4, 8, 2, 16, 16), (48, 6, 4, 3, 8, 8), (32, 2, 8, 1, 4, 32)])
-def test_ssd_scan_matches_the_literal_recurrence(t, h, p, g, n, chunk):
-    x, dt, a, b, c, w = _scan_inputs(t, h, p, g, n)
-    literal = jax.vmap(ref.ssd_recurrence, in_axes=(0, 0, None, 0, 0))
-    got = ssm_ops.ssd_scan(x, dt, a, b, c, chunk)
-    want = literal(x, dt, a, b, c)
+def _literal(x, dt, a, b, c, d):
+    """The recurrence position by position, in float32, with the skip."""
+    x, b, c = (z.astype(jnp.float32) for z in (x, b, c))
+    scan = jax.vmap(ref.ssd_recurrence, in_axes=(0, 0, None, 0, 0))
+    return scan(x, dt, a, b, c) + d[:, None] * x
+
+
+def _grads(f, args, w):
+    return jax.grad(lambda *z: jnp.sum(f(*z).astype(jnp.float32) * w),
+                    tuple(range(6)))(*args)
+
+
+@pytest.fixture
+def kernels_on(monkeypatch):
+    """The scan's Pallas kernels, through the interpreter."""
+    monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
+
+
+# the einsum form's three small shapes (the rule does not take them), then
+# the smallest the kernels do take: chunk 128, N 128, T of three chunks,
+# R·P 128 and 512, one and two groups, float32 and bf16 inputs
+_EINSUM_SHAPES = [(64, 4, 8, 2, 16, 16), (48, 6, 4, 3, 8, 8),
+                  (32, 2, 8, 1, 4, 32)]
+_KERNEL_SHAPES = [(384, 2, 64, 1, 128, 128), (384, 16, 64, 2, 128, 128),
+                  (384, 4, 64, 2, 128, 128), (384, 8, 64, 1, 128, 128)]
+
+
+@pytest.mark.parametrize("path,dtype,shape", [
+    *(("einsum", "float32", s) for s in _EINSUM_SHAPES),
+    *(("pallas", "float32", s) for s in _KERNEL_SHAPES),
+    *(("pallas", "bfloat16", s) for s in _KERNEL_SHAPES)],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v)
+def test_ssd_scan_matches_the_literal_recurrence(kernels_on, path, dtype,
+                                                 shape):
+    t, h, p, g, n, chunk = shape
+    *args, w = _scan_inputs(t, h, p, g, n, dtype=jnp.dtype(dtype))
+    assert ssm_ops.scan_path(t, h, p, g, n, chunk) == path
+    got = ssm_ops.ssd_scan(*args, chunk)
+    want = _literal(*args)
+    assert got.dtype == args[0].dtype
+    # float32: PR 26's tolerances at its small shapes; at the kernels'
+    # (sums over a state of 128 and 384 positions) the literal recurrence's
+    # own round-off is larger, and the einsum form reads the same gaps there
+    # (values 2.3e-6 to 3.3e-6, dA 0.7e-5 to 3.5e-5). bf16: what rounding the
+    # weights, the state's update and the output to 8 bits allows
+    tol, gtol = {("einsum", "float32"): (2e-6, 5e-5),
+                 ("pallas", "float32"): (1e-5, 2e-4),
+                 ("pallas", "bfloat16"): (1e-2, 2e-2)}[path, dtype]
     scale = float(jnp.abs(want).max())
-    assert float(jnp.abs(got - want).max()) < 2e-6 * scale
-    g_got = jax.grad(lambda *z: jnp.sum(ssm_ops.ssd_scan(*z, chunk) * w),
-                     (0, 1, 2, 3, 4))(x, dt, a, b, c)
-    g_want = jax.grad(lambda *z: jnp.sum(literal(*z) * w),
-                      (0, 1, 2, 3, 4))(x, dt, a, b, c)
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < tol * scale
+    g_got = _grads(lambda *z: ssm_ops.ssd_scan(*z, chunk), args, w)
+    g_want = _grads(_literal, args, w)
     for u, v in zip(g_got, g_want):
-        assert float(jnp.abs(u - v).max()) < 5e-5 * float(jnp.abs(v).max())
+        assert u.dtype == v.dtype and u.shape == v.shape
+        gap = jnp.abs(u.astype(jnp.float32) - v.astype(jnp.float32)).max()
+        assert float(gap) < gtol * float(jnp.abs(v).max())
+
+
+@pytest.mark.parametrize("shape", [
+    (384, 16, 64, 2, 128), (256, 1, 128, 1, 128), (256, 8, 32, 2, 256)],
+    ids=lambda s: "x".join(map(str, s)))
+def test_the_kernels_and_the_einsum_form_agree_to_round_off(kernels_on,
+                                                            shape):
+    """Float32 inputs: the two forms differ by the order of their sums
+    alone. The one that shows is the log-decays' running sum along a chunk
+    (the kernels add by doubling strides, `cumsum` in order): the sums reach
+    -100 here, a last-place difference there is 1e-5 in the exponent, and
+    the decays carry it. Heads of 64 (two to a 128-lane tile), 128 (one) and
+    32 (four, state 256)."""
+    t, h, p, g, n = shape
+    *args, w = _scan_inputs(t, h, p, g, n, seed=1)
+    assert ssm_ops.scan_path(t, h, p, g, n, 128) == "pallas"
+    got = ssd_kernels.ssd_scan(*args, 128)
+    want = ssm_ops.ssd_scan_einsum(*args, 128)
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+    g_got = _grads(lambda *z: ssd_kernels.ssd_scan(*z, 128), args, w)
+    g_want = _grads(lambda *z: ssm_ops.ssd_scan_einsum(*z, 128), args, w)
+    for u, v in zip(g_got, g_want):       # dA and dD sum B·T·P terms
+        assert float(jnp.abs(u - v).max()) < 1e-4 * float(jnp.abs(v).max())
+
+
+def _lowered(path):
+    from paddle_tpu.observability import get_registry
+    return sum(s["value"] for s in get_registry().series()
+               if s["name"] == "ops/ssd_scan_lowered"
+               and s["labels"].get("path") == path)
+
+
+def _run_scan_op(t, h, p, g, n, chunk=128):
+    from paddle_tpu.ops import eager
+    eager._jit_cache.clear()      # lower the op anew: the counter counts that
+    x, dt, a, b, c, d, _ = _scan_inputs(t, h, p, g, n)
+    return _eager("ssd_scan", {
+        "X": [x.reshape(2, t, h * p)], "Dt": [dt], "ALog": [jnp.log(-a)],
+        "B": [b.reshape(2, t, g * n)], "C": [c.reshape(2, t, g * n)],
+        "D": [d], "DtBias": [jnp.zeros((h,))]},
+        {"num_heads": h, "n_groups": g, "chunk": chunk})["Out"][0]
+
+
+def test_the_shapes_and_the_backend_choose_the_scan_s_form(monkeypatch):
+    """Off the TPU: the einsum form, whatever the shapes. Where the kernels
+    may run (a TPU; here the interpreter): the kernels for the shapes they
+    take, the einsum form for the rest; the counter says which."""
+    taken, not_taken = (256, 2, 64, 1, 128), (256, 2, 64, 1, 64)
+    assert not ssd_kernels._on_tpu()
+    for shape in (taken, not_taken):
+        before = _lowered("einsum"), _lowered("pallas")
+        _run_scan_op(*shape)
+        assert (_lowered("einsum"), _lowered("pallas")) == (
+            before[0] + 1, before[1])
+    monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
+    before = _lowered("einsum"), _lowered("pallas")
+    got = _run_scan_op(*taken)
+    assert (_lowered("einsum"), _lowered("pallas")) == (
+        before[0], before[1] + 1)
+    _run_scan_op(*not_taken)                  # N 64: not a vreg's width
+    assert (_lowered("einsum"), _lowered("pallas")) == (
+        before[0] + 1, before[1] + 1)
+    for bad in [(250, 2, 64, 1, 128, 125), (256, 3, 64, 1, 128, 128),
+                (256, 2, 96, 1, 128, 128), (256, 2, 64, 1, 128, 64)]:
+        assert not ssd_kernels.supports(*bad), bad
+    # the op gives the same numbers by either form
+    monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", False)
+    want = _run_scan_op(*taken)
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
 
 
 def test_the_reference_s_chunked_scan_is_the_literal_recurrence():
-    x, dt, a, b, c, w = (z[0] if z.ndim > 1 else z
-                         for z in _scan_inputs(64, 4, 8, 2, 16, seed=3))
+    x, dt, a, b, c, _, w = (z[0] if z.ndim > 1 else z
+                            for z in _scan_inputs(64, 4, 8, 2, 16, seed=3))
     want = ref.ssd_recurrence(x, dt, a, b, c)
     got = ref.ssd_chunked(x, dt, a, b, c, 16)
     assert float(jnp.abs(got - want).max()) < 2e-6 * float(
@@ -120,9 +236,9 @@ def test_the_reference_s_chunked_scan_is_the_literal_recurrence():
 def test_the_scan_never_forms_a_sequence_by_sequence_array():
     """T = 512 in chunks of 16: the largest intermediate is [chunk, chunk] a
     chunk and head, never [T, T]."""
-    x, dt, a, b, c, _ = _scan_inputs(512, 2, 4, 1, 4)
+    *args, _ = _scan_inputs(512, 2, 4, 1, 4)
     jaxpr = jax.make_jaxpr(lambda *z: jax.grad(
-        lambda *y: jnp.sum(ssm_ops.ssd_scan(*y, 16)))(*z))(x, dt, a, b, c)
+        lambda *y: jnp.sum(ssm_ops.ssd_scan(*y, 16)))(*z))(*args)
     sizes = [int(np.prod(v.aval.shape)) for eqn in jaxpr.eqns
              for v in eqn.outvars if hasattr(v.aval, "shape")]
     assert max(sizes) < 2 * 512 * 512      # batch 2: far under [T, T] a head
@@ -286,10 +402,23 @@ def _reference_loss_and_grads(cfg, weights, batch):
     return jax.value_and_grad(total)(weights)
 
 
-@pytest.mark.parametrize("pattern", ["M", "E", "*"])
-def test_one_block_of_each_kind_against_the_reference(pattern):
-    cfg = _cfg(pattern)
-    main, loss, _, exe, scope = _program(cfg)
+# the smallest mixer the scan's kernels take: 4 heads of 64 in 2 groups
+# (R·P 128), state 128, chunks of 128
+_KERNEL_MIXER = dict(mamba_num_heads=4, mamba_head_dim=64, n_groups=2,
+                     ssm_state_size=128, chunk_size=128)
+
+
+@pytest.mark.parametrize("pattern,kernels", [
+    ("M", False), ("E", False), ("*", False), ("M", True)],
+    ids=["M", "E", "*", "M-kernels"])
+def test_one_block_of_each_kind_against_the_reference(monkeypatch, pattern,
+                                                      kernels):
+    cfg, t = _cfg(pattern), 64
+    if kernels:
+        monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
+        cfg, t = _cfg(pattern, **_KERNEL_MIXER), 256
+    lowered = _lowered("pallas")
+    main, loss, _, exe, scope = _program(cfg, t=t)
     weights = ref.make_weights(cfg, 5)
     params = main.global_block().all_parameters()
     assert sorted(p.name for p in params) == sorted(weights)
@@ -297,10 +426,10 @@ def test_one_block_of_each_kind_against_the_reference(pattern):
             == [k for k in weights if k.endswith(ref.FROZEN)])
     for k, v in weights.items():
         scope.set_var(k, jnp.copy(v))
-    (batch,) = _batches(cfg, 1)
+    (batch,) = _batches(cfg, 1, t=t)
     want_loss, want_grads = _reference_loss_and_grads(cfg, weights, batch)
     # the block's gradients: the program's backward, fetched by name
-    main_b, loss_b, _, exe_b, scope_b = _program(cfg, lr=1e-3)
+    main_b, loss_b, _, exe_b, scope_b = _program(cfg, t=t, lr=1e-3)
     for k, v in weights.items():
         scope_b.set_var(k, jnp.copy(v))
     (got_loss,) = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)
@@ -313,6 +442,7 @@ def test_one_block_of_each_kind_against_the_reference(pattern):
         want = want_grads[k]
         scale = max(float(jnp.abs(want).max()), 1e-6)
         assert float(jnp.abs(got - want).max()) < 2e-4 * scale, k
+    assert (_lowered("pallas") > lowered) == kernels
 
 
 def test_the_nine_block_model_follows_the_reference():
@@ -382,15 +512,21 @@ def test_the_builder_reads_the_pattern_and_counts_its_parameters():
             nh.NemotronHConfig(pattern="MX", vocab_size=8), 1, 128)
 
 
-def test_remat_blocks_give_the_same_step(monkeypatch):
+@pytest.mark.parametrize("kernels", [False, True], ids=["einsum", "kernels"])
+def test_remat_blocks_give_the_same_step(monkeypatch, kernels):
     """The blocks are recomputed in the backward pass by the builder's own
-    request; without it the step computes the same numbers."""
-    cfg = _cfg("ME*")
-    (batch,) = _batches(cfg, 1)
+    request; without it the step computes the same numbers (with the scan's
+    kernels too, whose forward then runs twice)."""
+    cfg, t = _cfg("ME*"), 64
+    if kernels:
+        monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
+        cfg, t = _cfg("ME*", **_KERNEL_MIXER), 256
+    lowered = _lowered("pallas")
+    (batch,) = _batches(cfg, 1, t=t)
     weights = ref.make_weights(cfg, 2)
     results = []
     for policy in ("full", None):
-        main, loss, _, exe, scope = _program(cfg, lr=1e-3)
+        main, loss, _, exe, scope = _program(cfg, t=t, lr=1e-3)
         main.remat_policy = policy
         for k, v in weights.items():
             scope.set_var(k, jnp.copy(v))
@@ -401,6 +537,7 @@ def test_remat_blocks_give_the_same_step(monkeypatch):
     for k in weights:
         np.testing.assert_allclose(results[0][1][k], results[1][1][k],
                                    rtol=1e-4, atol=1e-6)
+    assert (_lowered("pallas") > lowered) == kernels
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ import paddle_tpu as fluid
 from paddle_tpu.ops.pallas_kernels import fused_bn
 
 fa = importlib.import_module("paddle_tpu.ops.pallas_kernels.flash_attention")
+ssd = importlib.import_module("paddle_tpu.ops.pallas_kernels.ssd_scan")
 
 
 @pytest.fixture(autouse=True)
@@ -28,6 +29,7 @@ def _dispatch_as_on_tpu(monkeypatch):
     """Take the dispatch decisions a TPU host would take."""
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     monkeypatch.setattr(fused_bn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
 
 
 def _lower_for_tpu(fn, *args):
@@ -108,6 +110,45 @@ def test_data_parallel_labelled_rows_head_lowers_for_tpu(dp_step_text):
     assert text.count("stablehlo.while") >= 2
     assert "sdy.manual_computation" in text or "shard_map" in text
     assert "1024x100x" not in text and "8x128x100x" not in text
+
+
+def test_data_parallel_ssd_scan_lowers_for_tpu():
+    """A Mamba-2 block under a data-parallel mesh: the scan's two kernels
+    run per data shard inside a shard_map (GSPMD cannot partition a Mosaic
+    call), forward, recomputed forward and backward."""
+    from paddle_tpu.core.executor import convert_feed_value
+    from paddle_tpu.models import nemotron_h as nh
+    from paddle_tpu.observability import get_registry
+
+    def lowered():
+        return sum(s["value"] for s in get_registry().series()
+                   if s["name"] == "ops/ssd_scan_lowered"
+                   and s["labels"].get("path") == "pallas")
+
+    cfg = nh.NemotronHConfig(
+        vocab_size=64, hidden_size=32, pattern="M", mamba_num_heads=4,
+        mamba_head_dim=64, ssm_state_size=128, n_groups=2, chunk_size=128)
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = nh.build_pretrain_program(
+            cfg, 8, 256, lambda: fluid.optimizer.SGD(0.1))
+    cp = fluid.CompiledProgram(main).with_data_parallel(loss_name=loss.name)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    ids = np.zeros((8, 256), "int32")
+    feed = {k: convert_feed_value(main.global_block(), k, v)
+            for k, v in {"ids": ids, "labels": ids[:, :, None]}.items()}
+    names = sorted(v.name for v in main.list_vars()
+                   if v.persistable and scope.has_var(v.name))
+    step = cp._build(sorted(feed), [loss.name], names, names,
+                     {k: v.ndim for k, v in feed.items()})
+    before = lowered()
+    text = step.trace({n: scope.find_var(n) for n in names}, feed,
+                      jax.random.key(0)).lower(
+                          lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "sdy.manual_computation" in text or "shard_map" in text
+    assert lowered() > before
 
 
 @pytest.fixture(scope="module")
@@ -207,3 +248,54 @@ def test_grouped_expert_product_compiles_for_v5e_at_nemotron_width(one_chip):
     assert f"[{n * k},{d}]" not in text and f"[{n * k},{h}]" not in text
     all_pairs_rows = n * k * d * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * all_pairs_rows
+
+
+def _scan_structs(one_chip, b, t, h, p, g, n, dtype):
+    return [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in (((b, t, h, p), dtype),
+                              ((b, t, h), jnp.float32), ((h,), jnp.float32),
+                              ((b, t, g, n), dtype), ((b, t, g, n), dtype),
+                              ((h,), jnp.float32))]
+
+
+def test_ssd_scan_kernels_compile_for_v5e_at_nemotron_width(one_chip):
+    """A mixer's scan of `nemotron3_nano.train8k` (b2 x T8192, 64 heads of
+    64 in 8 groups, state 128, chunks of 128, bf16), forward and backward,
+    through the TPU's own compiler: two kernels, and no temporary of the
+    size of the per-head [chunk, chunk] decays the einsum form writes (537
+    MB, float32, three times over): the largest is the chunks' entering
+    states (268 MB)."""
+    b, t, h, p, g, n = 2, 8192, 64, 64, 8, 128
+    assert ssd.takes(t, h, p, g, n, 128)
+
+    def loss(*z):
+        return jnp.sum(ssd.ssd_scan(*z, 128).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(6)))).trace(
+        *_scan_structs(one_chip, b, t, h, p, g, n, jnp.bfloat16)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert f"f32[{b},{t // 128},{g},{h // g},128,128]" not in text
+    decays = b * (t // 128) * h * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * decays
+
+
+@pytest.mark.parametrize("h,p,g,n,chunk,dtype", [
+    (1, 128, 1, 128, 128, "float32"), (8, 32, 2, 128, 128, "bfloat16"),
+    (32, 8, 2, 128, 128, "bfloat16"), (4, 64, 2, 256, 256, "bfloat16")],
+    ids=["one_head_a_tile", "four_heads_a_tile", "sixteen_heads_a_tile",
+         "chunk_and_state_of_256"])
+def test_ssd_scan_kernels_compile_for_the_shapes_the_rule_takes(
+        one_chip, h, p, g, n, chunk, dtype):
+    """Mosaic accepts what `ssd_scan.supports` accepts: heads of a whole
+    128-lane tile and of a fraction of it, chunks and states of 256."""
+    assert ssd.supports(512, h, p, g, n, chunk)
+
+    def loss(*z):
+        return jnp.sum(ssd.ssd_scan(*z, chunk).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(6)))).trace(
+        *_scan_structs(one_chip, 2, 512, h, p, g, n, jnp.dtype(dtype))
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
