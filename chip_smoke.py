@@ -51,11 +51,11 @@ import numpy as np
 
 OUT_DIR = "chiprun_out"
 
-# ERNIE/BERT-base exactly as bench.py's headline section builds it
+# ERNIE/BERT-base at the shape of the benchmark's cell ernie_base.seq512
 ERNIE_LAYERS = 12
 ERNIE_BATCH, ERNIE_SEQ = 64, 512
 ERNIE_STEPS = 10
-# DeepFM at bench.py's configuration
+# DeepFM at the benchmark's configuration deepfm_criteo
 DEEPFM_VOCAB, DEEPFM_BATCH, DEEPFM_STEPS = 33_554_432, 4096, 3
 # dp4: per-chip batch of the sharded run, and the dropout-free equality run
 DP4_PER_CHIP, DP4_EQ_BATCH, DP4_EQ_STEPS, DP4_EQ_RTOL = 64, 64, 5, 1e-2

@@ -23,8 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import jax.numpy as jnp
 
-from .executor import (_RNG_STATE, _CACHE_HITS, _CACHE_MISSES, _EXECUTE_MS,
-                       _OBS, _WATCHDOG, _sig_digest, _Step, ExecContext,
+from .executor import (_RNG_STATE, _CACHE_HITS, _CACHE_MISSES, _WATCHDOG,
+                       _record_dispatch, _sig_digest, _Step, ExecContext,
                        _run_block)
 from .program import Program, Variable
 from ..observability import scopes as _scopes
@@ -589,7 +589,6 @@ class CompiledProgram:
                                         multiproc)
 
         from ..observability.flight import get_flight_recorder
-        from ..observability.steps import get_step_profiler
         with get_flight_recorder().guard(
                 "CompiledProgram._run",
                 program=f"0x{id(self._program):x}",
@@ -600,31 +599,10 @@ class CompiledProgram:
         dt_ms = call.dur_ms
 
         with trace_span("executor/telemetry"):    # the instrument, timed
-            if compiling:
-                # perf ledger for the mesh executable: trace-only lower on
-                # the avals of the call for XLA's cost numbers (the mesh jit
-                # is lazy — there is no AOT Compiled to ask), analytic IR
-                # walk otherwise
-                from ..observability import perf as _perf
-                lowered = None
-                if _perf.trace_cost_enabled():
-                    try:
-                        lowered = fn.lower(*fn._avals)
-                    except Exception:
-                        lowered = None
-                _perf.get_ledger().register(
-                    id(self._program), sig, executable=lowered,
-                    program=program, feed=feed_vals)
-                _OBS.histogram("executor/compile_ms", sig=sig).observe(dt_ms)
-                # gauge the state footprint once per compiled signature —
-                # the number ShardingStrategy shrinks — plus allocator
-                # occupancy
-                from ..observability.memory import record_state_memory
-                record_state_memory(new_state.values())
-            else:
-                _EXECUTE_MS.observe(dt_ms)
-            get_step_profiler().record(dt_ms, program_id=id(self._program),
-                                       sig=sig, compiled=compiling)
+            # on a compile also the state footprint, once per signature: the
+            # number ShardingStrategy shrinks
+            _record_dispatch(program, sig, fn, dt_ms, compiling,
+                             feed=feed_vals, new_state=new_state)
         with trace_span("executor/state_out"):
             for n, v in new_state.items():
                 scope.set_var(n, v)

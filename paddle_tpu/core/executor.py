@@ -44,6 +44,7 @@ from ..observability.watchdog import get_watchdog
 import collections
 import itertools
 import weakref
+from types import SimpleNamespace
 
 _RNG_STATE = "@RNG_STATE@"
 
@@ -63,6 +64,52 @@ _STEPS = get_step_profiler()
 _FLIGHT = get_flight_recorder()
 # the ordinal every `executor/step` span carries (plain and mesh path alike)
 _STEP_ORDINAL = itertools.count()
+
+
+def _avals_of(args):
+    """Shapes and dtypes of a call's arguments: what a step can be lowered
+    on again once the call has donated its buffers."""
+    return jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(jnp.shape(v), v.dtype), args)
+
+
+def _record_dispatch(program, sig, fn, dt_ms, compiling, *, feed, avals=None,
+                     steps=1, new_state=None):
+    """Record one dispatch of the jitted step `fn`: what `_run_program`,
+    `CompiledProgram._run` and `_run_scan` call inside `executor/telemetry`.
+
+    `dt_ms` is the time of the jitted call, which on an accelerator returns
+    when the step is enqueued: there `executor/execute_ms` and
+    `steps/wall_ms` hold the host's enqueue time, not the step's (on the
+    CPU, where dispatch is synchronous, the step's). No rate is computed
+    from it; that takes a time that ends at a fetch.
+
+    A compile is observed per signature (a shape-churning feed shows as
+    many one-count `executor/compile_ms` histograms) and registers one cost
+    entry in the perf ledger: from the AOT executable where `fn` kept one
+    (`_AutoLayoutStep`, free), else from a trace-only lower on the avals of
+    the call (`fn._avals`; `avals` where `fn` is a bare jit), else from the
+    analytic IR walk. `new_state` asks for the state-footprint gauges too;
+    `steps` > 1 marks a scan dispatch of that many steps."""
+    if compiling:
+        _OBS.histogram("executor/compile_ms", sig=sig).observe(dt_ms)
+        from ..observability import perf as _perf
+        executable = getattr(fn, "_compiled", None)
+        if executable is None and _perf.trace_cost_enabled():
+            try:
+                executable = fn.lower(*(avals or fn._avals))
+            except Exception:
+                executable = None
+        _perf.get_ledger().register(id(program), sig, executable=executable,
+                                    program=program, feed=feed, steps=steps)
+        if new_state is not None:
+            from ..observability.memory import record_state_memory
+            record_state_memory(new_state.values())
+    else:
+        _EXECUTE_MS.observe(dt_ms)
+    _STEPS.record(dt_ms, program_id=id(program), sig=sig, compiled=compiling,
+                  steps=steps)
+
 
 # live executors, so the flight recorder can dump which compiled
 # signatures were resident when a run died (weak: a GC'd executor's
@@ -976,8 +1023,7 @@ class _Step:
     def _count(self, *args):
         self.calls += 1
         if self._avals is None:
-            self._avals = jax.tree.map(
-                lambda v: jax.ShapeDtypeStruct(jnp.shape(v), v.dtype), args)
+            self._avals = _avals_of(args)
 
     def __call__(self, state, feed, key):
         self._count(state, feed, key)
@@ -1391,33 +1437,8 @@ class Executor:
         dt_ms = call.dur_ms
 
         with trace_span("executor/telemetry"):    # the instrument, timed
-            if compiling:
-                # the first call pays trace+compile (+ the first dispatch);
-                # labeled per signature so a shape-churning feed shows up as
-                # many one-count compile histograms
-                _OBS.histogram("executor/compile_ms", sig=sig).observe(dt_ms)
-                # perf ledger: one cost entry per (program, signature). The
-                # AUTO-layout AOT executable gives XLA's cost/memory
-                # analysis for free; the plain-jit fallback pays one
-                # trace-only lower on the avals of the call (or falls back
-                # to the analytic IR walk). Registered before the profiler
-                # record so even the compile dispatch can see it.
-                from ..observability import perf as _perf
-                executable = getattr(fn, "_compiled", None)
-                if executable is None and _perf.trace_cost_enabled():
-                    try:
-                        executable = fn.lower(*fn._avals)
-                    except Exception:
-                        executable = None
-                _perf.get_ledger().register(
-                    id(program), sig, executable=executable,
-                    program=program, feed=feed_vals)
-            else:
-                # steady-state host dispatch time (device work is async on
-                # real accelerators; on CPU this is the full step)
-                _EXECUTE_MS.observe(dt_ms)
-            _STEPS.record(dt_ms, program_id=id(program), sig=sig,
-                          compiled=compiling)
+            _record_dispatch(program, sig, fn, dt_ms, compiling,
+                             feed=feed_vals)
 
         with trace_span("executor/state_out"):
             for n, v in new_state.items():
@@ -1656,50 +1677,33 @@ class Executor:
         key = scope.find_var(_RNG_STATE)
         if key is None:
             key = _make_key(program.random_seed or 0)
+        avals = per_step_feed = None
         if compiling:
-            # perf ledger for the scan executable: the cost entry covers
-            # the whole K-step dispatch. The scan jit is lazy, so XLA
-            # numbers come from a trace-only lower (before the call, while
-            # the state buffers are still live / undonated); the analytic
-            # fallback scales one IR-walk step by n.
-            from types import SimpleNamespace as _NS2
-
-            from ..observability import perf as _perf
-            lowered = None
-            if _perf.trace_cost_enabled():
-                try:
-                    lowered = fn.lower(state, stacked, key)
-                except Exception:
-                    lowered = None
+            # what the perf ledger asks for once the call has donated
+            # `state`: the avals to lower on again, and one step's feed for
+            # the analytic fallback, which scales one IR-walk step by n
+            avals = _avals_of((state, stacked, key))
             per_step_feed = {
-                k: _NS2(shape=tuple(v.shape[1:]),
-                        nbytes=int(getattr(v, "nbytes", 0)) // max(n, 1))
+                k: SimpleNamespace(
+                    shape=tuple(v.shape[1:]),
+                    nbytes=int(getattr(v, "nbytes", 0)) // max(n, 1))
                 for k, v in stacked.items()}
-            _perf.get_ledger().register(
-                id(program), _sig_digest(stacked_sig), executable=lowered,
-                program=program, feed=per_step_feed, steps=n)
-        with _FLIGHT.guard(site,
-                           program=f"0x{id(program):x}",
-                           sig=_sig_digest(stacked_sig), steps=n,
-                           compiling=compiling), \
+        sig = _sig_digest(stacked_sig)
+        with _FLIGHT.guard(site, program=f"0x{id(program):x}", sig=sig,
+                           steps=n, compiling=compiling), \
                 trace_span(site.replace("Executor.", "executor/"), steps=n,
-                           sig=_sig_digest(stacked_sig)) as call:
+                           sig=sig) as call:
             ys, new_state, new_key = fn(state, stacked, key)
         dt_ms = call.dur_ms
-        if compiling:
-            _OBS.histogram("executor/compile_ms",
-                           sig=_sig_digest(stacked_sig)).observe(dt_ms)
-        else:
-            _EXECUTE_MS.observe(dt_ms)
-        _STEPS.record(dt_ms, program_id=id(program),
-                      sig=_sig_digest(stacked_sig), compiled=compiling,
-                      steps=n)
+        with trace_span("executor/telemetry"):
+            # the cost entry covers the whole n-step dispatch
+            _record_dispatch(
+                program, sig, fn, dt_ms, compiling, feed=per_step_feed,
+                avals=avals, steps=n,
+                new_state=new_state if compiled is not None else None)
         for nm, v in new_state.items():
             scope.set_var(nm, v)
         scope.set_var(_RNG_STATE, new_key)
-        if compiling and compiled is not None:
-            from ..observability.memory import record_state_memory
-            record_state_memory(new_state.values())
 
         self._advance_epilogues(program, scope, n, compiled=compiled)
         if return_numpy:

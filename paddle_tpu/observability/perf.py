@@ -1,15 +1,9 @@
 """Perf-attribution ledger: per-(program, signature) cost accounting.
 
-The reference framework's platform layer made performance a first-class
-runtime surface (profiler.h per-op timers, sorted kernel summaries);
-this reproduction had the equivalent knowledge scattered across five
-hand-rolled roofline calculations inside bench.py, so the *runtime*
-could never say how close a compiled program runs to the hardware.
-
-This module closes that gap. At compile time the dispatch sites
-(`Executor.run`, `Executor.run_batched`/`train_scanned`,
-`CompiledProgram._run`) register what one dispatch of the executable
-costs, in extraction-preference order:
+At compile time the dispatch sites (`Executor.run`,
+`Executor.run_batched`/`train_scanned`, `CompiledProgram._run`, through
+`core.executor._record_dispatch`; `Predictor.run`) register what one
+dispatch of the executable costs, in extraction-preference order:
 
 1. **XLA's own numbers** — ``cost_analysis()`` (flops, bytes accessed,
    transcendentals) and ``memory_analysis()`` (per-device
@@ -21,26 +15,30 @@ costs, in extraction-preference order:
    conv flops walked from the Program IR (×3 when the program carries a
    backward pass) plus a state/feed byte count (``source="analytic"``).
 
-At dispatch time `StepProfiler.record` joins each wall time with the
-ledger entry and the shared chip floors from
-:mod:`~paddle_tpu.observability.calibrate`, emitting live per-program
-gauges into the process registry — visible on ``/metrics``,
-``/metrics.json``, flight dumps, and federation like every other
-series:
+A rate is computed only from a time that ends at a fetch. The training
+dispatch sites have no such time (their jitted call returns when the step
+is enqueued), so they register costs and attribute nothing; a training
+rate comes from ``benchmark/``, which times blocks that end at a fetched
+loss. `Predictor.run` waits for its outputs, so it joins its wall time
+with its ledger entry (`CostLedger.on_dispatch` → `attribute`) against the
+published peaks of :mod:`~paddle_tpu.observability.calibrate` and sets
+live per-program gauges in the process registry — visible on
+``/metrics``, ``/metrics.json``, flight dumps, and federation like every
+other series:
 
 - ``perf/achieved_tflops{program,sig}``
 - ``perf/achieved_gbs{program,sig}``
 - ``perf/mfu{program,sig}``         (vs the chip's peak flops)
-- ``perf/roofline_fraction{program,sig}`` (vs max(matmul, stream) floor)
+- ``perf/roofline_fraction{program,sig}`` (vs max(matmul, stream) bound)
 
 Caveats the numbers inherit from XLA's cost model: ``bytes accessed``
 counts VMEM-staged re-reads, so achieved GB/s (and hence the roofline
 fraction of a memory-bound program) can legitimately exceed the
-measured stream floor; ``flops`` is model flops, not MXU-padded flops.
+published stream rate; ``flops`` is model flops, not MXU-padded flops.
 See docs/migration.md "Performance attribution".
 
-``PDTPU_PERF_LEDGER=0`` disables registration and dispatch-time
-attribution entirely; ``PDTPU_PERF_TRACE_COST=0`` skips the trace-only
+``PDTPU_PERF_LEDGER=0`` disables registration and attribution
+entirely; ``PDTPU_PERF_TRACE_COST=0`` skips the trace-only
 ``Lowered`` extraction on the lazy-jit paths (the one path whose
 extraction is not free — it re-traces the step function once per
 compile).
@@ -226,14 +224,16 @@ def analytic_cost(program, feed: Optional[Dict[str, Any]] = None) -> dict:
 def attribute(*, flops: float = 0.0, bytes_accessed: float = 0.0,
               seconds: float, calib: Optional[calibrate.Calibration] = None
               ) -> dict:
-    """Join a cost with a wall time against the calibrated chip floors.
+    """Join a cost with a wall time against the chip's published peaks.
+    `seconds` must end at a fetch: the time of an enqueue gives a rate
+    that is too high by the step's length over the enqueue's.
 
     Returns achieved_tflops / achieved_gbs / mfu / roofline_fraction /
     bound. roofline_fraction is floor_time/actual_time where the floor
-    is max(flops at the measured matmul rate, bytes at the measured
-    stream rate); it is NOT capped at 1.0 here — XLA's bytes_accessed
-    includes VMEM re-reads, so honest fractions can exceed unity (cap at
-    presentation time if a bounded number is wanted).
+    is max(flops at the matmul rate, bytes at the stream rate); it is
+    NOT capped at 1.0 here — XLA's bytes_accessed includes VMEM re-reads,
+    so honest fractions can exceed unity (cap at presentation time if a
+    bounded number is wanted).
     """
     calib = calib or calibrate.get_calibration()
     seconds = max(float(seconds), 1e-12)
@@ -260,8 +260,8 @@ def _pkey(program_id) -> str:
 
 
 class CostLedger:
-    """Bounded map (program, sig) → :class:`ProgramCost`, with
-    dispatch-time attribution into the registry."""
+    """Bounded map (program, sig) → :class:`ProgramCost`, with the
+    serving path's attribution into the registry."""
 
     def __init__(self, registry=None, max_entries: int = _MAX_ENTRIES):
         self._reg = registry
@@ -373,7 +373,8 @@ class CostLedger:
                     ) -> Optional[dict]:
         """Attribute one non-compile dispatch against its ledger entry;
         sets the live ``perf/*`` gauges and returns the attribution (or
-        None when there is no entry)."""
+        None when there is no entry). `wall_ms` must include the wait for
+        the outputs (`Predictor.run`'s does)."""
         if not enabled():
             return None
         entry = self.get(program_id, sig)
@@ -398,24 +399,6 @@ class CostLedger:
         reg.gauge("perf/roofline_fraction", **labels).set(
             att["roofline_fraction"])
         return att
-
-    def annotate_record(self, rec: dict) -> None:
-        """StepProfiler hook: join a step record with its ledger entry —
-        non-compile records gain ``achieved_tflops`` (plus ``mfu`` when
-        the entry has real flops) and the gauges update. Mutates `rec`
-        in place; never raises."""
-        if rec.get("compile") or "program" not in rec:
-            return
-        try:
-            att = self.on_dispatch(rec["program"], rec.get("sig"),
-                                   float(rec.get("wall_ms", 0.0)))
-        except Exception:
-            return
-        if att is None:
-            return
-        rec["achieved_tflops"] = round(att["achieved_tflops"], 4)
-        if att["mfu"] > 0.0:
-            rec["mfu"] = round(att["mfu"], 4)
 
     # -- introspection ---------------------------------------------------
     def snapshot(self) -> dict:

@@ -26,13 +26,12 @@ Safety rails (always-on profiling in production must be boring):
 Skipped arms are counted in ``profiler/skipped{reason=...}``; captures
 in ``profiler/captures{trigger=...}``.
 
-Golden traces are per-machine like `calibrate.py` floors: one JSON per
-(device kind, host) under ``PDTPU_GOLDEN_DIR`` (default
-``~/.cache/paddle_tpu/golden``), written by `record_golden()` (also a
-CLI: ``python -m paddle_tpu.tools.roofline --save-golden``) during a
-known-healthy run. Without a golden, attribution falls back to the
-capture's own top-k kernels — still a named culprit, just without the
-"vs healthy" delta.
+Golden traces are per-machine: one JSON per (device kind, host) under
+``PDTPU_GOLDEN_DIR`` (default ``~/.cache/paddle_tpu/golden``), written
+by `record_golden()` (also a CLI: ``python -m paddle_tpu.tools.roofline
+--save-golden``) during a known-healthy run. Without a golden,
+attribution falls back to the capture's own top-k kernels — still a
+named culprit, just without the "vs healthy" delta.
 
 The profiler backend is injectable (`profiler=` — anything with
 ``start(logdir)``/``stop()``) so the gating semantics are testable
@@ -102,8 +101,7 @@ def _golden_dir() -> str:
 
 def golden_path(device_kind: Optional[str] = None,
                 host: Optional[str] = None) -> str:
-    """Golden-trace cache file for this (device kind, host) — keyed the
-    same way as `calibrate.py` floors."""
+    """Golden-trace cache file for this (device kind, host)."""
     if device_kind is None:
         from .calibrate import _device_kind
         device_kind, _ = _device_kind()
@@ -144,12 +142,12 @@ def record_golden(run_step: Callable[[], None], steps: int = 2,
 
 
 def _floors() -> tuple:
-    """(mm_tflops, stream_gbs) from the calibration cache; permissive
-    fallback so attribution still tabulates on an uncalibrated box."""
+    """(mm_tflops, stream_gbs) of the published table; permissive fallback
+    so attribution still tabulates on a device kind the table lacks."""
+    from .calibrate import get_calibration
     try:
-        from .calibrate import get_calibration
-        return get_calibration().floors()
-    except Exception:
+        return get_calibration().floors
+    except ValueError:
         return (1.0, 10.0)
 
 

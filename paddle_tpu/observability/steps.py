@@ -3,13 +3,17 @@
 The reference framework's profiler emitted one RecordEvent per op; a
 jit-compiled executor's natural grain is the *step* — one device
 dispatch of the fused program. `StepProfiler.record()` is called by the
-executor after every dispatch with the wall time and identity of the
-step; the profiler enriches the record with whatever the rest of the
-runtime already published to the registry (dataio h2d time and prefetch
-queue depth when a DeviceLoader is attached, last fetch wait, device
-memory in use), keeps a rolling window for ``/debug/steps``, forwards
-each record to the flight recorder's ring, and runs a straggler
-detector over it.
+executor after every dispatch with the identity of the step and the wall
+time of the jitted call. On an accelerator that call returns when the
+step is enqueued, so `wall_ms` (and the `steps/wall_ms` histogram) is
+the host's enqueue time there, not the step's; on the CPU, where
+dispatch is synchronous, it is the step. No rate is computed from it: a
+rate needs a time that ends at a fetch (`benchmark/`, `Predictor.run`).
+The profiler enriches the record with whatever the rest of the runtime
+already published to the registry (dataio h2d time and prefetch queue
+depth when a DeviceLoader is attached, last fetch wait, device memory in
+use), keeps a rolling window for ``/debug/steps``, forwards each record
+to the flight recorder's ring, and runs a straggler detector over it.
 
 Straggler detection is median/MAD (median absolute deviation): robust
 to the long right tail of step times, no assumption of normality, and
@@ -28,12 +32,12 @@ warning line naming the step and its deviation.
 Window size: ``PDTPU_STEP_WINDOW`` (default 512).
 
 Environment sampling is rate-limited: gauge reads are cheap but
-``device_memory_stats`` is a runtime call, and at deepfm's ~1 ms steps
-sampling on every dispatch measurably slowed the hot loop (BENCH_r05's
-0.957x regression vs r04). One dispatch in ``PDTPU_STEP_SAMPLE_EVERY``
-(default 16) takes a fresh sample; the others stamp the cached values,
-so every record still carries the environment fields at the cost of up
-to 15 dispatches of staleness. The first record after construction or
+``device_memory_stats`` is a runtime call, and sampling on every
+dispatch slowed deepfm's hot loop (a CPU-era finding; not measured on
+the chip). One dispatch in ``PDTPU_STEP_SAMPLE_EVERY`` (default 16)
+takes a fresh sample; the others stamp the cached values, so every
+record still carries the environment fields at the cost of up to 15
+dispatches of staleness. The first record after construction or
 ``reset()`` always samples fresh.
 """
 from __future__ import annotations
@@ -150,7 +154,8 @@ class StepProfiler:
     def record(self, wall_ms: float, *, program_id: Optional[int] = None,
                sig: Optional[str] = None, compiled: bool = False,
                steps: int = 1, sample_env: bool = True, **extra) -> dict:
-        """Record one dispatch; returns the (possibly annotated) record.
+        """Record one dispatch; returns the record. `wall_ms` is the time
+        of the jitted call: the host's enqueue time on an accelerator.
         `compiled` marks a trace+compile dispatch (excluded from the
         straggler baseline); `steps` > 1 for run_batched dispatches."""
         rec: dict = {
@@ -168,17 +173,6 @@ class StepProfiler:
             rec.update(extra)
         if sample_env:
             self._sample_environment(rec)
-        if program_id is not None:
-            # perf-attribution join: when the dispatched program has a
-            # cost-ledger entry, the record gains achieved_tflops (and
-            # the live perf/* gauges update) — so /debug/steps and
-            # straggler anomalies carry utilization context. Lazy import:
-            # perf depends only on registry/calibrate, never on steps.
-            try:
-                from . import perf
-                perf.get_ledger().annotate_record(rec)
-            except Exception:
-                pass
 
         stream = (rec.get("program"), rec.get("sig"))
         anomaly = None

@@ -65,9 +65,8 @@ class Plan:
     fits: Optional[bool] = None
     error: Optional[str] = None
     # XLA's predicted per-microbatch-step cost (cost_analysis of the
-    # candidate executable the memory estimate already compiles) — the
-    # "predicted" half of predicted-vs-achieved: the perf ledger's
-    # perf/achieved_* gauges supply the achieved half at dispatch time
+    # candidate executable the memory estimate already compiles): the
+    # cost a measured step time (the benchmark's `step_ms`) divides into
     predicted_flops: Optional[float] = None
     predicted_bytes_accessed: Optional[float] = None
 
@@ -278,8 +277,8 @@ def _record(plan: Plan, candidates: List[Plan], where: str) -> None:
             plan.est_bytes_per_device)
     if plan.budget_bytes is not None:
         reg.gauge("planner/budget_bytes").set(plan.budget_bytes)
-    # predicted side of predicted-vs-achieved: read these against the
-    # perf/achieved_* gauges the cost ledger sets at dispatch time
+    # XLA's predicted cost of the chosen plan's step; the achieved side is
+    # a measured step time, which the benchmark has and a dispatch has not
     if plan.predicted_flops is not None:
         reg.gauge("planner/predicted_flops").set(plan.predicted_flops)
     if plan.predicted_bytes_accessed is not None:

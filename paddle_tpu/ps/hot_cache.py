@@ -1,8 +1,11 @@
 """HotRowCache — device-resident hot rows between the tier and the shards.
 
-BENCH_r05 put deepfm's exact-Adagrad path at 0.957 of its streaming
-roofline: the step already moves the touched rows at memory speed, so the
-next factor must come from *not moving them*. CTR id streams are heavily
+The row path (`ops/deferred_rows.py`: a gather, a sort and merge of
+duplicate ids, a scatter of the touched rows) is 7.8 of DeepFM's 12.8 ms
+device step in the cell `deepfm_criteo.fields` (ledger, PR 27: `rows_ms`,
+`xla_ms`), and what takes that time is moving rows: the next factor must
+come from *not moving them*. This cache has not run on the chip (no cell
+trains through the PS tier). CTR id streams are heavily
 Zipfian — a small fraction of the 33.5M-row table absorbs almost all
 touches — so the tier keeps those rows resident in HBM and lets the PS
 shards hold only the cold tail.
@@ -57,12 +60,12 @@ scatter deterministic while the executable set stays O(log slab).
 Metrics (process-wide, unlabeled so multiple tables sum):
 ``ps/cache_hits|misses|admitted|evictions|bypass|writeback_bytes``
 counters and ``ps/cache_resident_rows|dirty_rows|capacity`` gauges —
-surfaced by ``tools/ps_admin stats``/``dump-health`` and the bench.
+surfaced by ``tools/ps_admin stats``/``dump-health``.
 ``hits``/``misses`` count UNIQUE rows per step (the tier dedups before
 planning — that is the unit of pull/push traffic); the
 ``lookup_hits``/``lookup_misses`` pair weights each uid by its raw
 occurrence count, i.e. the fraction of embedding LOOKUPS served from
-resident HBM rows — the number the Zipfian bench claim is stated in.
+resident HBM rows — the number to hold against the Zipfian head mass.
 """
 from __future__ import annotations
 

@@ -16,7 +16,8 @@ scopes, so the per-step losses double as the bitwise-equivalence check
 of the handle path against ``return_numpy=True``.
 
 Run: ``python -m paddle_tpu.tools.pipeline_bench [--steps N]`` — prints
-one JSON object; ``bench.py`` embeds the same dict in the BENCH json.
+one JSON object. Off the TPU its speedup is a CPU figure, not a device
+metric.
 """
 from __future__ import annotations
 

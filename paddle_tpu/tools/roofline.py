@@ -1,12 +1,11 @@
 """Per-kernel roofline report from a chrome/jax-profiler trace.
 
-Generalizes bench.py's resnet ``per_kernel`` accounting into a
-standalone surface: given a trace (a ``.trace.json[.gz]`` file or a
+Given a trace (a ``.trace.json[.gz]`` file or a
 ``jax.profiler`` log dir), report the top-k kernels by device time with
 their achieved GB/s and TFLOP/s and ``util_vs_bound`` — the kernel's
-achieved fraction of whichever calibrated chip bound (stream or matmul)
-it sits closer to — plus the sub-cutoff tail in aggregate. Floors come
-from the shared calibration cache (observability/calibrate.py) unless
+achieved fraction of whichever chip bound (stream or matmul) it sits
+closer to — plus the sub-cutoff tail in aggregate. The bounds are the
+device kind's published peaks (observability/calibrate.py) unless
 overridden with ``--matmul-tflops/--stream-gbs``.
 
 ``--diff OTHER`` compares two traces: per-kernel ms deltas sorted by
@@ -97,7 +96,7 @@ def _aggregate(tr: dict) -> "collections.defaultdict":
 
 def kernel_table(tr: dict, floors: Tuple[float, float], steps: int = 1,
                  cutoff_ms: float = 0.5, topk: Optional[int] = None) -> dict:
-    """The bench ``per_kernel`` dict from an in-memory trace: every
+    """The per-kernel table of an in-memory trace: every
     kernel >= cutoff_ms per step with achieved GB/s / TFLOP/s /
     util_vs_bound, the sub-cutoff tail in aggregate, and whole-trace
     aggregate rates."""
@@ -195,7 +194,7 @@ def _resolve_floors(args) -> Tuple[float, float, str]:
     if args.matmul_tflops and args.stream_gbs:
         return args.matmul_tflops, args.stream_gbs, "flags"
     from ..observability.calibrate import get_calibration
-    c = get_calibration(recalibrate=args.recalibrate)
+    c = get_calibration()
     return c.matmul_tflops, c.stream_gbs, c.source
 
 
@@ -248,8 +247,8 @@ def main(argv=None) -> int:
                         "(trace - golden)")
     p.add_argument("--golden-path", default=None,
                    help="override the golden cache file "
-                        "(default: PDTPU_GOLDEN_DIR keyed like "
-                        "calibrate.py)")
+                        "(default: under PDTPU_GOLDEN_DIR, keyed by "
+                        "device kind and host)")
     p.add_argument("--topk", type=int, default=20)
     p.add_argument("--cutoff-ms", type=float, default=0.5)
     p.add_argument("--steps", type=int, default=1,
@@ -257,9 +256,6 @@ def main(argv=None) -> int:
     p.add_argument("--json", action="store_true", dest="as_json")
     p.add_argument("--matmul-tflops", type=float, default=None)
     p.add_argument("--stream-gbs", type=float, default=None)
-    p.add_argument("--recalibrate", action="store_true",
-                   help="re-measure the chip floors instead of using the "
-                        "calibration cache")
     args = p.parse_args(argv)
 
     try:

@@ -9,7 +9,6 @@ profiler start/stop guards, and the copy-on-read histogram snapshot
 under concurrent observers — all on the CPU backend.
 """
 import json
-import os
 import threading
 
 import numpy as np
@@ -987,86 +986,6 @@ def test_serving_registers_and_unregisters_health_checks(predictor):
         srv.stop()
     _, detail = ihttp.run_health_checks()
     assert not any(n in detail for n in names)
-
-
-# -- bench subprocess isolation --------------------------------------------
-
-def test_bench_section_subprocess_forced_oom(tmp_path, monkeypatch):
-    """The isolation contract: a forced RESOURCE_EXHAUSTED inside one
-    bench section exits only that child; the parent records the error
-    AND the path of the flight dump the child wrote."""
-    import bench
-
-    monkeypatch.setenv("PDTPU_BENCH_FORCE_OOM", "ring_attn")
-    monkeypatch.setenv("PDTPU_FLIGHT_DIR", str(tmp_path))
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    extras = {}
-    result, errrec = bench._run_section_subprocess(
-        "ring_attn", extras, timeout=600)
-    assert result is None
-    assert "RESOURCE_EXHAUSTED" in errrec["error"]
-    assert errrec["flight_dump"] is not None
-    assert errrec["flight_dump"].startswith(str(tmp_path))
-    with open(errrec["flight_dump"]) as f:
-        dump = json.load(f)
-    assert dump["context"]["where"] == "bench/ring_attn"
-    assert "RESOURCE_EXHAUSTED" in dump["exception"]["message"]
-
-
-_BENCH_STUBS = """
-import json, sys
-import bench
-
-FAIL = {fail!r}
-
-def fake_section(name, extras, timeout=2400, recalibrate=False):
-    if name == FAIL:
-        return None, {{"error": "boom", "flight_dump": None}}
-    if name == "bert":
-        return {{"metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-                 "extra": {{"mfu": 0.4}}}}, None
-    return {{"extra": {{name + "_ran": True}}}}, None
-
-def fake_finalize(doc, gate_against=None):
-    print(json.dumps(doc))
-    return 0
-
-bench._run_section_subprocess = fake_section
-bench._finalize_subprocess = fake_finalize
-rc = bench.main()
-assert "jax" not in sys.modules, "the bench sequencer imported JAX"
-assert "paddle_tpu" not in sys.modules
-sys.exit(rc)
-"""
-
-
-def _run_bench_sequencer(fail):
-    import subprocess
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, "-c", _BENCH_STUBS.format(fail=fail)], cwd=root,
-        capture_output=True, text=True, timeout=120)
-    return proc, json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-def test_bench_sequencer_never_imports_jax():
-    """`python bench.py` holds no chip: the parent only sequences children
-    (stubbed here) and merges their lines, every section in order."""
-    import bench
-    proc, doc = _run_bench_sequencer(fail=None)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert doc["value"] == 1.0 and doc["extra"]["mfu"] == 0.4
-    for name in bench.SECTIONS[1:]:
-        assert doc["extra"][name + "_ran"] is True
-
-
-def test_bench_sequencer_exits_nonzero_when_a_section_fails():
-    proc, doc = _run_bench_sequencer(fail="deepfm")
-    assert proc.returncode != 0
-    assert doc["extra"]["deepfm_error"] == "boom"   # line still printed
-    assert doc["extra"]["resnet50_ran"] and doc["extra"]["root_cause_ran"]
-    assert "deepfm" in proc.stderr
 
 
 # -- timeline --flight renderer --------------------------------------------

@@ -5,9 +5,7 @@ The planner compiles candidates against shape structs and reads XLA's
 CPU mesh, which is what makes these tests real: stage3 genuinely shrinks
 the measured argument bytes here."""
 import json
-import os
 import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -116,24 +114,6 @@ def test_guard_passes_non_oom_through():
     with pytest.raises(ValueError):
         with planner.guard("test/guard"):
             raise ValueError("not a memory problem")
-
-
-# -- bench integration -----------------------------------------------------
-
-def test_forced_oom_surfaces_budget_error_with_plan(monkeypatch):
-    """PDTPU_BENCH_FORCE_OOM: the synthetic OOM inside a bench section
-    must come out of the planner guard as HbmBudgetError carrying the
-    plan in effect."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    monkeypatch.setenv("PDTPU_BENCH_FORCE_OOM", "nmt_big")
-    with pytest.raises(planner.HbmBudgetError) as ei:
-        bench._run_section_child("nmt_big")
-    assert ei.value.plan is not None
-    assert "RESOURCE_EXHAUSTED" in str(ei.value)
-    assert "stage0/remat=none/K=1" in str(ei.value)
 
 
 # -- CLI -------------------------------------------------------------------
