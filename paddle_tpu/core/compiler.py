@@ -102,9 +102,10 @@ class RematSpec:
       `fluid.remat_unit(...)` block is wrapped in one jax.checkpoint —
       "minimal" keeps matmul outputs (`jax.checkpoint_policies.
       dots_saveable`), "full"/True saves nothing (max HBM savings).
-    - ``saveable_names``: optional tuple of var names mapped onto
-      `save_only_these_names` — those intermediates are kept as residuals,
-      everything else in the unit recomputes.
+    - ``saveable_names``: the values a unit keeps as residuals, mapped onto
+      `save_only_these_names`; everything else in the unit recomputes. A
+      tuple of names (the caller's: every unit) or a dict unit path -> names
+      (the program's own `remat_keep`), or None.
     - ``token``: hashable identity for executable cache keys.
     """
 
@@ -116,11 +117,18 @@ class RematSpec:
         self.saveable_names = saveable_names
         self.token = token
 
-    def jax_policy(self, unit_decision):
-        """jax.checkpoint `policy=` for one unit's decision."""
-        if self.saveable_names:
-            return jax.checkpoint_policies.save_only_these_names(
-                *self.saveable_names)
+    def names_for(self, unit):
+        """The names of the values remat block `unit` (its path) keeps."""
+        names = self.saveable_names
+        if isinstance(names, dict):
+            names = names.get(unit)
+        return tuple(names or ())
+
+    def jax_policy(self, unit_decision, unit):
+        """jax.checkpoint `policy=` for remat block `unit` and its decision."""
+        names = self.names_for(unit)
+        if names:
+            return jax.checkpoint_policies.save_only_these_names(*names)
         if unit_decision == "minimal":
             return jax.checkpoint_policies.dots_saveable
         return None  # "full"/True: save nothing, recompute the whole unit
@@ -135,32 +143,38 @@ def resolve_remat(policy=None, legacy_remat=False, saveable_names=None,
     DistributedStrategy.remat_policy / legacy boolean-or-set
     BuildStrategy.remat) onto a RematSpec. Where none of them gives a
     policy, `program.remat_policy` (what the model's builder asked for its
-    own remat units) is taken."""
+    own remat units) is taken, and with it, where the caller names no values
+    either, what the builder said those units keep (`program.remat_keep`)."""
     names = tuple(saveable_names) if saveable_names else None
     if policy is None and program is not None:
         policy = getattr(program, "remat_policy", None)
+        keep = getattr(program, "remat_keep", None)
+        if policy is not None and names is None and keep:
+            names = {unit: tuple(ns) for unit, ns in keep.items()}
+    # the names' part of the token: a dict does not hash
+    tok = tuple(sorted(names.items())) if isinstance(names, dict) else names
     if policy is None:
         # legacy knob: True = per-op checkpoint everywhere, a set = only
         # those op types; no unit grouping (exact pre-policy behavior)
         if legacy_remat is True:
-            return RematSpec(True, None, names, ("legacy", True, names))
+            return RematSpec(True, None, names, ("legacy", True, tok))
         if isinstance(legacy_remat, (set, frozenset)) and legacy_remat:
             fs = frozenset(legacy_remat)
             return RematSpec(fs, None, names,
-                             ("legacy", tuple(sorted(fs)), names))
+                             ("legacy", tuple(sorted(fs)), tok))
         return RematSpec(False, None, None, ("none",))
     if callable(policy):
         # per-layer predicate: unit_name -> False | True | "minimal" | "full"
         return RematSpec(False, policy, names,
-                         ("predicate", id(policy), names))
+                         ("predicate", id(policy), tok))
     p = str(policy)
     if p == "none":
         return RematSpec(False, None, None, ("none",))
     if p == "minimal":
         return RematSpec(frozenset(_MINIMAL_REMAT_OPS),
-                         lambda unit: "minimal", names, ("minimal", names))
+                         lambda unit: "minimal", names, ("minimal", tok))
     if p == "full":
-        return RematSpec(True, lambda unit: "full", names, ("full", names))
+        return RematSpec(True, lambda unit: "full", names, ("full", tok))
     raise ValueError(
         f"remat_policy must be one of {REMAT_POLICIES}, a per-layer "
         f"predicate (unit_name -> bool|'minimal'|'full'), or None for the "

@@ -26,8 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import registry
+from . import remat as _remat
 from .program import (UNIT_ATTR, Block, Program, Variable,
-                      default_main_program, grad_var_name)
+                      default_main_program, grad_var_name, remat_unit_of)
 from .scope import Scope, _scope, global_scope
 
 from ..dataio.handle import FetchHandle
@@ -818,8 +819,6 @@ def _plan_remat_items(block: Block, ctx: ExecContext):
     ("group", decision, [ops]) maximal runs of consecutive ops sharing a
     remat block (`program.remat_unit_of`) whose unit decision (RematSpec.unit_policy) is
     truthy. Cheap when no policy is active (the common path)."""
-    from .program import remat_unit_of
-
     spec = ctx.remat_units
     pred = getattr(spec, "unit_policy", None) if spec is not None else None
     if pred is None or ctx.is_test:
@@ -865,8 +864,13 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
     single tape entry whose vjp recomputes the whole unit from its entry
     values under the policy's `policy=` (dots_saveable etc.). This is the
     per-model-block form of remat — per-op jax.checkpoint still saves every
-    op-boundary activation; wrapping the unit drops those too."""
+    op-boundary activation; wrapping the unit drops those too. What the unit
+    keeps all the same (`RematSpec.names_for`: op outputs, named here, and
+    residuals that ops name themselves) is weighed while it is traced, and
+    the gauges `remat/kept_values{unit}` / `remat/kept_bytes{unit}` say it."""
     spec = ctx.remat_units
+    unit = remat_unit_of(ops[0])
+    keep = frozenset(spec.names_for(unit))
     reads, read_set, writes, write_set = [], set(), [], set()
     for op in ops:
         for slot in sorted(op.inputs):
@@ -884,7 +888,6 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
     # checkpointed backward replays the SAME key, so recomputed dropout
     # masks match the forward exactly
     gkey = ctx.rng()
-    name_tags = bool(getattr(spec, "saveable_names", None))
 
     def fwd(*vals):
         sub = ExecContext(gkey, is_test=ctx.is_test, mesh=ctx.mesh,
@@ -895,14 +898,17 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
         local = dict(zip(in_names, vals))
         for op in ops:
             _run_op(op, local, sub)
-            if name_tags:
-                from jax.ad_checkpoint import checkpoint_name
-                for n in op.output_names():
-                    local[n] = checkpoint_name(local[n], n)
+            for n in op.output_names():
+                if n in keep:
+                    local[n] = _remat.kept(local[n], n)
         return tuple(local[n] for n in out_names)
 
-    wrapped = jax.checkpoint(fwd, policy=spec.jax_policy(decision))
-    out_vals, vjp_fn = jax.vjp(wrapped, *[env[n] for n in in_names])
+    wrapped = jax.checkpoint(fwd, policy=spec.jax_policy(decision, unit))
+    with _remat.weighing(keep) as weighed:
+        out_vals, vjp_fn = jax.vjp(wrapped, *[env[n] for n in in_names])
+    reg = get_registry()
+    reg.gauge("remat/kept_values", unit=unit).set(weighed.values)
+    reg.gauge("remat/kept_bytes", unit=unit).set(weighed.bytes)
     for n, v in zip(out_names, out_vals):
         env[n] = v
     # an input is non-differentiable for the GROUP only if every use of it
@@ -1200,7 +1206,12 @@ class _AutoLayoutStep(_Step):
                        and jnp.shape(v) == shapes[n])
                    for n, v in state.items()):
                 out = self._compiled(state, feed, key)
-                self._last_out = out[1]
+                # a step that takes no state (a startup program) has no leaf
+                # to know again, and holding what it made would keep alive
+                # every array the scope replaces afterwards (seeded weights
+                # set over the startup's draw: a parameter set's bytes)
+                if state:
+                    self._last_out = out[1]
                 return out
             # slow path (first call, or a var swapped via scope.set_var):
             # validate shapes/dtypes — checkpoint surgery may have replaced

@@ -271,6 +271,11 @@ class Program:
         # "full". Taken where neither BuildStrategy.remat_policy nor
         # PDTPU_REMAT_POLICY gives one (compiler.resolve_remat)
         self.remat_policy = None
+        # what those blocks keep for the backward pass under that policy
+        # (`keep`): remat block path -> the names of the values kept. Taken
+        # with `remat_policy`, and where the caller names no values of their
+        # own (BuildStrategy.remat_saveable_names)
+        self.remat_keep: Dict[str, List[str]] = {}
 
     def _bump_version(self):
         self._version += 1
@@ -419,7 +424,8 @@ def grad_var_name(name: str) -> str:
 # expressed the same boundary through RecomputeOptimizer's checkpoints=[...]
 # var list (fleet meta optimizer); here it is a trace-time scope, nested
 # remat scopes keep the innermost. A unit that is only a name never changes
-# what is rematerialised.
+# what is rematerialised. A remat block recomputes everything but the values
+# its builder declares with `keep(...)`.
 _UNIT_STACK: List[Tuple[str, bool]] = []
 
 UNIT_ATTR = "__unit__"
@@ -451,14 +457,37 @@ def remat_unit_of(op) -> Optional[str]:
     return "/".join(op.attrs[UNIT_ATTR].split("/")[:depth])
 
 
+def _remat_depth() -> int:
+    """How many leading units of the stack name the innermost remat block."""
+    return max((i + 1 for i, (_, remat) in enumerate(_UNIT_STACK) if remat),
+               default=0)
+
+
 def _tag_units(attrs: dict) -> None:
     if not _UNIT_STACK or UNIT_ATTR in attrs:
         return
     attrs[UNIT_ATTR] = "/".join(n for n, _ in _UNIT_STACK)
-    depth = max((i + 1 for i, (_, remat) in enumerate(_UNIT_STACK) if remat),
-                default=0)
+    depth = _remat_depth()
     if depth:
         attrs[REMAT_ATTR] = depth
+
+
+def keep(*values) -> None:
+    """Inside a remat block of the program being built: these values stay
+    for the backward pass, and the rest of the block is made again. A value
+    is a Variable (the output of an op of the block) or the name an op gives
+    a residual of its own (`core.remat.kept`: a kernel's outputs, a routing
+    plan). Worth it for a value whose remaking costs far more operations a
+    byte than the chip's ridge (a deep product, a kernel's forward, a sort);
+    the program's `remat_keep` carries them to `compiler.resolve_remat`."""
+    depth = _remat_depth()
+    if not depth:
+        raise ValueError("keep(): not inside a unit(..., remat=True)")
+    block = "/".join(n for n, _ in _UNIT_STACK[:depth])
+    program = default_main_program()
+    program.remat_keep.setdefault(block, []).extend(
+        v if isinstance(v, str) else v.name for v in values)
+    program._bump_version()
 
 
 _dygraph_tracer = None

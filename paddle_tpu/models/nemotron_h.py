@@ -19,10 +19,13 @@ parts are sub-units (`mamba/in_proj`, `mamba/conv`, `mamba/ssd`,
 `mamba/norm`, `mamba/out_proj`; `attn`; `moe/router`, `moe/dispatch`,
 `moe/experts`, `moe/combine` inside the `moe_ffn` op and `moe/shared`), so a
 device trace names the part every operation belongs to. The program asks for
-its blocks to be rematerialised (`Program.remat_policy = "full"`): a block
-keeps its input only and its forward is made again in the backward pass,
-which is what lets 16k tokens of a 9-block cut train beside 16 bytes a
-parameter of state.
+its blocks to be rematerialised (`Program.remat_policy = "full"`): a block's
+forward is made again in the backward pass, which is what lets 16k tokens of
+a 9-block cut train beside 16 bytes a parameter of state. A block keeps its
+input and the few values that are dear to remake and cheap to hold
+(`core.program.keep`; the rule is at `build_pretrain_program`): the
+in-projection's result, the q/k/v product with the attention kernel's
+outputs, the shared expert's first product, the routing and its plan.
 """
 from __future__ import annotations
 
@@ -34,9 +37,11 @@ import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.core.program import unit
+from paddle_tpu.core.program import keep, unit
 from paddle_tpu.initializer import (ConstantInitializer, NormalInitializer,
                                     NumpyArrayInitializer)
+from paddle_tpu.ops.pallas_kernels.flash_attention import KEPT as _ATTN_KEPT
+from paddle_tpu.parallel.moe import KEPT as _MOE_KEPT
 from paddle_tpu.param_attr import ParamAttr
 
 # a block's kind in the pattern -> its letter in a unit's name (`*` does not
@@ -126,6 +131,7 @@ def mamba_mixer(cfg: NemotronHConfig, x, pre: str):
         with unit("in_proj"):
             zxbcdt = _linear(cfg, x, cfg.d_inner + cfg.conv_dim
                              + cfg.mamba_num_heads, f"{pre}.in_proj.w")
+            keep(zxbcdt)
             z, xbc, dt = layers.split(
                 zxbcdt, [cfg.d_inner, cfg.conv_dim, cfg.mamba_num_heads],
                 dim=2)
@@ -160,6 +166,7 @@ def attention_mixer(cfg: NemotronHConfig, x, pre: str):
     kv_dim = cfg.num_kv_heads * cfg.head_dim
     with unit("attn"):
         qkv = _linear(cfg, x, q_dim + 2 * kv_dim, f"{pre}.qkv.w")
+        keep(qkv, *_ATTN_KEPT)
         q, k, v = layers.split(qkv, [q_dim, kv_dim, kv_dim], dim=2)
         ctx = layers.flash_attention(q, k, v, causal=True,
                                      num_heads=cfg.num_heads,
@@ -177,10 +184,13 @@ def moe_mixer(cfg: NemotronHConfig, x, pre: str):
             experts_held=cfg.held(), scoring="sigmoid", correction_bias=True,
             norm_topk=cfg.norm_topk_prob,
             routed_scaling=cfg.routed_scaling_factor, return_counts=True)
+        keep(*_MOE_KEPT)
         with unit("shared"):
             up = _linear(cfg, x, cfg.shared_intermediate_size,
-                         f"{pre}.shared.up.w", act="relu2")
-            shared = _linear(cfg, up, cfg.hidden_size, f"{pre}.shared.down.w")
+                         f"{pre}.shared.up.w")
+            keep(up)
+            shared = _linear(cfg, layers.relu2(up), cfg.hidden_size,
+                             f"{pre}.shared.down.w")
         with unit("combine"):
             return layers.elementwise_add(routed, shared), tokens, pairs
 
@@ -237,7 +247,16 @@ def build_pretrain_program(cfg: NemotronHConfig, batch_size: int,
             loss = layers.reduce_mean(per_token)
         if optimizer_factory is not None:
             optimizer_factory().minimize(loss)
-    # each block is recomputed from its input in the backward pass
+    # Each block is recomputed in the backward pass from its input and from
+    # what the mixers `keep`. The rule: keep a value whose remaking costs far
+    # more operations a byte held than the chip's ridge (a v5e: 197 TFLOP/s
+    # over 819 GB/s, 240) and recompute what costs a handful. A product 2,688
+    # deep is 2,688 operations a byte of its bf16 result (the in-projection,
+    # q/k/v, the shared expert's up-projection, the router's logits), the
+    # attention kernel's forward about 8,000 for `out` and `lse`, the plan a
+    # sort for a few integers. The convolution (8 a byte), the norms, relu^2,
+    # the splits and the scan's forward (its `y` and states are 0.37 GiB a
+    # layer for under 2 ms) are made again.
     main.remat_policy = "full"
     return main, startup, ["ids", "labels"], loss, counters
 

@@ -38,6 +38,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.remat import kept as _kept
+
 # 512² blocks keep the whole [T,T] score tile in VMEM for BERT-scale
 # sequence lengths: measured on v5e, bq=bk=512 runs the forward ~2.5× faster
 # than 128² (fewer grid steps amortize the per-step DMA + online-softmax
@@ -52,6 +54,12 @@ _NEG_INF = -1e30
 # returns zeros, so dropout-path numerics are TPU-only). Nothing else turns
 # the interpreter on.
 FORCE_PALLAS_INTERPRET = False
+
+# The residuals a forward rule makes itself, by the names a remat block keeps
+# them under (`core.program.keep(*KEPT)`). Both or neither: the backward
+# kernels read both, and with one of them missing the forward call is made
+# again whole.
+KEPT = ("flash_attention/out", "flash_attention/lse")
 
 
 def _on_tpu() -> bool:
@@ -1100,6 +1108,7 @@ def _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale, causal,
 def _flash_core_fwd(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
     out, lse = _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale,
                                    causal, dropout_rate)
+    out, lse = _kept(out, KEPT[0]), _kept(lse, KEPT[1])
     key = dropout_key if dropout_rate > 0.0 else None
     return out, (q, k, v, bias, key, out, lse)
 
@@ -1670,6 +1679,7 @@ def _flash_sparse_core_fwd(q, k, v, se, dropout_key, nh, sm_scale, causal,
                            dropout_rate):
     out, lse = _flash_sparse_fwd_dispatch(q, k, v, se, dropout_key, nh,
                                           sm_scale, causal, dropout_rate)
+    out, lse = _kept(out, KEPT[0]), _kept(lse, KEPT[1])
     key = dropout_key if dropout_rate > 0.0 else None
     return out, (q, k, v, se, key, out, lse)
 

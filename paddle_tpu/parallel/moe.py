@@ -44,6 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..core.remat import kept
 from ..observability.scopes import unit_scope
 from .collective import shard_map
 
@@ -81,6 +82,7 @@ def route(x, gate_w, k: int = 2, scoring: str = "softmax",
     e = gate_w.shape[1]
     logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=_HI)
+    logits = kept(logits, KEPT_ROUTING[0])
     if scoring == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
@@ -93,6 +95,9 @@ def route(x, gate_w, k: int = 2, scoring: str = "softmax",
         choose = scores + lax.stop_gradient(
             correction_bias.astype(jnp.float32))
     _, idx = lax.top_k(choose, k)                                 # [N, k]
+    # named before its first reader: a reader of the unnamed value would
+    # have the backward pass make the top-k again
+    idx = kept(idx.astype(jnp.int32), KEPT_ROUTING[1])
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
         weight = weight / jnp.maximum(
@@ -108,7 +113,7 @@ def route(x, gate_w, k: int = 2, scoring: str = "softmax",
         frac_tokens = lax.pmean(frac_tokens, axis)
         frac_probs = lax.pmean(frac_probs, axis)
     aux = e * jnp.sum(frac_tokens * frac_probs)
-    return Routing(idx.astype(jnp.int32), weight, aux)
+    return Routing(idx, kept(weight, KEPT_ROUTING[2]), aux)
 
 
 # ---------------------------------------------------------------------------
@@ -122,6 +127,16 @@ class _Plan(NamedTuple):
     tile_hi: jax.Array      # [max_tiles] end of its expert's run
     n_tiles: jax.Array      # [] live tiles
     counts: jax.Array       # [E_held] pairs on each held expert
+
+
+# What a remat block can keep of a layer (`core.program.keep(*KEPT)`), by the
+# names `route` and `_dispatch` give it: the router's logits, the chosen
+# experts and their weights, and every array of the plan that the grouped
+# product's backward reads (`counts` it does not). Small integers and [N, E]
+# floats, against a float32 product, a top-k, a sort and a search.
+KEPT_ROUTING = ("moe/logits", "moe/idx", "moe/weight")
+KEPT_PLAN = tuple(f"moe/plan.{f}" for f in _Plan._fields[:-1])
+KEPT = KEPT_ROUTING + KEPT_PLAN
 
 
 def _dispatch(idx, first, count: int, tile: int) -> _Plan:
@@ -142,8 +157,8 @@ def _dispatch(idx, first, count: int, tile: int) -> _Plan:
     e = jnp.minimum(jnp.searchsorted(tile_ends, i, side="right"),
                     count - 1).astype(jnp.int32)
     within = i - (tile_ends[e] - tiles[e])
-    return _Plan(order, e, starts[e] + within * tile, ends[e],
-                 tile_ends[-1], counts)
+    plan = (order, e, starts[e] + within * tile, ends[e], tile_ends[-1])
+    return _Plan(*map(kept, plan, KEPT_PLAN), counts)
 
 
 # ---------------------------------------------------------------------------

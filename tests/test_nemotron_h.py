@@ -501,7 +501,24 @@ def test_the_builder_reads_the_pattern_and_counts_its_parameters():
     units = {op.attrs.get("__unit__") for op in main.global_block().ops}
     assert {"blk0.M/mamba/ssd", "blk1.E/moe", "blk5.A/attn",
             "blk1.E/moe/shared", "lm_head", "loss"} <= units
+    # every block is made again in the backward pass, all but what its mixer
+    # keeps: the in-projection's result; the shared expert's first product,
+    # the routing and its plan; the q/k/v product and the kernel's outputs
     assert main.remat_policy == "full"
+    keep = main.remat_keep
+    assert sorted(keep) == sorted(
+        nh.unit_name(i, c) for i, c in enumerate(mcfg.pattern))
+    produced_in = {n: op.attrs["__unit__"] for op in main.global_block().ops
+                   for n in op.output_names()}
+    for block, names in keep.items():
+        ops = [produced_in[n] for n in names if n in produced_in]
+        own = [n for n in names if n not in produced_in]
+        if block.endswith(".M"):
+            assert (ops, own) == ([f"{block}/mamba/in_proj"], [])
+        elif block.endswith(".E"):
+            assert (ops, own) == ([f"{block}/moe/shared"], list(moe.KEPT))
+        else:
+            assert (ops, own) == ([f"{block}/attn"], list(fa.KEPT))
     # the published model: 52 blocks, 31.6B parameters
     full = nh.NemotronHConfig()
     assert (full.pattern.count("M"), full.pattern.count("E"),
@@ -512,11 +529,13 @@ def test_the_builder_reads_the_pattern_and_counts_its_parameters():
             nh.NemotronHConfig(pattern="MX", vocab_size=8), 1, 128)
 
 
+@pytest.mark.parametrize("policy", ["kept", "full"])
 @pytest.mark.parametrize("kernels", [False, True], ids=["einsum", "kernels"])
-def test_remat_blocks_give_the_same_step(monkeypatch, kernels):
+def test_remat_blocks_give_the_same_step(monkeypatch, kernels, policy):
     """The blocks are recomputed in the backward pass by the builder's own
-    request; without it the step computes the same numbers (with the scan's
-    kernels too, whose forward then runs twice)."""
+    request, all but the values it keeps ("kept") or all of them ("full":
+    nothing kept); without the request the step computes the same numbers
+    (with the scan's kernels too, whose forward then runs twice)."""
     cfg, t = _cfg("ME*"), 64
     if kernels:
         monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
@@ -525,9 +544,13 @@ def test_remat_blocks_give_the_same_step(monkeypatch, kernels):
     (batch,) = _batches(cfg, 1, t=t)
     weights = ref.make_weights(cfg, 2)
     results = []
-    for policy in ("full", None):
+    for remat in (True, False):
         main, loss, _, exe, scope = _program(cfg, t=t, lr=1e-3)
-        main.remat_policy = policy
+        assert main.remat_policy == "full" and len(main.remat_keep) == 3
+        if not remat:
+            main.remat_policy = None
+        elif policy == "full":
+            main.remat_keep = {}
         for k, v in weights.items():
             scope.set_var(k, jnp.copy(v))
         (got,) = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)
@@ -538,6 +561,221 @@ def test_remat_blocks_give_the_same_step(monkeypatch, kernels):
         np.testing.assert_allclose(results[0][1][k], results[1][1][k],
                                    rtol=1e-4, atol=1e-6)
     assert (_lowered("pallas") > lowered) == kernels
+
+
+# ---------------------------------------------------------------------------
+# what a block keeps
+# ---------------------------------------------------------------------------
+
+def _count_primitives(jaxpr, counts):
+    """Every equation of `jaxpr` and of the jaxprs inside it, by primitive; a
+    product also by its result's shape, a kernel call also by its kernel."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        counts[name] += 1
+        if name == "dot_general":
+            counts[name, tuple(eqn.outvars[0].aval.shape)] += 1
+        elif name == "pallas_call":
+            counts[name, eqn.params.get("name")
+                   or eqn.params["jaxpr"].debug_info.func_name] += 1
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count_primitives(sub, counts)
+    return counts
+
+
+def _step_primitives(cfg, t, edit=None):
+    """The primitives of the whole training step (forward, backward and
+    SGD) of `cfg`'s program, its `remat_keep` first changed by `edit`."""
+    import collections
+    from paddle_tpu.core.executor import convert_feed_value
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = nh.build_pretrain_program(
+            _model_cfg(cfg), 2, t, lambda: fluid.optimizer.SGD(0.1))
+    if edit:
+        edit(main.remat_keep)
+    exe, scope = fluid.Executor(fluid.TPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    ids = np.zeros((2, t), "int32")
+    feed = {k: convert_feed_value(main.global_block(), k, v)
+            for k, v in {"ids": ids, "labels": ids[:, :, None]}.items()}
+    names = sorted(v.name for v in main.list_vars()
+                   if v.persistable and scope.has_var(v.name))
+    step = exe._build(main, sorted(feed), [loss.name], names, names)
+    jaxpr = jax.make_jaxpr(step._step)(
+        {n: scope.find_var(n) for n in names}, feed, jax.random.key(0))
+    return _count_primitives(jaxpr.jaxpr, collections.Counter())
+
+
+@pytest.fixture
+def all_kernels_on(monkeypatch):
+    """The scan's and attention's Pallas kernels, through the interpreter."""
+    monkeypatch.setattr(ssd_kernels, "FORCE_PALLAS_INTERPRET", True)
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", True)
+
+
+# a mixer the scan's kernels take and heads the attention kernels take
+_KERNEL_BLOCKS = dict(_KERNEL_MIXER, head_dim=128)
+
+
+def test_a_block_s_backward_does_not_remake_what_it_keeps(all_kernels_on):
+    """`ME*`, the step's jaxpr with the builder's kept set against the one
+    that keeps nothing: the backward pass holds no sort, no second forward
+    call of the attention kernel, and one product fewer of each kept shape
+    (in-projection, q/k/v, the shared expert's first, the router's). The
+    scan's forward kernel is still called twice: nothing of it is kept."""
+    cfg, t = _cfg("ME*", **_KERNEL_BLOCKS), 256
+    full = _step_primitives(cfg, t, edit=dict.clear)
+    kept = _step_primitives(cfg, t)
+    mcfg, n = _model_cfg(cfg), 2 * t
+    assert (full["sort"], kept["sort"]) == (2, 1)
+    assert (full["top_k"], kept["top_k"]) == (2, 1)
+    # attention: forward, remade forward, dq, dkv -> forward, dq, dkv
+    assert (full["pallas_call", "kernel"],
+            kept["pallas_call", "kernel"]) == (2, 1)
+    for call in ("dq_kernel", "dkv_kernel", "ssd_scan_bwd"):
+        assert full["pallas_call", call] == kept["pallas_call", call] == 1
+    assert (full["pallas_call", "ssd_scan_fwd"]
+            == kept["pallas_call", "ssd_scan_fwd"] == 2)
+    for width in (mcfg.d_inner + mcfg.conv_dim + mcfg.mamba_num_heads,
+                  (mcfg.num_heads + 2 * mcfg.num_kv_heads) * mcfg.head_dim,
+                  mcfg.shared_intermediate_size, mcfg.n_routed_experts):
+        product = "dot_general", (n, width)
+        assert kept[product] == full[product] - 1 >= 1, width
+    # and no other product went: those four, and QK^T and PV inside the
+    # remade attention forward's own jaxpr
+    assert kept["dot_general"] == full["dot_general"] - 4 - 2
+
+
+def test_the_kernel_s_out_without_its_lse_keeps_nothing(all_kernels_on):
+    """The backward kernels read `out` and `lse`, the values the forward
+    rule made: with one of the two names missing the forward call is made
+    again whole, whatever else is kept."""
+    cfg, t = _cfg("*", **_KERNEL_BLOCKS), 256
+
+    def drop(name):
+        return lambda keep: keep["blk0.A"].remove(name)
+
+    assert _step_primitives(cfg, t)["pallas_call", "kernel"] == 1
+    for name in fa.KEPT:
+        assert _step_primitives(cfg, t, drop(name))[
+            "pallas_call", "kernel"] == 2, name
+
+
+def _kept_gauges(what, units=("blk0.M", "blk1.E", "blk2.A")):
+    """As the last step traced left them (the registry is the process's)."""
+    from paddle_tpu.observability import get_registry
+    return {s["labels"]["unit"]: int(s["value"])
+            for s in get_registry().series()
+            if s["name"] == f"remat/kept_{what}"
+            and s["labels"]["unit"] in units}
+
+
+def test_the_gauges_say_what_each_block_keeps():
+    """`remat/kept_bytes{unit}` and `remat/kept_values{unit}`, written when
+    the step is traced, from the shapes: float32 here. 0 where the policy
+    is "full" and nothing is named."""
+    cfg, t = _cfg("ME*"), 64
+    mcfg, n, f32 = _model_cfg(cfg), 2 * 64, 4
+    (batch,) = _batches(cfg, 1, t=t)
+    main, loss, _, exe, scope = _program(cfg, t=t, lr=1e-3)
+    exe.run(main, feed=batch, fetch_list=[loss], scope=scope)
+    k, held = mcfg.num_experts_per_tok, mcfg.held()[1]
+    tiles = -(-n * k // moe.TILE) + held
+    want = {
+        "blk0.M": n * (mcfg.d_inner + mcfg.conv_dim
+                       + mcfg.mamba_num_heads) * f32,
+        "blk1.E": (n * mcfg.shared_intermediate_size * f32     # shared up
+                   + n * mcfg.n_routed_experts * f32           # logits
+                   + 2 * n * k * 4                             # idx, weight
+                   + n * k * 4 + 3 * tiles * 4 + 4),           # the plan
+        "blk2.A": (n * (mcfg.num_heads + 2 * mcfg.num_kv_heads)
+                   * mcfg.head_dim * f32                       # qkv
+                   + n * mcfg.num_heads * mcfg.head_dim * f32  # out
+                   + n * mcfg.num_heads * 4)}                  # lse
+    assert _kept_gauges("bytes") == want
+    assert _kept_gauges("values") == {"blk0.M": 1, "blk1.E": 9, "blk2.A": 3}
+    main, loss, _, exe, scope = _program(cfg, t=t, lr=1e-3)
+    main.remat_keep = {}
+    exe.run(main, feed=batch, fetch_list=[loss], scope=scope)
+    assert _kept_gauges("bytes") == dict.fromkeys(want, 0)
+    assert _kept_gauges("values") == dict.fromkeys(want, 0)
+
+
+def test_what_is_weighed_is_what_the_policy_saves():
+    """`core.remat.weighing` counts the named values while a block is
+    traced; jax's own list of saved residuals says what the policy of those
+    names really keeps: the same values, the same bytes."""
+    from paddle_tpu.core import remat
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(ks[0], (64, 16))
+    wq, gate = (jax.random.normal(k, (16, w)) for k, w in zip(ks[1:], (48, 8)))
+    w1 = jax.random.normal(ks[3], (4, 16, 8))
+    w2 = jax.random.normal(ks[4], (4, 8, 16))
+    names = fa.KEPT + moe.KEPT + ("qkv",)
+
+    def block(x, wq, gate, w1, w2):
+        qkv = remat.kept(x @ wq, "qkv")
+        q, k, v = (z.reshape(1, 64, 2, 8).transpose(0, 2, 1, 3)
+                   for z in jnp.split(qkv, 3, axis=1))
+        a = fa.flash_attention(q, k, v, causal=True)
+        y = moe.moe_ffn(x, gate, w1, None, w2, None, k=2, act=relu2,
+                        experts_held=(2, 4), scoring="sigmoid").y
+        return jnp.sum(a) + jnp.sum(y)
+
+    args = (x, wq, gate, w1, w2)
+    wrapped = jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*names))
+    with remat.weighing(names) as weighed:
+        jax.vjp(wrapped, *args)
+    # what `jax.ad_checkpoint.print_saved_residuals` prints, as a list: the
+    # arguments, the named values (a float one as the `reduce_precision`
+    # that jax puts behind it) and one [N, k] index array that a jitted
+    # `take_along_axis` derives from the kept `idx`
+    from jax._src.ad_checkpoint import saved_residuals
+    saved = [aval for aval, why in saved_residuals(wrapped, *args)
+             if not why.startswith(("from the argument",
+                                    "output of jitted function"))]
+    assert weighed.values == len(saved) == len(names)
+    assert weighed.bytes == sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in saved)
+    with remat.weighing(()) as nothing:
+        jax.vjp(jax.checkpoint(block), *args)
+    assert (nothing.values, nothing.bytes) == (0, 0)
+
+
+@pytest.mark.parametrize("given", ["names", "policy", "none"])
+def test_the_caller_s_remat_strategy_overrides_the_program_s(given):
+    """What `BuildStrategy` says wins over what the builder declared: its
+    names replace the kept set under the program's own policy, its "full"
+    with no names keeps nothing (the way out for a batch that needs every
+    byte), its "none" leaves no remat block."""
+    cfg = _cfg("ME")
+    main, loss, _, exe, scope = _program(cfg, lr=1e-3)
+    (zxbcdt,) = main.remat_keep["blk0.M"]
+    bs = fluid.BuildStrategy()
+    if given == "names":
+        bs.remat_saveable_names = [zxbcdt]
+    else:
+        bs.remat_policy = {"policy": "full", "none": "none"}[given]
+    cp = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, build_strategy=bs, places=jax.devices()[:1])
+    spec = cp._remat_spec()
+    own = fluid.CompiledProgram(main)._remat_spec()
+    assert own.names_for("blk1.E") == tuple(main.remat_keep["blk1.E"])
+    assert (spec.unit_policy is None) == (given == "none")
+    kept = {"names": (zxbcdt,), "policy": (), "none": ()}[given]
+    assert spec.names_for("blk0.M") == spec.names_for("blk1.E") == kept
+    assert spec.token != own.token
+    (batch,) = _batches(cfg, 1)
+    exe.run(cp, feed=batch, fetch_list=[loss], scope=scope)
+    if given != "none":
+        assert _kept_gauges("values")["blk0.M"] == len(kept)
+        assert _kept_gauges("bytes")["blk1.E"] == 0
 
 
 # ---------------------------------------------------------------------------

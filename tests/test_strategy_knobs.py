@@ -137,3 +137,56 @@ def test_sync_batch_norm_global_stats():
                                atol=1e-6)
     np.testing.assert_allclose(stats[None][1], stats[4][1], rtol=1e-4,
                                atol=1e-6)
+
+
+def test_the_kept_value_names_are_identities_without_a_policy(monkeypatch):
+    """The flash forward rule and the experts' routing name the residuals a
+    remat block may keep (`core.remat.kept`). A program that asks for no
+    remat policy (an ERNIE-shaped encoder, an expert layer beside it) has no
+    checkpoint to keep them in, and its step lowers to the same text as with
+    the names taken out: kernel forward, kernel backward, sort and all."""
+    import importlib
+    import re
+    import jax
+    from paddle_tpu.core.executor import convert_feed_value
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel import moe
+    fa = importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.flash_attention")
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", True)
+
+    def lowered():
+        cfg = bert.BertConfig(vocab_size=128, hidden_size=256, num_layers=2,
+                              num_heads=2, ffn_size=64, max_position=128)
+        with fluid.unique_name.guard():
+            main, startup, feeds, loss = bert.build_pretrain_program(
+                cfg, 2, 128, lambda: fluid.optimizer.SGD(0.1))
+            with fluid.program_guard(main, startup):
+                x = layers.data("x", [128, 16])
+                routed, aux = layers.moe_ffn(x, 4, 8, k=2)
+                extra = layers.reduce_mean(routed)
+                fluid.optimizer.SGD(0.1).minimize(extra)
+        assert main.remat_policy is None and not main.remat_keep
+        exe, scope = fluid.Executor(fluid.TPUPlace()), fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+        block = main.global_block()
+        feed = {n: convert_feed_value(block, n, np.zeros(
+            [2] + list(block.var(n).shape[1:]), block.var(n).dtype))
+            for n in feeds + ["x"]}
+        names = sorted(v.name for v in main.list_vars()
+                       if v.persistable and scope.has_var(v.name))
+        step = exe._build(main, sorted(feed), [loss.name, extra.name], names,
+                          names)
+        text = jax.jit(step._step).trace(
+            {n: scope.find_var(n) for n in names}, feed,
+            jax.random.key(0)).lower().as_text()
+        # a private function's number is its place among all the symbols
+        # the lowering asked a name for, used or not
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    with_names = lowered()
+    assert "sort" in with_names and "while" in with_names
+    monkeypatch.setattr(fa, "_kept", lambda value, name: value)
+    monkeypatch.setattr(moe, "kept", lambda value, name: value)
+    assert lowered() == with_names

@@ -299,3 +299,45 @@ def test_ssd_scan_kernels_compile_for_the_shapes_the_rule_takes(
         *_scan_structs(one_chip, 2, 512, h, p, g, n, jnp.dtype(dtype))
     ).lower(lowering_platforms=("tpu",)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "full"])
+def test_remat_blocks_with_their_kept_values_compile_for_v5e(one_chip, kept):
+    """A Mamba-2 block, an expert block and an attention block at the
+    smallest sizes the kernels take, the whole training step under the
+    builder's own remat policy, through the TPU's own compiler. With what
+    the blocks keep (`Program.remat_keep`) the attention forward kernel is
+    called once and the dispatch sorts once; with nothing kept, twice. The
+    scan's forward kernel runs twice either way."""
+    from paddle_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig(
+        vocab_size=256, hidden_size=256, pattern="ME*", num_heads=4,
+        num_kv_heads=2, head_dim=128, mamba_num_heads=4, mamba_head_dim=64,
+        ssm_state_size=128, n_groups=2, chunk_size=128, n_routed_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=128,
+        shared_intermediate_size=256, experts_held=(2, 4))
+    b, t = 2, 512
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = nh.build_pretrain_program(
+            cfg, b, t, lambda: fluid.optimizer.SGD(0.1))
+    if not kept:
+        main.remat_keep = {}
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in startup.list_vars() if v.persistable}
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = fluid.Executor(fluid.TPUPlace())._build(
+        main, sorted(feed), [loss.name], names, names)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(step._step).trace(state, feed, key).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    # the scan: forward, forward again, backward; attention: forward (and
+    # again where nothing is kept), dq, dkv
+    assert text.count("tpu_custom_call") == 3 + (3 if kept else 4)
+    # the router's top-k and the dispatch's argsort: a sort each to XLA
+    assert text.count(" sort(") == (2 if kept else 4)
