@@ -24,6 +24,9 @@ Phases, one chip:
            the shapes the models use
   deepfm   three DeepFM steps at the benchmark's configuration (33.5M-row
            packed table, batch 4096, exact Adagrad) on its default path
+  looped   three steps of a looped decoder (models/ouro.py) at Ouro-2.6B's
+           widths, two layers run twice over shared weights, b1 x 1024, bf16
+           AMP Adam: one parameter a layer's matrix, the exit shares sum to 1
 
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
@@ -57,6 +60,8 @@ ERNIE_BATCH, ERNIE_SEQ = 64, 512
 ERNIE_STEPS = 10
 # DeepFM at the benchmark's configuration deepfm_criteo
 DEEPFM_VOCAB, DEEPFM_BATCH, DEEPFM_STEPS = 33_554_432, 4096, 3
+# the looped decoder: depth, passes and batch small enough to add under 30 s
+LOOPED_LAYERS, LOOPED_PASSES, LOOPED_SEQ, LOOPED_STEPS = 2, 2, 1024, 3
 # dp4: per-chip batch of the sharded run, and the dropout-free equality run
 DP4_PER_CHIP, DP4_EQ_BATCH, DP4_EQ_STEPS, DP4_EQ_RTOL = 64, 64, 5, 1e-2
 
@@ -714,6 +719,70 @@ def phase_deepfm(args):
 
 
 # ---------------------------------------------------------------------------
+# looped: a decoder whose layers run twice over the same weights
+# ---------------------------------------------------------------------------
+
+def phase_looped(args):
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import ouro
+
+    cfg = ouro.OuroConfig(num_layers=LOOPED_LAYERS,
+                          total_ut_steps=LOOPED_PASSES)
+
+    def opt():
+        return mp.decorate(fluid.optimizer.Adam(1e-4), dtype="bfloat16",
+                           use_dynamic_loss_scaling=False)
+
+    with fluid.unique_name.guard():
+        main, startup, _, loss, (share, entropy) = ouro.build_pretrain_program(
+            cfg, 1, LOOPED_SEQ, opt)
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (1, LOOPED_SEQ + 1)).astype("int32")
+    feed = {"ids": jnp.asarray(ids[:, :-1]),
+            "labels": jnp.asarray(ids[:, 1:, None])}
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        fetched = [exe.run(main, feed=feed, fetch_list=[loss, share, entropy],
+                           return_numpy=False)
+                   for _ in range(LOOPED_STEPS)]
+        vals = [float(np.asarray(f[0])) for f in fetched]
+        wall_s = time.perf_counter() - t0
+        shares = np.asarray(fetched[-1][1], dtype=np.float64)
+        entropy_nats = float(np.asarray(fetched[-1][2]))
+        matrices = [n for n in scope.var_names() if n.endswith(".qkv.w")]
+    _require(all(np.isfinite(vals)), f"non-finite looped loss in {vals}")
+    _require(vals[-1] < vals[0],
+             f"looped loss did not fall on a fixed batch: {vals}")
+    _require(_platforms(fetched[-1][0]) == {"tpu"},
+             f"looped loss lives on {_platforms(fetched[-1][0])}, not tpu")
+    _require(shares.shape == (LOOPED_PASSES,)
+             and abs(shares.sum() - 1.0) < 1e-5,
+             f"exit shares {shares.tolist()} do not sum to 1")
+    _require(len(matrices) == LOOPED_LAYERS,
+             f"{len(matrices)} q/k/v matrices for {LOOPED_LAYERS} layers "
+             f"run {LOOPED_PASSES} times: {sorted(matrices)}")
+    print(f"looped: {LOOPED_LAYERS} layers x {LOOPED_PASSES} passes b1 x "
+          f"{LOOPED_SEQ}, {ouro.param_count(cfg) / 1e6:.1f}M parameters, loss "
+          f"{vals[0]:.5f} -> {vals[-1]:.5f} over {LOOPED_STEPS} steps "
+          f"(startup+compile+steps {wall_s:.1f} s, set-up fact)")
+    del fetched, scope, exe
+    gc.collect()
+    return {"config": {"layers": LOOPED_LAYERS, "passes": LOOPED_PASSES,
+                       "seq": LOOPED_SEQ,
+                       "parameters": ouro.param_count(cfg)},
+            "losses": [round(v, 5) for v in vals],
+            "exit_share": [round(float(x), 6) for x in shares],
+            "exit_entropy": round(entropy_nats, 6),
+            "setup": {"startup_compile_and_steps_s": round(wall_s, 2)}}
+
+
+# ---------------------------------------------------------------------------
 # dp4: the same ERNIE program, data-parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -824,7 +893,8 @@ def phase_dp4(args):
 # ---------------------------------------------------------------------------
 
 PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
-                   ("kernels", phase_kernels), ("deepfm", phase_deepfm)]
+                   ("kernels", phase_kernels), ("deepfm", phase_deepfm),
+                   ("looped", phase_looped)]
 PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
 
 

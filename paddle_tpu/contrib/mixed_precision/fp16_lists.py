@@ -38,6 +38,12 @@ WHITE_LIST = {"conv2d", "conv3d", "depthwise_conv2d", "conv2d_transpose",
 # whatever arrives (a near-tie between two experts flips on rounding), its
 # expert products take the activations' dtype with float32 accumulation, and
 # the experts' weight gradients are summed in float32 (parallel/moe.py).
+# rotary_embedding and swiglu are gray: bf16 in and out beside the bf16
+# products they sit between, the angles and the rotation, the silu and the
+# product in float32 inside (ops/nn_ops.py). loop_exit_gate and
+# loop_exit_loss are gray and float32 inside whatever arrives: the gate is a
+# full-precision product (its output weights the loss) and black-listing
+# them would only add a cast of the states they read.
 BLACK_LIST = {"cross_entropy", "mean",
               "reduce_mean", "softmax", "sum",
               "exp", "log", "rsqrt", "sqrt"}

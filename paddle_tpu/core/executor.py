@@ -649,6 +649,17 @@ def _walk_tape(op, env, ctx: ExecContext):
         else:
             cots[loss_name] = jnp.ones_like(env[loss_name])
 
+    # a parameter that several tape entries read (a layer applied more than
+    # once: models/ouro.py) gets its gradient as a sum of partial products.
+    # Left alone XLA puts those products off until the optimizer wants the
+    # sum and holds their operands, a block's activations and cotangents,
+    # meanwhile; so an entry that reads such a parameter hands on what it
+    # has summed so far together with its activation cotangents, in order
+    reads = collections.Counter(
+        n for entry in ctx.tape for n in set(entry.in_names))
+    summed = {n for n, k in reads.items() if k > 1 and not _stop_grad(n)
+              and getattr(block._find_var_recursive(n), "persistable", False)}
+
     for entry in reversed(ctx.tape):
         if not any(n in cots for n in entry.out_names):
             continue
@@ -673,6 +684,11 @@ def _walk_tape(op, env, ctx: ExecContext):
                 cots[name] = cots[name] + g
             else:
                 cots[name] = g
+        if summed.intersection(entry.in_names):
+            held = [n for n in dict.fromkeys(entry.in_names)
+                    if isinstance(cots.get(n), jax.Array)]
+            cots.update(zip(held, jax.lax.optimization_barrier(
+                tuple(cots[n] for n in held))))
 
     for t in targets:
         gname = grad_var_name(t)
@@ -883,7 +899,14 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
                 if n not in write_set:
                     write_set.add(n)
                     writes.append(n)
-    in_names, out_names = reads, writes
+    in_names = reads
+    # what the rest of the block reads of the unit is what the backward pass
+    # has cotangents for; every other value the unit writes goes out beside
+    # them (a fetch may name it) and takes none, so the walk makes no zeros
+    # for it and the backward's barrier holds none
+    read_outside = _read_outside(ops)
+    out_names = [n for n in writes if n in read_outside]
+    aux_names = [n for n in writes if n not in read_outside]
     # one split per group, closed over (not a traced argument): the
     # checkpointed backward replays the SAME key, so recomputed dropout
     # masks match the forward exactly
@@ -901,16 +924,18 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
             for n in op.output_names():
                 if n in keep:
                     local[n] = _remat.kept(local[n], n)
-        return tuple(local[n] for n in out_names)
+        return (tuple(local[n] for n in out_names),
+                tuple(local[n] for n in aux_names))
 
     wrapped = jax.checkpoint(fwd, policy=spec.jax_policy(decision, unit))
     with _remat.weighing(keep) as weighed:
-        out_vals, vjp_fn = jax.vjp(wrapped, *[env[n] for n in in_names])
+        out_vals, vjp_fn, aux_vals = jax.vjp(
+            wrapped, *[env[n] for n in in_names], has_aux=True)
+    env.update(zip(out_names, out_vals))
+    env.update(zip(aux_names, aux_vals))
     reg = get_registry()
     reg.gauge("remat/kept_values", unit=unit).set(weighed.values)
     reg.gauge("remat/kept_bytes", unit=unit).set(weighed.bytes)
-    for n, v in zip(out_names, out_vals):
-        env[n] = v
     # an input is non-differentiable for the GROUP only if every use of it
     # inside is through a nondiff slot
     used_diff, used_nondiff = set(), set()
@@ -921,6 +946,33 @@ def _run_remat_group(ops, decision, env: Dict[str, object],
     nondiff_in = (used_nondiff - used_diff) & set(in_names)
     ctx.tape.append(TapeEntry(list(in_names), list(out_names), vjp_fn,
                               list(out_vals), nondiff_in))
+
+
+def _read_outside(ops) -> set:
+    """The names that ops of the block other than `ops` read (the ops of
+    their nested blocks too), or that its `autodiff` ops name as a loss or a
+    target."""
+    inside = {id(op) for op in ops}
+    names = set()
+
+    def visit(block_ops):
+        for op in block_ops:
+            if id(op) in inside:
+                continue
+            for slot_names in op.inputs.values():
+                names.update(slot_names)
+            for v in op.attrs.values():
+                if isinstance(v, Block):
+                    visit(v.ops)
+            if op.type == "autodiff":
+                for key in ("loss_name", "init_grad_name"):
+                    if op.attrs.get(key):
+                        names.add(op.attrs[key])
+                for key in ("loss_names", "targets", "init_grad_names"):
+                    names.update(n for n in op.attrs.get(key) or () if n)
+
+    visit(ops[0].block.ops)
+    return names
 
 
 def eval_inference_block(program, env: Dict[str, object]) -> Dict[str, object]:

@@ -9,7 +9,8 @@ from typing import Optional
 
 from .core import unique_name
 from .core.dtypes import convert_dtype
-from .core.program import default_main_program, default_startup_program
+from .core.program import (Parameter, default_main_program,
+                           default_startup_program)
 from .initializer import ConstantInitializer, XavierInitializer
 from .param_attr import ParamAttr
 
@@ -45,18 +46,37 @@ class LayerHelper:
                                    else XavierInitializer())
         init = attr.initializer or default_initializer
 
-        block = self.main_program.current_block()
-        param = block.create_parameter(
-            name=attr.name, shape=list(shape), dtype=convert_dtype(dtype),
+        dtype = convert_dtype(dtype)
+        shape = [int(d) for d in shape]
+        gblock = self.main_program.global_block()
+        param = gblock.vars.get(attr.name)
+        if param is not None:
+            # a name the program already holds is that parameter (sharing by
+            # ParamAttr(name=...)): one Parameter, one initialiser, one
+            # optimizer slot, whatever the later ParamAttr says
+            if not isinstance(param, Parameter):
+                raise ValueError(
+                    f"create_parameter: {attr.name!r} names a variable of "
+                    f"the program that is no parameter")
+            if list(param.shape) != shape or param.dtype != dtype:
+                raise ValueError(
+                    f"create_parameter: {attr.name!r} exists with shape "
+                    f"{list(param.shape)} {param.dtype}, asked for again "
+                    f"with shape {shape} {dtype}")
+            return param
+        param = self.main_program.current_block().create_parameter(
+            name=attr.name, shape=shape, dtype=dtype,
             trainable=attr.trainable, regularizer=attr.regularizer,
             need_clip=attr.need_clip, shard_spec=attr.shard_spec)
         param.optimize_attr = {"learning_rate": attr.learning_rate}
 
         sblock = self.startup_program.global_block()
-        svar = sblock.create_var(
-            name=attr.name, shape=list(shape), dtype=convert_dtype(dtype),
-            persistable=True)
-        init(svar, sblock)
+        # a startup program shared by two main programs (train and test built
+        # under the same names) initialises the parameter once as well
+        if attr.name not in sblock.vars:
+            svar = sblock.create_var(
+                name=attr.name, shape=shape, dtype=dtype, persistable=True)
+            init(svar, sblock)
         return param
 
     def create_variable_for_type_inference(self, dtype="float32", shape=None,

@@ -293,6 +293,78 @@ def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
     return out
 
 
+def rotary_embedding(x: Variable, num_heads: int, theta: float = 10000.0,
+                     name=None) -> Variable:
+    """Rotary position embedding on packed heads [B, T, H*D] (TPU
+    extension): every head's channel pairs (j, j + D/2) are rotated by
+    `t * theta^(-2j/D)` at position t, angles in float32. For q
+    and k of one fused product, hand both in as one [B, T, 2*H*D] tensor
+    with `num_heads=2*H`."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    if x.shape is not None and int(x.shape[-1]) % (2 * num_heads):
+        raise ValueError(f"rotary_embedding: {x.shape[-1]} channels are not "
+                         f"{num_heads} heads of an even size")
+    out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    helper.append_op(type="rotary_embedding", inputs={"X": [x.name]},
+                     outputs={"Out": [out.name]},
+                     attrs={"num_heads": int(num_heads),
+                            "theta": float(theta)})
+    return out
+
+
+def swiglu(gate: Variable, up: Variable, name=None) -> Variable:
+    """`silu(gate) * up`, the gated MLP's activation (TPU extension): one op,
+    the product in float32 under AMP."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(gate.dtype, gate.shape)
+    helper.append_op(type="swiglu", inputs={"X": [gate.name],
+                                            "Y": [up.name]},
+                     outputs={"Out": [out.name]}, attrs={})
+    return out
+
+
+def loop_exit_gate(states: Variable, param_attr=None, bias_attr=None,
+                   name=None) -> Variable:
+    """The exit distribution of a looped (weight-shared, multi-exit) decoder
+    (TPU extension): `states` [P, B, T, D] holds the state after each pass;
+    a learned gate `sigmoid(x . w + b)` a position and pass says how much of
+    what has not left yet leaves there, the last pass takes the remainder.
+    Creates the gate's weight [D, 1] and bias [1].
+    Returns p [P, B, T], float32, summing to 1 over P."""
+    helper = LayerHelper("loop_exit_gate", name=name)
+    d = int(states.shape[-1])
+    w = helper.create_parameter(param_attr, shape=[d, 1], dtype=states.dtype)
+    b = helper.create_parameter(bias_attr, shape=[1], dtype=states.dtype,
+                                is_bias=True)
+    out = helper.create_variable_for_type_inference(
+        "float32", tuple(states.shape[:-1]))
+    helper.append_op(type="loop_exit_gate",
+                     inputs={"X": [states.name], "W": [w.name],
+                             "Bias": [b.name]},
+                     outputs={"Out": [out.name]}, attrs={})
+    return out
+
+
+def loop_exit_loss(p: Variable, ce: Variable, beta: float = 0.0, name=None):
+    """A looped decoder's objective (TPU extension): the mean over the
+    positions of `sum_t p_t ce_t - beta H(p)`. `p` [P, B, T] from
+    `loop_exit_gate`, `ce` [P, B, T(, 1)] each exit's per-position loss.
+    Returns (loss [], exit share [P] = mean p_t, exit entropy [] = mean
+    H(p)); the last two carry no gradient."""
+    helper = LayerHelper("loop_exit_loss", name=name)
+    loss = helper.create_variable_for_type_inference("float32", shape=())
+    share = helper.create_variable_for_type_inference(
+        "float32", shape=(p.shape[0],), stop_gradient=True)
+    entropy = helper.create_variable_for_type_inference(
+        "float32", shape=(), stop_gradient=True)
+    helper.append_op(type="loop_exit_loss",
+                     inputs={"P": [p.name], "CE": [ce.name]},
+                     outputs={"Loss": [loss.name], "ExitShare": [share.name],
+                              "ExitEntropy": [entropy.name]},
+                     attrs={"beta": float(beta)})
+    return loss, share, entropy
+
+
 def causal_conv1d(input: Variable, filter_size: int, act: str = "",
                   param_attr=None, bias_attr=None, name=None) -> Variable:
     """Depthwise causal convolution along the time axis of [B, T, C] (TPU

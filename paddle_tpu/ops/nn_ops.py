@@ -366,6 +366,90 @@ def _rms_norm(ctx, inputs, attrs):
     return one(y.astype(x.dtype))
 
 
+@register_op("rotary_embedding")
+def _rotary_embedding(ctx, inputs, attrs):
+    """Rotary position embedding (Su et al. 2021) on packed heads: X
+    [B, T, H*D], every head rotated alike, position t by the angles
+    `t * theta^(-2j/D)`, j < D/2. The rotate-half convention:
+    channel j pairs with channel j + D/2 of its head,
+    out[j] = x[j] cos - x[j + D/2] sin, out[j + D/2] = x[j + D/2] cos +
+    x[j] sin. Gray under AMP: the angles, their sines and the rotation in
+    float32, the input dtype back."""
+    (x,) = inputs["X"]
+    heads = int(attrs["num_heads"])
+    b, t, hd = x.shape
+    d = hd // heads
+    half = d // 2
+    inv_freq = jnp.asarray(attrs.get("theta", 10000.0), jnp.float32) ** (
+        -jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    ang = (jnp.arange(t, dtype=jnp.float32)[:, None]
+           * inv_freq[None, :])                                # [T, D/2]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32).reshape(b, t, heads, d)
+    lo, hi = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], -1)
+    return one(out.reshape(x.shape).astype(x.dtype))
+
+
+@register_op("swiglu")
+def _swiglu(ctx, inputs, attrs):
+    """The gated MLP's activation (Shazeer 2020): silu(X) * Y, elementwise.
+    Gray under AMP: the product in float32 (one rounding, not one after the
+    silu and one after the product), the input dtype back."""
+    (gate,) = inputs["X"]
+    (up,) = inputs["Y"]
+    out = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return one(out.astype(gate.dtype))
+
+
+@register_op("loop_exit_gate")
+def _loop_exit_gate(ctx, inputs, attrs):
+    """The exit distribution of a looped model (Ouro, "Scaling Latent
+    Reasoning via Looped Language Models", 2025). X [P, ..., D] holds the
+    state after each of P passes; the gate of pass t < P is
+    lambda_t = sigmoid(x_t . W + Bias) a position, and a position leaves at
+    pass t with probability p_t = lambda_t prod_{j<t} (1 - lambda_j); what
+    is left goes to the last pass. Out [P, ...] float32, summing to 1 over
+    P. Gray under AMP: whatever arrives, the gate is computed in float32 at
+    full matmul precision (the loss's weights are learnt through it)."""
+    (x,) = inputs["X"]
+    (w,) = inputs["W"]
+    (b,) = inputs["Bias"]
+    logits = jnp.einsum("p...d,d->p...", x[:-1].astype(jnp.float32),
+                        w.astype(jnp.float32).reshape(-1),
+                        precision=lax.Precision.HIGHEST)
+    logits = logits + b.astype(jnp.float32).reshape(())
+    # log-space: p_t = exp(log lambda_t + sum_{j<t} log(1 - lambda_j))
+    log_stay = jax.nn.log_sigmoid(-logits)
+    before = jnp.cumsum(log_stay, axis=0)
+    zero = jnp.zeros_like(before[:1])
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(logits) + jnp.concatenate([zero, before[:-1]]),
+         before[-1:]])
+    return one(jnp.exp(log_p))
+
+
+@register_op("loop_exit_loss")
+def _loop_exit_loss(ctx, inputs, attrs):
+    """The exit-weighted objective of a looped model (Ouro's first stage):
+    the mean over the positions of sum_t p_t CE_t - beta H(p), H(p) =
+    -sum_t p_t log p_t. P [P, ...] (`loop_exit_gate`), CE [P, ...(, 1)] the
+    per-position loss of each pass's exit. Also ExitShare [P], the mean p_t,
+    and ExitEntropy, the mean H(p), for the counters. Float32 throughout."""
+    (p,) = inputs["P"]
+    (ce,) = inputs["CE"]
+    p = p.astype(jnp.float32)
+    ce = ce.astype(jnp.float32).reshape(p.shape)
+    # 0 log 0 = 0, with a finite gradient there
+    entropy = -jnp.sum(p * jnp.log(jnp.maximum(
+        p, jnp.finfo(jnp.float32).tiny)), axis=0)
+    per_pos = jnp.sum(p * ce, axis=0) - attrs.get("beta", 0.0) * entropy
+    share = jnp.mean(p.reshape(p.shape[0], -1), axis=1)
+    return {"Loss": [jnp.mean(per_pos)],
+            "ExitShare": [lax.stop_gradient(share)],
+            "ExitEntropy": [lax.stop_gradient(jnp.mean(entropy))]}
+
+
 @register_op("group_norm")
 def _group_norm(ctx, inputs, attrs):
     (x,) = inputs["X"]

@@ -341,3 +341,42 @@ def test_remat_blocks_with_their_kept_values_compile_for_v5e(one_chip, kept):
     assert text.count("tpu_custom_call") == 3 + (3 if kept else 4)
     # the router's top-k and the dispatch's argsort: a sort each to XLA
     assert text.count(" sort(") == (2 if kept else 4)
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "full"])
+def test_looped_decoder_compiles_for_v5e_with_its_kept_values(one_chip, kept):
+    """Two layers run twice over shared weights (`models/ouro.py`) at the
+    smallest sizes the attention kernels take, the whole training step
+    through the TPU's own compiler: every application keeps its kernel's
+    `out` and `lse`, so the forward kernel is called once an application
+    (at this length a forward and one backward kernel; with nothing kept the
+    forward twice), and the step holds one master weight a layer and matrix
+    whatever the number of passes."""
+    from paddle_tpu.models import ouro
+
+    cfg = ouro.OuroConfig(vocab_size=256, hidden_size=256, num_layers=2,
+                          num_heads=2, head_dim=128, intermediate_size=384,
+                          total_ut_steps=2)
+    b, t = 2, 512
+    with fluid.unique_name.guard():
+        main, startup, _, loss, _ = ouro.build_pretrain_program(
+            cfg, b, t, lambda: fluid.optimizer.SGD(0.1))
+    if not kept:
+        main.remat_keep = {}
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in startup.list_vars() if v.persistable}
+    assert sum(int(np.prod(s.shape)) for n, s in state.items()
+               if n.startswith(("blk", "embed", "lm_head", "final_norm",
+                                "exit_gate"))) == ouro.param_count(cfg)
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = fluid.Executor(fluid.TPUPlace())._build(
+        main, sorted(feed), [loss.name], names, names)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(step._step).trace(state, feed, key).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count("tpu_custom_call") == (2 if kept else 3) * 2 * 2
