@@ -565,7 +565,12 @@ def _run_op(op, env: Dict[str, object], ctx: ExecContext):
             # selective remat: BuildStrategy.remat may be a set of op types
             # (cheap-to-recompute ops only — BN/activations) instead of
             # all-ops True
-            fn = jax.checkpoint(fn)
+            if opdef.own_remat:
+                # its gradient rule already keeps its inputs alone: wrapped,
+                # its forward rule would run again in the backward pass
+                _OBS.counter("remat/op_own", op=op.type).inc()
+            else:
+                fn = jax.checkpoint(fn)
         flat_out_vals, vjp_fn = jax.vjp(fn, *flat_in_vals)
 
         out_names = []

@@ -35,10 +35,11 @@ OpImpl = Callable[..., Dict[str, List[Any]]]
 
 class OpDef:
     __slots__ = ("type", "fn", "differentiable", "nondiff_inputs",
-                 "mutable_persistables", "grad_fn")
+                 "mutable_persistables", "grad_fn", "own_remat")
 
     def __init__(self, type: str, fn: OpImpl, differentiable: bool = True,
-                 nondiff_inputs: Optional[List[str]] = None, grad_fn=None):
+                 nondiff_inputs: Optional[List[str]] = None, grad_fn=None,
+                 own_remat: bool = False):
         self.type = type
         self.fn = fn
         self.differentiable = differentiable
@@ -50,18 +51,24 @@ class OpDef:
         # {slot: [cotangent or None, ...]}. May return None to fall back to
         # jax.vjp for this invocation (attr-dependent sparsity).
         self.grad_fn = grad_fn
+        # the op is its own rematerialisation: its gradient rule keeps only
+        # the op's inputs and O(rows) vectors and recomputes the rest
+        # itself, so a jax.checkpoint around it would free nothing and run
+        # its forward rule a second time in the backward pass. The executor
+        # leaves such an op unwrapped under a per-op remat policy.
+        self.own_remat = own_remat
 
 
 _REGISTRY: Dict[str, OpDef] = {}
 
 
 def register_op(type: str, differentiable: bool = True, nondiff_inputs=None,
-                grad_fn=None):
+                grad_fn=None, own_remat: bool = False):
     def deco(fn: OpImpl):
         if type in _REGISTRY:
             raise ValueError(f"op {type!r} registered twice")
         _REGISTRY[type] = OpDef(type, fn, differentiable, nondiff_inputs,
-                                grad_fn)
+                                grad_fn, own_remat)
         return fn
 
     return deco

@@ -532,12 +532,17 @@ def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
     label, ignore_index=ignore_index)` as one op (TPU extension, no
     reference analog): the same per-position loss [..., 1] and the same
     gradients, but only the positions whose label is not `ignore_index` are
-    projected onto the [hidden, size] matrix, a chunk at a time, so the
-    [positions, size] logits never exist (ops/nn_ops.py `_linear_ce`). For a
-    masked-LM head, where 85% of the labels are ignored. The parameters are
-    those `fc` would create (weight [hidden, size], then bias [size];
-    `bias_attr=False`: no bias). `return_rows=True` also returns the rows the op projected (whole
-    chunks) and the labelled count, both int32 scalars."""
+    projected onto the [hidden, size] matrix, a chunk of rows at a time, so
+    [positions, size] logits never exist: a chunk's are held, and made again
+    in the backward pass (ops/nn_ops.py `_linear_ce`; the rows of a chunk
+    follow from the shapes, `linear_ce_chunk_rows`). For a masked-LM head,
+    where 85% of the labels are ignored, and for a vocabulary whose logits
+    over all positions do not fit. The op is its own rematerialisation (it
+    keeps its inputs and a float32 scalar a row), so a remat policy does not
+    checkpoint it again. The parameters are those `fc` would create (weight
+    [hidden, size], then bias [size]; `bias_attr=False`: no bias).
+    `return_rows=True` also returns the rows the forward pass projected
+    (whole chunks) and the labelled count, both int32 scalars."""
     helper = LayerHelper("fc", name=name)
     w = helper.create_parameter(param_attr, shape=[input.shape[-1], size],
                                 dtype=input.dtype)

@@ -143,8 +143,9 @@ def objective(cfg: OuroConfig, exits, labels, seq_len: int):
     """(loss, ExitShare [passes], ExitEntropy []) from the exits' states and
     the labels [B, T, 1]. The states and the labels, once a pass, go through
     ONE `linear_softmax_with_cross_entropy` of passes x B x T rows (one
-    gradient accumulator of the head's matrix, the logits never held); its
-    per-row loss is weighted by the exit distribution."""
+    gradient accumulator of the head's matrix; of the logits a chunk of rows
+    is held at a time, and made again in the backward pass); its per-row
+    loss is weighted by the exit distribution."""
     passes = len(exits)
     states = layers.reshape(layers.concat(exits, axis=0),
                             [passes, -1, seq_len, cfg.hidden_size])

@@ -734,22 +734,52 @@ def _softmax_with_cross_entropy(ctx, inputs, attrs):
     return {"Loss": [loss], "Softmax": [jnp.exp(logp)]}
 
 
-# a loop iteration's float32 logits stay under this many bytes
-_CE_CHUNK_LOGITS_BYTES = 128 << 20
+# What a loop iteration hands from one fusion to the next stays under this
+# many bytes, which the compiler then keeps on the chip (memory space 1 in
+# the compiled text; a v5e places 100 MB there and not 200): the forward
+# loop's float32 [rows, vocab] logits, between the product with its row
+# maximum and the exponentials, and the backward loop's [rows, vocab] softmax
+# gradient, which the MXU reads in two bytes an element
+_CE_CHUNK_BYTES = 128 << 20
+# Rows from which an iteration of the backward loop is no longer bound by its
+# accumulator: it adds a [hidden, rows] x [rows, vocab] product into the
+# float32 [hidden, vocab] sum of dW, 2 * rows * hidden * vocab operations for
+# the 8 * hidden * vocab bytes the sum is read and written, rows / 4 a byte
+# whatever the hidden width; a v5e's MXU and HBM take equally long at 240
+# (197 TFLOP/s over 819 GB/s), 960 rows: the next power of two
+_CE_RIDGE_ROWS = 1024
 
 
 def _cdiv(a, b):
     return (a + b - 1) // b
 
 
-def linear_ce_chunk_rows(n_pos: int, vocab: int) -> int:
-    """Rows one iteration of the labelled-rows loop projects: the largest
-    power of two whose float32 [rows, vocab] logits stay under
-    _CE_CHUNK_LOGITS_BYTES (1024 rows at BERT's 30,522: eight MXU tiles
-    high), never more than the positions there are, rounded up to a
-    sublane multiple."""
-    fit = max(8, _CE_CHUNK_LOGITS_BYTES // (4 * vocab))
-    return min(1 << (fit.bit_length() - 1), _cdiv(n_pos, 8) * 8)
+def linear_ce_chunk_rows(n_pos: int, vocab: int):
+    """(forward rows, backward rows): the rows one iteration of each of the
+    labelled-rows loops projects, from the shapes alone.
+
+    Forward: the largest power of two whose float32 [rows, vocab] logits
+    stay under _CE_CHUNK_BYTES (1,024 rows at BERT's 30,522, 2,048 at
+    16,384, 512 at 49,152). Its product runs at the MXU's pace from 512 rows
+    on; more rows only push the logits out to HBM.
+
+    Backward: that loop holds no float32 logits (they are fused into the
+    softmax gradient) but reads and writes the float32 [hidden, vocab]
+    accumulator of dW every iteration, whatever the rows. So it takes whole
+    forward chunks up to _CE_RIDGE_ROWS, as far as the softmax gradient
+    stays under _CE_CHUNK_BYTES. Where the forward's rows are at the ridge
+    already (BERT's, Nemotron's) both loops take the same.
+
+    Never more than the positions there are, rounded up to a sublane
+    multiple."""
+    def under_cap(itemsize):
+        rows = max(8, _CE_CHUNK_BYTES // (itemsize * vocab))
+        return 1 << (rows.bit_length() - 1)
+
+    n_rows = _cdiv(n_pos, 8) * 8
+    fwd = min(under_cap(4), n_rows)
+    bwd = min(max(fwd, min(_CE_RIDGE_ROWS, under_cap(2))), n_rows)
+    return fwd, fwd * (bwd // fwd)
 
 
 def _labelled_first(valid, chunk):
@@ -777,30 +807,39 @@ def _chunk_logits(x, w, b, order, lbl, at, chunk):
     labels."""
     slots = lax.dynamic_slice(order, (at,), (chunk,))
     xc = _rows(x, slots)
-    logits = jnp.dot(xc, w, preferred_element_type=jnp.float32) + b
+    logits = jnp.dot(xc, w, preferred_element_type=jnp.float32)
+    if b is not None:
+        logits = logits + b
     hot = (lax.broadcasted_iota(jnp.int32, logits.shape, 1)
            == _rows(lbl, slots)[:, None])
     return slots, xc, logits, hot
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _linear_ce(x, w, b, lbl, ignore, chunk):
+def _linear_ce(x, w, b, lbl, ignore, chunks):
     """Per-position softmax cross-entropy of `x @ w + b` against hard labels
     `lbl`, 0 where the label is `ignore` — computed on the labelled rows
-    only, `chunk` of them at a time, so that [positions, vocab] logits never
-    exist. x [N, H]; w [H, V] and b [V] are rounded to x's dtype for the
-    products, which accumulate in float32 like everything after them; lbl
-    [N] int. Exact for any number of labelled rows: the loop runs
-    ceil(labelled / chunk) times, in the backward pass too (jax does not
-    reverse a loop of dynamic length by itself, hence the custom_vjp)."""
-    loss, _ = _linear_ce_fwd(x, w, b, lbl, ignore, chunk)
+    only, a chunk of them at a time, so that [positions, vocab] logits never
+    exist. x [N, H]; w [H, V] and b [V] (or None: no bias) are rounded to
+    x's dtype for the products, which accumulate in float32 like everything
+    after them; lbl [N] int; `chunks` the (forward, backward) rows an
+    iteration, the second a multiple of the first
+    (`linear_ce_chunk_rows`). Exact for any number of labelled rows: the
+    loops run ceil(labelled / rows) times (jax does not reverse a loop of
+    dynamic length by itself, hence the custom_vjp). The gradient rule keeps
+    the inputs and the rows' log-sum-exp and makes the logits again, a chunk
+    at a time: the op is its own rematerialisation (`own_remat` where it is
+    registered)."""
+    loss, _ = _linear_ce_fwd(x, w, b, lbl, ignore, chunks)
     return loss
 
 
-def _linear_ce_fwd(x, w, b, lbl, ignore, chunk):
+def _linear_ce_fwd(x, w, b, lbl, ignore, chunks):
+    chunk, bwd_chunk = chunks
     valid = lbl != ignore
-    rank, order, n = _labelled_first(valid, chunk)
-    w_lo, bf = w.astype(x.dtype), b.astype(jnp.float32)
+    rank, order, n = _labelled_first(valid, bwd_chunk)
+    w_lo = w.astype(x.dtype)
+    bf = None if b is None else b.astype(jnp.float32)
 
     def body(i, carry):
         loss_c, lse_c = carry
@@ -818,10 +857,12 @@ def _linear_ce_fwd(x, w, b, lbl, ignore, chunk):
     return loss, (x, w, b, lbl, rank, order, n, lse_c)
 
 
-def _linear_ce_bwd(ignore, chunk, res, g):
+def _linear_ce_bwd(ignore, chunks, res, g):
     x, w, b, lbl, rank, order, n, lse_c = res
+    fwd_chunk, chunk = chunks
     valid = lbl != ignore
-    w_lo, bf = w.astype(x.dtype), b.astype(jnp.float32)
+    w_lo = w.astype(x.dtype)
+    bf = None if b is None else b.astype(jnp.float32)
     g = g.astype(jnp.float32)
     row = jnp.arange(chunk, dtype=jnp.int32)
 
@@ -831,29 +872,36 @@ def _linear_ce_bwd(ignore, chunk, res, g):
         slots, xc, logits, hot = _chunk_logits(x, w_lo, bf, order, lbl, at,
                                                chunk)
         # slots from the labelled count on hold ignored positions
-        gc = jnp.where(at + row < n, _rows(g, slots), 0.0)
+        live = at + row < n
+        gc = jnp.where(live, _rows(g, slots), 0.0)
         lse = lax.dynamic_slice(lse_c, (at,), (chunk,))
+        if chunk != fwd_chunk:
+            # and past the forward loop's last chunk no log-sum-exp
+            lse = jnp.where(live, lse, jnp.inf)
         dl = (jnp.exp(logits - lse[:, None]) - hot) * gc[:, None]
         dl_lo = dl.astype(x.dtype)
         dxc = lax.dot_general(dl_lo, w_lo, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
         dw = dw + lax.dot_general(xc, dl_lo, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        return (lax.dynamic_update_slice(dx_c, dxc.astype(x.dtype), (at, 0)),
-                dw, db + jnp.sum(dl, axis=0))
+        dx_c = lax.dynamic_update_slice(dx_c, dxc.astype(x.dtype), (at, 0))
+        return dx_c, dw, None if db is None else db + jnp.sum(dl, axis=0)
 
     dx_c, dw, db = lax.fori_loop(
         0, _cdiv(n, chunk), body,
         (jnp.zeros((order.shape[0], x.shape[1]), x.dtype),
-         jnp.zeros(w.shape, jnp.float32), jnp.zeros(b.shape, jnp.float32)))
+         jnp.zeros(w.shape, jnp.float32),
+         None if b is None else jnp.zeros(b.shape, jnp.float32)))
     dx = jnp.where(valid[:, None], _rows(dx_c, rank), 0)
-    return dx, dw.astype(w.dtype), db.astype(b.dtype), None
+    return (dx, dw.astype(w.dtype),
+            None if b is None else db.astype(b.dtype), None)
 
 
 _linear_ce.defvjp(_linear_ce_fwd, _linear_ce_bwd)
 
 
-@register_op("linear_softmax_with_cross_entropy", nondiff_inputs=["Label"])
+@register_op("linear_softmax_with_cross_entropy", nondiff_inputs=["Label"],
+             own_remat=True)
 def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
     """softmax_with_cross_entropy(X @ W + Bias, Label, ignore_index) in one
     op that projects the labelled positions only (`_linear_ce`): the loss
@@ -870,22 +918,21 @@ def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
     (x,) = inputs["X"]
     (w,) = inputs["W"]
     (label,) = inputs["Label"]
-    b = opt_input(inputs, "Bias")
-    if b is None:          # a head without a bias: a zero one, never learnt
-        b = jnp.zeros((w.shape[1],), jnp.float32)
+    b = opt_input(inputs, "Bias")      # None: a head without a bias
     ignore = attrs.get("ignore_index", -100)
     path = "per_data_shard" if _under_mesh(ctx) else "whole"
 
     def head(x, idx, w, b, key=None):
         flat = idx.reshape(-1)
-        chunk = linear_ce_chunk_rows(flat.shape[0], w.shape[1])
+        chunks = linear_ce_chunk_rows(flat.shape[0], w.shape[1])
         obs = get_registry()
         obs.counter("ops/linear_ce_lowered", path=path).inc()
-        obs.gauge("ops/linear_ce_chunk_rows").set(chunk)
+        for loop, chunk in zip(("forward", "backward"), chunks):
+            obs.gauge("ops/linear_ce_chunk_rows", loop=loop).set(chunk)
         loss = _linear_ce(x.reshape(flat.shape[0], -1), w, b, flat, ignore,
-                          chunk)
+                          chunks)
         n = jnp.sum(flat != ignore, dtype=jnp.int32)
-        rows = _cdiv(n, chunk) * chunk
+        rows = _cdiv(n, chunks[0]) * chunks[0]
         return loss.reshape(idx.shape), jnp.stack([rows, n])[None]
 
     idx = label.reshape(x.shape[:-1] + (1,))

@@ -22,24 +22,35 @@ CHUNK = 8
 N = B * T
 
 
+def _shrink_chunks(monkeypatch, vocab):
+    """Forward chunks of CHUNK rows and backward chunks of two of them at
+    this vocabulary (the rows are derived from the shapes; at these sizes
+    one chunk would cover all positions)."""
+    monkeypatch.setattr(nn_ops, "_CE_CHUNK_BYTES", 4 * vocab * CHUNK)
+    assert nn_ops.linear_ce_chunk_rows(N, vocab) == (CHUNK, 2 * CHUNK)
+
+
 @pytest.fixture()
 def small_chunks(monkeypatch):
-    """Chunks of CHUNK rows at this vocabulary (the size is derived from the
-    shapes; at V = 64 it would cover all positions in one)."""
-    monkeypatch.setattr(nn_ops, "_CE_CHUNK_LOGITS_BYTES", 4 * V * CHUNK)
-    assert nn_ops.linear_ce_chunk_rows(N, V) == CHUNK
+    _shrink_chunks(monkeypatch, V)
 
 
-def _labels(count, rng):
+def _labels(count, rng, vocab=V):
     lab = np.full(N, -100, "int64")
     at = rng.permutation(N)[:count]
-    lab[at] = rng.randint(0, V, count)
+    lab[at] = rng.randint(0, vocab, count)
     return lab.reshape(B, T, 1)
 
 
-def _head_grads(fused, amp, lab, rng):
+def _head_grads(fused, amp, lab, rng, bias=True, vocab=V, remat=None,
+                text=None):
     """Loss (weighted by a seeded cotangent) and d/dX, d/dW, d/dBias of one
-    head, fused or dense, float32 or bf16-AMP."""
+    head, fused or dense, float32 or bf16-AMP, with or without a bias.
+    `remat`: "policy" sets `Program.remat_policy = "full"`; "strategy" and
+    "strategy_off" run over two data shards with `BuildStrategy.remat` True
+    and False. `text` (a list) receives the compiled step's text."""
+    import jax
+
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         x = layers.data("x", [T, H], dtype="float32")
@@ -47,49 +58,77 @@ def _head_grads(fused, amp, lab, rng):
         lbl = layers.data("lbl", [T, 1], dtype="int64")
         cot = layers.data("cot", [T, 1], dtype="float32")
         h = layers.cast(x, "bfloat16") if amp else x
-        w_attr, b_attr = fluid.ParamAttr(name="w"), fluid.ParamAttr(name="b")
+        w_attr = fluid.ParamAttr(name="w")
+        b_attr = fluid.ParamAttr(name="b") if bias else False
         extra = []
         if fused:
             loss, rows, n = layers.linear_softmax_with_cross_entropy(
-                h, lbl, V, param_attr=w_attr, bias_attr=b_attr,
+                h, lbl, vocab, param_attr=w_attr, bias_attr=b_attr,
                 return_rows=True)
             extra = [rows, n]
         else:
-            logits = layers.fc(h, V, num_flatten_dims=2, param_attr=w_attr,
-                               bias_attr=b_attr)
+            logits = layers.fc(h, vocab, num_flatten_dims=2,
+                               param_attr=w_attr, bias_attr=b_attr)
             loss = layers.softmax_with_cross_entropy(logits, lbl,
                                                      ignore_index=-100)
         total = layers.reduce_sum(layers.elementwise_mul(loss, cot))
-        w, b = (main.global_block().var(n) for n in "wb")
-        grads = fluid.gradients([total], [x, w, b])
+        params = [main.global_block().var(n) for n in ("wb" if bias else "w")]
+        grads = fluid.gradients([total], [x, *params])
     if amp:
         lists = AutoMixedPrecisionLists()
         main._amp = {"dtype": "bfloat16", "white_list": lists.white_list,
                      "black_list": lists.black_list}
     feed = {"x": rng.randn(B, T, H).astype("float32"), "lbl": lab,
             "cot": rng.rand(B, T, 1).astype("float32")}
+    prog = main
+    if remat == "policy":
+        main.remat_policy = "full"
+    elif remat is not None:
+        strategy = fluid.BuildStrategy()
+        strategy.remat = remat == "strategy"
+        prog = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=total.name, build_strategy=strategy,
+            places=jax.devices()[:2])
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
-        scope.set_var("w", (rng.randn(H, V) * 0.3).astype("float32"))
-        scope.set_var("b", (rng.randn(V) * 0.1).astype("float32"))
-        out = exe.run(main, feed=feed, fetch_list=[loss, *grads, *extra])
+        scope.set_var("w", (rng.randn(H, vocab) * 0.3).astype("float32"))
+        b = (rng.randn(vocab) * 0.1).astype("float32")  # drawn either way
+        if bias:
+            scope.set_var("b", b)
+        out = exe.run(prog, feed=feed, fetch_list=[loss, *grads, *extra])
+        if text is not None:
+            text.append(exe.compiled_step(prog).as_text())
     return [np.asarray(o, "float32") for o in out]
 
 
+_COUNTS = {"none": 0, "one": 1, "chunk-1": CHUNK - 1, "chunk": CHUNK,
+           "chunk+1": CHUNK + 1, "15pct": round(0.15 * N), "all": N}
+# (labelled count, bias, vocabulary): every count at the narrow vocabulary
+# with a bias; a head without a bias (both decoders'); every row labelled at
+# a vocabulary eight times as wide (Ouro's case: the backward's rows are not
+# the forward's)
+_HEADS = {**{k: (c, True, V) for k, c in _COUNTS.items()},
+          "15pct_no_bias": (_COUNTS["15pct"], False, V),
+          "all_no_bias": (N, False, V),
+          "all_wide_vocab": (N, True, 8 * V),
+          "all_wide_vocab_no_bias": (N, False, 8 * V)}
+
+
 @pytest.mark.parametrize("amp", [False, True], ids=["float32", "bf16_amp"])
-@pytest.mark.parametrize(
-    "count", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1, round(0.15 * N), N],
-    ids=["none", "one", "chunk-1", "chunk", "chunk+1", "15pct", "all"])
-def test_fused_head_equals_dense_pair(small_chunks, count, amp):
-    lab = _labels(count, np.random.RandomState(count))
-    got = _head_grads(True, amp, lab, np.random.RandomState(7))
-    ref = _head_grads(False, amp, lab, np.random.RandomState(7))
+@pytest.mark.parametrize("head", list(_HEADS))
+def test_fused_head_equals_dense_pair(monkeypatch, head, amp):
+    count, bias, vocab = _HEADS[head]
+    _shrink_chunks(monkeypatch, vocab)
+    lab = _labels(count, np.random.RandomState(count), vocab)
+    got = _head_grads(True, amp, lab, np.random.RandomState(7), bias, vocab)
+    ref = _head_grads(False, amp, lab, np.random.RandomState(7), bias, vocab)
     # bf16: the pair rounds its logits to bf16 before the softmax, the fused
     # op keeps them in float32; both multiply bf16 operands
     tol = 3e-2 if amp else 1e-5
-    for name, a, r in zip(("loss", "dX", "dW", "dBias"), got, ref):
+    names = ("loss", "dX", "dW", "dBias") if bias else ("loss", "dX", "dW")
+    for name, a, r in zip(names, got, ref):
         assert a.shape == r.shape, name
         np.testing.assert_allclose(a, r, rtol=tol,
                                    atol=tol * max(1e-6, np.abs(r).max()),
@@ -98,19 +137,73 @@ def test_fused_head_equals_dense_pair(small_chunks, count, amp):
     loss, dx = got[0].reshape(N), got[1].reshape(N, H)
     assert not loss[ignored].any() and not dx[ignored].any()
     assert count == 0 or np.abs(dx[~ignored]).min(axis=1).max() > 0
-    rows, labelled = int(got[4]), int(got[5])
+    rows, labelled = (int(v) for v in got[-2:])
     assert labelled == count
     assert rows == -(-count // CHUNK) * CHUNK
 
 
-def test_chunk_rows_follow_the_shapes():
-    """1,024 rows at BERT's vocabulary (125 MB of float32 logits), never
-    more than the positions there are, always a sublane multiple."""
-    assert nn_ops.linear_ce_chunk_rows(64 * 512, 30522) == 1024
-    assert nn_ops.linear_ce_chunk_rows(16 * 512, 30522) == 1024
-    assert nn_ops.linear_ce_chunk_rows(2 * 128, 30522) == 256
-    assert nn_ops.linear_ce_chunk_rows(100, 64) == 104
-    assert nn_ops.linear_ce_chunk_rows(32768, 1 << 20) == 32
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("remat, plain", [("policy", None),
+                                          ("strategy", "strategy_off")],
+                         ids=["remat_policy_full", "build_strategy_remat"])
+def test_head_under_a_remat_policy_is_not_wrapped_again(small_chunks, remat,
+                                                        plain, bias):
+    """The op is its own rematerialisation (`own_remat` in its registration):
+    under a policy that checkpoints every op outside a remat block the step
+    still holds the head's two loops, forward and backward, not a third (the
+    forward rule made again), the skipped wrap is counted, and the loss and
+    every gradient are the unwrapped op's bit for bit."""
+    def own():
+        snap = get_registry().snapshot(deep=False)
+        return sum(v for k, v in snap.items()
+                   if k.startswith("remat/op_own")
+                   and "linear_softmax_with_cross_entropy" in k)
+
+    lab = _labels(N, np.random.RandomState(1))
+    texts = []
+    ref = _head_grads(True, False, lab, np.random.RandomState(7), bias,
+                      remat=plain, text=texts)
+    before = own()
+    got = _head_grads(True, False, lab, np.random.RandomState(7), bias,
+                      remat=remat, text=texts)
+    assert [t.count(" while(") for t in texts] == [2, 2]
+    assert own() > before
+    for name, a, r in zip(("loss", "dX", "dW", "dBias"), got, ref):
+        np.testing.assert_array_equal(a, r, err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "n_pos, vocab, want",
+    [(64 * 512, 30522, (1024, 1024)),
+     (256 * 128, 30522, (1024, 1024)),
+     (64 * 512, 30522, (1024, 1024)),            # a data shard of dp4's 256
+     (2 * 8192, 16384, (2048, 2048)),
+     (4 * 2 * 4096, 49152, (512, 1024)),
+     (2 * 128, 30522, (256, 256)),
+     (100, 64, (104, 104)),
+     (1000, 49152, (512, 512)),
+     (32768, 1 << 20, (32, 64))],
+    ids=["ernie_base.seq512", "ernie_base.seq128", "ernie_base.dp4_seq512",
+         "nemotron3_nano.train8k", "ouro_2_6b.train4k", "fewer_positions",
+         "sublane_multiple", "whole_forward_chunks", "widest_vocabulary"])
+def test_chunk_rows_follow_the_shapes(n_pos, vocab, want):
+    """The five token cells' heads and the rule's edges. Forward: the float32
+    logits of a chunk stay on the chip (125 MB at BERT's 1,024 rows).
+    Backward: whole forward chunks, at or over the ridge of the dW
+    accumulator's traffic (rows / 4 operations a byte against 240, whatever
+    the hidden width: ERNIE's 768, Nemotron's 2,688, Ouro's 2,048) wherever
+    the positions and the softmax gradient's bytes allow: BERT's and
+    Nemotron's rows are the forward's, as before the backward had rows of
+    its own; Ouro's are twice the forward's. Never more rows than positions,
+    always a sublane multiple."""
+    fwd, bwd = nn_ops.linear_ce_chunk_rows(n_pos, vocab)
+    assert (fwd, bwd) == want
+    assert fwd % 8 == 0 and bwd % fwd == 0 and bwd <= -(-n_pos // 8) * 8
+    assert 4 * fwd * vocab <= nn_ops._CE_CHUNK_BYTES or fwd == 8
+    ridge = nn_ops._CE_RIDGE_ROWS
+    assert ridge / 4 >= 240 > ridge / 8        # a v5e: 197 TFLOP/s, 819 GB/s
+    if n_pos >= ridge and 2 * ridge * vocab <= nn_ops._CE_CHUNK_BYTES:
+        assert bwd >= ridge
 
 
 def _cfg(tp_axis=None):

@@ -470,10 +470,10 @@ def test_linear_ce_rows_weighted_by_a_learnt_weight_against_the_dense_pair(
     g = jnp.asarray(rng.normal(size=(h,)) * 0.3, jnp.float32)
     b = jnp.zeros((v,), jnp.float32)
     lbl = jnp.asarray(rng.integers(0, v, n), jnp.int32)
-    chunk = 16                                   # three chunks of rows
+    chunks = (16, 32)               # three chunks of rows, backward two
 
     def fused_rows(x, w):
-        return nn_ops._linear_ce(x, w, b, lbl, -100, chunk)
+        return nn_ops._linear_ce(x, w, b, lbl, -100, chunks)
 
     def dense_rows(x, w):
         logp = jax.nn.log_softmax(jnp.matmul(

@@ -163,29 +163,40 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_labelled_rows_head_compiles_for_v5e_at_ernie_width(one_chip):
+@pytest.mark.parametrize(
+    "n, h, v, x_dtype, bias, chunks",
+    [(64 * 512, 768, 30522, jnp.bfloat16, True, (1024, 1024)),
+     (4 * 2 * 4096, 2048, 49152, jnp.float32, False, (512, 1024))],
+    ids=["ernie_base", "ouro_2_6b"])
+def test_labelled_rows_head_compiles_for_v5e(one_chip, n, h, v, x_dtype,
+                                             bias, chunks):
     """The head of `ernie_base.seq512` (64 x 512 positions, bf16
-    activations, the float32 768 x 30,522 matrix), loss and gradients,
-    through the TPU's own compiler: the loops keep their dynamic length and
-    the step's temporaries stay far under the 1.86 GiB that bf16 logits of
-    all positions would take."""
+    activations, the float32 768 x 30,522 matrix) and of `ouro_2_6b.train4k`
+    (four exits of 2 x 4,096 positions in float32, 2,048 x 49,152, no bias),
+    loss and gradients, through the TPU's own compiler with the rows the
+    rule gives: two loops of dynamic length, no [positions, vocab] value,
+    the step's temporaries far under what bf16 logits of all positions would
+    take, and the accumulator of dW read and written at most once for every
+    1,024 rows."""
     from paddle_tpu.ops import nn_ops
 
-    n, h, v = 64 * 512, 768, 30522
-    chunk = nn_ops.linear_ce_chunk_rows(n, v)
+    assert nn_ops.linear_ce_chunk_rows(n, v) == chunks
 
     def loss(x, w, b, lbl):
-        return jnp.sum(nn_ops._linear_ce(x, w, b, lbl, -100, chunk))
+        return jnp.sum(nn_ops._linear_ce(x, w, b if bias else None, lbl,
+                                         -100, chunks))
 
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-            for shape, dtype in (((n, h), jnp.bfloat16),
+            for shape, dtype in (((n, h), x_dtype),
                                  ((h, v), jnp.float32), ((v,), jnp.float32),
                                  ((n,), jnp.int32))]
-    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).trace(
-        *args).lower(lowering_platforms=("tpu",)).compile()
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2) if bias else (0, 1))
+                       ).trace(*args).lower(
+                           lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    assert text.count(" while(") >= 2
+    assert text.count(" while(") == 2
     assert f"[{n},{v}]" not in text and f"[{v},{n}]" not in text
+    assert f"f32[{h},{v}]" in text and f"[{chunks[1]},{v}]" in text
     dense_logits = n * v * 2
     assert compiled.memory_analysis().temp_size_in_bytes < dense_logits / 2
 
