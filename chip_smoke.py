@@ -27,6 +27,11 @@ Phases, one chip:
   looped   three steps of a looped decoder (models/ouro.py) at Ouro-2.6B's
            widths, two layers run twice over shared weights, b1 x 1024, bf16
            AMP Adam: one parameter a layer's matrix, the exit shares sum to 1
+  lfm2     three steps of models/lfm2.py at LFM2-24B-A2B's widths, the dense
+           layer and one period (conv + dense MLP; attention, conv, conv,
+           conv with 8 of 64 gated experts held), b1 x 1024, bf16 AMP Adam:
+           the head reads the embedding's table (one parameter), every held
+           pair is multiplied
 
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
@@ -62,6 +67,9 @@ ERNIE_STEPS = 10
 DEEPFM_VOCAB, DEEPFM_BATCH, DEEPFM_STEPS = 33_554_432, 4096, 3
 # the looped decoder: depth, passes and batch small enough to add under 30 s
 LOOPED_LAYERS, LOOPED_PASSES, LOOPED_SEQ, LOOPED_STEPS = 2, 2, 1024, 3
+# the hybrid decoder: the dense layer and one period of the layer pattern
+LFM2_LAYERS = ["conv", "full_attention", "conv", "conv", "conv"]
+LFM2_SEQ, LFM2_STEPS = 1024, 3
 # dp4: per-chip batch of the sharded run, and the dropout-free equality run
 DP4_PER_CHIP, DP4_EQ_BATCH, DP4_EQ_STEPS, DP4_EQ_RTOL = 64, 64, 5, 1e-2
 
@@ -783,6 +791,73 @@ def phase_looped(args):
 
 
 # ---------------------------------------------------------------------------
+# lfm2: gated short convolutions, GQA with q/k norm, gated experts, tied head
+# ---------------------------------------------------------------------------
+
+def phase_lfm2(args):
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config(vocab_size=8192, layer_types=list(LFM2_LAYERS),
+                          num_dense_layers=1, experts_held=(0, 8))
+
+    def opt():
+        return mp.decorate(fluid.optimizer.Adam(1e-4), dtype="bfloat16",
+                           use_dynamic_loss_scaling=False)
+
+    with fluid.unique_name.guard():
+        main, startup, _, loss, counters = lfm2.build_pretrain_program(
+            cfg, 1, LFM2_SEQ, opt)
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (1, LFM2_SEQ + 1)).astype("int32")
+    feed = {"ids": jnp.asarray(ids[:, :-1]),
+            "labels": jnp.asarray(ids[:, 1:, None])}
+    fetch = [loss] + [v for _, tokens, pairs in counters
+                      for v in (tokens, pairs)]
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        fetched = [exe.run(main, feed=feed, fetch_list=fetch,
+                           return_numpy=False) for _ in range(LFM2_STEPS)]
+        vals = [float(np.asarray(f[0])) for f in fetched]
+        wall_s = time.perf_counter() - t0
+        tables = [n for n in scope.var_names()
+                  if n in ("embed.w", "lm_head.w")]
+    _require(all(np.isfinite(vals)), f"non-finite lfm2 loss in {vals}")
+    _require(vals[-1] < vals[0],
+             f"lfm2 loss did not fall on a fixed batch: {vals}")
+    _require(_platforms(fetched[-1][0]) == {"tpu"},
+             f"lfm2 loss lives on {_platforms(fetched[-1][0])}, not tpu")
+    _require(tables == ["embed.w"],
+             f"a tied head has one table, the scope holds {tables}")
+    held = []
+    for tokens, pairs in zip(fetched[-1][1::2], fetched[-1][2::2]):
+        tokens, pairs = np.asarray(tokens), int(np.asarray(pairs))
+        _require(tokens.shape == (8,) and tokens.sum() == pairs
+                 and 0 < pairs <= LFM2_SEQ * cfg.num_experts_per_tok,
+                 f"held pairs {pairs} against per-expert {tokens.tolist()}")
+        held.append(pairs)
+    _require(len(held) == len(LFM2_LAYERS) - 1,
+             f"{len(held)} expert layers of {len(LFM2_LAYERS) - 1}")
+    print(f"lfm2: {len(LFM2_LAYERS)} layers b1 x {LFM2_SEQ}, "
+          f"{lfm2.param_count(cfg) / 1e6:.1f}M parameters, loss "
+          f"{vals[0]:.5f} -> {vals[-1]:.5f} over {LFM2_STEPS} steps, pairs "
+          f"held a layer {held} of {LFM2_SEQ * cfg.num_experts_per_tok} "
+          f"(startup+compile+steps {wall_s:.1f} s, set-up fact)")
+    del fetched, scope, exe
+    gc.collect()
+    return {"config": {"layer_types": LFM2_LAYERS, "seq": LFM2_SEQ,
+                       "parameters": lfm2.param_count(cfg)},
+            "losses": [round(v, 5) for v in vals], "pairs_held": held,
+            "setup": {"startup_compile_and_steps_s": round(wall_s, 2)}}
+
+
+# ---------------------------------------------------------------------------
 # dp4: the same ERNIE program, data-parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -894,7 +969,7 @@ def phase_dp4(args):
 
 PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
                    ("kernels", phase_kernels), ("deepfm", phase_deepfm),
-                   ("looped", phase_looped)]
+                   ("looped", phase_looped), ("lfm2", phase_lfm2)]
 PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
 
 

@@ -527,7 +527,8 @@ def softmax_with_cross_entropy(logits: Variable, label: Variable,
 def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
                                       size: int, ignore_index: int = -100,
                                       param_attr=None, bias_attr=None,
-                                      return_rows: bool = False, name=None):
+                                      return_rows: bool = False, name=None,
+                                      tied_table: bool = False):
     """`softmax_with_cross_entropy(fc(input, size, num_flatten_dims=rank-1),
     label, ignore_index=ignore_index)` as one op (TPU extension, no
     reference analog): the same per-position loss [..., 1] and the same
@@ -541,11 +542,17 @@ def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
     keeps its inputs and a float32 scalar a row), so a remat policy does not
     checkpoint it again. The parameters are those `fc` would create (weight
     [hidden, size], then bias [size]; `bias_attr=False`: no bias).
-    `return_rows=True` also returns the rows the forward pass projected
-    (whole chunks) and the labelled count, both int32 scalars."""
+    `tied_table=True`: the weight is [size, hidden], an embedding's table
+    (name it in `param_attr`: a name the program already holds is that
+    parameter), read transposed; its one gradient is the lookup's rows plus
+    the projection's. `return_rows=True` also returns the rows the forward
+    pass projected (whole chunks) and the labelled count, both int32
+    scalars."""
     helper = LayerHelper("fc", name=name)
-    w = helper.create_parameter(param_attr, shape=[input.shape[-1], size],
-                                dtype=input.dtype)
+    hidden = input.shape[-1]
+    w = helper.create_parameter(
+        param_attr, shape=[size, hidden] if tied_table else [hidden, size],
+        dtype=input.dtype)
     ins = {"X": [input.name], "W": [w.name], "Label": [label.name]}
     if bias_attr is not False:
         b = helper.create_parameter(bias_attr, shape=[size],
@@ -560,7 +567,8 @@ def linear_softmax_with_cross_entropy(input: Variable, label: Variable,
                      outputs={"Loss": [loss.name],
                               "RowsComputed": [rows.name],
                               "Labelled": [labelled.name]},
-                     attrs={"ignore_index": ignore_index})
+                     attrs={"ignore_index": ignore_index,
+                            "transpose_w": bool(tied_table)})
     if return_rows:
         return loss, rows, labelled
     return loss
@@ -834,14 +842,16 @@ def moe_ffn(input: Variable, num_experts: int, hidden_size: int, k: int = 2,
             bias_attr=None, experts_held=None, scoring: str = "softmax",
             correction_bias: bool = False, norm_topk: bool = True,
             routed_scaling: float = 1.0, return_counts: bool = False,
-            name=None):
+            gated: bool = False, name=None):
     """Mixture-of-Experts feed-forward block (no reference analog — the
     reference predates MoE; exposed like its fused composite ops).
 
     Top-k routing over all `num_experts` in float32 (`scoring` "softmax", or
     "sigmoid" with, under `correction_bias=True`, a selection-only bias
     parameter `<name>.corr_bias`; the chosen scores divided by their sum
-    under `norm_topk`, times `routed_scaling`). Dropless: the (token,
+    under `norm_topk`, times `routed_scaling`). An expert is
+    `act(x·W1)·W2`, or with `gated=True` `(act(x·W1) ⊙ x·W3)·W2` with a third
+    parameter `<name>.w3` shaped like `w1`. Dropless: the (token,
     expert) pairs are sorted by expert and each expert multiplies exactly
     the tokens routed to it (parallel/moe.py) — no capacity, no dropped
     token. `experts_held = (first, count)` makes the layer hold that range
@@ -878,6 +888,11 @@ def moe_ffn(input: Variable, num_experts: int, hidden_size: int, k: int = 2,
                                  dtype=input.dtype)
     ins = {"X": [input.name], "GateW": [gate.name], "W1": [w1.name],
            "W2": [w2.name]}
+    if gated:
+        w3 = helper.create_parameter(_attr(param_attr, "w3"),
+                                     shape=[held, d, hidden_size],
+                                     dtype=input.dtype)
+        ins["W3"] = [w3.name]
     if bias_attr is not False:
         base = param_attr if bias_attr is None else bias_attr
         b1 = helper.create_parameter(_attr(base, "b1"),

@@ -5,11 +5,11 @@ machinery of parallel/moe.py to static-graph programs as a single `moe_ffn`
 op, the same way the reference exposes composite blocks as fused ops (e.g.
 fused_embedding_seq_pool_op.cc): a float32 router over all the layer's
 experts, dropless sort-and-segment dispatch, one grouped product over the
-experts held. Under a compiled mesh with an `ep` axis the experts are sharded
-over it and the tokens gathered and reduce-scattered; otherwise the op
-computes the part of the result its `experts_held` give. Differentiable
-through the executor's vjp tape (the grouped product brings its own
-backward).
+experts held (plain, or gated where the op has a `W3` input). Under a
+compiled mesh with an `ep` axis the experts are sharded over it and the
+tokens gathered and reduce-scattered; otherwise the op computes the part of
+the result its `experts_held` give. Differentiable through the executor's
+vjp tape (the grouped product brings its own backward).
 
 Gray under AMP (not listed in contrib/mixed_precision/fp16_lists.py): the
 router runs in float32 whatever dtype the activations arrive in, the expert
@@ -33,13 +33,15 @@ def _moe_ffn(ctx, inputs, attrs):
     (w2,) = inputs["W2"]               # [E_held, H, D]
     b1 = opt_input(inputs, "B1")       # [E_held, H]
     b2 = opt_input(inputs, "B2")       # [E_held, D]
+    w3 = opt_input(inputs, "W3")       # [E_held, D, H]: gated experts
     bias = opt_input(inputs, "CorrectionBias")     # [E]
     axis = attrs.get("ep_axis", "ep")
     act = act_map()[attrs.get("act", "gelu")]
     kw = dict(k=int(attrs.get("k", 2)), act=act,
               scoring=attrs.get("scoring", "softmax"), correction_bias=bias,
               norm_topk=bool(attrs.get("norm_topk", True)),
-              routed_scaling=float(attrs.get("routed_scaling", 1.0)))
+              routed_scaling=float(attrs.get("routed_scaling", 1.0)),
+              w3=w3)
     e = gate_w.shape[1]
     first = int(attrs.get("experts_first", 0))
 

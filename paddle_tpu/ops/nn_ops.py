@@ -910,8 +910,10 @@ def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
     multiplied. Under a mesh each data shard compacts its own positions (a
     global compaction would make GSPMD gather the batch) and the gradients
     of W and Bias are summed over the shards once, by the shard_map's own
-    transpose. Also returns the rows it projected (whole chunks) and the
-    labelled count, summed over the shards."""
+    transpose. With the attribute `transpose_w` W is [size, hidden], an
+    embedding's table serving as the head's matrix. Also returns the rows it
+    projected (whole chunks) and the labelled count, summed over the
+    shards."""
     from ..observability import get_registry
     from .fused_ops import _per_data_shard, _under_mesh
 
@@ -919,6 +921,10 @@ def _linear_softmax_with_cross_entropy(ctx, inputs, attrs):
     (w,) = inputs["W"]
     (label,) = inputs["Label"]
     b = opt_input(inputs, "Bias")      # None: a head without a bias
+    if attrs.get("transpose_w", False):
+        # a tied head: W is an embedding's [size, hidden] table, and its
+        # gradient comes back in the table's layout to meet the lookup's
+        w = jnp.swapaxes(w, 0, 1)
     ignore = attrs.get("ignore_index", -100)
     path = "per_data_shard" if _under_mesh(ctx) else "whole"
 

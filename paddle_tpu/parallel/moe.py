@@ -18,13 +18,17 @@ operators/distributed/parameter_prefetch.cc).
 - **Dispatch** sorts the held pairs by expert and cuts each expert's run
   into tiles of `TILE` rows. **The grouped product** (`_grouped_ffn`) walks
   the live tiles only — a loop whose trip count is the number of tiles the
-  routing made, each tile one [TILE, D] x [D, H] x [H, D] expert MLP on
-  gathered rows, weighted and scatter-added back to its tokens — so the work
-  follows the pairs held, there is no capacity, no token is ever dropped
-  (all tokens on one expert just make more tiles), and nothing of size
-  [N, E, C] or [N*k, D] exists. Its backward walks the same tiles again
-  (jax cannot reverse a loop of dynamic length, hence the custom_vjp),
-  recomputing each tile's hidden activations.
+  routing made, each tile one expert MLP on [TILE, D] gathered rows,
+  weighted and scatter-added back to its tokens — so the work follows the
+  pairs held, there is no capacity, no token is ever dropped (all tokens on
+  one expert just make more tiles), and nothing of size [N, E, C] or
+  [N*k, D] exists. An expert is **plain**, `act(x·W1 + b1)·W2 + b2` (two
+  matrices [D, H], [H, D]), or, where a third matrix `w3` [E_held, D, H] is
+  given, **gated**, `(act(x·W1 + b1) ⊙ x·W3)·W2 + b2`: the same tile with one
+  more product from the same rows and an elementwise product in float32
+  between. Its backward walks the same tiles again (jax cannot reverse a
+  loop of dynamic length, hence the custom_vjp), recomputing each tile's
+  hidden activations (both halves of a gated expert's).
 - **Expert parallelism** (`moe_ffn_expert_parallel`): tokens sharded over the
   axis, experts sharded over the same axis. Each device gathers the tokens
   (all-gather), computes its held experts' part for all of them, and a
@@ -49,9 +53,12 @@ from ..observability.scopes import unit_scope
 from .collective import shard_map
 
 _HI = lax.Precision.HIGHEST
-# rows of one expert handled per step of the grouped product. 256 rows
-# against a [2688, 1856] expert read its weights about as fast as it
-# multiplies by them on a v5e; an expert's last tile is part empty, which
+# rows of one expert handled per step of the grouped product. A tile of R
+# rows multiplies by each of the expert's [D, H] matrices (two plain, three
+# gated) 2·R·D·H operations for the 2·D·H bytes it reads of it in bf16: R
+# operations a byte whatever D, H and the number of matrices, so 256 rows
+# read an expert's weights about as fast as they multiply by them on a v5e
+# (ridge 240) in either form; an expert's last tile is part empty, which
 # costs E_held * TILE / 2 rows a layer on average
 TILE = 256
 
@@ -178,13 +185,18 @@ def _tile_rows(plan: _Plan, weight_flat, i, k: int, tile: int):
     return e, pair // k, wgt, pair, live
 
 
-def _expert_tile(xt, w1e, b1e, w2e, b2e, wgt, act):
-    """One expert on one tile of rows: act(xt·W1 + b1)·W2 + b2, weighted.
-    Products take operands in xt's dtype and accumulate in float32."""
+def _expert_tile(xt, w1e, b1e, w2e, b2e, wgt, w3e, act):
+    """One expert on one tile of rows: act(xt·W1 + b1)·W2 + b2, weighted;
+    with `w3e` the hidden activation is act(xt·W1 + b1) ⊙ xt·W3 (a gated
+    expert). Products take operands in xt's dtype and accumulate in float32,
+    and the gate's product is of float32 halves."""
     h = jnp.dot(xt, w1e, preferred_element_type=jnp.float32)
     if b1e is not None:
         h = h + b1e
-    h = act(h).astype(xt.dtype)
+    h = act(h)
+    if w3e is not None:
+        h = h * jnp.dot(xt, w3e, preferred_element_type=jnp.float32)
+    h = h.astype(xt.dtype)
     o = jnp.dot(h, w2e, preferred_element_type=jnp.float32)
     if b2e is not None:
         o = o + b2e
@@ -195,20 +207,21 @@ def _at(a, e):
     return None if a is None else lax.dynamic_index_in_dim(a, e, 0, False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _grouped_ffn(x, weight, w1, b1, w2, b2, plan, act, k, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _grouped_ffn(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile):
     """Σ over the held (token, expert) pairs of weight · expert(x[token]),
     as [N, D] float32. x: [N, D]; weight: [N, k] float32; w1: [E_held, D, H];
-    w2: [E_held, H, D]; b1, b2: [E_held, H], [E_held, D] or None."""
-    return _grouped_fwd(x, weight, w1, b1, w2, b2, plan, act, k, tile)[0]
+    w2: [E_held, H, D]; b1, b2: [E_held, H], [E_held, D] or None; w3:
+    [E_held, D, H] (gated experts) or None (plain)."""
+    return _grouped_fwd(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile)[0]
 
 
 def _lo(a, dtype):
     return None if a is None else a.astype(dtype)
 
 
-def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, act, k, tile):
-    w1c, w2c = w1.astype(x.dtype), w2.astype(x.dtype)
+def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile):
+    w1c, w2c, w3c = w1.astype(x.dtype), w2.astype(x.dtype), _lo(w3, x.dtype)
     b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
     weight_flat = weight.reshape(-1)
 
@@ -216,17 +229,17 @@ def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, act, k, tile):
         e, token, wgt, _, _ = _tile_rows(plan, weight_flat, i, k, tile)
         xt = x.at[token].get(mode="promise_in_bounds")
         o = _expert_tile(xt, _at(w1c, e), _at(b1f, e), _at(w2c, e),
-                         _at(b2f, e), wgt, act)
+                         _at(b2f, e), wgt, _at(w3c, e), act)
         return y.at[token].add(o, mode="promise_in_bounds")
 
     y = lax.fori_loop(0, plan.n_tiles, body,
                       jnp.zeros(x.shape, jnp.float32))
-    return y, (x, weight, w1, b1, w2, b2, plan)
+    return y, (x, weight, w1, b1, w2, b2, plan, w3)
 
 
 def _grouped_bwd(act, k, tile, res, g):
-    x, weight, w1, b1, w2, b2, plan = res
-    w1c, w2c = w1.astype(x.dtype), w2.astype(x.dtype)
+    x, weight, w1, b1, w2, b2, plan, w3 = res
+    w1c, w2c, w3c = w1.astype(x.dtype), w2.astype(x.dtype), _lo(w3, x.dtype)
     b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
     weight_flat = weight.reshape(-1)
     g = g.astype(jnp.float32)
@@ -239,30 +252,32 @@ def _grouped_bwd(act, k, tile, res, g):
             + d.astype(jnp.float32), e, 0)
 
     def body(i, carry):
-        dx, dwgt, dw1, db1, dw2, db2 = carry
+        dx, dwgt, dw1, db1, dw2, db2, dw3 = carry
         e, token, wgt, pair, live = _tile_rows(plan, weight_flat, i, k, tile)
         xt = x.at[token].get(mode="promise_in_bounds")
         gt = jnp.where(live[:, None],
                        g.at[token].get(mode="promise_in_bounds"), 0.0)
-        args = (xt, _at(w1c, e), _at(b1f, e), _at(w2c, e), _at(b2f, e), wgt)
+        args = (xt, _at(w1c, e), _at(b1f, e), _at(w2c, e), _at(b2f, e), wgt,
+                _at(w3c, e))
         _, vjp = jax.vjp(functools.partial(_expert_tile, act=act), *args)
-        dxt, d1, dbias1, d2, dbias2, dw = vjp(gt)
+        dxt, d1, dbias1, d2, dbias2, dw, d3 = vjp(gt)
         dx = dx.at[token].add(dxt.astype(jnp.float32),
                               mode="promise_in_bounds")
         # an empty row's pair id is a live pair's: it must add nothing
         dwgt = dwgt.at[pair].add(jnp.where(live, dw, 0.0),
                                  mode="promise_in_bounds")
         return (dx, dwgt, add_at(dw1, e, d1), add_at(db1, e, dbias1),
-                add_at(dw2, e, d2), add_at(db2, e, dbias2))
+                add_at(dw2, e, d2), add_at(db2, e, dbias2),
+                add_at(dw3, e, d3))
 
     def zeros(a):
         return None if a is None else jnp.zeros(a.shape, jnp.float32)
 
-    dx, dwgt, dw1, db1, dw2, db2 = lax.fori_loop(
+    dx, dwgt, dw1, db1, dw2, db2, dw3 = lax.fori_loop(
         0, plan.n_tiles, body,
         (jnp.zeros(x.shape, jnp.float32),
          jnp.zeros(weight_flat.shape, jnp.float32),
-         zeros(w1), zeros(b1), zeros(w2), zeros(b2)))
+         zeros(w1), zeros(b1), zeros(w2), zeros(b2), zeros(w3)))
 
     def like(d, a):
         return None if a is None else d.astype(a.dtype)
@@ -271,24 +286,24 @@ def _grouped_bwd(act, k, tile, res, g):
         lambda a: jnp.zeros(a.shape, jax.dtypes.float0), plan)
     return (dx.astype(x.dtype), dwgt.reshape(weight.shape).astype(weight.dtype),
             like(dw1, w1), like(db1, b1), like(dw2, w2), like(db2, b2),
-            plan_ct)
+            plan_ct, like(dw3, w3))
 
 
 _grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def experts_ffn(x, routing: Routing, w1, b1, w2, b2, first=0,
-                act=jax.nn.gelu, tile: Optional[int] = None):
+                act=jax.nn.gelu, tile: Optional[int] = None, w3=None):
     """The held experts' part of the layer for routed tokens x [N, D]: the
     experts `first .. first + w1.shape[0] - 1` of those `routing` chose
-    among. Returns (y [N, D] in x's dtype, tokens on each held expert,
-    pairs held)."""
+    among, gated where `w3` is given. Returns (y [N, D] in x's dtype, tokens
+    on each held expert, pairs held)."""
     count, k = w1.shape[0], routing.idx.shape[1]
     tile = tile or TILE
     with unit_scope("dispatch"):
         plan = _dispatch(routing.idx, first, count, tile)
     with unit_scope("experts"):
-        y = _grouped_ffn(x, routing.weight, w1, b1, w2, b2, plan, act, k,
+        y = _grouped_ffn(x, routing.weight, w1, b1, w2, b2, plan, w3, act, k,
                          tile)
     with unit_scope("combine"):
         y = y.astype(x.dtype)
@@ -298,12 +313,13 @@ def experts_ffn(x, routing: Routing, w1, b1, w2, b2, first=0,
 def moe_ffn(x, gate_w, w1, b1, w2, b2, k: int = 2, act=jax.nn.gelu,
             experts_held: Optional[Tuple[int, int]] = None,
             scoring: str = "softmax", correction_bias=None,
-            norm_topk: bool = True, routed_scaling: float = 1.0) -> MoEOutput:
+            norm_topk: bool = True, routed_scaling: float = 1.0,
+            w3=None) -> MoEOutput:
     """Single-device MoE FFN. x: [N, D]. gate_w: [D, E] routes over all E
     experts; w1: [E_held, D, H], b1: [E_held, H] or None, w2: [E_held, H, D],
-    b2: [E_held, D] or None are the weights of the experts held,
-    `experts_held = (first, count)` (default: all E). Pairs on experts that
-    are not held add nothing."""
+    b2: [E_held, D] or None and, for gated experts, w3: [E_held, D, H] are
+    the weights of the experts held, `experts_held = (first, count)`
+    (default: all E). Pairs on experts that are not held add nothing."""
     first, count = experts_held or (0, gate_w.shape[1])
     if w1.shape[0] != count:
         raise ValueError(f"moe_ffn: {count} experts held but w1 has "
@@ -311,7 +327,8 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, k: int = 2, act=jax.nn.gelu,
     with unit_scope("router"):
         routing = route(x, gate_w, k, scoring, correction_bias, norm_topk,
                         routed_scaling)
-    y, tokens, pairs = experts_ffn(x, routing, w1, b1, w2, b2, first, act)
+    y, tokens, pairs = experts_ffn(x, routing, w1, b1, w2, b2, first, act,
+                                   w3=w3)
     return MoEOutput(y, routing.aux_loss, tokens, pairs)
 
 
@@ -319,9 +336,11 @@ def moe_ffn_expert_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
                             axis: str = "ep", k: int = 2, act=jax.nn.gelu,
                             scoring: str = "softmax", correction_bias=None,
                             norm_topk: bool = True,
-                            routed_scaling: float = 1.0) -> MoEOutput:
+                            routed_scaling: float = 1.0,
+                            w3=None) -> MoEOutput:
     """Expert-parallel MoE FFN over `axis`: x [N, D] sharded on tokens, the
-    E experts' weights sharded on the expert dim (E / ep held a device).
+    E experts' weights (w1, w2, the biases and a gated layer's w3) sharded
+    on the expert dim (E / ep held a device).
     Every device routes its own tokens, the tokens and their routing are
     all-gathered, each device computes its experts' part for all tokens and
     a reduce-scatter sums the parts back to the tokens' owners. Equal to
@@ -333,8 +352,9 @@ def moe_ffn_expert_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
     held = e // ep
     has_bias = b1 is not None
 
-    def local(xs, gw, cb, w1s, w2s, *biases):
-        b1s, b2s = biases if has_bias else (None, None)
+    def local(xs, gw, cb, w1s, w2s, *rest):
+        b1s, b2s = rest[:2] if has_bias else (None, None)
+        w3s = rest[-1] if w3 is not None else None
         with unit_scope("router"):
             r = route(xs, gw, k, scoring, cb, norm_topk, routed_scaling,
                       axis=axis)
@@ -343,31 +363,38 @@ def moe_ffn_expert_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
         first = lax.axis_index(axis) * held
         y, tokens, pairs = experts_ffn(
             x_all, Routing(idx, weight, r.aux_loss), w1s, b1s, w2s, b2s,
-            first, act)
+            first, act, w3=w3s)
         y = lax.psum_scatter(y, axis, scatter_dimension=0, tiled=True)
         return (y, r.aux_loss, lax.all_gather(tokens, axis, axis=0, tiled=True),
                 lax.psum(pairs, axis))
 
     cb = (jnp.zeros((e,), jnp.float32) if correction_bias is None
           else correction_bias)
-    args = (x, gate_w, cb, w1, w2) + ((b1, b2) if has_bias else ())
-    specs = (P(axis), P(), P(), P(axis), P(axis)) + \
-        ((P(axis), P(axis)) if has_bias else ())
+    held_only = ((b1, b2) if has_bias else ()) + (
+        (w3,) if w3 is not None else ())
+    args = (x, gate_w, cb, w1, w2) + held_only
+    specs = (P(axis), P(), P()) + (P(axis),) * (2 + len(held_only))
     f = shard_map(local, mesh, in_specs=specs,
                   out_specs=(P(axis), P(), P(), P()))
     return MoEOutput(*f(*args))
 
 
 def init_moe_params(rng, d_model: int, d_hidden: int, num_experts: int,
-                    dtype=jnp.float32):
-    """Convenience initializer returning (gate_w, w1, b1, w2, b2)."""
+                    dtype=jnp.float32, gated: bool = False):
+    """Convenience initializer returning (gate_w, w1, b1, w2, b2), and with
+    `gated` a sixth, w3 (drawn like w1)."""
     k1, k2, k3 = jax.random.split(rng, 3)
     s1 = 1.0 / math.sqrt(d_model)
     s2 = 1.0 / math.sqrt(d_hidden)
+
+    def first(key):
+        return jax.random.normal(
+            key, (num_experts, d_model, d_hidden), dtype) * s1
+
     return (
         jax.random.normal(k1, (d_model, num_experts), dtype) * s1,
-        jax.random.normal(k2, (num_experts, d_model, d_hidden), dtype) * s1,
+        first(k2),
         jnp.zeros((num_experts, d_hidden), dtype),
         jax.random.normal(k3, (num_experts, d_hidden, d_model), dtype) * s2,
         jnp.zeros((num_experts, d_model), dtype),
-    )
+    ) + ((first(jax.random.fold_in(k2, 3)),) if gated else ())

@@ -285,3 +285,74 @@ def test_moe_layer_custom_param_attr_distinct_params(bias, expected):
         out = exe.run(main, feed={"x": np.zeros((4, 8), "float32")},
                       fetch_list=[h])
         assert out[0].shape == (4, 8)
+
+
+@pytest.mark.parametrize("ep,bias", [(4, False), (8, False), (4, True)],
+                         ids=["ep4", "ep8", "ep4-bias"])
+def test_gated_expert_parallel_matches_the_unsharded_layer(ep, bias):
+    """The gated form (`w3`) with the published sigmoid router and its
+    normaliser's epsilon: values, counters and every gradient, the third
+    matrix sharded over the axis like the other two."""
+    d, h, e = 16, 24, 8
+    n = 8 * 12
+    gw, w1, b1, w2, b2, w3 = moe.init_moe_params(
+        jax.random.PRNGKey(3), d, h, e, gated=True)
+    b1, b2 = (b1 + 0.1, b2 - 0.05) if bias else (None, None)
+    x = jax.random.normal(jax.random.PRNGKey(4), (n, d))
+    mesh = Mesh(np.array(jax.devices()[:ep]), ("ep",))
+    kw = dict(k=2, act=jax.nn.silu, scoring="sigmoid")
+
+    def sharded(x, gw, w1, w2, w3):
+        return moe.moe_ffn_expert_parallel(x, gw, w1, b1, w2, b2, mesh,
+                                           axis="ep", w3=w3, **kw)
+
+    def whole(x, gw, w1, w2, w3):
+        return moe.moe_ffn(x, gw, w1, b1, w2, b2, w3=w3, **kw)
+
+    args = (x, gw, w1, w2, w3)
+    got, ref = sharded(*args), whole(*args)
+    np.testing.assert_allclose(np.asarray(got.y), np.asarray(ref.y),
+                               rtol=2e-5, atol=2e-5)
+    assert np.array_equal(np.asarray(got.tokens_per_expert),
+                          np.asarray(ref.tokens_per_expert))
+    assert int(got.pairs_held) == int(ref.pairs_held) == 2 * n
+    # not the plain layer under another name
+    plain = moe.moe_ffn(x, gw, w1, b1, w2, b2, **kw)
+    assert not np.allclose(np.asarray(plain.y), np.asarray(ref.y), atol=1e-3)
+    ct = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    g_got = jax.grad(lambda *a: jnp.sum(sharded(*a).y * ct),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    g_ref = jax.grad(lambda *a: jnp.sum(whole(*a).y * ct),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b, name in zip(g_got, g_ref, ("x", "gate", "w1", "w2", "w3")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)
+    assert float(jnp.abs(g_ref[4]).max()) > 0
+
+
+def test_static_graph_layer_makes_the_third_matrix_of_gated_experts():
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", [6, 16], dtype="float32")
+        out, _ = layers.moe_ffn(
+            x, 8, 24, k=2, act="silu", gated=True, bias_attr=False,
+            experts_held=(2, 4), scoring="sigmoid",
+            param_attr=fluid.ParamAttr(name="m"))
+    shapes = {p.name: tuple(p.shape)
+              for p in main.global_block().all_parameters()}
+    assert shapes == {"m.gate": (16, 8), "m.w1": (4, 16, 24),
+                      "m.w2": (4, 24, 16), "m.w3": (4, 16, 24)}
+    exe, scope = fluid.Executor(fluid.TPUPlace()), fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    value = np.random.RandomState(0).randn(2, 6, 16).astype("float32")
+    (got,) = exe.run(main, feed={"x": value}, fetch_list=[out], scope=scope)
+    p = {k: jnp.asarray(np.asarray(scope.find_var(k))) for k in shapes}
+    want = moe.moe_ffn(jnp.asarray(value).reshape(12, 16), p["m.gate"],
+                       p["m.w1"], None, p["m.w2"], None, k=2,
+                       act=jax.nn.silu, experts_held=(2, 4),
+                       scoring="sigmoid", w3=p["m.w3"])
+    np.testing.assert_allclose(got.reshape(12, 16), np.asarray(want.y),
+                               rtol=1e-5, atol=1e-6)

@@ -391,3 +391,76 @@ def test_looped_decoder_compiles_for_v5e_with_its_kept_values(one_chip, kept):
         text = jax.jit(step._step).trace(state, feed, key).lower(
             lowering_platforms=("tpu",)).compile().as_text()
     assert text.count("tpu_custom_call") == (2 if kept else 3) * 2 * 2
+
+
+def test_gqa_flash_attention_compiles_for_v5e_at_lfm2_width(one_chip):
+    """An attention layer of `lfm2_24b_a2b.train8k`: 32 query heads on 8
+    key/value heads of 64 (grouped heads and head size 64 together), causal,
+    T 8,192, bf16, forward and backward, through the TPU's own compiler:
+    three kernels, k and v at their eight heads' width."""
+    b, t, hq, hkv, d = 2, 8192, 32, 8, 64
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_packed(
+            q, k, v, hq, causal=True, num_kv_heads=hkv).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((b, t, n * d), jnp.bfloat16,
+                                 sharding=one_chip) for n in (hq, hkv, hkv)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    dq, dk, dv = jax.eval_shape(jax.grad(loss, (0, 1, 2)), *args)
+    assert dk.shape == dv.shape == (b, t, hkv * d) and dq.shape[2] == hq * d
+    assert f"bf16[{b * hkv},{t},{d}]" in text
+
+
+def test_lfm2_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `lfm2_24b_a2b.train8k` (the configuration's
+    file and the traffic file as the benchmark reads them: 7 layers at the
+    published widths, 8 of 64 gated experts held, b2 x T8192, bf16 AMP, Adam,
+    remat blocks with what they keep) through the TPU's own compiler: it
+    fits a v5e's 15.75 GiB, holds 12 bytes a parameter of state, calls the
+    attention kernels three times a layer and keeps the experts' loops of
+    dynamic length."""
+    import json
+    import os
+
+    from benchmark.configs import lfm2_24b_a2b as adapter
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", "lfm2_24b_a2b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "traffic", "train8k.json")) as f:
+        traffic = json.load(f)
+    system = adapter.build(cfg, traffic, 1)
+    b, t = traffic["batch"], traffic["seq_len"]
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in system.startup.list_vars() if v.persistable}
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n and "corr_bias" not in n
+                 and n.startswith(("blk", "embed", "final_norm")))
+    assert params == 647_819_904 - 6 * 64
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = system.exe._build(system.main, sorted(feed),
+                             [v.name for v in system._fetch], names, names)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
+            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    gib = 2 ** 30
+    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.3
+    assert 11.0 < live / gib < 15.75
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * 3
+    # six expert layers: a forward and a backward tile loop each, and the
+    # head's two
+    assert text.count(" while(") >= 6 * 2 + 2
