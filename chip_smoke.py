@@ -426,6 +426,53 @@ def _dense_case(name, b, t, nh, causal, with_bias):
                       _with_grads(reference, 3), 2e-2)
 
 
+def _causal_reference_by_head(q, k, v, nh, nkv):
+    """Causal softmax attention on packed [B, T, H] tensors, a (batch, head)
+    at a time in f32 with exact matmuls, each head's [T, T] scores made
+    again in the backward pass: at T 8,192 all heads' scores at once are
+    17 GB. Query head h reads key/value head h // (nh / nkv)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, h = q.shape
+    d = h // nh
+    hp = jax.lax.Precision.HIGHEST
+
+    def heads(x, n):
+        x = x.astype(jnp.float32).reshape(b, t, n, d).transpose(0, 2, 1, 3)
+        return jnp.repeat(x, nh // n, axis=1).reshape(b * nh, t, d)
+
+    @jax.checkpoint
+    def one(qkv):
+        qh, kh, vh = qkv
+        s = jnp.dot(qh, kh.T, precision=hp) / np.sqrt(d)
+        s = jnp.where(_causal(t, t)[0, 0], s, -1e30)
+        return jnp.dot(jax.nn.softmax(s, axis=-1), vh, precision=hp)
+
+    o = jax.lax.map(one, (heads(q, nh), heads(k, nkv), heads(v, nkv)))
+    return o.reshape(b, nh, t, d).transpose(0, 2, 1, 3).reshape(
+        b, t, h).astype(q.dtype)
+
+
+def _blocked_causal_case(name, b, t, nh, nkv, d):
+    """A benchmark cell's attention call: causal, blocked (T over one block),
+    `nh` query heads of `d` on `nkv` key/value heads."""
+    def make_args(rng):
+        import jax.numpy as jnp
+        return tuple(jnp.asarray(rng.standard_normal((b, t, n * d)) * 0.5,
+                                 "bfloat16") for n in (nh, nkv, nkv))
+
+    def kernel(q, k, v):
+        return _fa().flash_attention_packed(q, k, v, nh, causal=True,
+                                            num_kv_heads=nkv)
+
+    def reference(q, k, v):
+        return _causal_reference_by_head(q, k, v, nh, nkv)
+
+    return KernelCase(name, make_args, _with_grads(kernel, 3),
+                      _with_grads(reference, 3), 2e-2)
+
+
 def _sparse_case(name, b, tq, tk, nh, causal):
     import jax.numpy as jnp
     h = nh * 64
@@ -547,9 +594,12 @@ def _ssd_case(name, b, t, heads=64, p=64, groups=8, n=128, chunk=128):
 
 
 def kernel_cases(batch: Optional[int] = None):
-    """The shapes the four models put through each kernel: ERNIE (b64, T=512,
+    """The shapes the models put through each kernel: ERNIE (b64, T=512,
     12 heads, [B,1,T] bias), NMT-big (16 heads; causal decoder, block-sparse
     packed self and cross attention), ring attention's causal T=4096 block,
+    the three decoder cells' blocked causal calls (LFM2's [64, 8192, 64] on
+    8 key/value heads, Nemotron's [64, 8192, 128] on 2, Ouro's
+    [32, 4096, 128]),
     ResNet-50's bottleneck tails at batch 128, Nemotron's Mamba-2 scan at
     the benchmark cell's own shape (b2 x T8192). `batch` overrides every batch
     size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
@@ -564,6 +614,12 @@ def kernel_cases(batch: Optional[int] = None):
                     causal=True, with_bias=False),
         _dense_case("flash_dense_t4096_causal_tiled", b(1), 4096, 16,
                     causal=True, with_bias=False),
+        _blocked_causal_case("flash_lfm2_t8192_h32on8_d64", b(2), 8192, 32,
+                             8, 64),
+        _blocked_causal_case("flash_nemotron_t8192_h32on2_d128", b(2), 8192,
+                             32, 2, 128),
+        _blocked_causal_case("flash_ouro_t4096_h16_d128", b(2), 4096, 16, 16,
+                             128),
         _sparse_case("flash_sparse_self_t256_causal", b(16), 256, 256, 16,
                      causal=True),
         _sparse_case("flash_sparse_cross_tq256_tk384", b(16), 256, 384, 16,
@@ -642,6 +698,50 @@ def dropout_check() -> dict:
             "key_changes_mask": not bool(jnp.array_equal(out, out2))}
 
 
+def blocked_dropout_check() -> dict:
+    """A blocked call (2 x 2 blocks of 512) under dropout. The dk/dv kernel
+    works on transposed scores and draws the forward's [bq, bk] mask
+    transposed, so a count of kept entries would not tell a wrong
+    orientation. With q = k = 0 every probability is 1/T, and with feature f
+    of v one-hot at key k_f, out[q, f] is keep[q, k_f] / (T (1 - rate)); with
+    a cotangent of ones dv[k_f, f] is the same sum over q of the BACKWARD's
+    mask: column k_f of both masks, 64 columns a head across both k blocks,
+    equal to float32's rounding (1/T is a power of two, so both kernels
+    round the kept probability to the same bf16)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, nh, d, rate = 2, 1024, 4, 64, 0.1
+    k_of = (np.arange(d) * 37 + 5) % t
+    v = np.zeros((b, t, nh, d), "float32")
+    v[:, k_of, :, np.arange(d)] = 1.0
+    v = jnp.asarray(v.reshape(b, t, nh * d))
+    q = jnp.zeros_like(v)
+
+    def f(v, key):
+        return _fa().flash_attention_packed(
+            q, q, v, nh, dropout_rate=rate, dropout_key=key)
+
+    # dq has no reader here, so XLA drops its kernel: forward and dk/dv
+    fwd_bwd = jax.jit(lambda v, key: jax.value_and_grad(
+        lambda v: jnp.sum(f(v, key)))(v))
+    n_mosaic = fwd_bwd.lower(v, jax.random.key(0)).as_text().count(
+        "tpu_custom_call")
+    out = jax.jit(f)(v, jax.random.key(0))
+    _, dv = fwd_bwd(v, jax.random.key(0))
+    col_fwd = np.asarray(out, np.float64).reshape(b, t, nh, d).sum(axis=1)
+    col_bwd = np.asarray(dv, np.float64).reshape(b, t, nh, d)[
+        :, k_of, :, np.arange(d)].transpose(1, 2, 0)
+    gap = float(np.abs(col_fwd - col_bwd).max())
+    # undropped, every column sums to 1; a kept share of 0.9 of 1,024
+    # entries leaves it within a few hundredths of 1, and not at 1
+    varies = float(np.abs(col_fwd - 1.0).max())
+    ok = n_mosaic >= 2 and gap < 1e-5 and 1e-3 < varies < 0.2
+    return {"name": "flash_dense_t1024_blocked_dropout0.1", "ok": ok,
+            "mosaic_calls": n_mosaic, "fwd_bwd_column_gap": gap,
+            "columns_differ_from_undropped": varies}
+
+
 def phase_kernels(args):
     fa = _fa()
     from paddle_tpu.ops.pallas_kernels import fused_bn
@@ -663,6 +763,11 @@ def phase_kernels(args):
           f"({res['mosaic_calls']} Mosaic calls), keep fraction "
           f"{res['keep_fraction']} (want {res['expected']}), fwd/bwd mask gap "
           f"{res['fwd_bwd_mask_gap']:.1e}", flush=True)
+    res = blocked_dropout_check()
+    results.append(res)
+    print(f"kernel {res['name']}: {'PASS' if res['ok'] else 'FAIL'} compiled "
+          f"({res['mosaic_calls']} Mosaic calls), forward and backward mask "
+          f"columns differ by {res['fwd_bwd_column_gap']:.1e}", flush=True)
     bad = [r["name"] for r in results if not r["ok"]]
     _require(not bad, f"kernels failed: {', '.join(bad)}")
     return {"kernels": results}

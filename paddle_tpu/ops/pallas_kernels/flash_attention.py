@@ -10,10 +10,14 @@ matmul over a materialized [B,H,T,T] score tensor — PaddleNLP on the SURVEY
   generated *inside* the kernel from the on-core PRNG (per-block reseed),
   so no mask tensor ever touches HBM;
 - backward: two Pallas kernels recompute p from the saved (q, k, lse)
-  blockwise — a dq kernel (grid b×nq×nk, dq accumulated in VMEM) and a
-  dk/dv kernel (grid b×nk×nq) — nothing quadratic is stored between fwd
-  and bwd. Dropout masks are regenerated bit-identically from the same
-  per-(batch, q-block, k-block) seeds;
+  blockwise — a dq kernel (dq accumulated in VMEM over a q block's k
+  blocks) and a dk/dv kernel (over a k block's q blocks, on transposed
+  scores) — nothing quadratic is stored between fwd and bwd. Dropout masks
+  are regenerated bit-identically from the same per-(batch, q-block,
+  k-block) seeds;
+- the blocked kernels (more than one block a side) walk a list of visited
+  tiles, under `causal` the triangle only ("The blocked kernels' tile
+  schedule" below);
 - a pure-JAX two-pass fallback with identical semantics runs on CPU (tests)
   and for shapes the kernel doesn't tile.
 
@@ -43,7 +47,7 @@ from ...core.remat import kept as _kept
 # 512² blocks keep the whole [T,T] score tile in VMEM for BERT-scale
 # sequence lengths: measured on v5e, bq=bk=512 runs the forward ~2.5× faster
 # than 128² (fewer grid steps amortize the per-step DMA + online-softmax
-# corrections; the kernel is VPU/exp-bound, so bigger MXU tiles are free)
+# corrections). Long sequences take 1,024²: `_pick_dense_blocks`.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _LANES = 128  # TPU lane width: scratch stats are kept lane-replicated
@@ -72,12 +76,17 @@ def _on_tpu() -> bool:
 # their grid iteration order.
 # ---------------------------------------------------------------------------
 
-def _keep_mask(seed_ref, block_index, shape, rate):
+def _keep_mask(seed_ref, block_index, shape, rate, transposed=False):
     # Mosaic supports at most 2 prng_seed values — the caller folds
     # (b, q-block, k-block) into one grid-order-independent index so the
     # same logical block regenerates the same stream in all three kernels.
+    # `transposed` gives the same draw of `shape` as its transpose (the
+    # dk/dv kernel's scores are [bk, bq]).
     pltpu.prng_seed(seed_ref[0], block_index)
-    bits = lax.bitcast_convert_type(pltpu.prng_random_bits(shape), jnp.uint32)
+    bits = pltpu.prng_random_bits(shape)
+    if transposed:
+        bits = bits.T
+    bits = lax.bitcast_convert_type(bits, jnp.uint32)
     # drop iff bits < rate·2³² → P(keep) = 1 − rate
     return bits >= jnp.uint32(int(round(rate * 4294967296.0)) & 0xFFFFFFFF)
 
@@ -94,16 +103,139 @@ def _seed_from_key(dropout_key):
 
 
 # ---------------------------------------------------------------------------
+# The blocked kernels' tile schedule.
+#
+# A blocked call (nq > 1 or nk > 1) walks a LIST of [block_q, block_k] score
+# tiles, not the nq x nk square: the grid is (heads, steps), and two tables
+# on the scalar-prefetch channel give each step its q block and k block, for
+# the kernel and for every BlockSpec's index map. Under `causal` the list
+# holds the tiles on and below the diagonal only, so a tile above it costs
+# neither a grid step nor a fetch. Forward and dq list them q-block-major
+# (their accumulators belong to a q block), dk/dv k-block-major. A run of
+# steps with the same major block is one accumulation: its first step
+# zeroes the scratch, its last writes the output block.
+# ---------------------------------------------------------------------------
+
+def _tile_visible(iq, ik, block_q, block_k):
+    """Some key of k block `ik` is at or before some query of q block `iq`."""
+    return ik * block_k <= iq * block_q + block_q - 1
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_schedule(nq, nk, block_q, block_k, causal, k_major=False,
+                   whole_square=False):
+    """(q block, k block) of each grid step, two int32 arrays, built once a
+    shape. `whole_square` lists the tiles above the diagonal too (the dq
+    kernel of a causal call with a per-q bias must still zero their `dbias`
+    blocks; they run no body)."""
+    steps = np.arange(nq * nk, dtype=np.int32)
+    if k_major:
+        ik, iq = np.divmod(steps, nq)
+    else:
+        iq, ik = np.divmod(steps, nk)
+    if causal and not whole_square:
+        visited = _tile_visible(iq, ik, block_q, block_k)
+        iq, ik = iq[visited], ik[visited]
+    return iq, ik
+
+
+def _record_tiles(kernel, nq, nk, block_q, block_k, causal):
+    """Static numbers a head of the last blocked call traced: the whole
+    square, the steps that run a body, those that apply the mask (under
+    `causal` every visited tile: a second body without it for the tiles
+    wholly below the diagonal measured 0.0-0.4 ms a call and doubled what
+    tracing a kernel costs, PERF.md section 6, PR 36)."""
+    from ...observability import get_registry
+    visited = len(_tile_schedule(nq, nk, block_q, block_k, causal)[0])
+    reg = get_registry()
+    reg.gauge("flash_attention/tiles_grid", kernel=kernel).set(nq * nk)
+    reg.gauge("flash_attention/tiles_scheduled", kernel=kernel).set(visited)
+    reg.gauge("flash_attention/tiles_masked", kernel=kernel).set(
+        visited if causal else 0)
+
+
+def _run_ends(major_ref, step, n_steps):
+    """(first, last): whether `step` opens / closes its run of steps with
+    the same major block."""
+    cur = major_ref[step]
+    first = jnp.logical_or(
+        step == 0, major_ref[jnp.maximum(step - 1, 0)] != cur)
+    last = jnp.logical_or(
+        step == n_steps - 1,
+        major_ref[jnp.minimum(step + 1, n_steps - 1)] != cur)
+    return first, last
+
+
+def _causal_mask(iq, ik, block_q, block_k, transposed=False):
+    """[bq, bk] (or [bk, bq]) bool: query position >= key position."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_pos = iq * block_q + lax.broadcasted_iota(
+        jnp.int32, shape, 1 if transposed else 0)
+    k_pos = ik * block_k + lax.broadcasted_iota(
+        jnp.int32, shape, 0 if transposed else 1)
+    return q_pos >= k_pos
+
+
+def _blocked_params(block_q, block_k):
+    """Compiler parameters of a blocked kernel: a body holds a handful of
+    float32 copies of its [block_q, block_k] tile (scores, probabilities
+    and, backward, their two cotangents) beside the double-buffered
+    operands, which at blocks of 1,024 is over Mosaic's default 16 MiB of a
+    v5e's 128."""
+    tile = block_q * block_k * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=min(100 * 2 ** 20, 16 * 2 ** 20 + 12 * tile))
+
+
+def _tile_specs(kv, block_q, block_k, d, bias):
+    """BlockSpecs of a blocked call's operands by name. An index map takes
+    (head, step, seed, q-block table, k-block table): `q` / `k` are
+    [block, d] blocks of the step's q / k block (`k` of the query head's
+    key/value head, `k_out` of the query head's own row), `rows` a q block's
+    row of statistics, `bias` the bias's block in the form it has."""
+    def at(f):
+        return lambda b, s, _, qi, ki: f(b, qi[s], ki[s])
+
+    specs = {
+        "q": pl.BlockSpec((1, block_q, d), at(lambda b, i, j: (b, i, 0))),
+        "k": pl.BlockSpec((1, block_k, d), at(lambda b, i, j: (kv(b), j, 0))),
+        "k_out": pl.BlockSpec((1, block_k, d),
+                              at(lambda b, i, j: (b, j, 0))),
+        "rows": pl.BlockSpec((1, 1, 1, block_q),
+                             at(lambda b, i, j: (b, i, 0, 0))),
+        "tile": pl.BlockSpec((1, block_q, block_k),
+                             at(lambda b, i, j: (b, i, j))),
+        "col": pl.BlockSpec((1, 1, block_k), at(lambda b, i, j: (b, 0, j))),
+    }
+    if bias is not None:
+        specs["bias"] = specs["tile" if bias.shape[1] != 1 else "col"]
+    return specs
+
+
+# The row statistics (lse, delta) travel compact, [BH, nq, 1, block_q]
+# float32: a q block's statistics are one lane-major row of block_q
+# numbers. On transposed scores (dk/dv) the row broadcasts down sublanes as
+# it is; forward and dq turn it into / from a [block_q, 1] column once a
+# step, a relayout of block_q numbers beside a tile of block_q x block_k.
+
+def _rows(x, nq, block_q):
+    """[BH, T] -> [BH, nq, 1, block_q]."""
+    return x.reshape(x.shape[0], nq, 1, block_q)
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, sm_scale, causal, block_q, block_k,
-                dropout_rate):
-    b, iq, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
+def _fwd_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
+                o_ref, lse_ref, acc_ref, m_ref, l_ref, *, sm_scale, causal,
+                block_q, block_k, nq, nk, n_steps, dropout_rate):
+    b, step = pl.program_id(0), pl.program_id(1)
+    iq, ik = qi_ref[step], ki_ref[step]
+    first, last = _run_ends(qi_ref, step, n_steps)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -119,11 +251,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)           # [bq or 1, bk]
         if causal:
-            q_pos = iq * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(_causal_mask(iq, ik, block_q, block_k), s,
+                          _NEG_INF)
 
         m_prev = m_ref[:, :1]                                 # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)            # [bq, 1]
@@ -147,20 +276,15 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
-        # whole block above the diagonal → nothing to do
-        @pl.when(ik * block_k <= iq * block_q + block_q - 1)
-        def _():
-            _body()
-    else:
-        _body()
+    _body()
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last)
     def _finalize():
         l = l_ref[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l_safe)).astype(jnp.float32)
+        lse = m_ref[:, :1] + jnp.log(l_safe)                  # [bq, 1]
+        lse_ref[0, 0] = lse.reshape(1, block_q).astype(jnp.float32)
 
 
 def _kv_row(kv_group: int):
@@ -191,32 +315,27 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
             return _flash_fwd_pallas_onepass(
                 q, k, v, bias, sm_scale, causal, group, interpret=interpret,
                 dropout_rate=dropout_rate, seed=seed)
-    grid = (bh, nq, nk)
+    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal)
+    n_steps = len(qi)
+    _record_tiles("fwd", nq, nk, block_q, block_k, causal)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
-    ]
+    specs = _tile_specs(kv, block_q, block_k, d, bias)
+    in_specs = [specs["q"], specs["k"], specs["k"]]
     args = [q, k, v]
     if bias is not None:
-        if bias.shape[1] != 1:
-            in_specs.append(pl.BlockSpec(
-                (1, block_q, block_k), lambda b, i, j, *_: (b, i, j)))
-        else:
-            in_specs.append(pl.BlockSpec(
-                (1, 1, block_k), lambda b, i, j, *_: (b, 0, j)))
+        in_specs.append(specs["bias"])
         args.append(bias)
 
     body = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                             block_q=block_q, block_k=block_k,
-                             dropout_rate=dropout_rate)
+                             block_q=block_q, block_k=block_k, nq=nq, nk=nk,
+                             n_steps=n_steps, dropout_rate=dropout_rate)
     if bias is not None:
         kernel = body
     else:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l):
-            body(seed_ref, q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                 acc, m, l)
+        def kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref,
+                   lse_ref, acc, m, l):
+            body(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, None, o_ref,
+                 lse_ref, acc, m, l)
 
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
@@ -224,14 +343,10 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
+            num_scalar_prefetch=3,
+            grid=(bh, n_steps),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_q, _LANES),
-                             lambda b, i, j, *_: (b, i, 0)),
-            ],
+            out_specs=[specs["q"], specs["rows"]],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -240,18 +355,14 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, nq, 1, block_q), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_blocked_params(block_q, block_k),
         interpret=interpret,
-    )(seed, *args)
-    # lse is sliced compact [BH, T] for the residual: keeping the
-    # lane-replicated [BH,T,128] form between fwd and bwd saves a
-    # slice→re-broadcast round trip (~2 ms/step) but costs 128× the memory
-    # (2.3 GB of residuals on BERT-base b=64) — which forces XLA into far
-    # more expensive rematerializations. Memory wins.
-    return out, lse[:, :, 0]
+    )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
+    # the residual is lse compact, [BH, T]: a lane-replicated form would
+    # cost 128x the memory between forward and backward
+    return out, lse.reshape(bh, t)
 
 
 # ---------------------------------------------------------------------------
@@ -515,13 +626,15 @@ def _pick_group(bh, t, d, tt_bytes, budget=10 * 2 ** 20):
 #   ds = p·(dp − delta)      dk = dsᵀ·q·scale       dq = Σ_j ds·k·scale
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
-                   delta_ref, dq_ref, dbias_ref, dq_acc, *, sm_scale, causal,
-                   block_q, block_k, dropout_rate):
-    b, iq, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
+def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
+                   g_ref, lse_ref, delta_ref, dq_ref, dbias_ref, dq_acc, *,
+                   sm_scale, causal, block_q, block_k, nq, nk, n_steps,
+                   dropout_rate):
+    b, step = pl.program_id(0), pl.program_id(1)
+    iq, ik = qi_ref[step], ki_ref[step]
+    first, last = _run_ends(qi_ref, step, n_steps)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -536,12 +649,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
         if causal:
-            q_pos = iq * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        lse = lse_ref[0][:, :1]                               # [bq, 1]
+            s = jnp.where(_causal_mask(iq, ik, block_q, block_k), s,
+                          _NEG_INF)
+        lse = lse_ref[0, 0].reshape(block_q, 1)               # [bq, 1]
         p = jnp.exp(s - lse)                                  # [bq, bk]
         dp = jax.lax.dot_general(
             g, v, (((1,), (1,)), ((), ())),
@@ -550,7 +660,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
             keep = _keep_mask(seed_ref, _block_index(b, iq, ik, nq, nk),
                               (block_q, block_k), dropout_rate)
             dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_rate)), 0.0)
-        delta = delta_ref[0][:, :1]                           # [bq, 1]
+        delta = delta_ref[0, 0].reshape(block_q, 1)           # [bq, 1]
         ds = p * (dp - delta)                                 # [bq, bk] f32
         if dbias_ref is not None:
             dbias_ref[0] = ds.astype(dbias_ref.dtype)
@@ -559,34 +669,41 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
             ds_c, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
 
-    if causal:
-        skip = ik * block_k > iq * block_q + block_q - 1
+    if causal and dbias_ref is not None:
+        # the whole square is scheduled: a tile above the diagonal runs no
+        # body but still zeroes its block of the per-q bias gradient
+        visible = _tile_visible(iq, ik, block_q, block_k)
 
-        @pl.when(jnp.logical_not(skip))
+        @pl.when(visible)
         def _():
             _body()
 
-        if dbias_ref is not None:
-            @pl.when(skip)
-            def _():
-                dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
+        @pl.when(jnp.logical_not(visible))
+        def _():
+            dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
     else:
         _body()
 
-    @pl.when(ik == nk - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dbias_col_ref, dk_acc, dv_acc,
-                    db_acc, *, sm_scale, causal, block_q, block_k,
-                    dropout_rate):
-    # grid is (bh, nk, nq): k-block outer, q-block inner
-    b, ik, iq = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nk, nq = pl.num_programs(1), pl.num_programs(2)
+def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
+                    g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dbias_col_ref,
+                    dk_acc, dv_acc, db_acc, *, sm_scale, causal, block_q,
+                    block_k, nq, nk, n_steps, dropout_rate, per_q_bias):
+    """dk and dv of one k block, on TRANSPOSED scores: sᵀ = k·qᵀ is
+    [bk, bq], so the q block's lse and delta are [1, bq] rows that broadcast
+    down sublanes as they come, and dv += pᵀ·g, dk += dsᵀ·q contract the
+    tile's lane axis like any product (on [bq, bk] scores both would
+    contract the row axis, a transposition of the tile each)."""
+    # the steps are k-block-major: a run is one k block's q blocks
+    b, step = pl.program_id(0), pl.program_id(1)
+    iq, ik = qi_ref[step], ki_ref[step]
+    first, last = _run_ends(ki_ref, step, n_steps)
 
-    @pl.when(iq == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -598,58 +715,50 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref, lse_ref,
         k = k_ref[0]                                          # [bk, D]
         v = v_ref[0]                                          # [bk, D]
         g = g_ref[0]                                          # [bq, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale    # [bq, bk]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale    # [bk, bq]
         if bias_ref is not None:
-            s = s + bias_ref[0].astype(jnp.float32)
+            bias = bias_ref[0].astype(jnp.float32)            # [bq or 1, bk]
+            st = st + (bias.T if per_q_bias
+                       else bias.reshape(block_k, 1))
         if causal:
-            q_pos = iq * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        lse = lse_ref[0][:, :1]
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bk]
+            st = jnp.where(
+                _causal_mask(iq, ik, block_q, block_k, transposed=True), st,
+                _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, 0])                      # [bk, bq]
+        dpt = jax.lax.dot_general(
+            v, g, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [bk, bq]
         if dropout_rate > 0.0:
-            # same (b, iq, ik) index as fwd/dq kernels → identical mask
-            keep = _keep_mask(seed_ref,
-                              _block_index(b, iq, ik, nq, nk),
-                              (block_q, block_k), dropout_rate)
+            # the forward's [bq, bk] draw for this (b, iq, ik), transposed
+            keep = _keep_mask(seed_ref, _block_index(b, iq, ik, nq, nk),
+                              (block_q, block_k), dropout_rate,
+                              transposed=True)
             inv = 1.0 / (1.0 - dropout_rate)
-            p_v = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
+            pt_v = jnp.where(keep, pt * inv, 0.0)
+            dpt = jnp.where(keep, dpt * inv, 0.0)
         else:
-            p_v = p
-        # dv += p_vᵀ·g   (contract q rows)
+            pt_v = pt
         dv_acc[...] += jax.lax.dot_general(
-            p_v.astype(g.dtype), g, (((0,), (0,)), ((), ())),
+            pt_v.astype(g.dtype), g, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, D]
-        delta = delta_ref[0][:, :1]
-        ds = p * (dp - delta)                                 # [bq, bk] f32
-        ds_c = ds.astype(q.dtype)
+        dst = pt * (dpt - delta_ref[0, 0])                    # [bk, bq] f32
         dk_acc[...] += jax.lax.dot_general(
-            ds_c, q, (((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale    # [bk, D]
         if db_acc is not None:
-            db_acc[...] += jnp.sum(ds, axis=0, keepdims=True)  # [1, bk]
+            db_acc[...] += jnp.sum(dst, axis=1, keepdims=True)  # [bk, 1]
 
-    if causal:
-        @pl.when(ik * block_k <= iq * block_q + block_q - 1)
-        def _():
-            _body()
-    else:
-        _body()
+    _body()
 
-    @pl.when(iq == nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
         if dbias_col_ref is not None:
-            dbias_col_ref[0] = db_acc[...].astype(dbias_col_ref.dtype)
+            dbias_col_ref[0] = db_acc[...].reshape(1, block_k).astype(
+                dbias_col_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
@@ -675,103 +784,75 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
 
-    gf, lse_r, delta_r = _bwd_host_prep(q, g, lse, out)
-
     has_bias = bias is not None
     per_q_bias = has_bias and bias.shape[1] != 1
+    col_bias = has_bias and not per_q_bias
 
-    # ---- dq kernel: grid (bh, nq, nk) --------------------------------------
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (kv(b), j, 0)),
-    ]
+    gf = g.astype(q.dtype)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    stats = [_rows(lse, nq, block_q), _rows(delta, nq, block_q)]
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate)
+
+    specs = _tile_specs(kv, block_q, block_k, d, bias)
+    in_specs = [specs["q"], specs["k"], specs["k"]]
     args = [q, k, v]
     if has_bias:
-        if per_q_bias:
-            in_specs.append(pl.BlockSpec(
-                (1, block_q, block_k), lambda b, i, j, *_: (b, i, j)))
-        else:
-            in_specs.append(pl.BlockSpec(
-                (1, 1, block_k), lambda b, i, j, *_: (b, 0, j)))
+        in_specs.append(specs["bias"])
         args.append(bias)
-    in_specs += [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),   # g
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j, *_: (b, i, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j, *_: (b, i, 0)),
-    ]
-    args += [gf, lse_r, delta_r]
+    in_specs += [specs["q"], specs["rows"], specs["rows"]]   # g, lse, delta
+    args += [gf, *stats]
+    n_in = len(args)
 
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0))]
+    def split_inputs(refs):
+        """(q, k, v, bias or None, g, lse, delta), the rest."""
+        ins = list(refs[:n_in])
+        if not has_bias:
+            ins.insert(3, None)
+        return ins, refs[n_in:]
+
+    # ---- dq kernel: q-block-major steps ------------------------------------
+    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal,
+                            whole_square=per_q_bias)
+    _record_tiles("dq", nq, nk, block_q, block_k, causal)
+    out_specs = [specs["q"]]
     out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
     if per_q_bias:
-        out_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda b, i, j, *_: (b, i, j)))
+        out_specs.append(specs["tile"])
         out_shape.append(jax.ShapeDtypeStruct((bh, t, t), jnp.float32))
 
-    body = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                             block_q=block_q, block_k=block_k,
-                             dropout_rate=dropout_rate)
+    body = functools.partial(_bwd_dq_kernel, n_steps=len(qi), **static)
 
-    def dq_kernel(seed_ref, *refs):
-        n_in = 6 + (1 if has_bias else 0)
-        ins, outs = refs[:n_in], refs[n_in:]
-        if has_bias:
-            q_r, k_r, v_r, b_r, g_r, l_r, d_r = ins
-        else:
-            (q_r, k_r, v_r, g_r, l_r, d_r), b_r = ins, None
+    def dq_kernel(seed_ref, qi_ref, ki_ref, *refs):
+        ins, outs = split_inputs(refs)
         if per_q_bias:
             dq_r, db_r, acc = outs
         else:
             (dq_r, acc), db_r = outs, None
-        body(seed_ref, q_r, k_r, v_r, b_r, g_r, l_r, d_r, dq_r, db_r, acc)
+        body(seed_ref, qi_ref, ki_ref, *ins, dq_r, db_r, acc)
 
     dq_out = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nq, nk),
+            num_scalar_prefetch=3,
+            grid=(bh, len(qi)),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_blocked_params(block_q, block_k),
         interpret=interpret,
-    )(seed, *args)
+    )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
     if per_q_bias:
         dq, dbias = dq_out
     else:
         (dq,), dbias = dq_out, None
 
-    # ---- dk/dv kernel: grid (bh, nk, nq) -----------------------------------
-    in_specs2 = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (kv(b), j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (kv(b), j, 0)),
-    ]
-    args2 = [q, k, v]
-    if has_bias:
-        if per_q_bias:
-            in_specs2.append(pl.BlockSpec(
-                (1, block_q, block_k), lambda b, j, i, *_: (b, i, j)))
-        else:
-            in_specs2.append(pl.BlockSpec(
-                (1, 1, block_k), lambda b, j, i, *_: (b, 0, j)))
-        args2.append(bias)
-    in_specs2 += [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0)),   # g
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i, *_: (b, i, 0)),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, j, i, *_: (b, i, 0)),
-    ]
-    args2 += [gf, lse_r, delta_r]
-
-    col_bias = has_bias and not per_q_bias
-    out_specs2 = [
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0)),
-    ]
+    # ---- dk/dv kernel: k-block-major steps, transposed scores --------------
+    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, k_major=True)
+    _record_tiles("dkv", nq, nk, block_q, block_k, causal)
+    out_specs2 = [specs["k_out"], specs["k_out"]]
     out_shape2 = [
         jax.ShapeDtypeStruct((bh, t, d), k.dtype),
         jax.ShapeDtypeStruct((bh, t, d), v.dtype),
@@ -779,43 +860,35 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     scratch2 = [pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32)]
     if col_bias:
-        out_specs2.append(pl.BlockSpec(
-            (1, 1, block_k), lambda b, j, i, *_: (b, 0, j)))
+        out_specs2.append(specs["col"])
         out_shape2.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
-        scratch2.append(pltpu.VMEM((1, block_k), jnp.float32))
+        scratch2.append(pltpu.VMEM((block_k, 1), jnp.float32))
 
-    body2 = functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
-                              causal=causal, block_q=block_q,
-                              block_k=block_k, dropout_rate=dropout_rate)
+    body2 = functools.partial(_bwd_dkv_kernel, n_steps=len(qi),
+                              per_q_bias=per_q_bias, **static)
 
-    def dkv_kernel(seed_ref, *refs):
-        n_in = 6 + (1 if has_bias else 0)
-        ins, rest = refs[:n_in], refs[n_in:]
-        if has_bias:
-            q_r, k_r, v_r, b_r, g_r, l_r, d_r = ins
-        else:
-            (q_r, k_r, v_r, g_r, l_r, d_r), b_r = ins, None
+    def dkv_kernel(seed_ref, qi_ref, ki_ref, *refs):
+        ins, rest = split_inputs(refs)
         if col_bias:
             dk_r, dv_r, dbc_r, dka, dva, dba = rest
         else:
             (dk_r, dv_r, dka, dva), dbc_r, dba = rest, None, None
-        body2(seed_ref, q_r, k_r, v_r, b_r, g_r, l_r, d_r,
-              dk_r, dv_r, dbc_r, dka, dva, dba)
+        body2(seed_ref, qi_ref, ki_ref, *ins, dk_r, dv_r, dbc_r, dka, dva,
+              dba)
 
     dkv_out = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nk, nq),
-            in_specs=in_specs2,
+            num_scalar_prefetch=3,
+            grid=(bh, len(qi)),
+            in_specs=in_specs,
             out_specs=out_specs2,
             scratch_shapes=scratch2,
         ),
         out_shape=out_shape2,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_blocked_params(block_q, block_k),
         interpret=interpret,
-    )(seed, *args2)
+    )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
     if col_bias:
         dk, dv, dbias = dkv_out
     else:
@@ -1024,6 +1097,21 @@ def _pick_blocks(t: int):
     return bq, bq
 
 
+def _pick_dense_blocks(t: int):
+    """Blocks of the dense kernels. From four blocks of 1,024 a side they are
+    1,024 square: a step's costs that do not grow with its tile (the forward's
+    row maxima and sums, a lane reduction of [block_q, 128] whatever block_k
+    is, and the rescaling of its accumulators) are then paid a quarter as
+    often, which on a v5e at T 8,192 takes the forward from 21.6 to 11.8 ms
+    a call at head size 64 and from 21.3 to 11.1 at 128, and dq and dk/dv
+    down by a tenth; at T 4,096, 3.7 to 2.4 (PERF.md section 6, PR 36).
+    Larger or oblong blocks measured no better. Below that the blocks are
+    `_pick_blocks`'s, as the block-sparse kernels' always are."""
+    if t % 1024 == 0 and t >= 4 * 1024:
+        return 1024, 1024
+    return _pick_blocks(t)
+
+
 def _pallas_ok(t: int, d: int) -> bool:
     """Static dispatch decision — must be identical in fwd and bwd so the
     in-kernel dropout masks regenerate consistently."""
@@ -1046,7 +1134,7 @@ def _flash_bwd_block_dispatch(q, k, v, g, lse, out, sm_scale, causal):
     return (dq, dk, dv) for that block via the Pallas dq/dkv kernels
     (jax fallback off-TPU). No bias/dropout on the ring path."""
     t, d = q.shape[1], q.shape[2]
-    bq, bk = _pick_blocks(t)
+    bq, bk = _pick_dense_blocks(t)
     if _pallas_ok(t, d):
         dq, dk, dv, _ = _flash_bwd_pallas(
             q, k, v, None, g, lse, out, sm_scale, causal, bq, bk,
@@ -1089,7 +1177,7 @@ def _sum_kv_group(dx, group: int, dtype):
 def _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale, causal,
                         dropout_rate):
     t, d = q.shape[1], q.shape[2]
-    bq, bk = _pick_blocks(t)
+    bq, bk = _pick_dense_blocks(t)
     group = _kv_group(q, k)
     if _pallas_ok(t, d):
         seed = (_seed_from_key(dropout_key) if dropout_rate > 0.0 else None)
@@ -1116,7 +1204,7 @@ def _flash_core_fwd(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
 def _flash_core_bwd(sm_scale, causal, dropout_rate, res, g):
     q, k, v, bias, key, out, lse = res
     t, d = q.shape[1], q.shape[2]
-    bq, bk = _pick_blocks(t)
+    bq, bk = _pick_dense_blocks(t)
     has_bias = bias is not None
     group = _kv_group(q, k)
     if _pallas_ok(t, d):

@@ -621,3 +621,208 @@ def test_sparse_op_and_layer():
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), nh, seg, seg,
         causal=True)
     np.testing.assert_allclose(got, np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the blocked kernels' tile schedule (ISSUE 36): a list of visited tiles, the
+# dk/dv on transposed scores, compact statistics
+# ---------------------------------------------------------------------------
+
+def _fa_mod():
+    import importlib
+    return importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.flash_attention")
+
+
+def _blocked_inputs(d, kv_group, bias_kind, t=512, bhq=4):
+    import jax.numpy as jnp
+    q, g = (jnp.asarray(_rand((bhq, t, d), i)) for i in (0, 3))
+    k, v = (jnp.asarray(_rand((bhq // kv_group, t, d), i)) for i in (1, 2))
+    bias = None
+    if bias_kind == "col":
+        bias = jnp.asarray(np.where(
+            np.random.RandomState(9).rand(bhq, 1, t) > 0.3, 0.0,
+            -1e4).astype(np.float32))
+        bias = bias.at[:, :, 0].set(0.0)   # no row without a visible key
+    elif bias_kind == "per_q":
+        bias = jnp.asarray(0.5 * _rand((bhq, t, t), 7))
+    return q, k, v, g, bias
+
+
+def _blocked_pallas(fa, q, k, v, g, bias, causal, bq, bk):
+    group = q.shape[0] // k.shape[0]
+    scale = 1.0 / np.sqrt(q.shape[2])
+    out, lse = fa._flash_fwd_pallas(q, k, v, bias, scale, causal, bq, bk,
+                                    interpret=True, kv_group=group)
+    dq, dk, dv, dbias = fa._flash_bwd_pallas(
+        q, k, v, bias, g, lse, out, scale, causal, bq, bk, interpret=True,
+        kv_group=group)
+    return out, lse, dq, dk, dv, dbias
+
+
+@pytest.mark.parametrize("bias_kind", ["none", "col", "per_q"])
+@pytest.mark.parametrize("kv_group", [1, 4])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("causal", [False, True])
+def test_blocked_kernels_match_blockwise_jax(causal, d, kv_group, bias_kind):
+    """4 x 4 blocks through the interpreter: out, lse, dq, dk, dv and dbias
+    of the scheduled kernels against the blockwise-JAX path."""
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    q, k, v, g, bias = _blocked_inputs(d, kv_group, bias_kind)
+    t, bq = q.shape[1], 128
+    got = _blocked_pallas(fa, q, k, v, g, bias, causal, bq, bq)
+    scale = 1.0 / np.sqrt(d)
+    kr, vr = fa._repeat_kv(k, kv_group), fa._repeat_kv(v, kv_group)
+    out, lse = fa._flash_fwd_jax(q, kr, vr, bias, scale, causal, bq)
+    dq, dk, dv, dbias = fa._flash_bwd_jax(
+        (q, kr, vr, bias, None, out, lse), g, sm_scale=scale, causal=causal,
+        block_k=bq, dropout_rate=0.0, has_bias=bias is not None)
+    if bias_kind == "col":
+        dbias = jnp.sum(dbias, axis=1, keepdims=True)
+    want = (out, lse, dq, dk, dv, dbias)
+    tols = (2e-5, 1e-5, 2e-4, 2e-4, 2e-4, 2e-4)
+    for name, a, b, tol in zip(("out", "lse", "dq", "dk", "dv", "dbias"),
+                               got, want, tols):
+        if b is None:
+            assert a is None
+            continue
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                                   atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128)])
+def test_blocked_kernels_with_unequal_blocks(bq, bk):
+    """A diagonal tile is then visible on more than its own triangle, and a
+    q block's last visible k block is not its own index."""
+    fa = _fa_mod()
+    q, k, v, g, _ = _blocked_inputs(64, 2, "none")
+    got = _blocked_pallas(fa, q, k, v, g, None, True, bq, bk)
+    want = _blocked_pallas(fa, q, k, v, g, None, True, 128, 128)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("bias_kind", ["none", "per_q"])
+def test_skipped_tiles_are_bitwise_invisible(monkeypatch, bias_kind):
+    """A tile above the diagonal that the list leaves out would have
+    changed nothing: with every tile of the square scheduled (and masked to
+    -1e30 throughout) all outputs keep their bits, so the schedule decides
+    what a call costs and nothing of what it computes."""
+    fa = _fa_mod()
+    q, k, v, g, bias = _blocked_inputs(64, 2, bias_kind)
+    got = _blocked_pallas(fa, q, k, v, g, bias, True, 128, 128)
+    monkeypatch.setattr(fa, "_tile_visible", lambda iq, ik, bq, bk: ik >= 0)
+    fa._tile_schedule.cache_clear()
+    try:
+        assert len(fa._tile_schedule(4, 4, 128, 128, True)[0]) == 16
+        want = _blocked_pallas(fa, q, k, v, g, bias, True, 128, 128)
+    finally:
+        fa._tile_schedule.cache_clear()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv", "dbias"), got,
+                          want):
+        if b is not None:
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+
+def _tile_gauges():
+    from paddle_tpu.observability import get_registry
+    return {(s["labels"]["kernel"], s["name"].split("/")[1]): s["value"]
+            for s in get_registry().series()
+            if s["name"].startswith("flash_attention/tiles_")}
+
+
+@pytest.mark.parametrize("causal,want", [(True, (256, 136, 136)),
+                                         (False, (256, 256, 0))])
+def test_tile_gauges_and_tables_built_once_a_shape(causal, want):
+    """16 x 16 blocks, traced only: the gauges say what the schedule is, and
+    a second call of the same shape builds no table."""
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    bh, t, d, bq = 2, 2048, 64, 128
+    x = jax.ShapeDtypeStruct((bh, t, d), jnp.float32)
+    lse = jax.ShapeDtypeStruct((bh, t), jnp.float32)
+
+    def trace():
+        jax.eval_shape(lambda q: fa._flash_fwd_pallas(
+            q, q, q, None, 0.125, causal, bq, bq, interpret=True), x)
+        jax.eval_shape(lambda q, l: fa._flash_bwd_pallas(
+            q, q, q, None, q, l, q, 0.125, causal, bq, bq, interpret=True),
+            x, lse)
+
+    trace()
+    gauges = _tile_gauges()
+    for kernel in ("fwd", "dq", "dkv"):
+        got = tuple(gauges[kernel, n] for n in
+                    ("tiles_grid", "tiles_scheduled", "tiles_masked"))
+        assert got == want, (kernel, got)
+    qi, ki = fa._tile_schedule(16, 16, bq, bq, causal)
+    assert len(qi) == want[1] and qi.dtype == ki.dtype == np.int32
+    # q-block-major for forward and dq, k-block-major for dk/dv
+    assert (np.diff(qi) >= 0).all()
+    assert (np.diff(fa._tile_schedule(16, 16, bq, bq, causal,
+                                      k_major=True)[1]) >= 0).all()
+    misses = fa._tile_schedule.cache_info().misses
+    trace()
+    assert fa._tile_schedule.cache_info().misses == misses
+
+
+def test_causal_per_q_bias_schedules_the_square_for_dq_only():
+    """A tile above the diagonal must still zero its block of the per-q
+    bias gradient: the dq kernel of such a call walks the whole square and
+    runs a body on the triangle; forward and dk/dv walk the triangle."""
+    fa = _fa_mod()
+    assert len(fa._tile_schedule(4, 4, 128, 128, True)[0]) == 10
+    assert len(fa._tile_schedule(4, 4, 128, 128, True,
+                                 whole_square=True)[0]) == 16
+    q, k, v, g, bias = _blocked_inputs(64, 1, "per_q")
+    *_, dbias = _blocked_pallas(fa, q, k, v, g, bias, True, 128, 128)
+    above = np.triu(np.ones((512, 512), bool), 1)
+    assert not np.asarray(dbias)[:, above].any()
+    assert np.asarray(dbias)[:, ~above].any()
+
+
+def test_tile_gauge_names_pass_the_metrics_lint():
+    from paddle_tpu.tools import metrics_lint
+    fa = _fa_mod()
+    names = {n for t, n, _ in metrics_lint.scan_file(fa.__file__)
+             if t == "gauge"}
+    assert names == {"flash_attention/tiles_grid",
+                     "flash_attention/tiles_scheduled",
+                     "flash_attention/tiles_masked"}
+    assert all(metrics_lint._LEGAL_RE.match(n) for n in names)
+
+
+def test_long_sequences_take_blocks_of_1024(monkeypatch):
+    """From four blocks of 1,024 a side the dense kernels' blocks are 1,024
+    square (the block-sparse ones keep `_pick_blocks`); the public entry at
+    T 4,096 then traces 4 x 4 tiles, 10 of them visited, and gives what
+    the blockwise-JAX path gives."""
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    assert [fa._pick_dense_blocks(t) for t in (8192, 4096, 5120)] == [
+        (1024, 1024)] * 3
+    for t in (3072, 2048, 1024, 512, 4096 + 512, 192):
+        assert fa._pick_dense_blocks(t) == fa._pick_blocks(t)
+    q, k, v, w = (jnp.asarray(_rand((1, 2 if i in (0, 3) else 1, 4096, 64),
+                                    i)) for i in range(4))
+
+    def run():
+        return jax.value_and_grad(lambda *x: jnp.sum(fa.flash_attention(
+            *x, causal=True) * w), (0, 1, 2))(q, k, v)
+
+    want = run()
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", True)
+    got = run()
+    gauges = _tile_gauges()
+    for kernel in ("fwd", "dq", "dkv"):
+        assert tuple(gauges[kernel, n] for n in (
+            "tiles_grid", "tiles_scheduled", "tiles_masked")) == (16, 10, 10)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4)
