@@ -15,6 +15,9 @@ API shape follows fluid for migration friendliness::
     exe.run(fluid.default_startup_program())
     exe.run(feed={...}, fetch_list=[loss])
 """
+import time as _time
+
+_IMPORT_BEGAN = _time.perf_counter()  # the set-up account's `import` phase
 
 from . import initializer  # noqa: F401
 from . import ops  # registers all ops  # noqa: F401
@@ -141,3 +144,6 @@ class backward:  # namespace parity: fluid.backward.append_backward
     append_backward = staticmethod(append_backward)
     calc_gradient = staticmethod(calc_gradient)
     gradients = staticmethod(gradients)
+
+observability.setup_account.note_import(_IMPORT_BEGAN)
+del _time

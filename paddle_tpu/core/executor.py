@@ -38,6 +38,7 @@ from ..observability.flight import (get_flight_recorder,
 from ..observability.http import maybe_serve_from_env
 from ..observability.registry import get_registry
 from ..observability import scopes as _scopes
+from ..observability import setup_account as _setup
 from ..observability.steps import get_step_profiler
 from ..observability.tracer import step_span, trace_span
 from ..observability.watchdog import get_watchdog
@@ -98,7 +99,8 @@ def _record_dispatch(program, sig, fn, dt_ms, compiling, *, feed, avals=None,
         executable = getattr(fn, "_compiled", None)
         if executable is None and _perf.trace_cost_enabled():
             try:
-                executable = fn.lower(*(avals or fn._avals))
+                with _setup.staging("cost"):
+                    executable = fn.lower(*(avals or fn._avals))
             except Exception:
                 executable = None
         _perf.get_ledger().register(id(program), sig, executable=executable,
@@ -179,6 +181,8 @@ def _enable_compile_cache() -> str:
 
 
 _enable_compile_cache()
+# the account of every staging in the process (observability/setup_account.py)
+_setup.install()
 
 
 # -- FLAGS_check_nan_inf device-side probe ----------------------------------
@@ -204,7 +208,9 @@ def _check_finite(named_vals) -> None:
                 ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(v)))
             return ok
         _FINITE_PROBE = _probe
-    if bool(_FINITE_PROBE([v for _, v in floats])):
+    with _setup.staging("probe", root=False):
+        finite = bool(_FINITE_PROBE([v for _, v in floats]))
+    if finite:
         return
     for n, v in floats:  # slow path: find and name the offender(s)
         a = np.asarray(v)
@@ -289,10 +295,11 @@ def _make_key(seed: int):
     VPU cycles generating mask bits (measured ~100ms/step on the BERT-base
     recipe); XLA's hardware RngBitGenerator ("rbg") is an order of magnitude
     cheaper and statistically fine for dropout."""
-    if jax.default_backend() == "tpu":
-        # typed key so split()/bernoulli() dispatch on the rbg impl
-        return jax.random.key(seed, impl="rbg")
-    return jax.random.PRNGKey(seed)
+    with _setup.staging("probe", root=False):   # the seed's own small jits
+        if jax.default_backend() == "tpu":
+            # typed key so split()/bernoulli() dispatch on the rbg impl
+            return jax.random.key(seed, impl="rbg")
+        return jax.random.PRNGKey(seed)
 
 
 class ExecContext:
@@ -471,6 +478,13 @@ def _op_scope(op, ctx: ExecContext):
 
 
 def _run_op(op, env: Dict[str, object], ctx: ExecContext):
+    """Lower one op, on the clock of the set-up account: its time less that
+    of the ops walked inside it is `setup/trace_op_seconds{op}`."""
+    with _setup.walk(op.type):
+        _lower_op(op, env, ctx)
+
+
+def _lower_op(op, env: Dict[str, object], ctx: ExecContext):
     opdef = registry.get_op(op.type)
     ctx.out_arity = {slot: len(names) for slot, names in op.outputs.items()}
     in_vals = {slot: [env[n] for n in names] for slot, names in op.inputs.items()}
@@ -607,7 +621,7 @@ def _run_autodiff(op, env, ctx: ExecContext):
     executed functionally. The walk runs under the `autodiff` name scope
     (cotangent sums and custom gradients are backward work too); what the
     block lowers after it is the optimizer's."""
-    with _scopes.autodiff_scope():
+    with _setup.walk(op.type), _scopes.autodiff_scope():
         _walk_tape(op, env, ctx)
     ctx.after_autodiff = True
 
@@ -767,6 +781,11 @@ def _group_key(op, env, mode):
 
 
 def _run_update_group(ops, env, ctx: ExecContext):
+    with _setup.walk(ops[0].type):
+        _lower_update_group(ops, env, ctx)
+
+
+def _lower_update_group(ops, env, ctx: ExecContext):
     opdef = registry.get_op(ops[0].type)
     spec = _FUSABLE_UPDATES[ops[0].type]
     shapes = [jnp.shape(env[op.inputs["Param"][0]]) for op in ops]
@@ -881,6 +900,15 @@ def _plan_remat_items(block: Block, ctx: ExecContext):
 
 def _run_remat_group(ops, decision, env: Dict[str, object],
                      ctx: ExecContext):
+    """`_lower_remat_group` on the set-up account's clock: the unit's ops
+    hold their own time, `setup/trace_op_seconds{op="remat_group"}` what the
+    wrapping (`jax.checkpoint`, `jax.vjp`) costs."""
+    with _setup.walk("remat_group"):
+        _lower_remat_group(ops, decision, env, ctx)
+
+
+def _lower_remat_group(ops, decision, env: Dict[str, object],
+                       ctx: ExecContext):
     """Run a remat unit as ONE checkpointed function: forward now, and a
     single tape entry whose vjp recomputes the whole unit from its entry
     values under the policy's `policy=` (dots_saveable etc.). This is the
@@ -1083,13 +1111,29 @@ class _Step:
         self._lowered_again = None
         _scopes.track_step(self)
 
-    def _count(self, *args):
-        self.calls += 1
-        if self._avals is None:
-            self._avals = _avals_of(args)
+    def _first(self, state, feed, key):
+        """The first dispatch, on the set-up account: what jax stages inside
+        it is the call's, the rest of it the phase `first_run`. The steady
+        body runs once inside, as it is."""
+        self._avals = _avals_of((state, feed, key))
+        with _setup.staging("call"):
+            self._stage(state, feed, key)
+            with _setup.phase("first_run", self.name):
+                return self(state, feed, key)
+
+    def _stage(self, state, feed, key):
+        """What is compiled ahead of the first call: on a plain jit,
+        nothing."""
+
+    @property
+    def name(self) -> str:
+        """The jitted function's name: the `step` of the account's records."""
+        return getattr(self._jitted, "__name__", "?")
 
     def __call__(self, state, feed, key):
-        self._count(state, feed, key)
+        if self._avals is None:
+            return self._first(state, feed, key)
+        self.calls += 1
         return self._jitted(state, feed, key)
 
     def __getattr__(self, name):
@@ -1102,7 +1146,9 @@ class _Step:
         if self._lowered_again is None:
             if self._avals is None:
                 raise RuntimeError("the step has not been dispatched yet")
-            self._lowered_again = self._jitted.lower(*self._avals).compile()
+            with _setup.staging("executable"):
+                self._lowered_again = self._jitted.lower(
+                    *self._avals).compile()
         return self._lowered_again
 
 
@@ -1203,7 +1249,8 @@ class _AutoLayoutStep(_Step):
             self._step, donate_argnums=(0,),
             in_shardings=(in_state, None, None),
             out_shardings=(out_fmts[0], out_state, out_fmts[2]))
-        self._compiled = relayout.lower(state, feed, key).compile()
+        with _setup.staging("relayout"):
+            self._compiled = relayout.lower(state, feed, key).compile()
         self._in_format = self._compiled.input_formats[0][0]
 
     def compiled(self):
@@ -1213,8 +1260,10 @@ class _AutoLayoutStep(_Step):
             return self._compiled
         return super().compiled()
 
-    def __call__(self, state, feed, key):
-        self._count(state, feed, key)
+    def _stage(self, state, feed, key):
+        """The AUTO-layout compile of the first dispatch and, as the phase
+        `relayout`, the look at the accumulators' layouts after it (the
+        `device_put`s of the steady body's slow path fall in `first_run`)."""
         if self._auto is not None and self._compiled is None:
             # huge state leaves (Criteo-scale embedding tables): a layout
             # disagreement between the AUTO solver and the producing
@@ -1234,14 +1283,23 @@ class _AutoLayoutStep(_Step):
                 self._in_shapes = {n: jnp.shape(v) for n, v in state.items()}
                 self._sig = self._signature(state, feed)
                 try:
-                    self._relayout_accumulators(state, feed, key)
-                except Exception:  # keep the AUTO-layout executable
-                    pass
-            except Exception:  # backend without AUTO layout support
+                    with _setup.phase("relayout", self.name):
+                        self._relayout_accumulators(state, feed, key)
+                except Exception as e:  # keep the AUTO-layout executable
+                    _setup.auto_layout_fallback(
+                        e, self.name, "the AUTO-layout step without the "
+                        "accumulators' pass")
+            except Exception as e:  # backend without AUTO layout support
+                _setup.auto_layout_fallback(e, self.name, "the plain jit")
                 self._auto = None
                 self._compiled = None
                 self._in_format = None
                 self._in_shapes = None
+
+    def __call__(self, state, feed, key):
+        if self._avals is None:
+            return self._first(state, feed, key)
+        self.calls += 1
         if self._compiled is not None:
             # steady-state fast path: after step 1 every state leaf is the
             # previous step's output, already in the compiled entry format —
@@ -1280,6 +1338,7 @@ class _AutoLayoutStep(_Step):
             # entry format (device_put of an already-in-format tiled array
             # is NOT a no-op on all backends — it can launch a relayout
             # program the runtime rejects for exotic tilings)
+            # (on the first call a part of the set-up account's `first_run`)
             state = {
                 n: (v if getattr(v, "format", None) == fmts[n]
                     else jax.device_put(v, fmts[n]))
@@ -1761,7 +1820,12 @@ class Executor:
                            steps=n, compiling=compiling), \
                 trace_span(site.replace("Executor.", "executor/"), steps=n,
                            sig=sig) as call:
-            ys, new_state, new_key = fn(state, stacked, key)
+            if compiling:
+                with _setup.staging("call"), _setup.phase(
+                        "first_run", getattr(fn, "__name__", "scan")):
+                    ys, new_state, new_key = fn(state, stacked, key)
+            else:
+                ys, new_state, new_key = fn(state, stacked, key)
         dt_ms = call.dur_ms
         with trace_span("executor/telemetry"):
             # the cost entry covers the whole n-step dispatch

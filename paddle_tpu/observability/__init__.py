@@ -27,6 +27,12 @@ capability along its natural seam:
   metadata from the Program IR, ``op_scopes(exe.compiled_step(main))``
   reads phase, unit and op types back per HLO instruction, and
   ``hottest_step()`` hands out the step this process runs.
+- **setup_account** (setup_account.py) — who staged which function, in
+  which phase, how long: every trace, lowering, compile, cache read,
+  relayout and first run in the process lands in
+  ``setup/seconds{phase,reason}`` and as a ``setup/<phase>`` span under
+  ``executor/compile+run`` (or a root ``executor/stage``), each second once;
+  the op walk's time by op type in ``setup/trace_op_seconds{op}``.
 - **RecompileWatchdog** (watchdog.py) — the executor reports every
   executable-cache miss; past a threshold the watchdog warns once,
   naming exactly which feed's shape/dtype diverged between the cached
@@ -85,6 +91,7 @@ from . import context  # noqa: F401
 from . import federate  # noqa: F401
 from . import perf  # noqa: F401
 from . import scopes  # noqa: F401
+from . import setup_account  # noqa: F401
 from .alerts import (Alert, AlertFiringError, AlertManager,  # noqa: F401
                      FileSink, WebhookSink, get_alert_manager,
                      install_alert_manager)

@@ -46,7 +46,8 @@ from collections import Counter
 from typing import Dict, NamedTuple, Optional, Tuple
 
 __all__ = ["OpScope", "op_scope", "unit_scope", "autodiff_scope",
-           "scheme_name", "op_scopes", "track_step", "hottest_step", "PHASES"]
+           "scheme_name", "is_scheme_name", "op_scopes", "track_step",
+           "hottest_step", "PHASES"]
 
 PHASES = ("fwd", "bwd", "opt", "mixed", "none")
 _OPT, _AUTODIFF = "opt", "autodiff"
@@ -56,6 +57,9 @@ _UNIT, _OP = "u.", "op."
 # hashes it; a change to either without a new number would be handed the
 # executables cached under the old names
 _SCHEME = 2
+# the names `scheme_name` has handed out: how the set-up account
+# (setup_account.py) tells a step of ours from any other jitted function
+_NAMED = set()
 
 
 def op_scope(op_type: str, unit: Optional[str] = None, opt: bool = False):
@@ -99,7 +103,15 @@ def scheme_name(base: str, program) -> str:
     for block in program.blocks:
         for op in block.ops:
             h = zlib.crc32(f"{op.attrs.get(UNIT_ATTR)}/{op.type};".encode(), h)
-    return f"{base}_{h & 0xFFFFFFFF:08x}"
+    name = f"{base}_{h & 0xFFFFFFFF:08x}"
+    _NAMED.add(name)
+    return name
+
+
+def is_scheme_name(name: str) -> bool:
+    """Whether `scheme_name` gave out `name` in this process: the function
+    so named is a step of ours."""
+    return name in _NAMED
 
 
 # ---------------------------------------------------------------------------

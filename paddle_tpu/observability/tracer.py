@@ -180,6 +180,9 @@ class Tracer:
         with self._lock:
             events = list(self._events)
             names = dict(self._thread_names)
+        # by time: a span may be written after the spans inside it (the
+        # set-up account writes jax's events when they end)
+        events.sort(key=lambda ev: ev.get("ts", 0))
         pid = os.getpid()
         meta: List[dict] = [
             {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,

@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.remat import kept as _kept
+from ._account import kernel_call
 
 # 512² blocks keep the whole [T,T] score tile in VMEM for BERT-scale
 # sequence lengths: measured on v5e, bq=bk=512 runs the forward ~2.5× faster
@@ -340,8 +341,8 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
 
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = kernel_call(
+        "flash_fwd", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, n_steps),
@@ -443,8 +444,8 @@ def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
 
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = kernel_call(
+        "flash_fwd_onepass", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -578,8 +579,8 @@ def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
         body(seed_ref, q_r, k_r, v_r, b_r, g_r, l_r, d_r,
              dq_r, dk_r, dv_r, db_r, dbc_r)
 
-    res = pl.pallas_call(
-        kernel,
+    res = kernel_call(
+        "flash_bwd_onepass", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh // group,),
@@ -831,8 +832,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             (dq_r, acc), db_r = outs, None
         body(seed_ref, qi_ref, ki_ref, *ins, dq_r, db_r, acc)
 
-    dq_out = pl.pallas_call(
-        dq_kernel,
+    dq_out = kernel_call(
+        "flash_bwd_dq", dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, len(qi)),
@@ -876,8 +877,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
         body2(seed_ref, qi_ref, ki_ref, *ins, dk_r, dv_r, dbc_r, dka, dva,
               dba)
 
-    dkv_out = pl.pallas_call(
-        dkv_kernel,
+    dkv_out = kernel_call(
+        "flash_bwd_dkv", dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, len(qi)),
@@ -1396,8 +1397,8 @@ def _flash_fwd_pallas_sparse(q, k, v, se_rep, vis, nh, sm_scale, causal,
                                causal=causal, block_q=block_q,
                                block_k=block_k, dropout_rate=dropout_rate,
                                nh=nh)
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = kernel_call(
+        "flash_fwd_sparse", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, nq, nk),
@@ -1547,8 +1548,8 @@ def _flash_bwd_pallas_sparse(q, k, v, se_rep, vis, nh, g, lse, out, sm_scale,
     dq_kernel = functools.partial(
         _bwd_dq_kernel_sparse, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate, nh=nh)
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq = kernel_call(
+        "flash_bwd_dq_sparse", dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, nq, nk),
@@ -1577,8 +1578,8 @@ def _flash_bwd_pallas_sparse(q, k, v, se_rep, vis, nh, g, lse, out, sm_scale,
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel_sparse, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, dropout_rate=dropout_rate, nh=nh)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    dk, dv = kernel_call(
+        "flash_bwd_dkv_sparse", dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, nk, nq),

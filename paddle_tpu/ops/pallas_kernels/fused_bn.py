@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ._account import kernel_call
+
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -126,8 +128,8 @@ def bn_stats(x, *, interpret=False):
     x2 = _nhwc_2d(x)
     mk, ck = x2.shape
     bm = _pick_bm(mk, ck, x.dtype.itemsize)
-    s, ss = pl.pallas_call(
-        _stats_kernel,
+    s, ss = kernel_call(
+        "bn_stats", _stats_kernel,
         grid=(mk // bm,),
         in_specs=[pl.BlockSpec((bm, ck), lambda mb: (mb, 0))],
         out_specs=[pl.BlockSpec((1, ck), lambda mb: (0, 0)),
@@ -180,7 +182,8 @@ def bn_apply(x, mean, inv, scale, bias, *, act="", residual=None,
     if residual is not None:
         args.append(_nhwc_2d(residual))
         in_specs.append(big)
-    y2 = pl.pallas_call(
+    y2 = kernel_call(
+        "bn_apply",
         functools.partial(_apply_kernel, act=act,
                           has_res=residual is not None),
         grid=(mk // bm,),
@@ -288,8 +291,8 @@ def _bn_bwd_2d(dy2, x2, y2, mean, inv, scale, act, has_res, m, k, interpret):
     args += [meanv, invv]
     in_specs += [vec, vec]
 
-    dbeta2, dgamma2 = pl.pallas_call(
-        functools.partial(_bwd_reduce_kernel, act=act),
+    dbeta2, dgamma2 = kernel_call(
+        "bn_bwd_stats", functools.partial(_bwd_reduce_kernel, act=act),
         grid=(mk // bm,),
         in_specs=in_specs,
         out_specs=[vec, vec],
@@ -310,7 +313,8 @@ def _bn_bwd_2d(dy2, x2, y2, mean, inv, scale, act, has_res, m, k, interpret):
     if has_res:
         out_specs.append(big)
         out_shape.append(jax.ShapeDtypeStruct((mk, ck), x2.dtype))
-    outs = pl.pallas_call(
+    outs = kernel_call(
+        "bn_bwd_apply",
         functools.partial(_bwd_dx_kernel, act=act, has_res=has_res, m=m),
         grid=(mk // bm,),
         in_specs=in_specs2,
@@ -420,8 +424,8 @@ def _conv_stats(x2, w2, out_dtype, interpret):
     mk, ci = x2.shape
     co = w2.shape[1]
     bm = _pick_bm(mk, max(ci, co), max(x2.dtype.itemsize, 2))
-    y2, s, ss = pl.pallas_call(
-        _conv_stats_kernel,
+    y2, s, ss = kernel_call(
+        "conv_bn_stats", _conv_stats_kernel,
         grid=(mk // bm,),
         in_specs=[pl.BlockSpec((bm, ci), lambda mb: (mb, 0)),
                   pl.BlockSpec((ci, co), lambda mb: (0, 0))],
@@ -452,7 +456,8 @@ def _apply2d(x2, mean, inv, scale, bias, act, res2, interpret):
     if res2 is not None:
         args.append(res2)
         in_specs.append(big)
-    return pl.pallas_call(
+    return kernel_call(
+        "conv_bn_apply",
         functools.partial(_apply_kernel, act=act, has_res=res2 is not None),
         grid=(mk // bm,),
         in_specs=in_specs,

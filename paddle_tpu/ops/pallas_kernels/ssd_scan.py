@@ -54,6 +54,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._account import kernel_call
+
 _LANES = 128
 _F32 = jnp.float32
 
@@ -355,8 +357,8 @@ def _forward(x, dt, a, b, c, d, *, chunk, interpret):
     g, n = b.shape[2], b.shape[3]
     r, nc, rp = h // g, t // chunk, h // g * p
     sp = _specs(rp, n, r, chunk, lambda z: z)
-    y, states = pl.pallas_call(
-        functools.partial(_fwd_kernel, r=r, p=p),
+    y, states = kernel_call(
+        "ssd_fwd", functools.partial(_fwd_kernel, r=r, p=p),
         grid=(bsz, g, nc),
         in_specs=[sp["x"], sp["bc"], sp["bc"], sp["dt"], sp["dt"], sp["d"]],
         out_specs=[sp["x"], sp["state"]],
@@ -381,8 +383,8 @@ def _backward(x, dt, a, b, c, d, states, gy, *, chunk, interpret):
     r, nc, rp = h // g, t // chunk, h // g * p
     rows, rows_vjp = jax.vjp(lambda dt, a: _rows(dt, a, g), dt, a)
     sp = _specs(rp, n, r, chunk, lambda z: nc - 1 - z)
-    dx, db, dc, dda, ddt, dd = pl.pallas_call(
-        functools.partial(_bwd_kernel, r=r, p=p),
+    dx, db, dc, dda, ddt, dd = kernel_call(
+        "ssd_bwd", functools.partial(_bwd_kernel, r=r, p=p),
         grid=(bsz, g, nc),
         in_specs=[sp["x"], sp["bc"], sp["bc"], sp["dt"], sp["dt"], sp["d"],
                   sp["x"], sp["state"]],
