@@ -39,11 +39,13 @@ WHITE_LIST = {"conv2d", "conv3d", "depthwise_conv2d", "conv2d_transpose",
 # expert products take the activations' dtype with float32 accumulation, and
 # the experts' weight gradients are summed in float32 (parallel/moe.py).
 # rotary_embedding and swiglu are gray: bf16 in and out beside the bf16
-# products they sit between, the angles and the rotation, the silu and the
-# product in float32 inside (ops/nn_ops.py). loop_exit_gate and
-# loop_exit_loss are gray and float32 inside whatever arrives: the gate is a
-# full-precision product (its output weights the loss) and black-listing
-# them would only add a cast of the states they read.
+# products they sit between; inside, float32: the angles, the tables of
+# cosines and signed sines and the multiply-adds `x C + partner(x) S` with
+# one rounding at the end (forward, and the op's own backward rule on the
+# bf16 cotangent: ops/nn_ops.py `_rope_turn`), the silu and the product.
+# loop_exit_gate and loop_exit_loss are gray and float32 inside whatever
+# arrives: the gate is a full-precision product (its output weights the
+# loss) and black-listing them would only add a cast of the states they read.
 BLACK_LIST = {"cross_entropy", "mean",
               "reduce_mean", "softmax", "sum",
               "exp", "log", "rsqrt", "sqrt"}

@@ -297,9 +297,14 @@ def rotary_embedding(x: Variable, num_heads: int, theta: float = 10000.0,
                      name=None) -> Variable:
     """Rotary position embedding on packed heads [B, T, H*D] (TPU
     extension): every head's channel pairs (j, j + D/2) are rotated by
-    `t * theta^(-2j/D)` at position t, angles in float32. For q
-    and k of one fused product, hand both in as one [B, T, 2*H*D] tensor
-    with `num_heads=2*H`."""
+    `t * theta^(-2j/D)` at position t, angles and multiply-adds in float32,
+    one rounding to x's dtype. For q and k of one fused product, hand both
+    in as one [B, T, 2*H*D] tensor with `num_heads=2*H`. The op computes
+    `out = x C + partner(x) S` on whole heads (C, S: [T, D] tables of
+    cosines and signed sines; `partner` swaps a head's halves by a product
+    with a constant 0/1 matrix: no half-head slices, no concatenate); its
+    backward rule is the same pass at the negative angle and keeps no
+    residual."""
     helper = LayerHelper("rotary_embedding", name=name)
     if x.shape is not None and int(x.shape[-1]) % (2 * num_heads):
         raise ValueError(f"rotary_embedding: {x.shape[-1]} channels are not "

@@ -366,6 +366,86 @@ def _rms_norm(ctx, inputs, attrs):
     return one(y.astype(x.dtype))
 
 
+def _rope_pass(x, heads: int, theta: float, sign: float):
+    """`x · C + partner(x) · S` on packed heads X [B, T, H·D], float32
+    multiply-adds, x's dtype back. C = cos ‖ cos and S = ∓sin ‖ ±sin of the
+    angles `t * theta^(-2j/D)` are [T, D] float32 tables made from iotas
+    (`sign` +1: the rotation; -1: the rotation by the negative angle, its
+    transpose). `partner(x)` holds channel j + D/2 of its head at j and
+    channel j - D/2 at j + D/2: the product of the head with a constant
+    [D, D] matrix of 0 and 1, exact (each output is one input times 1,
+    float32 accumulation; float32 inputs at full precision), so nothing here
+    has a minor dimension of D/2 and nothing is concatenated. XLA hangs the
+    multiply-add on the product's output and the layout the product wants
+    on the relayouts its neighbours make anyway (PERF.md section 6,
+    PR 38)."""
+    b, t, hd = x.shape
+    d = hd // heads
+    half = d // 2
+    lane = jnp.arange(d, dtype=jnp.int32)
+    inv_freq = jnp.asarray(theta, jnp.float32) ** (
+        -(lane % half).astype(jnp.float32) * 2.0 / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(ang)[:, None, :]                               # [T, 1, D]
+    sin = (jnp.sin(ang) * jnp.where(lane < half, -sign, sign))[:, None, :]
+    xh = x.reshape(b, t, heads, d)
+    swap = (lane[:, None] == (lane[None, :] + half) % d).astype(x.dtype)
+    partner = lax.dot_general(
+        xh, swap, (((3,), (0,)), ((), ())),
+        precision=(lax.Precision.HIGHEST if x.dtype.itemsize > 2
+                   else lax.Precision.DEFAULT),
+        preferred_element_type=jnp.float32)
+    out = xh.astype(jnp.float32) * cos + partner * sin
+    return out.astype(x.dtype).reshape(x.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _rope_jaxpr(shape, dtype, heads: int, theta: float, sign: float):
+    return jax.make_jaxpr(
+        lambda x: _rope_pass(x, heads, theta, sign))(
+            jax.ShapeDtypeStruct(shape, dtype))
+
+
+def _rope_turn(x, heads: int, theta: float, sign: float):
+    """`_rope_pass` traced once a shape and a sign, its operations bound in
+    place at the call: no call's edge in the step. The backward rule needs
+    that: behind a `jax.jit` XLA concatenates dq‖dk in a pass of its own
+    before the product (32 passes, 5 ms a step in the Ouro cell; PERF.md
+    section 6, PR 38)."""
+    closed = _rope_jaxpr(x.shape, x.dtype, heads, theta, sign)
+    (out,) = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, x)
+    return out
+
+
+# The forward pass is asked for three times an application (the primal, the
+# forward rule, and the forward rule again behind the remat block) at 32
+# applications of a looped decoder: a call, so that each costs one bind and
+# not the pass's 36. Compiled, the step is the inline one's, operation for
+# operation (both cells, compiled for the v5e), and the Ouro cell's set-up is
+# 2 s shorter on the chip's host (PERF.md section 6, PR 38).
+_rope_forward = jax.jit(_rope_pass, static_argnums=(1, 2, 3))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _rope(x, heads: int, theta: float):
+    return _rope_forward(x, heads, theta, 1.0)
+
+
+def _rope_fwd(x, heads, theta):
+    return _rope_forward(x, heads, theta, 1.0), None
+
+
+def _rope_bwd(heads, theta, _, g):
+    """A rotation's transpose is the rotation by the negative angle,
+    `dX = g · C - partner(g) · S`: the same pass with the sines' sign
+    turned, over the cotangent alone. No residual: the tables are remade
+    from iotas, so a remat block keeps nothing for it."""
+    return (_rope_turn(g, heads, theta, -1.0),)
+
+
+_rope.defvjp(_rope_fwd, _rope_bwd)
+
+
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, inputs, attrs):
     """Rotary position embedding (Su et al. 2021) on packed heads: X
@@ -373,22 +453,16 @@ def _rotary_embedding(ctx, inputs, attrs):
     `t * theta^(-2j/D)`, j < D/2. The rotate-half convention:
     channel j pairs with channel j + D/2 of its head,
     out[j] = x[j] cos - x[j + D/2] sin, out[j + D/2] = x[j + D/2] cos +
-    x[j] sin. Gray under AMP: the angles, their sines and the rotation in
-    float32, the input dtype back."""
+    x[j] sin, computed as `x · C + partner(x) · S` on whole heads
+    (`_rope_turn`). Gray under AMP: the angles, their sines and the
+    multiply-adds in float32, one rounding to the input dtype. The backward
+    rule is the op's own (`_rope_bwd`; a `jax.custom_vjp` and not the
+    registry's `grad_fn`, which would keep the op out of a remat block's one
+    differentiated function): the same pass at the negative angle, no
+    residual."""
     (x,) = inputs["X"]
-    heads = int(attrs["num_heads"])
-    b, t, hd = x.shape
-    d = hd // heads
-    half = d // 2
-    inv_freq = jnp.asarray(attrs.get("theta", 10000.0), jnp.float32) ** (
-        -jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
-    ang = (jnp.arange(t, dtype=jnp.float32)[:, None]
-           * inv_freq[None, :])                                # [T, D/2]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    xf = x.astype(jnp.float32).reshape(b, t, heads, d)
-    lo, hi = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], -1)
-    return one(out.reshape(x.shape).astype(x.dtype))
+    return one(_rope(x, int(attrs["num_heads"]),
+                     float(attrs.get("theta", 10000.0))))
 
 
 @register_op("swiglu")

@@ -514,10 +514,13 @@ def test_a_tied_table_of_another_shape_is_refused():
                 bias_attr=False)
 
 
-def test_the_rotation_runs_over_q_and_k_in_one_tensor():
+@pytest.mark.parametrize("hq,hk,hd", [(4, 2, 16), (4, 1, 64)])
+def test_the_rotation_runs_over_q_and_k_in_one_tensor(hq, hk, hd):
     """40 heads of 64 in one tensor is the rotation of q's 32 and k's 8
-    apart (here 4 + 2 heads of 16), and the reference's rotate-half."""
-    hq, hk, hd, t = 4, 2, 16, 10
+    apart (here 4 + 2 heads of 16, and 4 + 1 of the cell's 64: two heads a
+    128-lane tile and half a tile left over), and the reference's
+    rotate-half."""
+    t = 10
     rng = np.random.RandomState(0)
     q = rng.randn(1, t, hq * hd).astype("float32")
     k = rng.randn(1, t, hk * hd).astype("float32")

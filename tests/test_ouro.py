@@ -8,6 +8,8 @@ shared weight's gradient is the sum of the copies'); the rotary embedding and
 the gated activation against their written-out formulas; the exit
 distribution; a head whose rows are weighted by a learnt weight; and what
 sharing a parameter by its name means."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -280,7 +282,10 @@ def _rope_by_complex_numbers(x, heads, theta):
 
 
 @pytest.mark.parametrize("heads,d,theta", [
-    (2, 32, 1e6), (4, 16, 1e4), (1, 8, 1e6)])
+    (2, 32, 1e6), (4, 16, 1e4), (1, 8, 1e6),
+    # the chip's head sizes and counts around a 128-lane tile: a head a
+    # tile, two heads a tile, half a tile left over, a head of two tiles
+    (2, 128, 1e6), (4, 64, 1e6), (3, 64, 1e4), (1, 256, 1e6)])
 def test_rotary_embedding_values_and_gradients(heads, d, theta):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(2, 12, heads * d)), jnp.float32)
@@ -308,6 +313,64 @@ def test_rotary_embedding_values_and_gradients(heads, d, theta):
     np.testing.assert_allclose(jnp.linalg.norm(op(x), axis=-1),
                                jnp.linalg.norm(x, axis=-1), rtol=1e-5)
     np.testing.assert_allclose(op(x)[:, 0], x[:, 0], atol=1e-6)
+
+
+@pytest.mark.parametrize("heads,d", [(2, 128), (4, 64), (3, 64)])
+def test_rotary_embedding_gradient_of_a_bf16_cotangent(heads, d):
+    """Under AMP the cotangent arrives in bf16: the backward rule turns it
+    in float32 and rounds once, as the forward does."""
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(2, 12, heads * d)), jnp.bfloat16)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.bfloat16)
+    _, pull = jax.vjp(lambda x: nn_ops._rope(x, heads, 1e6), x)
+    (got,) = pull(g)
+    assert got.dtype == jnp.bfloat16
+    _, pull = jax.vjp(lambda x: _rope_by_complex_numbers(x, heads, 1e6),
+                      x.astype(jnp.float32))
+    (want,) = pull(g.astype(jnp.float32))
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2)
+
+
+@pytest.mark.parametrize("heads,d", [(2, 128), (4, 64)])
+def test_the_rotation_is_lowered_without_half_heads(heads, d):
+    """The form is kept: neither the op nor its backward rule concatenates,
+    and no array in them has a minor dimension of D/2 (a half-head slice)."""
+    x = jax.ShapeDtypeStruct((2, 24, heads * d), jnp.bfloat16)
+    texts = [
+        jax.jit(lambda x: nn_ops._rope(x, heads, 1e6)).lower(x).as_text(),
+        jax.jit(lambda g: nn_ops._rope_bwd(heads, 1e6, None, g)[0]).lower(
+            x).as_text()]
+    half_minor = re.compile(rf"tensor<(\d+x)*{d // 2}x[a-z]")
+    for text in texts:
+        assert "stablehlo.multiply" in text
+        assert "concatenate" not in text
+        assert not half_minor.search(text)
+    # what the test would catch: the half-head form reads so
+    old = jax.jit(lambda x: _rope_by_complex_numbers(
+        x.astype(jnp.float32), heads, 1e6)).lower(x).as_text()
+    assert "concatenate" in old and half_minor.search(old)
+
+
+def test_the_rotation_s_backward_is_the_rotation_by_the_negative_angle():
+    """`vjp(g)` is the same pass over g with the sines' sign turned; it
+    undoes the forward (a rotation's transpose is its inverse); and it keeps
+    nothing of x's size for the backward pass."""
+    heads, d, theta = 4, 16, 1e4
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, 12, heads * d)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    out, pull = jax.vjp(lambda x: nn_ops._rope(x, heads, theta), x)
+    np.testing.assert_allclose(
+        pull(g)[0], nn_ops._rope_turn(g, heads, theta, -1.0), atol=1e-6)
+    np.testing.assert_allclose(nn_ops._rope_turn(out, heads, theta, -1.0), x,
+                               atol=1e-5)
+    assert nn_ops._rope_fwd(x, heads, theta)[1] is None
+    assert all(np.size(leaf) < x.size
+               for leaf in jax.tree_util.tree_leaves(pull))
+    # where a vjp does keep its input, the same count sees it
+    _, keeps = jax.vjp(lambda x: x * x, x)
+    assert any(np.size(leaf) == x.size
+               for leaf in jax.tree_util.tree_leaves(keeps))
 
 
 def test_rotary_embedding_keeps_bf16_and_rotates_in_float32():
