@@ -32,6 +32,12 @@ Phases, one chip:
            conv with 8 of 64 gated experts held), b1 x 1024, bf16 AMP Adam:
            the head reads the embedding's table (one parameter), every held
            pair is multiplied
+  joyai    three steps of models/joyai_flash.py at JoyAI-LLM-Flash's widths:
+           the dense layer, one expert layer (16 of 256 gated experts held,
+           a shared expert) and the multi-token-prediction module, latent
+           attention with 192-wide keys and 128-wide values, b1 x 1024, bf16
+           AMP Adam: the head matrix and the table are one parameter each
+           with two readers, both loss terms fall
 
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
@@ -430,17 +436,18 @@ def _causal_reference_by_head(q, k, v, nh, nkv):
     """Causal softmax attention on packed [B, T, H] tensors, a (batch, head)
     at a time in f32 with exact matmuls, each head's [T, T] scores made
     again in the backward pass: at T 8,192 all heads' scores at once are
-    17 GB. Query head h reads key/value head h // (nh / nkv)."""
+    17 GB. Query head h reads key/value head h // (nh / nkv); v's head size
+    is its own."""
     import jax
     import jax.numpy as jnp
 
     b, t, h = q.shape
-    d = h // nh
+    d, dv = h // nh, v.shape[2] // nkv
     hp = jax.lax.Precision.HIGHEST
 
     def heads(x, n):
-        x = x.astype(jnp.float32).reshape(b, t, n, d).transpose(0, 2, 1, 3)
-        return jnp.repeat(x, nh // n, axis=1).reshape(b * nh, t, d)
+        x = x.astype(jnp.float32).reshape(b, t, n, -1).transpose(0, 2, 1, 3)
+        return jnp.repeat(x, nh // n, axis=1).reshape(b * nh, t, -1)
 
     @jax.checkpoint
     def one(qkv):
@@ -450,17 +457,19 @@ def _causal_reference_by_head(q, k, v, nh, nkv):
         return jnp.dot(jax.nn.softmax(s, axis=-1), vh, precision=hp)
 
     o = jax.lax.map(one, (heads(q, nh), heads(k, nkv), heads(v, nkv)))
-    return o.reshape(b, nh, t, d).transpose(0, 2, 1, 3).reshape(
-        b, t, h).astype(q.dtype)
+    return o.reshape(b, nh, t, dv).transpose(0, 2, 1, 3).reshape(
+        b, t, nh * dv).astype(q.dtype)
 
 
-def _blocked_causal_case(name, b, t, nh, nkv, d):
+def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None):
     """A benchmark cell's attention call: causal, blocked (T over one block),
-    `nh` query heads of `d` on `nkv` key/value heads."""
+    `nh` query heads of `d` on `nkv` key/value heads, the values `dv` wide
+    (default: `d`)."""
     def make_args(rng):
         import jax.numpy as jnp
-        return tuple(jnp.asarray(rng.standard_normal((b, t, n * d)) * 0.5,
-                                 "bfloat16") for n in (nh, nkv, nkv))
+        return tuple(jnp.asarray(rng.standard_normal((b, t, width)) * 0.5,
+                                 "bfloat16")
+                     for width in (nh * d, nkv * d, nkv * (dv or d)))
 
     def kernel(q, k, v):
         return _fa().flash_attention_packed(q, k, v, nh, causal=True,
@@ -597,9 +606,10 @@ def kernel_cases(batch: Optional[int] = None):
     """The shapes the models put through each kernel: ERNIE (b64, T=512,
     12 heads, [B,1,T] bias), NMT-big (16 heads; causal decoder, block-sparse
     packed self and cross attention), ring attention's causal T=4096 block,
-    the three decoder cells' blocked causal calls (LFM2's [64, 8192, 64] on
+    the four decoder cells' blocked causal calls (LFM2's [64, 8192, 64] on
     8 key/value heads, Nemotron's [64, 8192, 128] on 2, Ouro's
-    [32, 4096, 128]),
+    [32, 4096, 128], JoyAI's 32 heads of 192-wide keys and 128-wide values
+    at T 8,192 and at the smoke phase's 1,024),
     ResNet-50's bottleneck tails at batch 128, Nemotron's Mamba-2 scan at
     the benchmark cell's own shape (b2 x T8192). `batch` overrides every batch
     size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
@@ -620,6 +630,10 @@ def kernel_cases(batch: Optional[int] = None):
                              32, 2, 128),
         _blocked_causal_case("flash_ouro_t4096_h16_d128", b(2), 4096, 16, 16,
                              128),
+        _blocked_causal_case("flash_joyai_t8192_h32_d192_v128", b(2), 8192,
+                             32, 32, 192, 128),
+        _blocked_causal_case("flash_joyai_t1024_h32_d192_v128", b(2), 1024,
+                             32, 32, 192, 128),
         _sparse_case("flash_sparse_self_t256_causal", b(16), 256, 256, 16,
                      causal=True),
         _sparse_case("flash_sparse_cross_tq256_tk384", b(16), 256, 384, 16,
@@ -963,6 +977,84 @@ def phase_lfm2(args):
 
 
 # ---------------------------------------------------------------------------
+# joyai: latent attention, gated experts beside a shared one, an MTP module
+# ---------------------------------------------------------------------------
+
+JOYAI_SEQ, JOYAI_STEPS = 1024, 3
+
+
+def phase_joyai(args):
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import joyai_flash
+
+    cfg = joyai_flash.JoyaiFlashConfig(
+        vocab_size=16160, num_hidden_layers=2, experts_held=(0, 16))
+
+    def opt():
+        return mp.decorate(fluid.optimizer.Adam(1e-4), dtype="bfloat16",
+                           use_dynamic_loss_scaling=False)
+
+    with fluid.unique_name.guard():
+        main, startup, _, loss, counters, terms = (
+            joyai_flash.build_pretrain_program(cfg, 1, JOYAI_SEQ, opt))
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (1, JOYAI_SEQ + 1)).astype("int32")
+    feed = {"ids": jnp.asarray(ids[:, :-1]),
+            "labels": jnp.asarray(ids[:, 1:, None])}
+    fetch = ([loss, terms["main"], terms["mtp"]]
+             + [v for _, tokens, pairs in counters for v in (tokens, pairs)])
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        fetched = [exe.run(main, feed=feed, fetch_list=fetch,
+                           return_numpy=False) for _ in range(JOYAI_STEPS)]
+        vals = [[float(np.asarray(v)) for v in f[:3]] for f in fetched]
+        wall_s = time.perf_counter() - t0
+        slots = [n for n in scope.var_names() if n.startswith(
+            ("embed.w_AdamOptimizer_moment1",
+             "lm_head.w_AdamOptimizer_moment1"))]
+    _require(np.isfinite(vals).all(), f"non-finite joyai loss in {vals}")
+    for term in range(3):         # the sum, the trunk's term, the module's
+        _require(vals[-1][term] < vals[0][term],
+                 f"joyai loss term {term} did not fall on a fixed batch: "
+                 f"{vals}")
+    _require(abs(vals[0][0] - vals[0][1] - cfg.mtp_loss_weight * vals[0][2])
+             < 1e-3, f"the fetched loss is not main + weight * mtp: {vals[0]}")
+    _require(_platforms(fetched[-1][0]) == {"tpu"},
+             f"joyai loss lives on {_platforms(fetched[-1][0])}, not tpu")
+    _require(len(slots) == 2,
+             f"the table and the head matrix have one Adam slot each, the "
+             f"scope holds {slots}")
+    held = []
+    for tokens, pairs in zip(fetched[-1][3::2], fetched[-1][4::2]):
+        tokens, pairs = np.asarray(tokens), int(np.asarray(pairs))
+        _require(tokens.shape == (16,) and tokens.sum() == pairs
+                 and 0 < pairs <= JOYAI_SEQ * cfg.num_experts_per_tok,
+                 f"held pairs {pairs} against per-expert {tokens.tolist()}")
+        held.append(pairs)
+    _require(len(held) == 2, f"{len(held)} expert layers of 2 (one of the "
+             f"trunk's, the module's)")
+    print(f"joyai: dense layer, expert layer and the MTP module b1 x "
+          f"{JOYAI_SEQ}, {joyai_flash.param_count(cfg) / 1e6:.1f}M "
+          f"parameters, loss (sum, main, mtp) {vals[0]} -> {vals[-1]} over "
+          f"{JOYAI_STEPS} steps, pairs held a layer {held} of "
+          f"{JOYAI_SEQ * cfg.num_experts_per_tok} "
+          f"(startup+compile+steps {wall_s:.1f} s, set-up fact)")
+    del fetched, scope, exe
+    gc.collect()
+    return {"config": {"seq": JOYAI_SEQ,
+                       "parameters": joyai_flash.param_count(cfg)},
+            "losses": [[round(v, 5) for v in row] for row in vals],
+            "pairs_held": held,
+            "setup": {"startup_compile_and_steps_s": round(wall_s, 2)}}
+
+
+# ---------------------------------------------------------------------------
 # dp4: the same ERNIE program, data-parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -1074,7 +1166,8 @@ def phase_dp4(args):
 
 PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
                    ("kernels", phase_kernels), ("deepfm", phase_deepfm),
-                   ("looped", phase_looped), ("lfm2", phase_lfm2)]
+                   ("looped", phase_looped), ("lfm2", phase_lfm2),
+                   ("joyai", phase_joyai)]
 PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
 
 

@@ -294,26 +294,30 @@ def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
 
 
 def rotary_embedding(x: Variable, num_heads: int, theta: float = 10000.0,
-                     name=None) -> Variable:
+                     interleaved: bool = False, name=None) -> Variable:
     """Rotary position embedding on packed heads [B, T, H*D] (TPU
-    extension): every head's channel pairs (j, j + D/2) are rotated by
-    `t * theta^(-2j/D)` at position t, angles and multiply-adds in float32,
-    one rounding to x's dtype. For q and k of one fused product, hand both
-    in as one [B, T, 2*H*D] tensor with `num_heads=2*H`. The op computes
-    `out = x C + partner(x) S` on whole heads (C, S: [T, D] tables of
-    cosines and signed sines; `partner` swaps a head's halves by a product
-    with a constant 0/1 matrix: no half-head slices, no concatenate); its
-    backward rule is the same pass at the negative angle and keeps no
-    residual."""
+    extension): every head's channel pairs are rotated by
+    `t * theta^(-2j/D)` at position t, j < D/2, angles and multiply-adds in
+    float32, one rounding to x's dtype. Two conventions for which channels
+    make pair j: rotate-half, (j, j + D/2), the default; and, with
+    `interleaved=True`, neighbours (2j, 2j + 1). For q and k of one fused
+    product, hand both in as one [B, T, 2*H*D] tensor with `num_heads=2*H`;
+    a model that turns a part of each head hands that part in as heads of
+    its own. The op computes `out = x C + partner(x) S` on whole heads (C,
+    S: [T, D] tables of cosines and signed sines; `partner` brings every
+    channel its pair's other channel by a product with a constant 0/1
+    matrix: no half-head slices, no concatenate); its backward rule is the
+    same pass at the negative angle and keeps no residual."""
     helper = LayerHelper("rotary_embedding", name=name)
     if x.shape is not None and int(x.shape[-1]) % (2 * num_heads):
         raise ValueError(f"rotary_embedding: {x.shape[-1]} channels are not "
                          f"{num_heads} heads of an even size")
     out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    attrs = {"num_heads": int(num_heads), "theta": float(theta)}
+    if interleaved:     # absent otherwise: a rotate-half op is as it was
+        attrs["interleaved"] = True
     helper.append_op(type="rotary_embedding", inputs={"X": [x.name]},
-                     outputs={"Out": [out.name]},
-                     attrs={"num_heads": int(num_heads),
-                            "theta": float(theta)})
+                     outputs={"Out": [out.name]}, attrs=attrs)
     return out
 
 
@@ -744,10 +748,27 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
 
     Grouped-query attention: k and v may have fewer heads than q, a divisor
     of q's count (4D: their head dim says so; packed: `num_kv_heads`, with
-    k, v [B, T, num_kv_heads·D]). Query head h reads key/value head
-    h // (H / Hkv); the kernels index the shared head, nothing is copied."""
+    k [B, T, num_kv_heads·D]). Query head h reads key/value head
+    h // (H / Hkv); the kernels index the shared head, nothing is copied.
+
+    Two head sizes: q and k share D, the width the scores contract over and
+    the one the softmax scale D^-1/2 is taken from; v may have a head size
+    Dv of its own, said by its shape (4D: [B, Hkv, T, Dv]; packed:
+    [B, T, num_kv_heads·Dv]), and the result then has it too ([B, H, T, Dv]
+    or [B, T, H·Dv]): latent attention's 192-wide keys beside 128-wide
+    values."""
     helper = LayerHelper("flash_attention", name=name)
-    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    out_shape = q.shape
+    if q.shape is not None and v.shape is not None:
+        width = None            # of the result's last axis, where v says it
+        if len(q.shape) == 4:
+            width = v.shape[3]
+        elif num_heads is not None:
+            width = int(num_heads) * (int(v.shape[2])
+                                      // int(num_kv_heads or num_heads))
+        if width is not None and width != q.shape[-1]:
+            out_shape = tuple(q.shape[:-1]) + (width,)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=out_shape)
     inputs = {"Q": [q.name], "K": [k.name], "V": [v.name]}
     if attn_bias is not None:
         inputs["BiasQK"] = [attn_bias.name]

@@ -171,7 +171,7 @@ def _flash_attention(ctx, inputs, attrs):
                 "num_heads attr — pass num_heads= to layers.flash_attention")
         nh = attrs["num_heads"]
         nkv = attrs.get("num_kv_heads", nh)
-        t, d = q.shape[1], q.shape[2] // nh
+        t, d, dv = q.shape[1], q.shape[2] // nh, v.shape[2] // nkv
 
         def attend(q, k, v, *rest):
             *bias, key = rest
@@ -179,7 +179,7 @@ def _flash_attention(ctx, inputs, attrs):
                 q, k, v, nh, bias=bias[0] if bias else None, causal=causal,
                 dropout_rate=rate, dropout_key=key, num_kv_heads=nkv)
     else:
-        t, d = q.shape[2], q.shape[3]
+        t, d, dv = q.shape[2], q.shape[3], v.shape[3]
 
         def attend(q, k, v, *rest):
             *bias, key = rest
@@ -187,7 +187,7 @@ def _flash_attention(ctx, inputs, attrs):
                 q, k, v, bias=bias[0] if bias else None, causal=causal,
                 dropout_rate=rate, dropout_key=key)
     arrays = (q, k, v) if bias is None else (q, k, v, bias)
-    if _under_mesh(ctx) and _fa._pallas_ok(t, d):
+    if _under_mesh(ctx) and _fa._pallas_ok(t, d, dv):
         return one(_per_data_shard(ctx, attend, arrays, key))
     return one(attend(*arrays, key))
 

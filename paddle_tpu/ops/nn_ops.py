@@ -366,7 +366,8 @@ def _rms_norm(ctx, inputs, attrs):
     return one(y.astype(x.dtype))
 
 
-def _rope_pass(x, heads: int, theta: float, sign: float):
+def _rope_pass(x, heads: int, theta: float, sign: float,
+               interleaved: bool = False):
     """`x · C + partner(x) · S` on packed heads X [B, T, H·D], float32
     multiply-adds, x's dtype back. C = cos ‖ cos and S = ∓sin ‖ ±sin of the
     angles `t * theta^(-2j/D)` are [T, D] float32 tables made from iotas
@@ -378,18 +379,27 @@ def _rope_pass(x, heads: int, theta: float, sign: float):
     has a minor dimension of D/2 and nothing is concatenated. XLA hangs the
     multiply-add on the product's output and the layout the product wants
     on the relayouts its neighbours make anyway (PERF.md section 6,
-    PR 38)."""
+    PR 38). `interleaved`: pair j is channels (2j, 2j + 1) and not
+    (j, j + D/2), so channel c turns by pair c // 2's angle, takes channel
+    c ^ 1 as its partner, and the even channels carry the -sin: another
+    partner matrix and other tables, the same pass."""
     b, t, hd = x.shape
     d = hd // heads
     half = d // 2
     lane = jnp.arange(d, dtype=jnp.int32)
+    # each in the place the rotate-half form has had it: that form's jaxpr
+    # is the one it was before there were two
+    pair = lane // 2 if interleaved else lane % half
     inv_freq = jnp.asarray(theta, jnp.float32) ** (
-        -(lane % half).astype(jnp.float32) * 2.0 / d)
+        -pair.astype(jnp.float32) * 2.0 / d)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[:, None, :]                               # [T, 1, D]
-    sin = (jnp.sin(ang) * jnp.where(lane < half, -sign, sign))[:, None, :]
+    sin = (jnp.sin(ang) * jnp.where(
+        lane % 2 == 0 if interleaved else lane < half,
+        -sign, sign))[:, None, :]
     xh = x.reshape(b, t, heads, d)
-    swap = (lane[:, None] == (lane[None, :] + half) % d).astype(x.dtype)
+    swap = (lane[:, None] == (lane[None, :] ^ 1 if interleaved
+                              else (lane[None, :] + half) % d)).astype(x.dtype)
     partner = lax.dot_general(
         xh, swap, (((3,), (0,)), ((), ())),
         precision=(lax.Precision.HIGHEST if x.dtype.itemsize > 2
@@ -400,19 +410,21 @@ def _rope_pass(x, heads: int, theta: float, sign: float):
 
 
 @functools.lru_cache(maxsize=None)
-def _rope_jaxpr(shape, dtype, heads: int, theta: float, sign: float):
+def _rope_jaxpr(shape, dtype, heads: int, theta: float, sign: float,
+                interleaved: bool = False):
     return jax.make_jaxpr(
-        lambda x: _rope_pass(x, heads, theta, sign))(
+        lambda x: _rope_pass(x, heads, theta, sign, interleaved))(
             jax.ShapeDtypeStruct(shape, dtype))
 
 
-def _rope_turn(x, heads: int, theta: float, sign: float):
+def _rope_turn(x, heads: int, theta: float, sign: float,
+               interleaved: bool = False):
     """`_rope_pass` traced once a shape and a sign, its operations bound in
     place at the call: no call's edge in the step. The backward rule needs
     that: behind a `jax.jit` XLA concatenates dq‖dk in a pass of its own
     before the product (32 passes, 5 ms a step in the Ouro cell; PERF.md
     section 6, PR 38)."""
-    closed = _rope_jaxpr(x.shape, x.dtype, heads, theta, sign)
+    closed = _rope_jaxpr(x.shape, x.dtype, heads, theta, sign, interleaved)
     (out,) = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, x)
     return out
 
@@ -423,24 +435,24 @@ def _rope_turn(x, heads: int, theta: float, sign: float):
 # not the pass's 36. Compiled, the step is the inline one's, operation for
 # operation (both cells, compiled for the v5e), and the Ouro cell's set-up is
 # 2 s shorter on the chip's host (PERF.md section 6, PR 38).
-_rope_forward = jax.jit(_rope_pass, static_argnums=(1, 2, 3))
+_rope_forward = jax.jit(_rope_pass, static_argnums=(1, 2, 3, 4))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def _rope(x, heads: int, theta: float):
-    return _rope_forward(x, heads, theta, 1.0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _rope(x, heads: int, theta: float, interleaved: bool = False):
+    return _rope_forward(x, heads, theta, 1.0, interleaved)
 
 
-def _rope_fwd(x, heads, theta):
-    return _rope_forward(x, heads, theta, 1.0), None
+def _rope_fwd(x, heads, theta, interleaved):
+    return _rope_forward(x, heads, theta, 1.0, interleaved), None
 
 
-def _rope_bwd(heads, theta, _, g):
+def _rope_bwd(heads, theta, interleaved, _, g):
     """A rotation's transpose is the rotation by the negative angle,
     `dX = g · C - partner(g) · S`: the same pass with the sines' sign
     turned, over the cotangent alone. No residual: the tables are remade
     from iotas, so a remat block keeps nothing for it."""
-    return (_rope_turn(g, heads, theta, -1.0),)
+    return (_rope_turn(g, heads, theta, -1.0, interleaved),)
 
 
 _rope.defvjp(_rope_fwd, _rope_bwd)
@@ -453,8 +465,10 @@ def _rotary_embedding(ctx, inputs, attrs):
     `t * theta^(-2j/D)`, j < D/2. The rotate-half convention:
     channel j pairs with channel j + D/2 of its head,
     out[j] = x[j] cos - x[j + D/2] sin, out[j + D/2] = x[j + D/2] cos +
-    x[j] sin, computed as `x · C + partner(x) · S` on whole heads
-    (`_rope_turn`). Gray under AMP: the angles, their sines and the
+    x[j] sin; under the attribute `interleaved` pair j is channels 2j and
+    2j + 1 instead, out[2j] = x[2j] cos - x[2j + 1] sin, out[2j + 1] =
+    x[2j + 1] cos + x[2j] sin. Both computed as `x · C + partner(x) · S` on
+    whole heads (`_rope_turn`). Gray under AMP: the angles, their sines and the
     multiply-adds in float32, one rounding to the input dtype. The backward
     rule is the op's own (`_rope_bwd`; a `jax.custom_vjp` and not the
     registry's `grad_fn`, which would keep the op out of a remat block's one
@@ -462,7 +476,8 @@ def _rotary_embedding(ctx, inputs, attrs):
     residual."""
     (x,) = inputs["X"]
     return one(_rope(x, int(attrs["num_heads"]),
-                     float(attrs.get("theta", 10000.0))))
+                     float(attrs.get("theta", 10000.0)),
+                     bool(attrs.get("interleaved", False))))
 
 
 @register_op("swiglu")

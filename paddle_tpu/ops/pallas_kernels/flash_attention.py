@@ -189,12 +189,16 @@ def _blocked_params(block_q, block_k):
         vmem_limit_bytes=min(100 * 2 ** 20, 16 * 2 ** 20 + 12 * tile))
 
 
-def _tile_specs(kv, block_q, block_k, d, bias):
+def _tile_specs(kv, block_q, block_k, d, bias, dv=None):
     """BlockSpecs of a blocked call's operands by name. An index map takes
     (head, step, seed, q-block table, k-block table): `q` / `k` are
     [block, d] blocks of the step's q / k block (`k` of the query head's
-    key/value head, `k_out` of the query head's own row), `rows` a q block's
-    row of statistics, `bias` the bias's block in the form it has."""
+    key/value head, `k_out` of the query head's own row), `o` / `v` /
+    `v_out` the same blocks at the value head size `dv` (out and its
+    cotangent, v, dv), `rows` a q block's row of statistics, `bias` the
+    bias's block in the form it has."""
+    dv = d if dv is None else dv
+
     def at(f):
         return lambda b, s, _, qi, ki: f(b, qi[s], ki[s])
 
@@ -202,6 +206,11 @@ def _tile_specs(kv, block_q, block_k, d, bias):
         "q": pl.BlockSpec((1, block_q, d), at(lambda b, i, j: (b, i, 0))),
         "k": pl.BlockSpec((1, block_k, d), at(lambda b, i, j: (kv(b), j, 0))),
         "k_out": pl.BlockSpec((1, block_k, d),
+                              at(lambda b, i, j: (b, j, 0))),
+        "o": pl.BlockSpec((1, block_q, dv), at(lambda b, i, j: (b, i, 0))),
+        "v": pl.BlockSpec((1, block_k, dv),
+                          at(lambda b, i, j: (kv(b), j, 0))),
+        "v_out": pl.BlockSpec((1, block_k, dv),
                               at(lambda b, i, j: (b, j, 0))),
         "rows": pl.BlockSpec((1, 1, 1, block_q),
                              at(lambda b, i, j: (b, i, 0, 0))),
@@ -302,16 +311,19 @@ def _kv_row(kv_group: int):
 def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
                       interpret=False, dropout_rate=0.0, seed=None,
                       kv_group=1):
-    """q: [BHq, T, D], k, v: [BHq / kv_group, T, D] (heads folded); bias:
-    [BHq, Tq_or_1, Tk] or None. Returns (out [BHq,T,D], lse [BHq,T])."""
+    """q: [BHq, T, D], k: [BHq / kv_group, T, D], v: [BHq / kv_group, T, Dv]
+    (heads folded; the value head size Dv may differ from D); bias:
+    [BHq, Tq_or_1, Tk] or None. Returns (out [BHq,T,Dv], lse [BHq,T])."""
     bh, t, d = q.shape
+    dv = v.shape[2]
     block_q, block_k = min(block_q, t), min(block_k, t)
     nq, nk = t // block_q, t // block_k
     kv = _kv_row(kv_group)
     if nq == 1 and nk == 1 and kv_group == 1:
         per_q_bias = bias is not None and bias.shape[1] != 1
         group = _pick_group(
-            bh, t, d, _tt_bytes_per_head(1, per_q_bias, dropout_rate, t))
+            bh, t, max(d, dv),
+            _tt_bytes_per_head(1, per_q_bias, dropout_rate, t))
         if group > 1:
             return _flash_fwd_pallas_onepass(
                 q, k, v, bias, sm_scale, causal, group, interpret=interpret,
@@ -320,8 +332,8 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
     n_steps = len(qi)
     _record_tiles("fwd", nq, nk, block_q, block_k, causal)
 
-    specs = _tile_specs(kv, block_q, block_k, d, bias)
-    in_specs = [specs["q"], specs["k"], specs["k"]]
+    specs = _tile_specs(kv, block_q, block_k, d, bias, dv)
+    in_specs = [specs["q"], specs["k"], specs["v"]]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(specs["bias"])
@@ -347,15 +359,15 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
             num_scalar_prefetch=3,
             grid=(bh, n_steps),
             in_specs=in_specs,
-            out_specs=[specs["q"], specs["rows"]],
+            out_specs=[specs["o"], specs["rows"]],
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, nq, 1, block_q), jnp.float32),
         ],
         compiler_params=_blocked_params(block_q, block_k),
@@ -425,8 +437,10 @@ def _fwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
                               interpret=False, dropout_rate=0.0, seed=None):
     bh, t, d = q.shape
+    dv = v.shape[2]
     grid = (bh // group,)
-    in_specs = [pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0))] * 3
+    in_specs = [pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0))] * 2 + [
+        pl.BlockSpec((group, t, dv), lambda b, *_: (b, 0, 0))]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(pl.BlockSpec((group, bias.shape[1], t),
@@ -451,12 +465,12 @@ def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((group, t, dv), lambda b, *_: (b, 0, 0)),
                 pl.BlockSpec((group, t, _LANES), lambda b, *_: (b, 0, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, t, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -532,6 +546,7 @@ def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
                               group, dropout_rate=0.0, seed=None,
                               interpret=False):
     bh, t, d = q.shape
+    dv = v.shape[2]
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
     gf, lse_r, delta_r = _bwd_host_prep(q, g, lse, out)
@@ -540,21 +555,23 @@ def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
     per_q_bias = has_bias and bias.shape[1] != 1
     col_bias = has_bias and not per_q_bias
 
-    in_specs = [pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0))] * 3
+    qk_spec = pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0))
+    v_spec = pl.BlockSpec((group, t, dv), lambda b, *_: (b, 0, 0))
+    in_specs = [qk_spec, qk_spec, v_spec]
     args = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec((group, bias.shape[1], t),
                                      lambda b, *_: (b, 0, 0)))
         args.append(bias)
     in_specs += [
-        pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0)),
+        pl.BlockSpec((group, t, dv), lambda b, *_: (b, 0, 0)),
         pl.BlockSpec((group, t, _LANES), lambda b, *_: (b, 0, 0)),
         pl.BlockSpec((group, t, _LANES), lambda b, *_: (b, 0, 0)),
     ]
     args += [gf, lse_r, delta_r]
 
-    out_specs = [pl.BlockSpec((group, t, d), lambda b, *_: (b, 0, 0))] * 3
-    out_shape = [jax.ShapeDtypeStruct((bh, t, d), x.dtype) for x in (q, k, v)]
+    out_specs = [qk_spec, qk_spec, v_spec]
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
     if per_q_bias:
         out_specs.append(pl.BlockSpec((group, t, t), lambda b, *_: (b, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((bh, t, t), jnp.float32))
@@ -769,15 +786,18 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     [BH,1,Tk] f32 for a broadcast (mask-like) bias, or None. With
     `kv_group` query heads to a key/value head, k and v are
     [BH / kv_group, T, D] and dk, dv come back per QUERY head ([BH, T, D]):
-    the caller sums them over the group."""
+    the caller sums them over the group. v, g and out may have a head size
+    Dv of their own ([.., T, Dv]); dv then has it too."""
     bh, t, d = q.shape
+    dv_ = v.shape[2]
     block_q, block_k = min(block_q, t), min(block_k, t)
     nq, nk = t // block_q, t // block_k
     kv = _kv_row(kv_group)
     if nq == 1 and nk == 1 and kv_group == 1:
         per_q_bias = bias is not None and bias.shape[1] != 1
         group = _pick_group(
-            bh, t, d, _tt_bytes_per_head(3, per_q_bias, dropout_rate, t))
+            bh, t, max(d, dv_),
+            _tt_bytes_per_head(3, per_q_bias, dropout_rate, t))
         if group > 1:
             return _flash_bwd_pallas_onepass(
                 q, k, v, bias, g, lse, out, sm_scale, causal, group,
@@ -795,13 +815,13 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                   block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate)
 
-    specs = _tile_specs(kv, block_q, block_k, d, bias)
-    in_specs = [specs["q"], specs["k"], specs["k"]]
+    specs = _tile_specs(kv, block_q, block_k, d, bias, dv_)
+    in_specs = [specs["q"], specs["k"], specs["v"]]
     args = [q, k, v]
     if has_bias:
         in_specs.append(specs["bias"])
         args.append(bias)
-    in_specs += [specs["q"], specs["rows"], specs["rows"]]   # g, lse, delta
+    in_specs += [specs["o"], specs["rows"], specs["rows"]]   # g, lse, delta
     args += [gf, *stats]
     n_in = len(args)
 
@@ -853,13 +873,13 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     # ---- dk/dv kernel: k-block-major steps, transposed scores --------------
     qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, k_major=True)
     _record_tiles("dkv", nq, nk, block_q, block_k, causal)
-    out_specs2 = [specs["k_out"], specs["k_out"]]
+    out_specs2 = [specs["k_out"], specs["v_out"]]
     out_shape2 = [
         jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-        jax.ShapeDtypeStruct((bh, t, d), v.dtype),
+        jax.ShapeDtypeStruct((bh, t, dv_), v.dtype),
     ]
     scratch2 = [pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32)]
+                pltpu.VMEM((block_k, dv_), jnp.float32)]
     if col_bias:
         out_specs2.append(specs["col"])
         out_shape2.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
@@ -954,7 +974,8 @@ def _flash_fwd_jax(q, k, v, bias, sm_scale, causal, block_k,
                                preferred_element_type=jnp.float32)
         return acc, None
 
-    out, _ = lax.scan(pass2, jnp.zeros((bh, t, d), jnp.float32), jnp.arange(nk))
+    out, _ = lax.scan(pass2, jnp.zeros((bh, t, v.shape[2]), jnp.float32),
+                      jnp.arange(nk))
     return out.astype(q.dtype), lse
 
 
@@ -1005,7 +1026,7 @@ def _flash_bwd_jax(res, g, *, sm_scale, causal, block_k,
     dq, (dk_blocks, dv_blocks, dbias_blocks) = lax.scan(step, dq0, jnp.arange(nk))
     # [nk, BH, bk, d] → [BH, T, d]
     dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(bh, t, d)
-    dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(bh, t, d)
+    dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(bh, t, v.shape[2])
     dbias = None
     if has_bias:
         # [nk, BH, Tq, bk] → [BH, Tq, nk, bk] → [BH, Tq, Tk]: the scanned
@@ -1044,24 +1065,32 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
                            sm_scale: Optional[float] = None,
                            dropout_rate: float = 0.0, dropout_key=None,
                            num_kv_heads: Optional[int] = None):
-    """Memory-efficient attention on packed [B, T, H] tensors (H = nh·d).
+    """Memory-efficient attention on packed tensors: q [B, T, nh·d], k
+    [B, T, nkv·d], v [B, T, nkv·dv].
 
+    Two head sizes: `d` of q and k (the width the scores contract over,
+    q's last dimension over `num_heads`) and `dv` of v and of the result
+    (v's last dimension over the key/value heads); they are the same in
+    most models and differ under latent attention (192 and 128).
     Adapts to the folded [B·nh, T, d] kernel layout; XLA inserts the
     head-split transposes (see the layout note above — measured optimum for
     d=64 heads on v5e). bias (optional) is the additive [B, 1, T] mask.
     `num_kv_heads` (a divisor of `num_heads`; default: the same) is the head
-    count of k and v ([B, T, nkv·d]): query head h reads key/value head
-    h // (nh / nkv). Returns [B, T, H]."""
+    count of k and v: query head h reads key/value head h // (nh / nkv).
+    `sm_scale` defaults to d^-1/2, the q/k head size's. Returns
+    [B, T, nh·dv]."""
     b_, t, hdim = q.shape
     num_kv_heads = num_heads if num_kv_heads is None else num_kv_heads
     if hdim % num_heads:
         raise ValueError(f"hidden {hdim} not divisible by heads {num_heads}")
     d = hdim // num_heads
-    if num_heads % num_kv_heads or k.shape[2] != num_kv_heads * d:
+    if (num_heads % num_kv_heads or k.shape[2] != num_kv_heads * d
+            or v.shape[2] % num_kv_heads):
         raise ValueError(
             f"flash_attention: {num_kv_heads} key/value heads must divide "
-            f"{num_heads} query heads and k, v be [B, T, {num_kv_heads * d}]"
-            f", got {k.shape}")
+            f"{num_heads} query heads, k be [B, T, {num_kv_heads * d}] (q's "
+            f"head size {d}) and v [B, T, {num_kv_heads} x its own head "
+            f"size], got k {k.shape}, v {v.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if not 0.0 <= dropout_rate < 1.0:
@@ -1113,12 +1142,14 @@ def _pick_dense_blocks(t: int):
     return _pick_blocks(t)
 
 
-def _pallas_ok(t: int, d: int) -> bool:
+def _pallas_ok(t: int, d: int, dv: Optional[int] = None) -> bool:
     """Static dispatch decision — must be identical in fwd and bwd so the
-    in-kernel dropout masks regenerate consistently."""
+    in-kernel dropout masks regenerate consistently. `d` is the head size
+    of q and k, `dv` that of v and out (default: the same)."""
     bq, _ = _pick_blocks(t)
     return ((_on_tpu() or FORCE_PALLAS_INTERPRET)
-            and bq is not None and bq >= 64 and d % 64 == 0)
+            and bq is not None and bq >= 64 and d % 64 == 0
+            and (d if dv is None else dv) % 64 == 0)
 
 
 def _interpret_arg(dropout_rate: float):
@@ -1136,7 +1167,7 @@ def _flash_bwd_block_dispatch(q, k, v, g, lse, out, sm_scale, causal):
     (jax fallback off-TPU). No bias/dropout on the ring path."""
     t, d = q.shape[1], q.shape[2]
     bq, bk = _pick_dense_blocks(t)
-    if _pallas_ok(t, d):
+    if _pallas_ok(t, d, v.shape[2]):
         dq, dk, dv, _ = _flash_bwd_pallas(
             q, k, v, None, g, lse, out, sm_scale, causal, bq, bk,
             interpret=_interpret_arg(0.0))
@@ -1180,7 +1211,7 @@ def _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale, causal,
     t, d = q.shape[1], q.shape[2]
     bq, bk = _pick_dense_blocks(t)
     group = _kv_group(q, k)
-    if _pallas_ok(t, d):
+    if _pallas_ok(t, d, v.shape[2]):
         seed = (_seed_from_key(dropout_key) if dropout_rate > 0.0 else None)
         return _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, bq, bk,
                                  dropout_rate=dropout_rate, seed=seed,
@@ -1208,7 +1239,7 @@ def _flash_core_bwd(sm_scale, causal, dropout_rate, res, g):
     bq, bk = _pick_dense_blocks(t)
     has_bias = bias is not None
     group = _kv_group(q, k)
-    if _pallas_ok(t, d):
+    if _pallas_ok(t, d, v.shape[2]):
         seed = (_seed_from_key(key) if dropout_rate > 0.0 else None)
         dq, dk, dv, dbias = _flash_bwd_pallas(
             q, k, v, bias, g, lse, out, sm_scale, causal, bq, bk,
@@ -1831,6 +1862,11 @@ def flash_attention_packed_sparse(q, k, v, num_heads: int, q_seg, k_seg,
             "dropout_key; pass one or set dropout_rate=0 for inference")
     if causal and tq != tk:
         raise ValueError("flash_attention_sparse: causal requires Tq == Tk")
+    if v.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"flash_attention_sparse: the block-sparse kernels know one head "
+            f"size for q, k and v; got k {k.shape}, v {v.shape} (the dense "
+            f"flash_attention takes a value head size of its own)")
     if q_seg.shape != (b_, tq) or k_seg.shape != (b_, tk):
         raise ValueError(
             f"flash_attention_sparse: seg shapes {q_seg.shape}/"
@@ -1850,16 +1886,19 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
                     dropout_rate: float = 0.0, dropout_key=None):
     """Memory-efficient multi-head attention.
 
-    q: [B, H, T, D]; k, v: [B, Hkv, T, D] with Hkv dividing H (query head h
-    reads key/value head h // (H / Hkv); Hkv == H is plain multi-head
-    attention). bias: additive, broadcastable to [B, H, T, T] (e.g. the
-    BERT mask [B,1,1,T]). Returns [B, H, T, D].
+    q: [B, H, T, D]; k: [B, Hkv, T, D], v: [B, Hkv, T, Dv] with Hkv dividing
+    H (query head h reads key/value head h // (H / Hkv); Hkv == H is plain
+    multi-head attention). The value head size Dv may differ from D, the
+    one the scores contract over. bias: additive, broadcastable to
+    [B, H, T, T] (e.g. the BERT mask [B,1,1,T]). `sm_scale` defaults to
+    D^-1/2. Returns [B, H, T, Dv].
     """
     b, h, t, d = q.shape
-    if h % k.shape[1] or k.shape != v.shape:
+    if (h % k.shape[1] or k.shape[3] != d or k.shape[:3] != v.shape[:3]):
         raise ValueError(
             f"flash_attention: the key/value head count must divide the "
-            f"query head count; got q {q.shape}, k {k.shape}, v {v.shape}")
+            f"query head count, k have q's head size and v k's heads and "
+            f"positions; got q {q.shape}, k {k.shape}, v {v.shape}")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if not 0.0 <= dropout_rate < 1.0:
@@ -1881,4 +1920,4 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         dropout_key = None  # cotangent structure must match the real usage
     out = _flash_core(qf, kf, vf, bias_f, dropout_key, float(sm_scale),
                       bool(causal), float(dropout_rate))
-    return out.reshape(b, h, t, d)
+    return out.reshape(b, h, t, v.shape[3])

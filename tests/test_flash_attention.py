@@ -826,3 +826,172 @@ def test_long_sequences_take_blocks_of_1024(monkeypatch):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                    atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# a value head size of its own (ISSUE 39): q and k share one head size, the
+# one the scores contract over and the scale is taken from; v, out and their
+# cotangents have another
+# ---------------------------------------------------------------------------
+
+def _plain_packed(q, k, v, nh, nkv, causal, sm_scale=None):
+    """Softmax attention on packed [B, T, heads * size] tensors with the
+    [T, T] scores written out; v's head size is its own."""
+    import jax
+    import jax.numpy as jnp
+    b, t, _ = q.shape
+    d, dv = q.shape[2] // nh, v.shape[2] // nkv
+    heads = lambda x, n: jnp.repeat(
+        x.reshape(b, t, n, -1).transpose(0, 2, 1, 3), nh // n, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", heads(q, nh), heads(k, nkv))
+    s = s * (sm_scale if sm_scale is not None else 1.0 / np.sqrt(d))
+    if causal:
+        s = jnp.where(np.tril(np.ones((t, t), bool)), s, -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), heads(v, nkv))
+    return out.transpose(0, 2, 1, 3).reshape(b, t, nh * dv)
+
+
+def _packed_inputs(b, t, nh, nkv, d, dv):
+    import jax.numpy as jnp
+    return tuple(jnp.asarray(_rand((b, t, width), i)) for i, width in
+                 enumerate((nh * d, nkv * d, nkv * dv, nh * dv)))
+
+
+# T 256 runs the one-pass kernels, 1,024 the blocked ones (two blocks a
+# side); 192 / 128 are the published latent-attention sizes, 64 / 128 values
+# wider than keys, and one case has grouped heads with it
+VALUE_WIDTH_CASES = [(256, 2, 2, 192, 128), (1024, 2, 2, 192, 128),
+                     (1024, 4, 1, 64, 128), (256, 8, 8, 128, 64),
+                     (1024, 2, 2, 128, 192)]
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["blockwise_jax", "pallas_interpreted"])
+@pytest.mark.parametrize("t,nh,nkv,d,dv", VALUE_WIDTH_CASES)
+def test_value_head_size_of_its_own(monkeypatch, interpret, t, nh, nkv, d,
+                                    dv):
+    """Forward and all three gradients against plain attention, causal, on
+    the path the CPU tests run and on the kernels through the interpreter:
+    out and dv are `dv` wide, dq and dk `d` wide, the scale is d^-1/2."""
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", interpret)
+    q, k, v, w = _packed_inputs(2, t, nh, nkv, d, dv)
+
+    def run(f):
+        return jax.value_and_grad(lambda *x: jnp.sum(f(*x) * w), (0, 1, 2))(
+            q, k, v)
+
+    got = run(lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, nh, causal=True, num_kv_heads=nkv))
+    want = run(lambda q, k, v: _plain_packed(q, k, v, nh, nkv, True))
+    assert [g.shape for g in got[1]] == [q.shape, k.shape, v.shape]
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4)
+    assert fa.flash_attention_packed(
+        q, k, v, nh, causal=True, num_kv_heads=nkv).shape == (2, t, nh * dv)
+
+
+def test_value_head_size_small_pair_not_causal_and_a_given_scale():
+    """A small pair of sizes (24 / 16: the blockwise-JAX path, no kernel
+    takes them), not causal, with the caller's scale and the 4D entry."""
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    q, k, v, _ = _packed_inputs(2, 64, 4, 4, 24, 16)
+    for scale in (None, 0.3):
+        got = fa.flash_attention_packed(q, k, v, 4, sm_scale=scale)
+        np.testing.assert_allclose(
+            got, _plain_packed(q, k, v, 4, 4, False, scale), rtol=2e-5,
+            atol=2e-5)
+    split = lambda x, n: x.reshape(2, 64, n, -1).transpose(0, 2, 1, 3)
+    got4 = fa.flash_attention(split(q, 4), split(k, 4), split(v, 4))
+    assert got4.shape == (2, 4, 64, 16)
+    np.testing.assert_allclose(
+        got4.transpose(0, 2, 1, 3).reshape(2, 64, 64),
+        _plain_packed(q, k, v, 4, 4, False), rtol=2e-5, atol=2e-5)
+    assert not fa._pallas_ok(1024, 128, 24) and not fa._pallas_ok(1024, 24)
+
+
+def test_value_head_size_refusals_say_what_holds():
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    q, k, v, _ = _packed_inputs(1, 64, 4, 2, 16, 8)
+    with pytest.raises(ValueError, match="q's head size 16"):
+        fa.flash_attention_packed(q, k[:, :, :24], v, 4, num_kv_heads=2)
+    with pytest.raises(ValueError, match="its own head size"):
+        fa.flash_attention_packed(q, k, v[:, :, :15], 4, num_kv_heads=2)
+    with pytest.raises(ValueError, match="k have q's head size"):
+        fa.flash_attention(jnp.zeros((1, 2, 8, 16)), jnp.zeros((1, 2, 8, 8)),
+                           jnp.zeros((1, 2, 8, 8)))
+    seg = jnp.ones((1, 64), jnp.int32)
+    with pytest.raises(ValueError, match="one head size for q, k and v"):
+        fa.flash_attention_packed_sparse(q, q, q[:, :, :32], 4, seg, seg)
+
+
+def test_the_layer_takes_the_value_width_from_v_s_shape():
+    """`layers.flash_attention` says the value width by v's shape, packed
+    and 4D, and its result's shape follows; the op runs it."""
+    import jax.numpy as jnp
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = layers.data("q", [64, 4 * 24], dtype="float32")
+        k = layers.data("k", [64, 2 * 24], dtype="float32")
+        v = layers.data("v", [64, 2 * 16], dtype="float32")
+        out = layers.flash_attention(q, k, v, causal=True, num_heads=4,
+                                     num_kv_heads=2)
+        q4 = layers.data("q4", [4, 64, 24], dtype="float32")
+        v4 = layers.data("v4", [4, 64, 16], dtype="float32")
+        out4 = layers.flash_attention(q4, q4, v4)
+    assert tuple(out.shape[1:]) == (64, 4 * 16)
+    assert tuple(out4.shape[1:]) == (4, 64, 16)
+    qv, kv, vv, _ = _packed_inputs(2, 64, 4, 2, 24, 16)
+    exe = fluid.Executor(fluid.TPUPlace())
+    (got,) = exe.run(main, feed={
+        "q": np.asarray(qv), "k": np.asarray(kv), "v": np.asarray(vv),
+        "q4": np.zeros((2, 4, 64, 24), "float32"),
+        "v4": np.zeros((2, 4, 64, 16), "float32")}, fetch_list=[out])
+    np.testing.assert_allclose(got, _plain_packed(qv, kv, vv, 4, 2, True),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_equal_head_sizes_trace_to_the_parent_s_kernels(monkeypatch):
+    """Where q, k and v have one head size the calls are the ones they were
+    before there were two: the same three kernels with [block, d] blocks,
+    one [block, d] accumulator, and a jaxpr whose digest is the parent's
+    (commit e7757d0, PR 38; recorded by PR 39 from a copy of that commit,
+    with the addresses of objects taken out). A PR that changes the kernels
+    on purpose records its own."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    digests = {}
+    for name, (b, t, nh, nkv, d) in {
+            "t8192_h32on8_d64": (2, 8192, 32, 8, 64),
+            "t8192_h32on2_d128": (2, 8192, 32, 2, 128),
+            "t4096_h16_d128": (2, 4096, 16, 16, 128)}.items():
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention_packed(
+                q, k, v, nh, causal=True,
+                num_kv_heads=nkv).astype(jnp.float32))
+        args = [jax.ShapeDtypeStruct((b, t, n * d), jnp.bfloat16)
+                for n in (nh, nkv, nkv)]
+        # the suite asks for float32-exact products; the chip's kernels
+        # take their bf16 operands as they are
+        with jax.default_matmul_precision("default"):
+            text = re.sub(
+                r" at 0x[0-9a-f]+", "",
+                str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*args)))
+        assert text.count("pallas_call") == 3
+        digests[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digests == {"t8192_h32on8_d64": "ae936b231495a322",
+                       "t8192_h32on2_d128": "0d67e6228d0ed0c9",
+                       "t4096_h16_d128": "6d99736715d4aecb"}

@@ -356,3 +356,75 @@ def test_static_graph_layer_makes_the_third_matrix_of_gated_experts():
                        scoring="sigmoid", w3=p["m.w3"])
     np.testing.assert_allclose(got.reshape(12, 16), np.asarray(want.y),
                                rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# top-8 of 256 with 16 held (ISSUE 39): the router at the width the new cell
+# runs it, the held pairs against a literal loop over pairs
+# ---------------------------------------------------------------------------
+
+def _top8_of_256(n=48, d=16, h=8, seed=0, favour=None):
+    """x, a 256-wide router, 16 held gated experts (16..31 of the layer).
+    `favour` = (expert, shift): a bias on that expert's logit for every
+    token (through an extra constant channel of x)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (n, d))
+    gate = jax.random.normal(ks[1], (d, 256)) * 0.5
+    if favour is not None:
+        x = x.at[:, 0].set(1.0)
+        gate = gate.at[0, favour[0]].set(favour[1])
+    w1, w3 = (jax.random.normal(q, (16, d, h)) * 0.3 for q in ks[2:4])
+    w2 = jax.random.normal(ks[4], (16, h, d)) * 0.3
+    return x, gate, w1, w3, w2
+
+
+def _literal_top8(x, gate, w1, w3, w2, first, scaling=2.5):
+    """Sigmoid scores over all 256, the 8 largest chosen, their scores over
+    their sum times `scaling`; a literal loop over the (token, expert) pairs
+    adds the held ones."""
+    scores = jax.nn.sigmoid(jnp.dot(x, gate, precision="highest"))
+    _, idx = jax.lax.top_k(scores, 8)
+    y = [jnp.zeros(x.shape[1])] * x.shape[0]
+    counts = [0] * w1.shape[0]
+    for n in range(x.shape[0]):
+        chosen = [int(e) for e in idx[n]]
+        total = sum(scores[n, e] for e in chosen)
+        for e in chosen:
+            j = e - first
+            if 0 <= j < w1.shape[0]:
+                hid = jax.nn.silu(x[n] @ w1[j]) * (x[n] @ w3[j])
+                y[n] = y[n] + scores[n, e] / total * scaling * (hid @ w2[j])
+                counts[j] += 1
+    return jnp.stack(y), counts
+
+
+@pytest.mark.parametrize("favour", [None, (20, 50.0), (20, -50.0)],
+                         ids=["as_drawn", "one_expert_has_every_token",
+                              "one_expert_has_none"])
+def test_top8_of_256_with_16_held_against_a_literal_loop(favour):
+    x, gate, w1, w3, w2 = _top8_of_256(favour=favour)
+    first = 16
+
+    def layer(x, gate, w1, w3, w2):
+        return moe.moe_ffn(x, gate, w1, None, w2, None, k=8,
+                           act=jax.nn.silu, experts_held=(first, 16),
+                           scoring="sigmoid", routed_scaling=2.5, w3=w3)
+
+    got = layer(x, gate, w1, w3, w2)
+    want, counts = _literal_top8(x, gate, w1, w3, w2, first)
+    np.testing.assert_allclose(got.y, want, rtol=2e-5, atol=2e-6)
+    assert list(np.asarray(got.tokens_per_expert)) == counts
+    assert int(got.pairs_held) == sum(counts) <= 48 * 8
+    if favour == (20, 50.0):
+        assert counts[4] == 48
+    elif favour == (20, -50.0):
+        assert counts[4] == 0 and sum(counts) > 0
+    else:       # 16 of 256 held: about a sixteenth of the 384 pairs
+        assert 8 < sum(counts) < 48
+    ct = jax.random.normal(jax.random.PRNGKey(7), want.shape)
+    grads = jax.grad(lambda *a: jnp.sum(layer(*a).y * ct),
+                     argnums=(0, 1, 2, 3, 4))(x, gate, w1, w3, w2)
+    lit = jax.grad(lambda *a: jnp.sum(_literal_top8(*a, first)[0] * ct),
+                   argnums=(0, 1, 2, 3, 4))(x, gate, w1, w3, w2)
+    for g, w, name in zip(grads, lit, ("x", "gate", "w1", "w3", "w2")):
+        np.testing.assert_allclose(g, w, rtol=5e-5, atol=5e-6, err_msg=name)

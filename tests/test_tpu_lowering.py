@@ -464,3 +464,86 @@ def test_lfm2_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
     # six expert layers: a forward and a backward tile loop each, and the
     # head's two
     assert text.count(" while(") >= 6 * 2 + 2
+
+
+def test_latent_attention_kernels_compile_for_v5e_at_joyai_width(one_chip):
+    """The attention call of `joyai_llm_flash.train8k`: 32 heads of 192-wide
+    queries and keys on 128-wide values, causal, T 8,192, bf16, forward and
+    backward, through the TPU's own compiler (a block's minor dimension of
+    192 is one and a half lane tiles: Mosaic's tiling and VMEM limits say
+    whether that runs): three kernels, out and dv at the values' width, dq
+    and dk at the keys'."""
+    b, t, h, d, dv = 2, 8192, 32, 192, 128
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_packed(
+            q, k, v, h, causal=True).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((b, t, h * n), jnp.bfloat16,
+                                 sharding=one_chip) for n in (d, d, dv)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).trace(
+            *args).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    dq, dk, dv_ = jax.eval_shape(jax.grad(loss, (0, 1, 2)), *args)
+    assert dq.shape == dk.shape == (b, t, h * d)
+    assert dv_.shape == (b, t, h * dv)
+    # the kernels' operands, heads folded: q and k 192 wide, v and out 128
+    assert f"bf16[{b * h},{t},{d}]" in text
+    assert f"bf16[{b * h},{t},{dv}]" in text
+
+
+def test_joyai_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `joyai_llm_flash.train8k` (the
+    configuration's file and the traffic file as the benchmark reads them:
+    the dense layer, four expert layers and the prediction module at the
+    published widths, 16 of 256 gated experts held beside a shared expert,
+    b2 x T8192, bf16 AMP, Adam, remat blocks with what they keep) through the
+    TPU's own compiler: it fits a v5e's 15.75 GiB, holds 12 bytes a
+    parameter of state with one slot each for the table and the head matrix,
+    calls the attention kernels three times a layer in six layers and keeps
+    the experts' and the two heads' loops of dynamic length."""
+    import json
+    import os
+
+    from benchmark.configs import joyai_llm_flash as adapter
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", "joyai_llm_flash.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "traffic", "train8k.json")) as f:
+        traffic = json.load(f)
+    system = adapter.build(cfg, traffic, 1)
+    b, t = traffic["batch"], traffic["seq_len"]
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in system.startup.list_vars() if v.persistable}
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n and "corr_bias" not in n
+                 and n.startswith(("blk", "embed", "final_norm", "lm_head",
+                                   "mtp")))
+    assert params == 680_439_808
+    assert sum(n.startswith(("embed.w_", "lm_head.w_")) and "moment" in n
+               for n in state) == 4
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = system.exe._build(system.main, sorted(feed),
+                             [v.name for v in system._fetch], names, names)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
+            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    gib = 2 ** 30
+    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.7
+    assert 11.0 < live / gib < 15.75
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6 * 3
+    # five expert layers: a forward and a backward tile loop each; the two
+    # heads' two loops each
+    assert text.count(" while(") >= 5 * 2 + 2 * 2
