@@ -319,13 +319,16 @@ class KernelCase(NamedTuple):
     arrays (outputs, then gradients); `make_args(rng)` draws the inputs.
     `tol` bounds ||kernel - reference|| / ||reference|| (Frobenius) per
     array: a max-norm would be set by the few elements whose relu mask
-    flips when two bf16 roundings of the same value straddle zero."""
+    flips when two bf16 roundings of the same value straddle zero.
+    `mosaic_calls`, where given, is how many Mosaic calls forward and
+    backward lower to."""
 
     name: str
     make_args: Callable
     kernel: Callable
     reference: Callable
     tol: float
+    mosaic_calls: Optional[int] = None
 
 
 def _fa():
@@ -464,7 +467,8 @@ def _causal_reference_by_head(q, k, v, nh, nkv):
 def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None):
     """A benchmark cell's attention call: causal, blocked (T over one block),
     `nh` query heads of `d` on `nkv` key/value heads, the values `dv` wide
-    (default: `d`)."""
+    (default: `d`). Two Mosaic calls: the forward, and one backward kernel
+    for all three gradients."""
     def make_args(rng):
         import jax.numpy as jnp
         return tuple(jnp.asarray(rng.standard_normal((b, t, width)) * 0.5,
@@ -479,7 +483,7 @@ def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None):
         return _causal_reference_by_head(q, k, v, nh, nkv)
 
     return KernelCase(name, make_args, _with_grads(kernel, 3),
-                      _with_grads(reference, 3), 2e-2)
+                      _with_grads(reference, 3), 2e-2, mosaic_calls=2)
 
 
 def _sparse_case(name, b, tq, tk, nh, causal):
@@ -665,7 +669,8 @@ def run_kernel_case(case: KernelCase) -> dict:
     errs = [_rel_err(g, w) for g, w in zip(got, want)]
     finite = all(bool(np.isfinite(np.asarray(g, np.float32)).all())
                  for g in got)
-    ok = n_mosaic > 0 and finite and max(errs) <= case.tol
+    ok = (n_mosaic == case.mosaic_calls if case.mosaic_calls
+          else n_mosaic > 0) and finite and max(errs) <= case.tol
     return {"name": case.name, "ok": ok, "mosaic_calls": n_mosaic,
             "finite": finite, "rel_err": [round(e, 5) for e in errs],
             "tol": case.tol}
@@ -713,15 +718,16 @@ def dropout_check() -> dict:
 
 
 def blocked_dropout_check() -> dict:
-    """A blocked call (2 x 2 blocks of 512) under dropout. The dk/dv kernel
-    works on transposed scores and draws the forward's [bq, bk] mask
-    transposed, so a count of kept entries would not tell a wrong
-    orientation. With q = k = 0 every probability is 1/T, and with feature f
-    of v one-hot at key k_f, out[q, f] is keep[q, k_f] / (T (1 - rate)); with
-    a cotangent of ones dv[k_f, f] is the same sum over q of the BACKWARD's
-    mask: column k_f of both masks, 64 columns a head across both k blocks,
-    equal to float32's rounding (1/T is a power of two, so both kernels
-    round the kept probability to the same bf16)."""
+    """A blocked call (2 x 2 blocks of 512) under dropout. The backward
+    kernel works on transposed scores and draws the forward's [bq, bk] mask
+    transposed, once for all three gradients, so a count of kept entries
+    would not tell a wrong orientation. With q = k = 0 every probability is
+    1/T, and with feature f of v one-hot at key k_f, out[q, f] is
+    keep[q, k_f] / (T (1 - rate)); with a cotangent of ones dv[k_f, f] is
+    the same sum over q of the BACKWARD's mask: column k_f of both masks, 64
+    columns a head across both k blocks, equal to float32's rounding (1/T is
+    a power of two, so both kernels round the kept probability to the same
+    bf16)."""
     import jax
     import jax.numpy as jnp
 
@@ -736,7 +742,7 @@ def blocked_dropout_check() -> dict:
         return _fa().flash_attention_packed(
             q, q, v, nh, dropout_rate=rate, dropout_key=key)
 
-    # dq has no reader here, so XLA drops its kernel: forward and dk/dv
+    # forward and the one backward kernel
     fwd_bwd = jax.jit(lambda v, key: jax.value_and_grad(
         lambda v: jnp.sum(f(v, key)))(v))
     n_mosaic = fwd_bwd.lower(v, jax.random.key(0)).as_text().count(
@@ -750,7 +756,7 @@ def blocked_dropout_check() -> dict:
     # undropped, every column sums to 1; a kept share of 0.9 of 1,024
     # entries leaves it within a few hundredths of 1, and not at 1
     varies = float(np.abs(col_fwd - 1.0).max())
-    ok = n_mosaic >= 2 and gap < 1e-5 and 1e-3 < varies < 0.2
+    ok = n_mosaic == 2 and gap < 1e-5 and 1e-3 < varies < 0.2
     return {"name": "flash_dense_t1024_blocked_dropout0.1", "ok": ok,
             "mosaic_calls": n_mosaic, "fwd_bwd_column_gap": gap,
             "columns_differ_from_undropped": varies}

@@ -9,12 +9,15 @@ matmul over a materialized [B,H,T,T] score tensor — PaddleNLP on the SURVEY
   HBM traffic is O(T·D) instead of O(T²); attention-probability dropout is
   generated *inside* the kernel from the on-core PRNG (per-block reseed),
   so no mask tensor ever touches HBM;
-- backward: two Pallas kernels recompute p from the saved (q, k, lse)
-  blockwise — a dq kernel (dq accumulated in VMEM over a q block's k
-  blocks) and a dk/dv kernel (over a k block's q blocks, on transposed
-  scores) — nothing quadratic is stored between fwd and bwd. Dropout masks
-  are regenerated bit-identically from the same per-(batch, q-block,
-  k-block) seeds;
+- backward: ONE Pallas kernel recomputes p from the saved (q, k, lse)
+  blockwise, over a k block's q blocks on transposed scores: a tile's
+  scores and their cotangent are made once and feed dv, dk (accumulated
+  over the k block's run) and dq (accumulated for the whole head in VMEM) —
+  nothing quadratic is stored between fwd and bwd. A call with a per-q bias,
+  or whose head is too long for that accumulator, runs a dq kernel (over a
+  q block's k blocks) beside the dk/dv kernel instead. Dropout masks are
+  regenerated bit-identically from the same per-(batch, q-block, k-block)
+  seeds;
 - the blocked kernels (more than one block a side) walk a list of visited
   tiles, under `causal` the triangle only ("The blocked kernels' tile
   schedule" below);
@@ -111,10 +114,11 @@ def _seed_from_key(dropout_key):
 # on the scalar-prefetch channel give each step its q block and k block, for
 # the kernel and for every BlockSpec's index map. Under `causal` the list
 # holds the tiles on and below the diagonal only, so a tile above it costs
-# neither a grid step nor a fetch. Forward and dq list them q-block-major
-# (their accumulators belong to a q block), dk/dv k-block-major. A run of
-# steps with the same major block is one accumulation: its first step
-# zeroes the scratch, its last writes the output block.
+# neither a grid step nor a fetch. The forward (and the dq kernel of a
+# two-kernel backward) lists them q-block-major (their accumulators belong
+# to a q block), the backward k-block-major. A run of steps with the same
+# major block is one accumulation: its first step zeroes the scratch, its
+# last writes the output block.
 # ---------------------------------------------------------------------------
 
 def _tile_visible(iq, ik, block_q, block_k):
@@ -177,16 +181,18 @@ def _causal_mask(iq, ik, block_q, block_k, transposed=False):
     return q_pos >= k_pos
 
 
-def _blocked_params(block_q, block_k):
+def _blocked_params(block_q, block_k, head_bytes=0):
     """Compiler parameters of a blocked kernel: a body holds a handful of
     float32 copies of its [block_q, block_k] tile (scores, probabilities
     and, backward, their two cotangents) beside the double-buffered
     operands, which at blocks of 1,024 is over Mosaic's default 16 MiB of a
-    v5e's 128."""
+    v5e's 128. `head_bytes` is what the kernel keeps for a whole head
+    besides (the backward's dq: `_dq_head_bytes`)."""
     tile = block_q * block_k * 4
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=min(100 * 2 ** 20, 16 * 2 ** 20 + 12 * tile))
+        vmem_limit_bytes=min(100 * 2 ** 20,
+                             16 * 2 ** 20 + 12 * tile + head_bytes))
 
 
 def _tile_specs(kv, block_q, block_k, d, bias, dv=None):
@@ -642,7 +648,32 @@ def _pick_group(bh, t, d, tt_bytes, budget=10 * 2 ** 20):
 #   p  = exp(s − lse)                               (recomputed per block)
 #   dv = p_dropᵀ·dO          dp = dO·vᵀ (drop-scaled)
 #   ds = p·(dp − delta)      dk = dsᵀ·q·scale       dq = Σ_j ds·k·scale
+#
+# Five products a tile, and `_bwd_dkv_kernel` makes all five where the
+# head's dq fits VMEM beside it (`_dq_in_dkv`): a k block's run adds to dk
+# and dv, every tile adds to its q block's rows of a [T, D] float32 dq that
+# lives from the head's first step to its last. The k blocks come in
+# ascending order, so a q block's sum runs in the order `_bwd_dq_kernel`
+# sums it. That kernel, which makes s and dp a second time, runs only
+# beside a dk/dv kernel that leaves dq out.
 # ---------------------------------------------------------------------------
+
+# VMEM the dk/dv kernel may spend on a whole head's dq: the float32
+# accumulator and the two buffers of its output block. 12.6 MB at T 8,192
+# with heads of 192 in bf16, 8.4 at 128; T 32,768 at 128 no longer fits.
+_DQ_HEAD_BUDGET = 24 * 2 ** 20
+
+
+def _dq_head_bytes(t, d, dtype):
+    return t * d * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _dq_in_dkv(t, d, dtype, per_q_bias):
+    """Whether a blocked backward is the one kernel. A per-q bias keeps the
+    dq kernel: its gradient is the [T, T] `ds` itself, written tile by tile
+    q-block-major, zeroed above the diagonal."""
+    return not per_q_bias and _dq_head_bytes(t, d, dtype) <= _DQ_HEAD_BUDGET
+
 
 def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                    g_ref, lse_ref, delta_ref, dq_ref, dbias_ref, dq_acc, *,
@@ -709,13 +740,17 @@ def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
 
 def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                     g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dbias_col_ref,
-                    dk_acc, dv_acc, db_acc, *, sm_scale, causal, block_q,
-                    block_k, nq, nk, n_steps, dropout_rate, per_q_bias):
+                    dq_ref, dk_acc, dv_acc, db_acc, dq_acc, *, sm_scale,
+                    causal, block_q, block_k, nq, nk, n_steps, dropout_rate,
+                    per_q_bias):
     """dk and dv of one k block, on TRANSPOSED scores: sᵀ = k·qᵀ is
     [bk, bq], so the q block's lse and delta are [1, bq] rows that broadcast
     down sublanes as they come, and dv += pᵀ·g, dk += dsᵀ·q contract the
     tile's lane axis like any product (on [bq, bk] scores both would
-    contract the row axis, a transposition of the tile each)."""
+    contract the row axis, a transposition of the tile each). With `dq_ref`
+    (a head's whole [T, D] block) the tile's dsᵀ also gives dq: the one
+    product that contracts the row axis, added to the q block's rows of
+    `dq_acc`."""
     # the steps are k-block-major: a run is one k block's q blocks
     b, step = pl.program_id(0), pl.program_id(1)
     iq, ik = qi_ref[step], ki_ref[step]
@@ -727,6 +762,11 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
         if db_acc is not None:
             db_acc[...] = jnp.zeros_like(db_acc)
+
+    if dq_acc is not None:
+        @pl.when(step == 0)
+        def _init_head():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def _body():
         q = q_ref[0]                                          # [bq, D]
@@ -762,11 +802,17 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
             pt_v.astype(g.dtype), g, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [bk, D]
         dst = pt * (dpt - delta_ref[0, 0])                    # [bk, bq] f32
+        dst_c = dst.astype(q.dtype)
         dk_acc[...] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            dst_c, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale    # [bk, D]
         if db_acc is not None:
             db_acc[...] += jnp.sum(dst, axis=1, keepdims=True)  # [bk, 1]
+        if dq_acc is not None:
+            rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                dst_c, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bq, D]
 
     _body()
 
@@ -777,6 +823,11 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
         if dbias_col_ref is not None:
             dbias_col_ref[0] = db_acc[...].reshape(1, block_k).astype(
                 dbias_col_ref.dtype)
+
+    if dq_acc is not None:
+        @pl.when(step == n_steps - 1)
+        def _finalize_head():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
@@ -832,47 +883,50 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             ins.insert(3, None)
         return ins, refs[n_in:]
 
-    # ---- dq kernel: q-block-major steps ------------------------------------
-    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal,
-                            whole_square=per_q_bias)
-    _record_tiles("dq", nq, nk, block_q, block_k, causal)
-    out_specs = [specs["q"]]
-    out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
-    if per_q_bias:
-        out_specs.append(specs["tile"])
-        out_shape.append(jax.ShapeDtypeStruct((bh, t, t), jnp.float32))
-
-    body = functools.partial(_bwd_dq_kernel, n_steps=len(qi), **static)
-
-    def dq_kernel(seed_ref, qi_ref, ki_ref, *refs):
-        ins, outs = split_inputs(refs)
+    one_kernel = _dq_in_dkv(t, d, q.dtype, per_q_bias)
+    dq = dbias = None
+    if not one_kernel:
+        # ---- dq kernel: q-block-major steps --------------------------------
+        qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal,
+                                whole_square=per_q_bias)
+        _record_tiles("dq", nq, nk, block_q, block_k, causal)
+        out_specs = [specs["q"]]
+        out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
         if per_q_bias:
-            dq_r, db_r, acc = outs
-        else:
-            (dq_r, acc), db_r = outs, None
-        body(seed_ref, qi_ref, ki_ref, *ins, dq_r, db_r, acc)
+            out_specs.append(specs["tile"])
+            out_shape.append(jax.ShapeDtypeStruct((bh, t, t), jnp.float32))
 
-    dq_out = kernel_call(
-        "flash_bwd_dq", dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(bh, len(qi)),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=out_shape,
-        compiler_params=_blocked_params(block_q, block_k),
-        interpret=interpret,
-    )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
-    if per_q_bias:
-        dq, dbias = dq_out
-    else:
-        (dq,), dbias = dq_out, None
+        body = functools.partial(_bwd_dq_kernel, n_steps=len(qi), **static)
 
-    # ---- dk/dv kernel: k-block-major steps, transposed scores --------------
+        def dq_kernel(seed_ref, qi_ref, ki_ref, *refs):
+            ins, outs = split_inputs(refs)
+            if per_q_bias:
+                dq_r, db_r, acc = outs
+            else:
+                (dq_r, acc), db_r = outs, None
+            body(seed_ref, qi_ref, ki_ref, *ins, dq_r, db_r, acc)
+
+        dq_out = kernel_call(
+            "flash_bwd_dq", dq_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(bh, len(qi)),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            ),
+            out_shape=out_shape,
+            compiler_params=_blocked_params(block_q, block_k),
+            interpret=interpret,
+        )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
+        dq = dq_out[0]
+        if per_q_bias:
+            dbias = dq_out[1]
+
+    # ---- dk/dv (and dq) kernel: k-block-major steps, transposed scores -----
     qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, k_major=True)
-    _record_tiles("dkv", nq, nk, block_q, block_k, causal)
+    _record_tiles("bwd" if one_kernel else "dkv", nq, nk, block_q, block_k,
+                  causal)
     out_specs2 = [specs["k_out"], specs["v_out"]]
     out_shape2 = [
         jax.ShapeDtypeStruct((bh, t, d), k.dtype),
@@ -884,21 +938,29 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
         out_specs2.append(specs["col"])
         out_shape2.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
         scratch2.append(pltpu.VMEM((block_k, 1), jnp.float32))
+    if one_kernel:
+        # the head's whole dq: a block that changes with the head alone
+        out_specs2.append(pl.BlockSpec((1, t, d), lambda b, *_: (b, 0, 0)))
+        out_shape2.append(jax.ShapeDtypeStruct((bh, t, d), q.dtype))
+        scratch2.append(pltpu.VMEM((t, d), jnp.float32))
 
     body2 = functools.partial(_bwd_dkv_kernel, n_steps=len(qi),
                               per_q_bias=per_q_bias, **static)
 
     def dkv_kernel(seed_ref, qi_ref, ki_ref, *refs):
         ins, rest = split_inputs(refs)
-        if col_bias:
-            dk_r, dv_r, dbc_r, dka, dva, dba = rest
-        else:
-            (dk_r, dv_r, dka, dva), dbc_r, dba = rest, None, None
-        body2(seed_ref, qi_ref, ki_ref, *ins, dk_r, dv_r, dbc_r, dka, dva,
-              dba)
+        outs, accs = (list(rest[:len(out_specs2)]),
+                      list(rest[len(out_specs2):]))
+        # (dk, dv, dbias column, dq) and their accumulators, an absent
+        # pair None
+        for slot, present in ((2, col_bias), (3, one_kernel)):
+            if not present:
+                outs.insert(slot, None)
+                accs.insert(slot, None)
+        body2(seed_ref, qi_ref, ki_ref, *ins, *outs, *accs)
 
     dkv_out = kernel_call(
-        "flash_bwd_dkv", dkv_kernel,
+        "flash_bwd" if one_kernel else "flash_bwd_dkv", dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(bh, len(qi)),
@@ -907,14 +969,16 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             scratch_shapes=scratch2,
         ),
         out_shape=out_shape2,
-        compiler_params=_blocked_params(block_q, block_k),
+        compiler_params=_blocked_params(
+            block_q, block_k,
+            _dq_head_bytes(t, d, q.dtype) if one_kernel else 0),
         interpret=interpret,
     )(seed, jnp.asarray(qi), jnp.asarray(ki), *args)
+    dk, dv = dkv_out[:2]
     if col_bias:
-        dk, dv, dbias = dkv_out
-    else:
-        dk, dv = dkv_out
-
+        dbias = dkv_out[2]
+    if one_kernel:
+        dq = dkv_out[-1]
     return dq, dk, dv, dbias
 
 
@@ -1163,7 +1227,7 @@ def _interpret_arg(dropout_rate: float):
 def _flash_bwd_block_dispatch(q, k, v, g, lse, out, sm_scale, causal):
     """Block-level backward for the RING path (parallel/ring_attention.py):
     given one resident K/V block and the GLOBAL lse/out/delta residuals,
-    return (dq, dk, dv) for that block via the Pallas dq/dkv kernels
+    return (dq, dk, dv) for that block via the Pallas backward kernel
     (jax fallback off-TPU). No bias/dropout on the ring path."""
     t, d = q.shape[1], q.shape[2]
     bq, bk = _pick_dense_blocks(t)

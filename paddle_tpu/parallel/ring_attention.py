@@ -162,7 +162,7 @@ def _ring_attn_flash_local(q, k, v, axis: str, causal: bool):
 
 def _ring_flash_bwd_local(q, k, v, o, lse, g, axis: str, causal: bool):
     """Per-device ring BACKWARD (VERDICT r4 #3): reuses the Pallas
-    dq/dkv kernels per ring block with f32 dq and rotating f32 dk/dv
+    backward kernel per ring block with f32 dq and rotating f32 dk/dv
     accumulators. The decomposition is exact: with the GLOBAL lse and
     delta=Σ dO·o as residuals, every (q-shard, kv-block) pair's
     contribution is independent — dq sums locally over blocks, dk/dv for

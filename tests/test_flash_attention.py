@@ -734,6 +734,12 @@ def _tile_gauges():
             if s["name"].startswith("flash_attention/tiles_")}
 
 
+def _clear_tile_gauges():
+    from paddle_tpu.observability import get_registry
+    for n in ("tiles_grid", "tiles_scheduled", "tiles_masked"):
+        get_registry().remove_matching("flash_attention/" + n)
+
+
 @pytest.mark.parametrize("causal,want", [(True, (256, 136, 136)),
                                          (False, (256, 256, 0))])
 def test_tile_gauges_and_tables_built_once_a_shape(causal, want):
@@ -755,13 +761,13 @@ def test_tile_gauges_and_tables_built_once_a_shape(causal, want):
 
     trace()
     gauges = _tile_gauges()
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "bwd"):
         got = tuple(gauges[kernel, n] for n in
                     ("tiles_grid", "tiles_scheduled", "tiles_masked"))
         assert got == want, (kernel, got)
     qi, ki = fa._tile_schedule(16, 16, bq, bq, causal)
     assert len(qi) == want[1] and qi.dtype == ki.dtype == np.int32
-    # q-block-major for forward and dq, k-block-major for dk/dv
+    # q-block-major for the forward, k-block-major for the backward
     assert (np.diff(qi) >= 0).all()
     assert (np.diff(fa._tile_schedule(16, 16, bq, bq, causal,
                                       k_major=True)[1]) >= 0).all()
@@ -772,14 +778,17 @@ def test_tile_gauges_and_tables_built_once_a_shape(causal, want):
 
 def test_causal_per_q_bias_schedules_the_square_for_dq_only():
     """A tile above the diagonal must still zero its block of the per-q
-    bias gradient: the dq kernel of such a call walks the whole square and
-    runs a body on the triangle; forward and dk/dv walk the triangle."""
+    bias gradient: such a call keeps a dq kernel of its own, which walks the
+    whole square and runs a body on the triangle; forward and dk/dv walk the
+    triangle."""
     fa = _fa_mod()
     assert len(fa._tile_schedule(4, 4, 128, 128, True)[0]) == 10
     assert len(fa._tile_schedule(4, 4, 128, 128, True,
                                  whole_square=True)[0]) == 16
     q, k, v, g, bias = _blocked_inputs(64, 1, "per_q")
+    _clear_tile_gauges()
     *_, dbias = _blocked_pallas(fa, q, k, v, g, bias, True, 128, 128)
+    assert {kernel for kernel, _ in _tile_gauges()} == {"fwd", "dq", "dkv"}
     above = np.triu(np.ones((512, 512), bool), 1)
     assert not np.asarray(dbias)[:, above].any()
     assert np.asarray(dbias)[:, ~above].any()
@@ -819,13 +828,118 @@ def test_long_sequences_take_blocks_of_1024(monkeypatch):
     monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", True)
     got = run()
     gauges = _tile_gauges()
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "bwd"):
         assert tuple(gauges[kernel, n] for n in (
             "tiles_grid", "tiles_scheduled", "tiles_masked")) == (16, 10, 10)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                    atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# one backward kernel (ISSUE 40): the dk/dv kernel makes dq too, summed for the
+# whole head in VMEM; a per-q bias or a head too long for that keeps the dq
+# kernel beside it
+# ---------------------------------------------------------------------------
+
+def _bwd_pallas_calls(fa, q, k, v, g, bias, causal, bq):
+    import jax
+    lse = jax.ShapeDtypeStruct(q.shape[:2], np.float32)
+    text = str(jax.make_jaxpr(lambda lse: fa._flash_bwd_pallas(
+        q, k, v, bias, g, lse, g, 0.125, causal, bq, bq, interpret=True,
+        kv_group=q.shape[0] // k.shape[0]))(lse))
+    return text.count("pallas_call")
+
+
+@pytest.mark.parametrize("blocks", [2, 4])
+@pytest.mark.parametrize("bias_kind", ["none", "col"])
+@pytest.mark.parametrize("d,dv", [(64, 64), (192, 128)])
+@pytest.mark.parametrize("kv_group", [1, 4])
+@pytest.mark.parametrize("causal", [False, True])
+def test_one_backward_kernel(monkeypatch, causal, kv_group, d, dv, bias_kind,
+                             blocks):
+    """dq, dk, dv and the column bias's gradient of the one kernel against
+    the blockwise-JAX path and against the two kernels it stands for, which
+    sum a q block's dq over the same k blocks in the same order."""
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    bq = 128
+    q, k, v, g, bias = _blocked_inputs(d, kv_group, bias_kind, t=blocks * bq)
+    v, g = v[:, :, :dv], g[:, :, :dv]
+    assert _bwd_pallas_calls(fa, q, k, v, g, bias, causal, bq) == 1
+    got = _blocked_pallas(fa, q, k, v, g, bias, causal, bq, bq)
+    scale = 1.0 / np.sqrt(d)
+    kr, vr = fa._repeat_kv(k, kv_group), fa._repeat_kv(v, kv_group)
+    dq, dk, dv_, dbias = fa._flash_bwd_jax(
+        (q, kr, vr, bias, None, got[0], got[1]), g, sm_scale=scale,
+        causal=causal, block_k=bq, dropout_rate=0.0,
+        has_bias=bias is not None)
+    if bias is not None:
+        dbias = jnp.sum(dbias, axis=1, keepdims=True)
+    # no head fits: the dq and dk/dv kernels
+    monkeypatch.setattr(fa, "_DQ_HEAD_BUDGET", 0)
+    assert _bwd_pallas_calls(fa, q, k, v, g, bias, causal, bq) == 2
+    two = _blocked_pallas(fa, q, k, v, g, bias, causal, bq, bq)
+    for name, a, jax_path, pair in zip(("dq", "dk", "dv", "dbias"), got[2:],
+                                       (dq, dk, dv_, dbias), two[2:]):
+        if jax_path is None:
+            assert a is None and pair is None
+            continue
+        assert a.shape == jax_path.shape == pair.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(jax_path),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(pair),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("why", ["per_q_bias", "long_head"])
+def test_what_keeps_the_two_backward_kernels(monkeypatch, why):
+    """A per-q bias (its gradient is `ds` itself, tile by tile) and a head
+    whose [T, d] float32 dq passes the VMEM budget: the call's shapes decide,
+    and the gauges say which backward a shape took."""
+    fa = _fa_mod()
+    q, k, v, g, bias = _blocked_inputs(
+        64, 1, "per_q" if why == "per_q_bias" else "none")
+    if why == "long_head":
+        assert fa._dq_in_dkv(8192, 192, "bfloat16", False)
+        assert fa._dq_in_dkv(16384, 128, "bfloat16", False)
+        assert not fa._dq_in_dkv(32768, 128, "bfloat16", False)
+        # this call's head: 512 x 64 in float32
+        monkeypatch.setattr(fa, "_DQ_HEAD_BUDGET",
+                            fa._dq_head_bytes(512, 64, "float32") - 1)
+    assert not fa._dq_in_dkv(512, 64, "float32", why == "per_q_bias")
+    _clear_tile_gauges()
+    assert _bwd_pallas_calls(fa, q, k, v, g, bias, True, 128) == 2
+    assert {kernel for kernel, _ in _tile_gauges()} == {"dq", "dkv"}
+    _clear_tile_gauges()
+    assert _bwd_pallas_calls(fa, q, k, v, g, None, True, 128) == (
+        2 if why == "long_head" else 1)
+    assert {kernel for kernel, _ in _tile_gauges()} == (
+        {"dq", "dkv"} if why == "long_head" else {"bwd"})
+
+
+def test_the_kernels_labels_on_the_set_up_account():
+    """`setup/kernel_traces{kernel}` counts a blocked backward as
+    `flash_bwd`, and as `flash_bwd_dq` and `flash_bwd_dkv` where two run."""
+    from paddle_tpu.observability import get_registry
+    fa = _fa_mod()
+
+    def traces():
+        n = {}
+        for s in get_registry().series():
+            if s["name"] == "setup/kernel_traces":
+                label = s["labels"]["kernel"]
+                n[label] = n.get(label, 0) + s["value"]
+        return n
+
+    before = traces()
+    q, k, v, g, bias = _blocked_inputs(64, 1, "per_q")
+    _bwd_pallas_calls(fa, q, k, v, g, None, True, 128)
+    _bwd_pallas_calls(fa, q, k, v, g, bias, True, 128)
+    grew = {label: n - before.get(label, 0) for label, n in traces().items()
+            if n != before.get(label, 0)}
+    assert grew == {"flash_bwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 
 
 # ---------------------------------------------------------------------------
@@ -961,11 +1075,12 @@ def test_the_layer_takes_the_value_width_from_v_s_shape():
 
 def test_equal_head_sizes_trace_to_the_parent_s_kernels(monkeypatch):
     """Where q, k and v have one head size the calls are the ones they were
-    before there were two: the same three kernels with [block, d] blocks,
-    one [block, d] accumulator, and a jaxpr whose digest is the parent's
-    (commit e7757d0, PR 38; recorded by PR 39 from a copy of that commit,
-    with the addresses of objects taken out). A PR that changes the kernels
-    on purpose records its own."""
+    before there were two: the same kernels with [block, d] blocks and
+    accumulators, and a jaxpr of a known digest, with the addresses of
+    objects taken out (PR 39 recorded the parent's, commit e7757d0, from a
+    copy of that commit). A PR that changes the kernels on purpose records
+    its own: PR 40's, whose backward is one kernel, so two calls for
+    three."""
     import hashlib
     import re
 
@@ -990,8 +1105,8 @@ def test_equal_head_sizes_trace_to_the_parent_s_kernels(monkeypatch):
             text = re.sub(
                 r" at 0x[0-9a-f]+", "",
                 str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*args)))
-        assert text.count("pallas_call") == 3
+        assert text.count("pallas_call") == 2
         digests[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
-    assert digests == {"t8192_h32on8_d64": "ae936b231495a322",
-                       "t8192_h32on2_d128": "0d67e6228d0ed0c9",
-                       "t4096_h16_d128": "6d99736715d4aecb"}
+    assert digests == {"t8192_h32on8_d64": "e4c8921e21abf131",
+                       "t8192_h32on2_d128": "fd0330c7a56451b1",
+                       "t4096_h16_d128": "3d38a98b1ea26c08"}

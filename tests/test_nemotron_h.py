@@ -634,11 +634,12 @@ def test_a_block_s_backward_does_not_remake_what_it_keeps(all_kernels_on):
     mcfg, n = _model_cfg(cfg), 2 * t
     assert (full["sort"], kept["sort"]) == (2, 1)
     assert (full["top_k"], kept["top_k"]) == (2, 1)
-    # attention: forward, remade forward, dq, dkv -> forward, dq, dkv
+    # attention: forward, remade forward, one backward -> forward, backward
     assert (full["pallas_call", "kernel"],
             kept["pallas_call", "kernel"]) == (2, 1)
-    for call in ("dq_kernel", "dkv_kernel", "ssd_scan_bwd"):
+    for call in ("dkv_kernel", "ssd_scan_bwd"):
         assert full["pallas_call", call] == kept["pallas_call", call] == 1
+    assert full["pallas_call", "dq_kernel"] == 0   # dq comes with dk and dv
     assert (full["pallas_call", "ssd_scan_fwd"]
             == kept["pallas_call", "ssd_scan_fwd"] == 2)
     for width in (mcfg.d_inner + mcfg.conv_dim + mcfg.mamba_num_heads,
