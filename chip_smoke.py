@@ -39,6 +39,13 @@ Phases, one chip:
            AMP Adam: the head matrix and the table are one parameter each
            with two readers, both loss terms fall
 
+  laguna   three steps of models/laguna.py at Laguna-XS.2's widths: the
+           dense layer at 48 heads with YaRN on half of each head, and one
+           window layer (window 512) at 64 heads with 32 of 256 gated
+           experts held beside a shared expert, the per-head output gate in
+           both, b1 x 1024, bf16 AMP Adam: the loss falls, every held pair
+           is counted
+
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
 split, the state shardings, the memory spread, and five dp4 losses against
@@ -435,12 +442,12 @@ def _dense_case(name, b, t, nh, causal, with_bias):
                       _with_grads(reference, 3), 2e-2)
 
 
-def _causal_reference_by_head(q, k, v, nh, nkv):
+def _causal_reference_by_head(q, k, v, nh, nkv, window=None):
     """Causal softmax attention on packed [B, T, H] tensors, a (batch, head)
     at a time in f32 with exact matmuls, each head's [T, T] scores made
     again in the backward pass: at T 8,192 all heads' scores at once are
     17 GB. Query head h reads key/value head h // (nh / nkv); v's head size
-    is its own."""
+    is its own. Under a `window` query i sees keys i - window < j <= i."""
     import jax
     import jax.numpy as jnp
 
@@ -456,7 +463,11 @@ def _causal_reference_by_head(q, k, v, nh, nkv):
     def one(qkv):
         qh, kh, vh = qkv
         s = jnp.dot(qh, kh.T, precision=hp) / np.sqrt(d)
-        s = jnp.where(_causal(t, t)[0, 0], s, -1e30)
+        visible = _causal(t, t)[0, 0]
+        if window is not None:
+            pos = jnp.arange(t)
+            visible = visible & (pos[:, None] - pos[None, :] < window)
+        s = jnp.where(visible, s, -1e30)
         return jnp.dot(jax.nn.softmax(s, axis=-1), vh, precision=hp)
 
     o = jax.lax.map(one, (heads(q, nh), heads(k, nkv), heads(v, nkv)))
@@ -464,11 +475,11 @@ def _causal_reference_by_head(q, k, v, nh, nkv):
         b, t, nh * dv).astype(q.dtype)
 
 
-def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None):
+def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None, window=None):
     """A benchmark cell's attention call: causal, blocked (T over one block),
     `nh` query heads of `d` on `nkv` key/value heads, the values `dv` wide
-    (default: `d`). Two Mosaic calls: the forward, and one backward kernel
-    for all three gradients."""
+    (default: `d`), under a sliding `window` where given. Two Mosaic calls:
+    the forward, and one backward kernel for all three gradients."""
     def make_args(rng):
         import jax.numpy as jnp
         return tuple(jnp.asarray(rng.standard_normal((b, t, width)) * 0.5,
@@ -477,10 +488,10 @@ def _blocked_causal_case(name, b, t, nh, nkv, d, dv=None):
 
     def kernel(q, k, v):
         return _fa().flash_attention_packed(q, k, v, nh, causal=True,
-                                            num_kv_heads=nkv)
+                                            num_kv_heads=nkv, window=window)
 
     def reference(q, k, v):
-        return _causal_reference_by_head(q, k, v, nh, nkv)
+        return _causal_reference_by_head(q, k, v, nh, nkv, window)
 
     return KernelCase(name, make_args, _with_grads(kernel, 3),
                       _with_grads(reference, 3), 2e-2, mosaic_calls=2)
@@ -613,7 +624,8 @@ def kernel_cases(batch: Optional[int] = None):
     the four decoder cells' blocked causal calls (LFM2's [64, 8192, 64] on
     8 key/value heads, Nemotron's [64, 8192, 128] on 2, Ouro's
     [32, 4096, 128], JoyAI's 32 heads of 192-wide keys and 128-wide values
-    at T 8,192 and at the smoke phase's 1,024),
+    at T 8,192 and at the smoke phase's 1,024, Laguna's 48 heads on 8 of 128
+    and its 64 on 8 under a window of 512, at T 8,192 and 1,024),
     ResNet-50's bottleneck tails at batch 128, Nemotron's Mamba-2 scan at
     the benchmark cell's own shape (b2 x T8192). `batch` overrides every batch
     size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
@@ -638,6 +650,12 @@ def kernel_cases(batch: Optional[int] = None):
                              32, 32, 192, 128),
         _blocked_causal_case("flash_joyai_t1024_h32_d192_v128", b(2), 1024,
                              32, 32, 192, 128),
+        _blocked_causal_case("flash_laguna_t8192_h48on8_d128", b(2), 8192,
+                             48, 8, 128),
+        _blocked_causal_case("flash_laguna_t8192_h64on8_d128_w512", b(2),
+                             8192, 64, 8, 128, window=512),
+        _blocked_causal_case("flash_laguna_t1024_h64on8_d128_w512", b(2),
+                             1024, 64, 8, 128, window=512),
         _sparse_case("flash_sparse_self_t256_causal", b(16), 256, 256, 16,
                      causal=True),
         _sparse_case("flash_sparse_cross_tq256_tk384", b(16), 256, 384, 16,
@@ -1061,6 +1079,74 @@ def phase_joyai(args):
 
 
 # ---------------------------------------------------------------------------
+# laguna: window and full attention at two head counts, the output gate,
+# YaRN on half a head, gated experts beside a shared one
+# ---------------------------------------------------------------------------
+
+LAGUNA_SEQ, LAGUNA_STEPS = 1024, 3
+
+
+def phase_laguna(args):
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig(vocab_size=12544, num_hidden_layers=2,
+                              experts_held=(0, 32))
+
+    def opt():
+        return mp.decorate(fluid.optimizer.Adam(1e-4), dtype="bfloat16",
+                           use_dynamic_loss_scaling=False)
+
+    with fluid.unique_name.guard():
+        main, startup, _, loss, counters = laguna.build_pretrain_program(
+            cfg, 1, LAGUNA_SEQ, opt)
+    kinds = [op.attrs.get("window") for op in main.global_block().ops
+             if op.type == "flash_attention"]
+    _require(kinds == [None, cfg.sliding_window],
+             f"a full layer and a window layer, the ops say {kinds}")
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (1, LAGUNA_SEQ + 1)).astype("int32")
+    feed = {"ids": jnp.asarray(ids[:, :-1]),
+            "labels": jnp.asarray(ids[:, 1:, None])}
+    fetch = [loss] + [v for _, tokens, pairs in counters
+                      for v in (tokens, pairs)]
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        fetched = [exe.run(main, feed=feed, fetch_list=fetch,
+                           return_numpy=False) for _ in range(LAGUNA_STEPS)]
+        vals = [float(np.asarray(f[0])) for f in fetched]
+        wall_s = time.perf_counter() - t0
+    _require(np.isfinite(vals).all(), f"non-finite laguna loss in {vals}")
+    _require(vals[-1] < vals[0],
+             f"laguna loss did not fall on a fixed batch: {vals}")
+    _require(_platforms(fetched[-1][0]) == {"tpu"},
+             f"laguna loss lives on {_platforms(fetched[-1][0])}, not tpu")
+    tokens, pairs = (np.asarray(fetched[-1][1]),
+                     int(np.asarray(fetched[-1][2])))
+    _require(tokens.shape == (32,) and tokens.sum() == pairs
+             and 0 < pairs <= LAGUNA_SEQ * cfg.num_experts_per_tok,
+             f"held pairs {pairs} against per-expert {tokens.tolist()}")
+    print(f"laguna: a full layer (48 heads, dense MLP) and a window layer "
+          f"(64 heads, window {cfg.sliding_window}, experts) b1 x "
+          f"{LAGUNA_SEQ}, {laguna.param_count(cfg) / 1e6:.1f}M parameters, "
+          f"loss {vals[0]} -> {vals[-1]} over {LAGUNA_STEPS} steps, pairs "
+          f"held {pairs} of {LAGUNA_SEQ * cfg.num_experts_per_tok} "
+          f"(startup+compile+steps {wall_s:.1f} s, set-up fact)")
+    del fetched, scope, exe
+    gc.collect()
+    return {"config": {"seq": LAGUNA_SEQ,
+                       "parameters": laguna.param_count(cfg)},
+            "losses": [round(v, 5) for v in vals], "pairs_held": pairs,
+            "setup": {"startup_compile_and_steps_s": round(wall_s, 2)}}
+
+
+# ---------------------------------------------------------------------------
 # dp4: the same ERNIE program, data-parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -1173,7 +1259,7 @@ def phase_dp4(args):
 PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
                    ("kernels", phase_kernels), ("deepfm", phase_deepfm),
                    ("looped", phase_looped), ("lfm2", phase_lfm2),
-                   ("joyai", phase_joyai)]
+                   ("joyai", phase_joyai), ("laguna", phase_laguna)]
 PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
 
 

@@ -7,6 +7,7 @@ the actual arrays at trace time; XLA owns layout).
 """
 from __future__ import annotations
 
+import math
 from typing import List, Optional, Sequence, Union
 
 import numpy as np
@@ -294,7 +295,10 @@ def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
 
 
 def rotary_embedding(x: Variable, num_heads: int, theta: float = 10000.0,
-                     interleaved: bool = False, name=None) -> Variable:
+                     interleaved: bool = False,
+                     rotary_dim: Optional[int] = None, yarn=None,
+                     attention_factor: Optional[float] = None,
+                     name=None) -> Variable:
     """Rotary position embedding on packed heads [B, T, H*D] (TPU
     extension): every head's channel pairs are rotated by
     `t * theta^(-2j/D)` at position t, j < D/2, angles and multiply-adds in
@@ -307,15 +311,41 @@ def rotary_embedding(x: Variable, num_heads: int, theta: float = 10000.0,
     S: [T, D] tables of cosines and signed sines; `partner` brings every
     channel its pair's other channel by a product with a constant 0/1
     matrix: no half-head slices, no concatenate); its backward rule is the
-    same pass at the negative angle and keeps no residual."""
+    same pass at the negative angle and keeps no residual.
+
+    A rotation on a part of each head: `rotary_dim` (even, at most the head
+    size) turns the first that many channels, pairs within them, and passes
+    the rest (C = 1, S = 0 on their lanes: the same pass on whole heads).
+    `yarn` = {"factor", "original_max_position_embeddings", "beta_fast",
+    "beta_slow"} takes YaRN's blended inverse frequencies
+    (`ops.nn_ops.yarn_inv_freq`) in the place of `theta^(-2j/D)`;
+    `attention_factor` multiplies cosines and sines, so the turning channels
+    and not the passing ones (with `yarn` and none given: YaRN's default,
+    `0.1 ln(factor) + 1`)."""
     helper = LayerHelper("rotary_embedding", name=name)
     if x.shape is not None and int(x.shape[-1]) % (2 * num_heads):
         raise ValueError(f"rotary_embedding: {x.shape[-1]} channels are not "
                          f"{num_heads} heads of an even size")
+    if rotary_dim is not None and (
+            rotary_dim < 2 or rotary_dim % 2 or (
+                x.shape is not None
+                and rotary_dim > int(x.shape[-1]) // num_heads)):
+        raise ValueError(f"rotary_embedding: rotary_dim {rotary_dim} is not "
+                         f"an even part of a head")
     out = helper.create_variable_for_type_inference(x.dtype, x.shape)
     attrs = {"num_heads": int(num_heads), "theta": float(theta)}
     if interleaved:     # absent otherwise: a rotate-half op is as it was
         attrs["interleaved"] = True
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
+    if yarn is not None:
+        attrs["yarn"] = [float(yarn[key]) for key in (
+            "factor", "original_max_position_embeddings", "beta_fast",
+            "beta_slow")]
+        if attention_factor is None:
+            attention_factor = 0.1 * math.log(attrs["yarn"][0]) + 1.0
+    if attention_factor is not None:
+        attrs["attention_factor"] = float(attention_factor)
     helper.append_op(type="rotary_embedding", inputs={"X": [x.name]},
                      outputs={"Out": [out.name]}, attrs=attrs)
     return out
@@ -733,6 +763,7 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
                     causal: bool = False, dropout_prob: float = 0.0,
                     is_test: bool = False, num_heads: Optional[int] = None,
                     num_kv_heads: Optional[int] = None,
+                    window: Optional[int] = None,
                     name=None) -> Variable:
     """Fused memory-efficient attention.
 
@@ -756,7 +787,11 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
     Dv of its own, said by its shape (4D: [B, Hkv, T, Dv]; packed:
     [B, T, num_kv_heads·Dv]), and the result then has it too ([B, H, T, Dv]
     or [B, T, H·Dv]): latent attention's 192-wide keys beside 128-wide
-    values."""
+    values.
+
+    A sliding window: with `causal=True`, `window=W` lets query i see keys
+    i - W < j <= i (its own and the W - 1 before it); the kernels visit the
+    band's tiles only. A window as long as the sequence is the causal call."""
     helper = LayerHelper("flash_attention", name=name)
     out_shape = q.shape
     if q.shape is not None and v.shape is not None:
@@ -778,6 +813,8 @@ def flash_attention(q: Variable, k: Variable, v: Variable,
         attrs["num_heads"] = int(num_heads)
     if num_kv_heads is not None and num_kv_heads != num_heads:
         attrs["num_kv_heads"] = int(num_kv_heads)
+    if window is not None:      # absent otherwise: an op without is as it was
+        attrs["window"] = int(window)
     helper.append_op(type="flash_attention", inputs=inputs,
                      outputs={"Out": [out.name]}, attrs=attrs)
     return out

@@ -161,6 +161,7 @@ def _flash_attention(ctx, inputs, attrs):
     if rate > 0.0 and not is_test:
         key = ctx.rng()
     causal = attrs.get("causal", False)
+    window = attrs.get("window")
     rate = 0.0 if is_test else rate
     if q.ndim == 3:
         # packed [B, T, H] layout — adapted to the folded kernel layout
@@ -177,7 +178,8 @@ def _flash_attention(ctx, inputs, attrs):
             *bias, key = rest
             return _fa.flash_attention_packed(
                 q, k, v, nh, bias=bias[0] if bias else None, causal=causal,
-                dropout_rate=rate, dropout_key=key, num_kv_heads=nkv)
+                dropout_rate=rate, dropout_key=key, num_kv_heads=nkv,
+                window=window)
     else:
         t, d, dv = q.shape[2], q.shape[3], v.shape[3]
 
@@ -185,7 +187,7 @@ def _flash_attention(ctx, inputs, attrs):
             *bias, key = rest
             return _fa.flash_attention(
                 q, k, v, bias=bias[0] if bias else None, causal=causal,
-                dropout_rate=rate, dropout_key=key)
+                dropout_rate=rate, dropout_key=key, window=window)
     arrays = (q, k, v) if bias is None else (q, k, v, bias)
     if _under_mesh(ctx) and _fa._pallas_ok(t, d, dv):
         return one(_per_data_shard(ctx, attend, arrays, key))

@@ -13,9 +13,11 @@ TPU-native replacement for SelectedRows sparse rows (selected_rows.h).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core.registry import register_op
@@ -366,8 +368,60 @@ def _rms_norm(ctx, inputs, attrs):
     return one(y.astype(x.dtype))
 
 
+def yarn_inv_freq(theta: float, dim: int, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies (Peng et al. 2023, arXiv:2309.00071, as
+    `transformers`' `_compute_yarn_parameters` has them) for the `dim // 2`
+    pairs of a rotated part of `dim` channels, float64: pair i turns at
+    `f_i = theta^(-2i/dim)` where it makes more than `beta_fast` turns over
+    the `original_max` positions the model was trained at, at `f_i / factor`
+    where it makes fewer than `beta_slow`, and at a blend between, linear in
+    the pair's index from `low = floor(c(beta_fast))` to `high =
+    ceil(c(beta_slow))`, `c(n) = dim ln(original_max / (2 pi n)) /
+    (2 ln theta)`."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = float(theta) ** (-2.0 * i / dim)
+
+    def c(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(c(beta_fast)), 0)
+    high = min(math.ceil(c(beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + f / factor * ramp
+
+
+def _rope_tables_rule(d: int, t: int, theta: float, sign: float,
+                      interleaved: bool, rule):
+    """(C, S [T, 1, D] float32, the partner matrix [D, D] bool) of a rotation
+    that `rule = (rotary_dim, yarn, factor)` changes. Per lane, as constants:
+    its inverse frequency (0 on the channels past `rotary_dim`, which pass:
+    cos 0 = 1, sin 0 = 0), its factor on both tables (1 on the passing
+    ones), its sine's sign and its partner (itself where it passes)."""
+    rotary_dim, yarn, factor = rule
+    d_r = d if rotary_dim is None else int(rotary_dim)
+    half = d_r // 2
+    lane = np.arange(d)
+    turns = lane < d_r
+    pair = np.where(turns, lane // 2 if interleaved else lane % half, 0)
+    freqs = (yarn_inv_freq(theta, d_r, *yarn) if yarn is not None
+             else float(theta) ** (-2.0 * np.arange(half) / d_r))
+    inv_freq = np.where(turns, freqs[pair], 0.0).astype(np.float32)
+    scale = np.where(turns, 1.0 if factor is None else factor,
+                     1.0).astype(np.float32)
+    minus = (lane % 2 == 0) if interleaved else (lane < half)
+    mate = np.where(turns, lane ^ 1 if interleaved else (lane + half) % d_r,
+                    lane)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = (jnp.cos(ang) * scale)[:, None, :]
+    sin = (jnp.sin(ang) * (scale * np.where(minus, -sign, sign)).astype(
+        np.float32))[:, None, :]
+    return cos, sin, jnp.asarray(lane[:, None] == mate[None, :])
+
+
 def _rope_pass(x, heads: int, theta: float, sign: float,
-               interleaved: bool = False):
+               interleaved: bool = False, rule=None):
     """`x · C + partner(x) · S` on packed heads X [B, T, H·D], float32
     multiply-adds, x's dtype back. C = cos ‖ cos and S = ∓sin ‖ ±sin of the
     angles `t * theta^(-2j/D)` are [T, D] float32 tables made from iotas
@@ -382,9 +436,18 @@ def _rope_pass(x, heads: int, theta: float, sign: float,
     PR 38). `interleaved`: pair j is channels (2j, 2j + 1) and not
     (j, j + D/2), so channel c turns by pair c // 2's angle, takes channel
     c ^ 1 as its partner, and the even channels carry the -sin: another
-    partner matrix and other tables, the same pass."""
+    partner matrix and other tables, the same pass. `rule = (rotary_dim,
+    yarn, factor)`: the first `rotary_dim` channels of each head turn (pairs
+    within them) and the rest pass, which is C = 1, S = 0 on their lanes;
+    `yarn` = (factor, original positions, beta_fast, beta_slow) takes the
+    inverse frequencies from `yarn_inv_freq`; `factor` multiplies both
+    tables on the turning lanes: other tables still, the same pass."""
     b, t, hd = x.shape
     d = hd // heads
+    if rule is not None:
+        cos, sin, swap = _rope_tables_rule(d, t, theta, sign, interleaved,
+                                           rule)
+        return _rope_apply(x, heads, cos, sin, lambda: swap.astype(x.dtype))
     half = d // 2
     lane = jnp.arange(d, dtype=jnp.int32)
     # each in the place the rotate-half form has had it: that form's jaxpr
@@ -397,9 +460,17 @@ def _rope_pass(x, heads: int, theta: float, sign: float,
     sin = (jnp.sin(ang) * jnp.where(
         lane % 2 == 0 if interleaved else lane < half,
         -sign, sign))[:, None, :]
-    xh = x.reshape(b, t, heads, d)
-    swap = (lane[:, None] == (lane[None, :] ^ 1 if interleaved
-                              else (lane[None, :] + half) % d)).astype(x.dtype)
+    return _rope_apply(x, heads, cos, sin, lambda: (
+        lane[:, None] == (lane[None, :] ^ 1 if interleaved
+                          else (lane[None, :] + half) % d)).astype(x.dtype))
+
+
+def _rope_apply(x, heads: int, cos, sin, swap_of):
+    """`x · C + partner(x) · S` from the [T, 1, D] tables; `swap_of()` makes
+    the partner matrix (after the view, where it has always been made)."""
+    b, t, hd = x.shape
+    xh = x.reshape(b, t, heads, hd // heads)
+    swap = swap_of()
     partner = lax.dot_general(
         xh, swap, (((3,), (0,)), ((), ())),
         precision=(lax.Precision.HIGHEST if x.dtype.itemsize > 2
@@ -411,20 +482,21 @@ def _rope_pass(x, heads: int, theta: float, sign: float,
 
 @functools.lru_cache(maxsize=None)
 def _rope_jaxpr(shape, dtype, heads: int, theta: float, sign: float,
-                interleaved: bool = False):
+                interleaved: bool = False, rule=None):
     return jax.make_jaxpr(
-        lambda x: _rope_pass(x, heads, theta, sign, interleaved))(
+        lambda x: _rope_pass(x, heads, theta, sign, interleaved, rule))(
             jax.ShapeDtypeStruct(shape, dtype))
 
 
 def _rope_turn(x, heads: int, theta: float, sign: float,
-               interleaved: bool = False):
+               interleaved: bool = False, rule=None):
     """`_rope_pass` traced once a shape and a sign, its operations bound in
     place at the call: no call's edge in the step. The backward rule needs
     that: behind a `jax.jit` XLA concatenates dq‖dk in a pass of its own
     before the product (32 passes, 5 ms a step in the Ouro cell; PERF.md
     section 6, PR 38)."""
-    closed = _rope_jaxpr(x.shape, x.dtype, heads, theta, sign, interleaved)
+    closed = _rope_jaxpr(x.shape, x.dtype, heads, theta, sign, interleaved,
+                         rule)
     (out,) = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, x)
     return out
 
@@ -435,24 +507,24 @@ def _rope_turn(x, heads: int, theta: float, sign: float,
 # not the pass's 36. Compiled, the step is the inline one's, operation for
 # operation (both cells, compiled for the v5e), and the Ouro cell's set-up is
 # 2 s shorter on the chip's host (PERF.md section 6, PR 38).
-_rope_forward = jax.jit(_rope_pass, static_argnums=(1, 2, 3, 4))
+_rope_forward = jax.jit(_rope_pass, static_argnums=(1, 2, 3, 4, 5))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _rope(x, heads: int, theta: float, interleaved: bool = False):
-    return _rope_forward(x, heads, theta, 1.0, interleaved)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _rope(x, heads: int, theta: float, interleaved: bool = False, rule=None):
+    return _rope_forward(x, heads, theta, 1.0, interleaved, rule)
 
 
-def _rope_fwd(x, heads, theta, interleaved):
-    return _rope_forward(x, heads, theta, 1.0, interleaved), None
+def _rope_fwd(x, heads, theta, interleaved, rule):
+    return _rope_forward(x, heads, theta, 1.0, interleaved, rule), None
 
 
-def _rope_bwd(heads, theta, interleaved, _, g):
+def _rope_bwd(heads, theta, interleaved, rule, _, g):
     """A rotation's transpose is the rotation by the negative angle,
     `dX = g · C - partner(g) · S`: the same pass with the sines' sign
     turned, over the cotangent alone. No residual: the tables are remade
     from iotas, so a remat block keeps nothing for it."""
-    return (_rope_turn(g, heads, theta, -1.0, interleaved),)
+    return (_rope_turn(g, heads, theta, -1.0, interleaved, rule),)
 
 
 _rope.defvjp(_rope_fwd, _rope_bwd)
@@ -473,11 +545,24 @@ def _rotary_embedding(ctx, inputs, attrs):
     rule is the op's own (`_rope_bwd`; a `jax.custom_vjp` and not the
     registry's `grad_fn`, which would keep the op out of a remat block's one
     differentiated function): the same pass at the negative angle, no
-    residual."""
+    residual.
+
+    Three attributes, each absent where unused, change the tables and
+    nothing else: `rotary_dim` (the first that many channels of each head
+    turn, pairs within them, and the rest pass), `yarn` = [factor, original
+    positions, beta_fast, beta_slow] (YaRN's blended inverse frequencies:
+    `yarn_inv_freq`) and `attention_factor` (on cosines and sines alike, so
+    on the turning channels and not on the passing ones)."""
     (x,) = inputs["X"]
+    rule = None
+    if any(a in attrs for a in ("rotary_dim", "yarn", "attention_factor")):
+        yarn = attrs.get("yarn")
+        rule = (attrs.get("rotary_dim"),
+                None if yarn is None else tuple(float(y) for y in yarn),
+                attrs.get("attention_factor"))
     return one(_rope(x, int(attrs["num_heads"]),
                      float(attrs.get("theta", 10000.0)),
-                     bool(attrs.get("interleaved", False))))
+                     bool(attrs.get("interleaved", False)), rule))
 
 
 @register_op("swiglu")

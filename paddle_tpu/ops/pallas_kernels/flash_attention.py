@@ -114,49 +114,77 @@ def _seed_from_key(dropout_key):
 # on the scalar-prefetch channel give each step its q block and k block, for
 # the kernel and for every BlockSpec's index map. Under `causal` the list
 # holds the tiles on and below the diagonal only, so a tile above it costs
-# neither a grid step nor a fetch. The forward (and the dq kernel of a
+# neither a grid step nor a fetch; under a sliding `window` (query i sees keys
+# i - window < j <= i) it holds the band's tiles only, so neither does a tile
+# whose keys all lie `window` or more behind every query of its q block. The
+# forward (and the dq kernel of a
 # two-kernel backward) lists them q-block-major (their accumulators belong
 # to a q block), the backward k-block-major. A run of steps with the same
 # major block is one accumulation: its first step zeroes the scratch, its
 # last writes the output block.
 # ---------------------------------------------------------------------------
 
-def _tile_visible(iq, ik, block_q, block_k):
-    """Some key of k block `ik` is at or before some query of q block `iq`."""
-    return ik * block_k <= iq * block_q + block_q - 1
+def _tile_visible(iq, ik, block_q, block_k, window=None):
+    """Some key of k block `ik` is at or before some query of q block `iq`,
+    and under a `window` less than `window` behind one: the block's newest
+    key against the q block's oldest query."""
+    visible = ik * block_k <= iq * block_q + block_q - 1
+    if window is None:
+        return visible
+    return visible & (iq * block_q - (ik * block_k + block_k - 1) < window)
 
 
 @functools.lru_cache(maxsize=None)
 def _tile_schedule(nq, nk, block_q, block_k, causal, k_major=False,
-                   whole_square=False):
+                   whole_square=False, window=None):
     """(q block, k block) of each grid step, two int32 arrays, built once a
-    shape. `whole_square` lists the tiles above the diagonal too (the dq
-    kernel of a causal call with a per-q bias must still zero their `dbias`
-    blocks; they run no body)."""
+    shape. `whole_square` lists the tiles above the diagonal (and behind the
+    window) too (the dq kernel of a causal call with a per-q bias must still
+    zero their `dbias` blocks; they run no body)."""
     steps = np.arange(nq * nk, dtype=np.int32)
     if k_major:
         ik, iq = np.divmod(steps, nq)
     else:
         iq, ik = np.divmod(steps, nk)
     if causal and not whole_square:
-        visited = _tile_visible(iq, ik, block_q, block_k)
+        visited = _tile_visible(iq, ik, block_q, block_k, window)
         iq, ik = iq[visited], ik[visited]
     return iq, ik
 
 
-def _record_tiles(kernel, nq, nk, block_q, block_k, causal):
-    """Static numbers a head of the last blocked call traced: the whole
-    square, the steps that run a body, those that apply the mask (under
-    `causal` every visited tile: a second body without it for the tiles
-    wholly below the diagonal measured 0.0-0.4 ms a call and doubled what
-    tracing a kernel costs, PERF.md section 6, PR 36)."""
+def _scores_visible(t, causal, window=None):
+    """Scores a head of a [t, t] call must compute: the square, the causal
+    half with its diagonal, or the band (a query's own key and the
+    `window - 1` before it)."""
+    if not causal:
+        return t * t
+    if window is None or window >= t:
+        return t * (t + 1) // 2
+    return window * (window + 1) // 2 + (t - window) * window
+
+
+def _record_tiles(kernel, nq, nk, block_q, block_k, causal, window=None):
+    """Static numbers a head of the last blocked call of its kind traced
+    (`call`: `full`, `causal` or `window`): the whole square, the steps that
+    run a body, those that apply the mask (under `causal` every visited
+    tile: a second body without it for the tiles wholly below the diagonal
+    measured 0.0-0.4 ms a call and doubled what tracing a kernel costs,
+    PERF.md section 6, PR 36), the scores the scheduled tiles compute and
+    those of them the call needs."""
     from ...observability import get_registry
-    visited = len(_tile_schedule(nq, nk, block_q, block_k, causal)[0])
+    visited = len(_tile_schedule(nq, nk, block_q, block_k, causal,
+                                 window=window)[0])
+    labels = dict(kernel=kernel, call=("window" if window is not None
+                                       else "causal" if causal else "full"))
     reg = get_registry()
-    reg.gauge("flash_attention/tiles_grid", kernel=kernel).set(nq * nk)
-    reg.gauge("flash_attention/tiles_scheduled", kernel=kernel).set(visited)
-    reg.gauge("flash_attention/tiles_masked", kernel=kernel).set(
+    reg.gauge("flash_attention/tiles_grid", **labels).set(nq * nk)
+    reg.gauge("flash_attention/tiles_scheduled", **labels).set(visited)
+    reg.gauge("flash_attention/tiles_masked", **labels).set(
         visited if causal else 0)
+    reg.gauge("flash_attention/scores_scheduled", **labels).set(
+        visited * block_q * block_k)
+    reg.gauge("flash_attention/scores_visible", **labels).set(
+        _scores_visible(nq * block_q, causal, window))
 
 
 def _run_ends(major_ref, step, n_steps):
@@ -171,14 +199,17 @@ def _run_ends(major_ref, step, n_steps):
     return first, last
 
 
-def _causal_mask(iq, ik, block_q, block_k, transposed=False):
-    """[bq, bk] (or [bk, bq]) bool: query position >= key position."""
+def _causal_mask(iq, ik, block_q, block_k, transposed=False, window=None):
+    """[bq, bk] (or [bk, bq]) bool: query position >= key position, and
+    under a `window` less than `window` past it."""
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     q_pos = iq * block_q + lax.broadcasted_iota(
         jnp.int32, shape, 1 if transposed else 0)
     k_pos = ik * block_k + lax.broadcasted_iota(
         jnp.int32, shape, 0 if transposed else 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
 def _blocked_params(block_q, block_k, head_bytes=0):
@@ -246,7 +277,7 @@ def _rows(x, nq, block_q):
 
 def _fwd_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                 o_ref, lse_ref, acc_ref, m_ref, l_ref, *, sm_scale, causal,
-                block_q, block_k, nq, nk, n_steps, dropout_rate):
+                block_q, block_k, nq, nk, n_steps, dropout_rate, window=None):
     b, step = pl.program_id(0), pl.program_id(1)
     iq, ik = qi_ref[step], ki_ref[step]
     first, last = _run_ends(qi_ref, step, n_steps)
@@ -267,8 +298,9 @@ def _fwd_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)           # [bq or 1, bk]
         if causal:
-            s = jnp.where(_causal_mask(iq, ik, block_q, block_k), s,
-                          _NEG_INF)
+            s = jnp.where(
+                _causal_mask(iq, ik, block_q, block_k, window=window), s,
+                _NEG_INF)
 
         m_prev = m_ref[:, :1]                                 # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)            # [bq, 1]
@@ -316,10 +348,12 @@ def _kv_row(kv_group: int):
 
 def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
                       interpret=False, dropout_rate=0.0, seed=None,
-                      kv_group=1):
+                      kv_group=1, window=None):
     """q: [BHq, T, D], k: [BHq / kv_group, T, D], v: [BHq / kv_group, T, Dv]
     (heads folded; the value head size Dv may differ from D); bias:
-    [BHq, Tq_or_1, Tk] or None. Returns (out [BHq,T,Dv], lse [BHq,T])."""
+    [BHq, Tq_or_1, Tk] or None; `window` (with `causal`): a query sees its
+    own key and the `window - 1` before it. Returns (out [BHq,T,Dv],
+    lse [BHq,T])."""
     bh, t, d = q.shape
     dv = v.shape[2]
     block_q, block_k = min(block_q, t), min(block_k, t)
@@ -333,10 +367,10 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
         if group > 1:
             return _flash_fwd_pallas_onepass(
                 q, k, v, bias, sm_scale, causal, group, interpret=interpret,
-                dropout_rate=dropout_rate, seed=seed)
-    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal)
+                dropout_rate=dropout_rate, seed=seed, window=window)
+    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, window=window)
     n_steps = len(qi)
-    _record_tiles("fwd", nq, nk, block_q, block_k, causal)
+    _record_tiles("fwd", nq, nk, block_q, block_k, causal, window)
 
     specs = _tile_specs(kv, block_q, block_k, d, bias, dv)
     in_specs = [specs["q"], specs["k"], specs["v"]]
@@ -347,7 +381,8 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
 
     body = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                              block_q=block_q, block_k=block_k, nq=nq, nk=nk,
-                             n_steps=n_steps, dropout_rate=dropout_rate)
+                             n_steps=n_steps, dropout_rate=dropout_rate,
+                             window=window)
     if bias is not None:
         kernel = body
     else:
@@ -399,10 +434,12 @@ def _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, block_q, block_k,
 # `b` when nq == nk == 1) — fwd and bwd may even pick different group sizes.
 # ---------------------------------------------------------------------------
 
-def _causal_mask_full(t):
+def _causal_mask_full(t, window=None):
     q_pos = lax.broadcasted_iota(jnp.int32, (t, t), 0)
     k_pos = lax.broadcasted_iota(jnp.int32, (t, t), 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
 def _group_keep_mask(seed_ref, g0, group, t, rate):
@@ -414,7 +451,8 @@ def _group_keep_mask(seed_ref, g0, group, t, rate):
 
 
 def _fwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                        lse_ref, *, sm_scale, causal, dropout_rate, group):
+                        lse_ref, *, sm_scale, causal, dropout_rate, group,
+                        window=None):
     g0 = pl.program_id(0)
     q, k, v = q_ref[...], k_ref[...], v_ref[...]          # [G, T, D]
     t = q.shape[1]
@@ -423,7 +461,7 @@ def _fwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
     if bias_ref is not None:
         s = s + bias_ref[...].astype(jnp.float32)         # [G, Tq or 1, T]
     if causal:
-        s = jnp.where(_causal_mask_full(t)[None], s, _NEG_INF)
+        s = jnp.where(_causal_mask_full(t, window)[None], s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)                # [G, T, 1]
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -441,7 +479,8 @@ def _fwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
 
 def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
-                              interpret=False, dropout_rate=0.0, seed=None):
+                              interpret=False, dropout_rate=0.0, seed=None,
+                              window=None):
     bh, t, d = q.shape
     dv = v.shape[2]
     grid = (bh // group,)
@@ -455,7 +494,7 @@ def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
 
     body = functools.partial(_fwd_kernel_onepass, sm_scale=sm_scale,
                              causal=causal, dropout_rate=dropout_rate,
-                             group=group)
+                             group=group, window=window)
     if bias is not None:
         kernel = body
     else:
@@ -489,7 +528,7 @@ def _flash_fwd_pallas_onepass(q, k, v, bias, sm_scale, causal, group,
 def _bwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref,
                         lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
                         dbias_ref, dbias_col_ref, *, sm_scale, causal,
-                        dropout_rate, group):
+                        dropout_rate, group, window=None):
     g0 = pl.program_id(0)
     q, k, v, g = q_ref[...], k_ref[...], v_ref[...], g_ref[...]  # [G, T, D]
     t = q.shape[1]
@@ -498,7 +537,7 @@ def _bwd_kernel_onepass(seed_ref, q_ref, k_ref, v_ref, bias_ref, g_ref,
     if bias_ref is not None:
         s = s + bias_ref[...].astype(jnp.float32)
     if causal:
-        s = jnp.where(_causal_mask_full(t)[None], s, _NEG_INF)
+        s = jnp.where(_causal_mask_full(t, window)[None], s, _NEG_INF)
     lse = lse_ref[:, :, :1]                                # [G, T, 1]
     p = jnp.exp(s - lse)                                   # [G, T, T]
     if dropout_rate > 0.0:
@@ -550,7 +589,7 @@ def _bwd_host_prep(q, g, lse, out):
 
 def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
                               group, dropout_rate=0.0, seed=None,
-                              interpret=False):
+                              interpret=False, window=None):
     bh, t, d = q.shape
     dv = v.shape[2]
     if seed is None:
@@ -587,7 +626,7 @@ def _flash_bwd_pallas_onepass(q, k, v, bias, g, lse, out, sm_scale, causal,
 
     body = functools.partial(_bwd_kernel_onepass, sm_scale=sm_scale,
                              causal=causal, dropout_rate=dropout_rate,
-                             group=group)
+                             group=group, window=window)
 
     def kernel(seed_ref, *refs):
         n_in = 6 + (1 if has_bias else 0)
@@ -678,7 +717,7 @@ def _dq_in_dkv(t, d, dtype, per_q_bias):
 def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                    g_ref, lse_ref, delta_ref, dq_ref, dbias_ref, dq_acc, *,
                    sm_scale, causal, block_q, block_k, nq, nk, n_steps,
-                   dropout_rate):
+                   dropout_rate, window=None):
     b, step = pl.program_id(0), pl.program_id(1)
     iq, ik = qi_ref[step], ki_ref[step]
     first, last = _run_ends(qi_ref, step, n_steps)
@@ -698,8 +737,9 @@ def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
         if bias_ref is not None:
             s = s + bias_ref[0].astype(jnp.float32)
         if causal:
-            s = jnp.where(_causal_mask(iq, ik, block_q, block_k), s,
-                          _NEG_INF)
+            s = jnp.where(
+                _causal_mask(iq, ik, block_q, block_k, window=window), s,
+                _NEG_INF)
         lse = lse_ref[0, 0].reshape(block_q, 1)               # [bq, 1]
         p = jnp.exp(s - lse)                                  # [bq, bk]
         dp = jax.lax.dot_general(
@@ -721,7 +761,7 @@ def _bwd_dq_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
     if causal and dbias_ref is not None:
         # the whole square is scheduled: a tile above the diagonal runs no
         # body but still zeroes its block of the per-q bias gradient
-        visible = _tile_visible(iq, ik, block_q, block_k)
+        visible = _tile_visible(iq, ik, block_q, block_k, window)
 
         @pl.when(visible)
         def _():
@@ -742,7 +782,7 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                     g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dbias_col_ref,
                     dq_ref, dk_acc, dv_acc, db_acc, dq_acc, *, sm_scale,
                     causal, block_q, block_k, nq, nk, n_steps, dropout_rate,
-                    per_q_bias):
+                    per_q_bias, window=None):
     """dk and dv of one k block, on TRANSPOSED scores: sᵀ = k·qᵀ is
     [bk, bq], so the q block's lse and delta are [1, bq] rows that broadcast
     down sublanes as they come, and dv += pᵀ·g, dk += dsᵀ·q contract the
@@ -782,7 +822,8 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
                        else bias.reshape(block_k, 1))
         if causal:
             st = jnp.where(
-                _causal_mask(iq, ik, block_q, block_k, transposed=True), st,
+                _causal_mask(iq, ik, block_q, block_k, transposed=True,
+                             window=window), st,
                 _NEG_INF)
         pt = jnp.exp(st - lse_ref[0, 0])                      # [bk, bq]
         dpt = jax.lax.dot_general(
@@ -832,7 +873,7 @@ def _bwd_dkv_kernel(seed_ref, qi_ref, ki_ref, q_ref, k_ref, v_ref, bias_ref,
 
 def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
                       block_q, block_k, dropout_rate=0.0, seed=None,
-                      interpret=False, kv_group=1):
+                      interpret=False, kv_group=1, window=None):
     """Returns (dq, dk, dv, dbias). dbias is [BH,Tq,Tk] f32 for a per-q bias,
     [BH,1,Tk] f32 for a broadcast (mask-like) bias, or None. With
     `kv_group` query heads to a key/value head, k and v are
@@ -852,7 +893,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
         if group > 1:
             return _flash_bwd_pallas_onepass(
                 q, k, v, bias, g, lse, out, sm_scale, causal, group,
-                dropout_rate=dropout_rate, seed=seed, interpret=interpret)
+                dropout_rate=dropout_rate, seed=seed, interpret=interpret,
+                window=window)
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
 
@@ -864,7 +906,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     stats = [_rows(lse, nq, block_q), _rows(delta, nq, block_q)]
     static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate)
+                  block_k=block_k, nq=nq, nk=nk, dropout_rate=dropout_rate,
+                  window=window)
 
     specs = _tile_specs(kv, block_q, block_k, d, bias, dv_)
     in_specs = [specs["q"], specs["k"], specs["v"]]
@@ -888,8 +931,8 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
     if not one_kernel:
         # ---- dq kernel: q-block-major steps --------------------------------
         qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal,
-                                whole_square=per_q_bias)
-        _record_tiles("dq", nq, nk, block_q, block_k, causal)
+                                whole_square=per_q_bias, window=window)
+        _record_tiles("dq", nq, nk, block_q, block_k, causal, window)
         out_specs = [specs["q"]]
         out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
         if per_q_bias:
@@ -924,9 +967,10 @@ def _flash_bwd_pallas(q, k, v, bias, g, lse, out, sm_scale, causal,
             dbias = dq_out[1]
 
     # ---- dk/dv (and dq) kernel: k-block-major steps, transposed scores -----
-    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, k_major=True)
+    qi, ki = _tile_schedule(nq, nk, block_q, block_k, causal, k_major=True,
+                            window=window)
     _record_tiles("bwd" if one_kernel else "dkv", nq, nk, block_q, block_k,
-                  causal)
+                  causal, window)
     out_specs2 = [specs["k_out"], specs["v_out"]]
     out_shape2 = [
         jax.ShapeDtypeStruct((bh, t, d), k.dtype),
@@ -992,7 +1036,7 @@ def _bias_block(bias, j0, bk):
     return lax.dynamic_slice_in_dim(bias, j0, bk, axis=-1).astype(jnp.float32)
 
 
-def _scores(q, k_blk, bias, j0, causal, sm_scale, bk):
+def _scores(q, k_blk, bias, j0, causal, sm_scale, bk, window=None):
     # q: [BH, Tq, D], k_blk: [BH, bk, D] → s: [BH, Tq, bk]
     # native-dtype operands (bf16 under AMP), f32 accumulation
     s = jnp.einsum("bqd,bkd->bqk", q, k_blk,
@@ -1002,12 +1046,15 @@ def _scores(q, k_blk, bias, j0, causal, sm_scale, bk):
         tq = q.shape[1]
         q_pos = lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
         k_pos = j0 + lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        visible = q_pos >= k_pos
+        if window is not None:
+            visible = visible & (q_pos - k_pos < window)
+        s = jnp.where(visible, s, _NEG_INF)
     return s
 
 
 def _flash_fwd_jax(q, k, v, bias, sm_scale, causal, block_k,
-                   dropout_rate=0.0, dropout_key=None):
+                   dropout_rate=0.0, dropout_key=None, window=None):
     """Two-pass online softmax: pass 1 → (m, lse); pass 2 → output.
     Handles attention-prob dropout (regenerated per block from a folded key,
     so the backward recompute sees identical masks)."""
@@ -1017,7 +1064,7 @@ def _flash_fwd_jax(q, k, v, bias, sm_scale, causal, block_k,
     def pass1(carry, j):
         m, l = carry
         s = _scores(q, lax.dynamic_slice_in_dim(k, j * block_k, block_k, 1),
-                    bias, j * block_k, causal, sm_scale, block_k)
+                    bias, j * block_k, causal, sm_scale, block_k, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         l = l * jnp.exp(m - m_new) + jnp.sum(jnp.exp(s - m_new), -1, keepdims=True)
         return (m_new, l), None
@@ -1030,7 +1077,7 @@ def _flash_fwd_jax(q, k, v, bias, sm_scale, causal, block_k,
 
     def pass2(acc, j):
         s = _scores(q, lax.dynamic_slice_in_dim(k, j * block_k, block_k, 1),
-                    bias, j * block_k, causal, sm_scale, block_k)
+                    bias, j * block_k, causal, sm_scale, block_k, window)
         p = jnp.exp(s - lse[..., None])
         p = _apply_dropout(p, dropout_rate, dropout_key, j)
         v_blk = lax.dynamic_slice_in_dim(v, j * block_k, block_k, 1)
@@ -1052,7 +1099,7 @@ def _apply_dropout(p, rate, key, block_idx):
 
 
 def _flash_bwd_jax(res, g, *, sm_scale, causal, block_k,
-                   dropout_rate, has_bias):
+                   dropout_rate, has_bias, window=None):
     """Flash backward: scan KV blocks, recompute p from (q,k,lse); per block
     dv_j = pᵀ·dO, ds = p∘(dO·vᵀ − D), dk_j = dsᵀ·q, dq += ds·k."""
     q, k, v, bias, dropout_key, out, lse = res
@@ -1067,7 +1114,7 @@ def _flash_bwd_jax(res, g, *, sm_scale, causal, block_k,
         j0 = j * block_k
         k_blk = lax.dynamic_slice_in_dim(k, j0, block_k, 1)
         v_blk = lax.dynamic_slice_in_dim(v, j0, block_k, 1)
-        s = _scores(q, k_blk, bias, j0, causal, sm_scale, block_k)
+        s = _scores(q, k_blk, bias, j0, causal, sm_scale, block_k, window)
         p = jnp.exp(s - lse[..., None])                            # [BH,T,bk]
         p_d = _apply_dropout(p, dropout_rate, dropout_key, j)
         dv_j = jnp.einsum("bqk,bqd->bkd", p_d.astype(cdt), gc,
@@ -1128,7 +1175,8 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
                            causal: bool = False,
                            sm_scale: Optional[float] = None,
                            dropout_rate: float = 0.0, dropout_key=None,
-                           num_kv_heads: Optional[int] = None):
+                           num_kv_heads: Optional[int] = None,
+                           window: Optional[int] = None):
     """Memory-efficient attention on packed tensors: q [B, T, nh·d], k
     [B, T, nkv·d], v [B, T, nkv·dv].
 
@@ -1141,7 +1189,9 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
     d=64 heads on v5e). bias (optional) is the additive [B, 1, T] mask.
     `num_kv_heads` (a divisor of `num_heads`; default: the same) is the head
     count of k and v: query head h reads key/value head h // (nh / nkv).
-    `sm_scale` defaults to d^-1/2, the q/k head size's. Returns
+    `sm_scale` defaults to d^-1/2, the q/k head size's. `window` (with
+    `causal`): a sliding window, query i sees keys i - window < j <= i, its
+    own among them; the kernels visit the band's tiles only. Returns
     [B, T, nh·dv]."""
     b_, t, hdim = q.shape
     num_kv_heads = num_heads if num_kv_heads is None else num_kv_heads
@@ -1177,7 +1227,8 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
     qf = _pack_to_folded(q, num_heads)
     kf, vf = (_pack_to_folded(x, num_kv_heads) for x in (k, v))
     out = _flash_core(qf, kf, vf, bias, dropout_key, float(sm_scale),
-                      bool(causal), float(dropout_rate))
+                      bool(causal), float(dropout_rate),
+                      _checked_window(window, causal, t))
     return _folded_to_pack(out, b_)
 
 
@@ -1185,13 +1236,37 @@ def flash_attention_packed(q, k, v, num_heads: int, bias=None,
 # Public entry
 # ---------------------------------------------------------------------------
 
+def _checked_window(window, causal: bool, t: int) -> Optional[int]:
+    """A caller's `window` as `_flash_core` takes it: None where there is no
+    window or it covers the sequence (the call is then the causal one, to
+    the bit), else the window checked."""
+    if window is None:
+        return None
+    if isinstance(window, bool) or int(window) != window or window < 1:
+        raise ValueError(f"flash_attention: window must be a whole number "
+                         f"of keys >= 1 (a query's own among them), got "
+                         f"{window!r}")
+    if not causal:
+        raise ValueError(
+            "flash_attention: a window looks back from the query (keys "
+            "i - window < j <= i) and needs causal=True; a window around "
+            "the query is not built")
+    return None if window >= t else int(window)
+
+
 def _pick_blocks(t: int):
     bq = next((b for b in (DEFAULT_BLOCK_Q, 256, 128, 64, 32, 16, 8)
                if t % b == 0), None)
     return bq, bq
 
 
-def _pick_dense_blocks(t: int):
+# Blocks of a call under a sliding window narrower than the dense default:
+# what a v5e measured at T 8,192, window 512, heads of 128 (PERF.md section
+# 6, PR 41).
+_WINDOW_BLOCK = 512
+
+
+def _pick_dense_blocks(t: int, window: Optional[int] = None):
     """Blocks of the dense kernels. From four blocks of 1,024 a side they are
     1,024 square: a step's costs that do not grow with its tile (the forward's
     row maxima and sums, a lane reduction of [block_q, 128] whatever block_k
@@ -1200,8 +1275,12 @@ def _pick_dense_blocks(t: int):
     a call at head size 64 and from 21.3 to 11.1 at 128, and dq and dk/dv
     down by a tenth; at T 4,096, 3.7 to 2.4 (PERF.md section 6, PR 36).
     Larger or oblong blocks measured no better. Below that the blocks are
-    `_pick_blocks`'s, as the block-sparse kernels' always are."""
+    `_pick_blocks`'s, as the block-sparse kernels' always are. Under a
+    `window` narrower than those blocks a tile of 1,024 is three quarters
+    masked: the blocks are `_WINDOW_BLOCK` then."""
     if t % 1024 == 0 and t >= 4 * 1024:
+        if window is not None and window < 1024:
+            return _WINDOW_BLOCK, _WINDOW_BLOCK
         return 1024, 1024
     return _pick_blocks(t)
 
@@ -1242,10 +1321,11 @@ def _flash_bwd_block_dispatch(q, k, v, g, lse, out, sm_scale, causal):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_core(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_core(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate,
+                window=None):
     out, _ = _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale,
-                                 causal, dropout_rate)
+                                 causal, dropout_rate, window)
     return out
 
 
@@ -1271,36 +1351,38 @@ def _sum_kv_group(dx, group: int, dtype):
 
 
 def _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale, causal,
-                        dropout_rate):
+                        dropout_rate, window=None):
     t, d = q.shape[1], q.shape[2]
-    bq, bk = _pick_dense_blocks(t)
+    bq, bk = _pick_dense_blocks(t, window)
     group = _kv_group(q, k)
     if _pallas_ok(t, d, v.shape[2]):
         seed = (_seed_from_key(dropout_key) if dropout_rate > 0.0 else None)
         return _flash_fwd_pallas(q, k, v, bias, sm_scale, causal, bq, bk,
                                  dropout_rate=dropout_rate, seed=seed,
                                  interpret=_interpret_arg(dropout_rate),
-                                 kv_group=group)
+                                 kv_group=group, window=window)
     if bq is None:
         raise ValueError(f"flash_attention: seq len {t} has no power-of-two "
                          f"block divisor ≥8; pad the sequence")
     key = dropout_key if dropout_rate > 0.0 else None
     return _flash_fwd_jax(q, _repeat_kv(k, group), _repeat_kv(v, group), bias,
-                          sm_scale, causal, bk, dropout_rate, key)
+                          sm_scale, causal, bk, dropout_rate, key,
+                          window=window)
 
 
-def _flash_core_fwd(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate):
+def _flash_core_fwd(q, k, v, bias, dropout_key, sm_scale, causal, dropout_rate,
+                    window=None):
     out, lse = _flash_fwd_dispatch(q, k, v, bias, dropout_key, sm_scale,
-                                   causal, dropout_rate)
+                                   causal, dropout_rate, window)
     out, lse = _kept(out, KEPT[0]), _kept(lse, KEPT[1])
     key = dropout_key if dropout_rate > 0.0 else None
     return out, (q, k, v, bias, key, out, lse)
 
 
-def _flash_core_bwd(sm_scale, causal, dropout_rate, res, g):
+def _flash_core_bwd(sm_scale, causal, dropout_rate, window, res, g):
     q, k, v, bias, key, out, lse = res
     t, d = q.shape[1], q.shape[2]
-    bq, bk = _pick_dense_blocks(t)
+    bq, bk = _pick_dense_blocks(t, window)
     has_bias = bias is not None
     group = _kv_group(q, k)
     if _pallas_ok(t, d, v.shape[2]):
@@ -1308,12 +1390,14 @@ def _flash_core_bwd(sm_scale, causal, dropout_rate, res, g):
         dq, dk, dv, dbias = _flash_bwd_pallas(
             q, k, v, bias, g, lse, out, sm_scale, causal, bq, bk,
             dropout_rate=dropout_rate, seed=seed,
-            interpret=_interpret_arg(dropout_rate), kv_group=group)
+            interpret=_interpret_arg(dropout_rate), kv_group=group,
+            window=window)
     else:
         res = (q, _repeat_kv(k, group), _repeat_kv(v, group)) + res[3:]
         dq, dk, dv, dbias = _flash_bwd_jax(
             res, g, sm_scale=sm_scale, causal=causal, block_k=bk,
-            dropout_rate=dropout_rate, has_bias=has_bias)
+            dropout_rate=dropout_rate, has_bias=has_bias,
+            window=window)
     dk = _sum_kv_group(dk, group, k.dtype)
     dv = _sum_kv_group(dv, group, v.dtype)
     if has_bias:
@@ -1901,7 +1985,7 @@ def flash_attention_packed_sparse(q, k, v, num_heads: int, q_seg, k_seg,
                                   causal: bool = False,
                                   sm_scale: Optional[float] = None,
                                   dropout_rate: float = 0.0,
-                                  dropout_key=None):
+                                  dropout_key=None, window=None):
     """Block-sparse packed-segment attention on [B, T, H] tensors.
 
     q_seg/k_seg are the packed segment-id rows (reader.pack_by_tokens
@@ -1924,6 +2008,11 @@ def flash_attention_packed_sparse(q, k, v, num_heads: int, q_seg, k_seg,
         raise ValueError(
             "flash_attention_sparse: dropout_rate > 0 requires a "
             "dropout_key; pass one or set dropout_rate=0 for inference")
+    if window is not None:
+        raise ValueError(
+            "flash_attention_sparse: the block-sparse kernels' per-row "
+            "descriptor is a query's segment and holds no window; the dense "
+            "flash_attention takes window= (one segment a row)")
     if causal and tq != tk:
         raise ValueError("flash_attention_sparse: causal requires Tq == Tk")
     if v.shape[2] != k.shape[2]:
@@ -1947,7 +2036,8 @@ def flash_attention_packed_sparse(q, k, v, num_heads: int, q_seg, k_seg,
 
 def flash_attention(q, k, v, bias=None, causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    dropout_rate: float = 0.0, dropout_key=None):
+                    dropout_rate: float = 0.0, dropout_key=None,
+                    window: Optional[int] = None):
     """Memory-efficient multi-head attention.
 
     q: [B, H, T, D]; k: [B, Hkv, T, D], v: [B, Hkv, T, Dv] with Hkv dividing
@@ -1955,7 +2045,8 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     multi-head attention). The value head size Dv may differ from D, the
     one the scores contract over. bias: additive, broadcastable to
     [B, H, T, T] (e.g. the BERT mask [B,1,1,T]). `sm_scale` defaults to
-    D^-1/2. Returns [B, H, T, Dv].
+    D^-1/2. `window` (with `causal`): query i sees keys i - window < j <= i.
+    Returns [B, H, T, Dv].
     """
     b, h, t, d = q.shape
     if (h % k.shape[1] or k.shape[3] != d or k.shape[:3] != v.shape[:3]):
@@ -1983,5 +2074,6 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     if dropout_rate == 0.0:
         dropout_key = None  # cotangent structure must match the real usage
     out = _flash_core(qf, kf, vf, bias_f, dropout_key, float(sm_scale),
-                      bool(causal), float(dropout_rate))
+                      bool(causal), float(dropout_rate),
+                      _checked_window(window, causal, t))
     return out.reshape(b, h, t, v.shape[3])

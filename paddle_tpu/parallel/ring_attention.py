@@ -258,12 +258,19 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def ring_self_attention(q, k, v, mesh: Mesh, axis: str = "sp",
-                        causal: bool = False, impl: str = "auto"):
+                        causal: bool = False, impl: str = "auto",
+                        window=None):
     """Array-level entry: q/k/v [B, H, T, D] with T sharded on `axis`.
 
     impl: "jnp" (scan of einsums — the correctness oracle), "pallas"
     (flash kernel per ring block, jnp-oracle backward), or "auto"
     (pallas when the kernel supports the local block shape)."""
+    if window is not None:
+        raise ValueError(
+            "ring_self_attention: no sliding window under the ring: every "
+            "device's keys go all the way round, and a window's band would "
+            "need the hops past it cut; flash_attention takes window= on "
+            "one device's sequence")
     if impl == "auto":
         from ..ops.pallas_kernels.flash_attention import _pallas_ok
         tl = q.shape[2] // mesh.shape[axis]

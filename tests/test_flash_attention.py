@@ -714,7 +714,8 @@ def test_skipped_tiles_are_bitwise_invisible(monkeypatch, bias_kind):
     fa = _fa_mod()
     q, k, v, g, bias = _blocked_inputs(64, 2, bias_kind)
     got = _blocked_pallas(fa, q, k, v, g, bias, True, 128, 128)
-    monkeypatch.setattr(fa, "_tile_visible", lambda iq, ik, bq, bk: ik >= 0)
+    monkeypatch.setattr(fa, "_tile_visible",
+                        lambda iq, ik, bq, bk, window=None: ik >= 0)
     fa._tile_schedule.cache_clear()
     try:
         assert len(fa._tile_schedule(4, 4, 128, 128, True)[0]) == 16
@@ -759,6 +760,7 @@ def test_tile_gauges_and_tables_built_once_a_shape(causal, want):
             q, q, q, None, q, l, q, 0.125, causal, bq, bq, interpret=True),
             x, lse)
 
+    _clear_tile_gauges()    # another kind of call's stay under their label
     trace()
     gauges = _tile_gauges()
     for kernel in ("fwd", "bwd"):
@@ -801,7 +803,9 @@ def test_tile_gauge_names_pass_the_metrics_lint():
              if t == "gauge"}
     assert names == {"flash_attention/tiles_grid",
                      "flash_attention/tiles_scheduled",
-                     "flash_attention/tiles_masked"}
+                     "flash_attention/tiles_masked",
+                     "flash_attention/scores_scheduled",
+                     "flash_attention/scores_visible"}
     assert all(metrics_lint._LEGAL_RE.match(n) for n in names)
 
 
@@ -826,6 +830,7 @@ def test_long_sequences_take_blocks_of_1024(monkeypatch):
 
     want = run()
     monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", True)
+    _clear_tile_gauges()
     got = run()
     gauges = _tile_gauges()
     for kernel in ("fwd", "bwd"):
@@ -1110,3 +1115,305 @@ def test_equal_head_sizes_trace_to_the_parent_s_kernels(monkeypatch):
     assert digests == {"t8192_h32on8_d64": "e4c8921e21abf131",
                        "t8192_h32on2_d128": "fd0330c7a56451b1",
                        "t4096_h16_d128": "3d38a98b1ea26c08"}
+
+
+# ---------------------------------------------------------------------------
+# a sliding window beside `causal` (ISSUE 41): query i sees keys
+# i - window < j <= i; the blocked kernels visit the band's tiles only
+# ---------------------------------------------------------------------------
+
+def _plain_window(q, k, v, nh, nkv, window):
+    """`_plain_packed` under a literal [T, T] mask of the band."""
+    import jax
+    import jax.numpy as jnp
+    b, t, _ = q.shape
+    d = q.shape[2] // nh
+    heads = lambda x, n: jnp.repeat(
+        x.reshape(b, t, n, -1).transpose(0, 2, 1, 3), nh // n, axis=1)
+    pos = np.arange(t)
+    band = (pos[None, :] <= pos[:, None]) & (pos[:, None] - pos[None, :]
+                                             < window)
+    s = jnp.einsum("bhqd,bhkd->bhqk", heads(q, nh), heads(k, nkv))
+    s = jnp.where(band, s / np.sqrt(d), -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), heads(v, nkv))
+    return out.transpose(0, 2, 1, 3).reshape(b, t, -1)
+
+
+# (T, query heads, key/value heads, d, window). On the interpreted path T
+# 1,536 is three blocks of 512 a side (a window of one block, of a block
+# and a half, of less than a block), T 256 and 512 with one key/value head a
+# query head the one-pass kernels; groups of 6 and of 8 as the two layer
+# kinds of Laguna have them
+WINDOW_CASES = [(1536, 6, 1, 64, 512), (1536, 8, 1, 64, 768),
+                (1536, 2, 2, 128, 100), (256, 2, 2, 64, 96),
+                (512, 8, 1, 64, 200)]
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["blockwise_jax", "pallas_interpreted"])
+@pytest.mark.parametrize("t,nh,nkv,d,window", WINDOW_CASES)
+def test_a_window_against_a_dense_masked_softmax(monkeypatch, interpret, t,
+                                                 nh, nkv, d, window):
+    """Forward and all three gradients, on the path the CPU tests run and on
+    the kernels through the interpreter."""
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", interpret)
+    q, k, v, w = _packed_inputs(1, t, nh, nkv, d, d)
+
+    def run(f):
+        return jax.value_and_grad(lambda *x: jnp.sum(f(*x) * w), (0, 1, 2))(
+            q, k, v)
+
+    got = run(lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, nh, causal=True, num_kv_heads=nkv, window=window))
+    want = run(lambda q, k, v: _plain_window(q, k, v, nh, nkv, window))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("window", [512, 384, 100, 1])
+@pytest.mark.parametrize("kv_group", [1, 2])
+def test_windowed_blocked_kernels_match_blockwise_jax(kv_group, window):
+    """4 x 4 blocks of 128 through the interpreter: a window of four blocks
+    (the whole sequence: nothing dropped), of three, narrower than a block,
+    and of the query's own key alone; out, lse, dq, dk, dv of the scheduled
+    kernels against the blockwise-JAX path under the same mask."""
+    fa = _fa_mod()
+    q, k, v, g, _ = _blocked_inputs(64, kv_group, "none")
+    scale = 1.0 / np.sqrt(64)
+    out, lse = fa._flash_fwd_pallas(q, k, v, None, scale, True, 128, 128,
+                                    interpret=True, kv_group=kv_group,
+                                    window=window)
+    got = (out, lse) + fa._flash_bwd_pallas(
+        q, k, v, None, g, lse, out, scale, True, 128, 128, interpret=True,
+        kv_group=kv_group, window=window)[:3]
+    kr, vr = fa._repeat_kv(k, kv_group), fa._repeat_kv(v, kv_group)
+    out, lse = fa._flash_fwd_jax(q, kr, vr, None, scale, True, 128,
+                                 window=window)
+    want = (out, lse) + fa._flash_bwd_jax(
+        (q, kr, vr, None, None, out, lse), g, sm_scale=scale, causal=True,
+        block_k=128, dropout_rate=0.0, has_bias=False, window=window)[:3]
+    for name, a, b, tol in zip(("out", "lse", "dq", "dk", "dv"), got, want,
+                               (2e-5, 1e-5, 2e-4, 2e-4, 2e-4)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                                   atol=tol, err_msg=name)
+    if window == 1:     # a query sees itself: out is v, the row's own
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(kr * 0 + vr),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_a_window_with_a_per_q_bias_keeps_the_dq_kernel_and_zeroes_behind():
+    """The two-kernel backward under a window: the dq kernel walks the whole
+    square for the per-q bias gradient and zeroes the tiles behind the band
+    as it does those above the diagonal."""
+    fa = _fa_mod()
+    q, k, v, g, bias = _blocked_inputs(64, 1, "per_q")
+    scale = 1.0 / np.sqrt(64)
+    out, lse = fa._flash_fwd_pallas(q, k, v, bias, scale, True, 128, 128,
+                                    interpret=True, window=130)
+    dq, dk, dv, dbias = fa._flash_bwd_pallas(
+        q, k, v, bias, g, lse, out, scale, True, 128, 128, interpret=True,
+        window=130)
+    out2, lse2 = fa._flash_fwd_jax(q, k, v, bias, scale, True, 128,
+                                   window=130)
+    want = fa._flash_bwd_jax(
+        (q, k, v, bias, None, out2, lse2), g, sm_scale=scale, causal=True,
+        block_k=128, dropout_rate=0.0, has_bias=True, window=130)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), (dq, dk, dv, dbias),
+                          want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+    pos = np.arange(512)
+    band = (pos[None, :] <= pos[:, None]) & (pos[:, None] - pos[None, :]
+                                             < 130)
+    assert not np.asarray(dbias)[:, ~band].any()
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["blockwise_jax", "pallas_interpreted"])
+def test_a_window_as_long_as_the_sequence_is_the_causal_call(monkeypatch,
+                                                             interpret):
+    """`window >= T` is `causal` bit for bit, forward and gradients, packed
+    and 4D: the call is the causal one."""
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    monkeypatch.setattr(fa, "FORCE_PALLAS_INTERPRET", interpret)
+    q, k, v, w = _packed_inputs(1, 1024, 4, 2, 64, 64)
+
+    def run(**kw):
+        return jax.value_and_grad(lambda *x: jnp.sum(
+            fa.flash_attention_packed(*x, 4, causal=True, num_kv_heads=2,
+                                      **kw) * w), (0, 1, 2))(q, k, v)
+
+    want = run()
+    for window in (1024, 5000):
+        got = run(window=window)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    narrower = run(window=1023)
+    assert not np.array_equal(np.asarray(narrower[0]), np.asarray(want[0]))
+    split = lambda x, n: x.reshape(1, 1024, n, -1).transpose(0, 2, 1, 3)
+    q4, k4, v4 = split(q, 4), split(k, 2), split(v, 2)
+    assert np.array_equal(
+        np.asarray(fa.flash_attention(q4, k4, v4, causal=True, window=1024)),
+        np.asarray(fa.flash_attention(q4, k4, v4, causal=True)))
+    np.testing.assert_allclose(
+        fa.flash_attention(q4, k4, v4, causal=True, window=77).transpose(
+            0, 2, 1, 3).reshape(1, 1024, 256),
+        _plain_window(q, k, v, 4, 2, 77), rtol=2e-4, atol=2e-4)
+
+
+def test_the_band_s_tile_list_by_hand():
+    """T 8,192 under a window of 512: at blocks of 1,024 a q block sees its
+    own k block and the one before (15 tiles), at 512 the same (31), at 256
+    its own and the two before (93); q-block-major and k-block-major list
+    the same tiles; the scores the window needs are 4,063,488 a head."""
+    fa = _fa_mod()
+    t, w = 8192, 512
+    for block, tiles in ((1024, 15), (512, 31), (256, 93)):
+        n = t // block
+        qi, ki = fa._tile_schedule(n, n, block, block, True, window=w)
+        assert len(qi) == tiles
+        by_hand = [(i, j) for i in range(n) for j in range(n)
+                   if j * block <= i * block + block - 1
+                   and i * block - (j * block + block - 1) < w]
+        assert list(zip(qi.tolist(), ki.tolist())) == by_hand
+        qk, kk = fa._tile_schedule(n, n, block, block, True, k_major=True,
+                                   window=w)
+        assert sorted(zip(qk.tolist(), kk.tolist())) == by_hand
+        assert (np.diff(kk) >= 0).all()
+    assert fa._scores_visible(t, True, w) == 4_063_488
+    assert fa._scores_visible(t, True) == 33_558_528
+    assert fa._scores_visible(t, False) == t * t
+    assert fa._scores_visible(256, True, 512) == 256 * 257 // 2
+    # a q block's oldest query reaches `window - 1` keys back: one key more
+    # than a block and a second block behind its own is visited
+    assert len(fa._tile_schedule(4, 4, 128, 128, True, window=129)[0]) == 7
+    assert len(fa._tile_schedule(4, 4, 128, 128, True, window=130)[0]) == 9
+    # the blocks a windowed call of the cell's length takes
+    assert fa._pick_dense_blocks(8192, 512) == (fa._WINDOW_BLOCK,) * 2
+    assert fa._pick_dense_blocks(8192) == (1024, 1024)
+    assert fa._pick_dense_blocks(8192, 2048) == (1024, 1024)
+    assert fa._pick_dense_blocks(1536, 512) == (512, 512)
+
+
+def test_the_tile_gauges_say_the_call_s_kind_and_the_scores_it_needs():
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.observability import get_registry
+    fa = _fa_mod()
+    _clear_tile_gauges()
+    for n in ("scores_scheduled", "scores_visible"):
+        get_registry().remove_matching("flash_attention/" + n)
+    x = jax.ShapeDtypeStruct((2, 2048, 64), jnp.float32)
+    lse = jax.ShapeDtypeStruct((2, 2048), jnp.float32)
+    for kw in ({}, {"window": 300}):
+        jax.eval_shape(lambda q: fa._flash_fwd_pallas(
+            q, q, q, None, 0.125, True, 256, 256, interpret=True, **kw), x)
+        jax.eval_shape(lambda q, l: fa._flash_bwd_pallas(
+            q, q, q, None, q, l, q, 0.125, True, 256, 256, interpret=True,
+            **kw), x, lse)
+    got = {(s["labels"]["call"], s["labels"]["kernel"],
+            s["name"].split("/")[1]): s["value"]
+           for s in get_registry().series()
+           if s["name"].startswith("flash_attention/")}
+    # 8 x 8 blocks of 256: the triangle is 36 tiles, the band of 300 keys a
+    # q block's own k block and the two before it (8 + 7 + 6)
+    for kernel in ("fwd", "bwd"):
+        assert got["causal", kernel, "tiles_scheduled"] == 36
+        assert got["window", kernel, "tiles_scheduled"] == 21
+        assert got["window", kernel, "tiles_grid"] == 64
+        assert got["window", kernel, "tiles_masked"] == 21
+        assert got["window", kernel, "scores_scheduled"] == 21 * 256 * 256
+        assert got["window", kernel, "scores_visible"] == (
+            300 * 301 // 2 + (2048 - 300) * 300)
+        assert got["causal", kernel, "scores_visible"] == 2048 * 2049 // 2
+
+
+def test_window_refusals_say_what_holds():
+    import jax
+    import jax.numpy as jnp
+    import importlib
+    ring_attention = importlib.import_module(
+        "paddle_tpu.parallel.ring_attention")
+    fa = _fa_mod()
+    q, k, v, _ = _packed_inputs(1, 64, 4, 2, 16, 16)
+    with pytest.raises(ValueError, match="needs causal=True"):
+        fa.flash_attention_packed(q, k, v, 4, num_kv_heads=2, window=8)
+    for bad in (0, -3, 2.5, True):
+        with pytest.raises(ValueError, match="whole number of keys >= 1"):
+            fa.flash_attention_packed(q, k, v, 4, causal=True,
+                                      num_kv_heads=2, window=bad)
+    seg = jnp.ones((1, 64), jnp.int32)
+    with pytest.raises(ValueError, match="holds no window"):
+        fa.flash_attention_packed_sparse(q, q, q, 4, seg, seg, causal=True,
+                                         window=8)
+    with pytest.raises(ValueError, match="no sliding window under the ring"):
+        ring_attention.ring_self_attention(
+            jnp.zeros((1, 2, 8, 16)), jnp.zeros((1, 2, 8, 16)),
+            jnp.zeros((1, 2, 8, 16)), mesh=None, window=4)
+
+
+def test_the_layer_writes_the_window_only_where_there_is_one():
+    """`layers.flash_attention(..., window=)`: the attribute is absent where
+    None (an op without it is as it was), and the op runs the band."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = layers.data("q", [64, 6 * 16], dtype="float32")
+        k = layers.data("k", [64, 16], dtype="float32")
+        v = layers.data("v", [64, 16], dtype="float32")
+        plain = layers.flash_attention(q, k, v, causal=True, num_heads=6,
+                                       num_kv_heads=1)
+        banded = layers.flash_attention(q, k, v, causal=True, num_heads=6,
+                                        num_kv_heads=1, window=9)
+    a, b = [op.attrs for op in main.global_block().ops
+            if op.type == "flash_attention"]
+    assert "window" not in a and b["window"] == 9
+    qv, kv, vv, _ = _packed_inputs(2, 64, 6, 1, 16, 16)
+    exe = fluid.Executor(fluid.TPUPlace())
+    got_plain, got = exe.run(main, feed={
+        "q": np.asarray(qv), "k": np.asarray(kv), "v": np.asarray(vv)},
+        fetch_list=[plain, banded])
+    np.testing.assert_allclose(got, _plain_window(qv, kv, vv, 6, 1, 9),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got_plain,
+                               _plain_packed(qv, kv, vv, 6, 1, True),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_without_a_window_the_four_cells_calls_are_the_parent_s():
+    """`window=None` traces to the jaxprs of the parent commit (2efe038, read
+    from a copy of it) to the character: LFM2's, Nemotron's and Ouro's calls
+    as `test_equal_head_sizes_trace_to_the_parent_s_kernels` holds them, and
+    JoyAI's 192-wide keys on 128-wide values."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    fa = _fa_mod()
+    fa_on_tpu = fa._on_tpu
+    fa._on_tpu = lambda: True
+    try:
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention_packed(
+                q, k, v, 32, causal=True).astype(jnp.float32))
+        args = [jax.ShapeDtypeStruct((2, 8192, 32 * w), jnp.bfloat16)
+                for w in (192, 192, 128)]
+        with jax.default_matmul_precision("default"):
+            text = re.sub(
+                r" at 0x[0-9a-f]+", "",
+                str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*args)))
+    finally:
+        fa._on_tpu = fa_on_tpu
+    assert text.count("pallas_call") == 2
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        "cdd37b7182978701")

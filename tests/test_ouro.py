@@ -338,8 +338,8 @@ def test_the_rotation_is_lowered_without_half_heads(heads, d):
     x = jax.ShapeDtypeStruct((2, 24, heads * d), jnp.bfloat16)
     texts = [
         jax.jit(lambda x: nn_ops._rope(x, heads, 1e6)).lower(x).as_text(),
-        jax.jit(lambda g: nn_ops._rope_bwd(heads, 1e6, False, None, g)[0]
-                ).lower(x).as_text()]
+        jax.jit(lambda g: nn_ops._rope_bwd(heads, 1e6, False, None, None,
+                                           g)[0]).lower(x).as_text()]
     half_minor = re.compile(rf"tensor<(\d+x)*{d // 2}x[a-z]")
     for text in texts:
         assert "stablehlo.multiply" in text
@@ -364,7 +364,7 @@ def test_the_rotation_s_backward_is_the_rotation_by_the_negative_angle():
         pull(g)[0], nn_ops._rope_turn(g, heads, theta, -1.0), atol=1e-6)
     np.testing.assert_allclose(nn_ops._rope_turn(out, heads, theta, -1.0), x,
                                atol=1e-5)
-    assert nn_ops._rope_fwd(x, heads, theta, False)[1] is None
+    assert nn_ops._rope_fwd(x, heads, theta, False, None)[1] is None
     assert all(np.size(leaf) < x.size
                for leaf in jax.tree_util.tree_leaves(pull))
     # where a vjp does keep its input, the same count sees it

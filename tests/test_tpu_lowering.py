@@ -551,3 +551,89 @@ def test_joyai_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
     # five expert layers: a forward and a backward tile loop each; the two
     # heads' two loops each
     assert text.count(" while(") >= 5 * 2 + 2 * 2
+
+
+@pytest.mark.parametrize("hq,window,tiles", [(64, 512, 31), (48, None, 36)],
+                         ids=["window_layer_64on8", "full_layer_48on8"])
+def test_laguna_attention_kernels_compile_for_v5e(one_chip, hq, window,
+                                                  tiles):
+    """An attention layer of `laguna_xs2.train8k`: 64 query heads under a
+    window of 512, or 48 causal, on 8 key/value heads of 128 (groups of 8 and
+    of 6), T 8,192, bf16, forward and backward, through the TPU's own
+    compiler: two kernels, k and v at their eight heads' width; the windowed
+    call walks the band's tiles alone."""
+    from paddle_tpu.observability import get_registry
+    b, t, hkv, d = 2, 8192, 8, 128
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention_packed(
+            q, k, v, hq, causal=True, num_kv_heads=hkv,
+            window=window).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((b, t, n * d), jnp.bfloat16,
+                                 sharding=one_chip) for n in (hq, hkv, hkv)]
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    dq, dk, dv = jax.eval_shape(jax.grad(loss, (0, 1, 2)), *args)
+    assert dk.shape == dv.shape == (b, t, hkv * d) and dq.shape[2] == hq * d
+    assert f"bf16[{b * hkv},{t},{d}]" in text
+    call = "window" if window else "causal"
+    scheduled = {s["labels"]["kernel"]: s["value"]
+                 for s in get_registry().series()
+                 if s["name"] == "flash_attention/tiles_scheduled"
+                 and s["labels"]["call"] == call}
+    assert scheduled["fwd"] == scheduled["bwd"] == tiles
+
+
+def test_laguna_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `laguna_xs2.train8k` (the configuration's
+    file and the traffic file as the benchmark reads them: the dense layer
+    and one period of four at the published widths, 32 of 256 gated experts
+    held beside a shared expert, b2 x T8192, bf16 AMP, Adam, remat blocks
+    with what they keep) through the TPU's own compiler: it fits a v5e's
+    15.75 GiB, holds 12 bytes a parameter of state, calls the attention
+    kernels twice a layer in five layers and keeps the experts' and the
+    head's loops of dynamic length."""
+    import json
+    import os
+
+    from benchmark.configs import laguna_xs2 as adapter
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", "laguna_xs2.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "traffic", "train8k.json")) as f:
+        traffic = json.load(f)
+    system = adapter.build(cfg, traffic, 1)
+    b, t = traffic["batch"], traffic["seq_len"]
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in system.startup.list_vars() if v.persistable}
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n
+                 and n.startswith(("blk", "embed", "final_norm", "lm_head")))
+    assert params == 691_623_936
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = system.exe._build(system.main, sorted(feed),
+                             [v.name for v in system._fetch], names, names)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
+            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    gib = 2 ** 30
+    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.8
+    assert 11.0 < live / gib < 15.0
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5 * 2
+    # four expert layers: a forward and a backward tile loop each; the
+    # head's two
+    assert text.count(" while(") >= 4 * 2 + 2
