@@ -617,6 +617,54 @@ def _ssd_case(name, b, t, heads=64, p=64, groups=8, n=128, chunk=128):
         _with_grads(lambda *a: ssm_ops.ssd_scan_einsum(*a, chunk), 6), 2e-2)
 
 
+def _grouped_case(name, b, t, d, h, held, experts, k, gated, act):
+    """The experts' grouped product (ops/pallas_kernels/grouped_ffn.py)
+    against the loops over tiles of parallel/moe.py on the same plan: the
+    result, dx, the pair weights' gradient and the matrices' (four Mosaic
+    calls: the rows laid out and the walk, forward and backward). bf16
+    activations over float32 masters, `held` of `experts` held, top-k by a
+    random draw that favours expert 0 (a dozen tiles beside experts of one
+    or two)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.common import act_map
+    from paddle_tpu.ops.pallas_kernels import grouped_ffn
+    from paddle_tpu.parallel import moe
+    n = b * t
+    _require(grouped_ffn.supports(d, h, gated, jnp.bfloat16, moe.TILE),
+             f"grouped_ffn.supports rejects D {d}, H {h}")
+    loops = jax.custom_vjp(lambda *a: moe._loop_fwd(*a)[0],
+                           nondiff_argnums=(8, 9, 10))
+    loops.defvjp(moe._loop_fwd, moe._loop_bwd)
+
+    def make_args(rng):
+        def normal(*shape, scale=1.0):
+            return jnp.asarray(rng.standard_normal(shape) * scale,
+                               jnp.float32)
+        logits = rng.standard_normal((n, experts)).astype(np.float32)
+        logits[:, 0] += 2.0
+        idx = np.argsort(-logits, axis=1)[:, :k].astype(np.int32)
+        weight = rng.uniform(0.1, 1.0, (n, k)).astype(np.float32)
+        mats = [normal(held, d, h, scale=d ** -0.5),
+                normal(held, h, d, scale=h ** -0.5)]
+        if gated:
+            mats.append(normal(held, d, h, scale=d ** -0.5))
+        return (normal(n, d).astype(jnp.bfloat16), jnp.asarray(weight),
+                *mats, jnp.asarray(idx))
+
+    def product(form):
+        def f(x, weight, w1, w2, *rest):
+            w3, idx = rest if gated else (None,) + rest
+            plan = moe._dispatch(idx, 0, held, moe.TILE)
+            return form(x, weight, w1, None, w2, None, plan, w3,
+                        act_map()[act], k, moe.TILE)
+        return _with_grads(f, 5 if gated else 4)
+
+    return KernelCase(name, make_args, product(moe._grouped_ffn),
+                      product(loops), 2e-2, mosaic_calls=4)
+
+
 def kernel_cases(batch: Optional[int] = None):
     """The shapes the models put through each kernel: ERNIE (b64, T=512,
     12 heads, [B,1,T] bias), NMT-big (16 heads; causal decoder, block-sparse
@@ -627,7 +675,9 @@ def kernel_cases(batch: Optional[int] = None):
     at T 8,192 and at the smoke phase's 1,024, Laguna's 48 heads on 8 of 128
     and its 64 on 8 under a window of 512, at T 8,192 and 1,024),
     ResNet-50's bottleneck tails at batch 128, Nemotron's Mamba-2 scan at
-    the benchmark cell's own shape (b2 x T8192). `batch` overrides every batch
+    the benchmark cell's own shape (b2 x T8192), the experts' grouped product
+    in both forms (plain relu^2 at Nemotron's 2,688 x 1,856, gated silu at
+    Laguna's 2,048 x 512; b2 x T8192 tokens). `batch` overrides every batch
     size (the tier-1 lowering test cuts it to 2); the 7x7 cases keep the 24
     images fused_bn's own shape gate needs (1024 rows, a multiple of 8)."""
     b = (lambda default, least=1: max(batch, least) if batch else default)
@@ -667,6 +717,10 @@ def kernel_cases(batch: Optional[int] = None):
         _conv_bn_case("fused_conv_bn_act_128x512x7x7_to_2048", b(128, 24),
                       512, 7, 2048),
         _ssd_case("ssd_scan_t8192_h64x64_g8_n128", b(2), 8192),
+        _grouped_case("grouped_ffn_plain_d2688_h1856_top6", b(2), 8192, 2688,
+                      1856, 4, 64, 6, False, "relu2"),
+        _grouped_case("grouped_ffn_gated_d2048_h512_top8", b(2), 8192, 2048,
+                      512, 8, 64, 8, True, "silu"),
     ]
 
 

@@ -8,8 +8,10 @@ experts, dropless sort-and-segment dispatch, one grouped product over the
 experts held (plain, or gated where the op has a `W3` input). Under a
 compiled mesh with an `ep` axis the experts are sharded over it and the
 tokens gathered and reduce-scattered; otherwise the op computes the part of
-the result its `experts_held` give. Differentiable through the executor's
-vjp tape (the grouped product brings its own backward).
+the result its `experts_held` give (under any other mesh, where the grouped
+product is the Pallas kernels, each data shard's tokens inside a
+shard_map). Differentiable through the executor's vjp tape (the grouped
+product brings its own backward).
 
 Gray under AMP (not listed in contrib/mixed_precision/fp16_lists.py): the
 router runs in float32 whatever dtype the activations arrive in, the expert
@@ -54,6 +56,14 @@ def _moe_ffn(ctx, inputs, attrs):
             and flat.shape[0] % mesh.shape[axis] == 0:
         out = _moe.moe_ffn_expert_parallel(flat, gate_w, w1, b1, w2, b2,
                                            mesh, axis=axis, **kw)
+    elif mesh is not None and mesh.size > 1 and _moe.grouped_path(
+            flat.shape[1], w1.shape[2], w3 is not None, flat.dtype,
+            _moe.TILE, flat.shape[0] * kw["k"]) == "pallas":
+        # GSPMD cannot partition a Mosaic kernel: each data shard's tokens
+        # (the path asked by all the tokens' pairs, a shard's upper bound)
+        out = _moe.moe_ffn_data_parallel(
+            flat, gate_w, w1, b1, w2, b2, mesh, ctx.data_axis,
+            experts_held=(first, w1.shape[0]), **kw)
     else:
         out = _moe.moe_ffn(flat, gate_w, w1, b1, w2, b2,
                            experts_held=(first, w1.shape[0]), **kw)

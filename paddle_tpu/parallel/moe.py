@@ -17,23 +17,34 @@ operators/distributed/parameter_prefetch.cc).
   all of them is the whole layer.
 - **Dispatch** sorts the held pairs by expert and cuts each expert's run
   into tiles of `TILE` rows. **The grouped product** (`_grouped_ffn`) walks
-  the live tiles only — a loop whose trip count is the number of tiles the
-  routing made, each tile one expert MLP on [TILE, D] gathered rows,
-  weighted and scatter-added back to its tokens — so the work follows the
-  pairs held, there is no capacity, no token is ever dropped (all tokens on
-  one expert just make more tiles), and nothing of size [N, E, C] or
-  [N*k, D] exists. An expert is **plain**, `act(x·W1 + b1)·W2 + b2` (two
-  matrices [D, H], [H, D]), or, where a third matrix `w3` [E_held, D, H] is
-  given, **gated**, `(act(x·W1 + b1) ⊙ x·W3)·W2 + b2`: the same tile with one
-  more product from the same rows and an elementwise product in float32
-  between. Its backward walks the same tiles again (jax cannot reverse a
-  loop of dynamic length, hence the custom_vjp), recomputing each tile's
-  hidden activations (both halves of a gated expert's).
+  the live tiles only, each tile one expert MLP on the [TILE, D] rows of its
+  tokens, weighted and added back to them, so the work follows the pairs
+  held, there is no capacity, no token is ever dropped (all tokens on one
+  expert just make more tiles), and nothing of size [N, E, C] or [N*k, D]
+  exists. It has two forms, chosen by the backend and the shapes alone
+  (`grouped_path`; the counter `ops/grouped_ffn_lowered{path}` says which):
+  on a TPU Pallas kernels (ops/pallas_kernels/grouped_ffn.py: a grid over
+  the tiles, the rows copied by DMA, a weight gradient summed in VMEM over
+  an expert's consecutive tiles and written once an expert); anywhere else
+  `_loop_fwd` / `_loop_bwd`, loops whose trip count is the number of tiles
+  the routing made, a tile's rows gathered and scatter-added by XLA, which
+  are also the kernels' oracle. An expert is **plain**,
+  `act(x·W1 + b1)·W2 + b2` (two matrices [D, H], [H, D]), or, where a
+  third matrix `w3` [E_held, D, H] is given, **gated**,
+  `(act(x·W1 + b1) ⊙ x·W3)·W2 + b2`: the same tile with one more product
+  from the same rows and an elementwise product in float32 between. Its
+  backward walks the same tiles again (a custom_vjp in either form: jax
+  cannot reverse a loop of dynamic length), recomputing each tile's hidden
+  activations (both halves of a gated expert's). The kernels' module is
+  imported where the form is picked (`_kernels`), never at the top of this
+  one: `import paddle_tpu` loads this module and must load no Pallas.
 - **Expert parallelism** (`moe_ffn_expert_parallel`): tokens sharded over the
   axis, experts sharded over the same axis. Each device gathers the tokens
   (all-gather), computes its held experts' part for all of them, and a
   reduce-scatter sums the parts and hands each device its own tokens'
-  rows. Both ride ICI; nothing is dropped, whatever the routing.
+  rows. Both ride ICI; nothing is dropped, whatever the routing. Under a
+  mesh without an expert axis the kernels run on each data shard's tokens
+  (`moe_ffn_data_parallel`): GSPMD cannot partition a Mosaic call.
 - Load-balance aux loss (Switch: E * Σ_e f_e·P_e), psum-averaged across the
   axis so it matches the unsharded run's.
 """
@@ -41,6 +52,7 @@ from __future__ import annotations
 
 import functools
 import math
+import sys
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -49,17 +61,24 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.remat import kept
+from ..observability import get_registry
 from ..observability.scopes import unit_scope
 from .collective import shard_map
 
 _HI = lax.Precision.HIGHEST
-# rows of one expert handled per step of the grouped product. A tile of R
-# rows multiplies by each of the expert's [D, H] matrices (two plain, three
-# gated) 2·R·D·H operations for the 2·D·H bytes it reads of it in bf16: R
-# operations a byte whatever D, H and the number of matrices, so 256 rows
-# read an expert's weights about as fast as they multiply by them on a v5e
-# (ridge 240) in either form; an expert's last tile is part empty, which
-# costs E_held * TILE / 2 rows a layer on average
+# rows of one expert handled per step of the grouped product. In the loops
+# a tile of R rows reads its expert's matrices anew: 2·R·D·H operations for
+# the 2·D·H bytes of each in bf16, R operations a byte whatever D, H and
+# the number of matrices, so 256 rows read an expert's weights about as fast
+# as they multiply by them on a v5e (ridge 240). The kernels fetch an
+# expert's matrices once for all its consecutive tiles, so the tile no
+# longer sets the products' intensity; what it sets there is the depth of a
+# weight gradient's product (xᵀ·d over R rows) and the rows left empty in
+# an expert's last tile, E_held * TILE / 2 a layer on average. They keep
+# 256 for every shape: what a tile costs beside its products is its rows'
+# copies, a cost a row and not a tile (PERF.md section 5), so a narrower
+# tile wins nothing where an expert holds one tile's rows, and a wider one
+# only makes last tiles emptier
 TILE = 256
 
 
@@ -220,7 +239,58 @@ def _lo(a, dtype):
     return None if a is None else a.astype(dtype)
 
 
+_KERNELS = "paddle_tpu.ops.pallas_kernels.grouped_ffn"
+
+
+def _kernels(d: int, h: int, gated: bool, dtype, tile: int, pairs: int = 0):
+    """The kernels' module where they take a product of these shapes over
+    `pairs` routed (token, expert) pairs, else None: on a TPU for the shapes
+    they are written for, or where a test has loaded the module and turned
+    its interpreter on. Imported here and not at the top, so that `import
+    paddle_tpu` loads no Pallas (1.3 s of set-up for a program without a
+    kernel: PERF.md section 6, PR 42)."""
+    if jax.default_backend() != "tpu" and _KERNELS not in sys.modules:
+        return None
+    from ..ops.pallas_kernels import grouped_ffn
+    taken = grouped_ffn.takes(d, h, gated, dtype, tile, pairs)
+    return grouped_ffn if taken else None
+
+
+def grouped_path(d: int, h: int, gated: bool, dtype, tile: int,
+                 pairs: int = 0) -> str:
+    """Which form the grouped product of these shapes is lowered to:
+    "pallas" (ops/pallas_kernels/grouped_ffn.py: on a TPU, for the shapes
+    and the count of routed pairs the kernels take) or "loop" (the loops
+    over tiles below). Nothing but the backend and the shapes chooses."""
+    return "pallas" if _kernels(d, h, gated, dtype, tile, pairs) else "loop"
+
+
+def _shapes_of(x, w1, w3, k, tile):
+    return (x.shape[1], w1.shape[2], w3 is not None, x.dtype, tile,
+            x.shape[0] * k)
+
+
 def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile):
+    res = (x, weight, w1, b1, w2, b2, plan, w3)
+    kernels = _kernels(*_shapes_of(x, w1, w3, k, tile))
+    if kernels is None:
+        return _loop_fwd(*res, act, k, tile)
+    return kernels.forward(*res, act=act, k=k, tile=tile), res
+
+
+def _grouped_bwd(act, k, tile, res, g):
+    x, _, w1, _, _, _, _, w3 = res
+    kernels = _kernels(*_shapes_of(x, w1, w3, k, tile))
+    if kernels is None:
+        return _loop_bwd(act, k, tile, res, g)
+    return _cotangents(res, *kernels.backward(*res, g, act=act, k=k,
+                                              tile=tile))
+
+
+def _loop_fwd(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile):
+    """The grouped product as a loop over the live tiles: the form off the
+    TPU, and the kernels' oracle."""
+    res = (x, weight, w1, b1, w2, b2, plan, w3)
     w1c, w2c, w3c = w1.astype(x.dtype), w2.astype(x.dtype), _lo(w3, x.dtype)
     b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
     weight_flat = weight.reshape(-1)
@@ -234,10 +304,10 @@ def _grouped_fwd(x, weight, w1, b1, w2, b2, plan, w3, act, k, tile):
 
     y = lax.fori_loop(0, plan.n_tiles, body,
                       jnp.zeros(x.shape, jnp.float32))
-    return y, (x, weight, w1, b1, w2, b2, plan, w3)
+    return y, res
 
 
-def _grouped_bwd(act, k, tile, res, g):
+def _loop_bwd(act, k, tile, res, g):
     x, weight, w1, b1, w2, b2, plan, w3 = res
     w1c, w2c, w3c = w1.astype(x.dtype), w2.astype(x.dtype), _lo(w3, x.dtype)
     b1f, b2f = _lo(b1, jnp.float32), _lo(b2, jnp.float32)
@@ -279,6 +349,13 @@ def _grouped_bwd(act, k, tile, res, g):
          jnp.zeros(weight_flat.shape, jnp.float32),
          zeros(w1), zeros(b1), zeros(w2), zeros(b2), zeros(w3)))
 
+    return _cotangents(res, dx, dwgt, dw1, db1, dw2, db2, dw3)
+
+
+def _cotangents(res, dx, dwgt, dw1, db1, dw2, db2, dw3):
+    """The float32 sums as the cotangents of `_grouped_ffn`'s arguments."""
+    x, weight, w1, b1, w2, b2, plan, w3 = res
+
     def like(d, a):
         return None if a is None else d.astype(a.dtype)
 
@@ -302,6 +379,9 @@ def experts_ffn(x, routing: Routing, w1, b1, w2, b2, first=0,
     tile = tile or TILE
     with unit_scope("dispatch"):
         plan = _dispatch(routing.idx, first, count, tile)
+    get_registry().counter(
+        "ops/grouped_ffn_lowered",
+        path=grouped_path(*_shapes_of(x, w1, w3, k, tile))).inc()
     with unit_scope("experts"):
         y = _grouped_ffn(x, routing.weight, w1, b1, w2, b2, plan, w3, act, k,
                          tile)
@@ -377,6 +457,42 @@ def moe_ffn_expert_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
     f = shard_map(local, mesh, in_specs=specs,
                   out_specs=(P(axis), P(), P(), P()))
     return MoEOutput(*f(*args))
+
+
+def moe_ffn_data_parallel(x, gate_w, w1, b1, w2, b2, mesh: Mesh,
+                          axis: Optional[str], k: int = 2, act=jax.nn.gelu,
+                          experts_held: Optional[Tuple[int, int]] = None,
+                          scoring: str = "softmax", correction_bias=None,
+                          norm_topk: bool = True, routed_scaling: float = 1.0,
+                          w3=None) -> MoEOutput:
+    """`moe_ffn` under a mesh without an expert axis, for the form GSPMD
+    cannot partition (the Mosaic kernels): the tokens split over `axis`
+    (the mesh's data axis; None: every device computes them all), the
+    weights whole on every device, each shard's tokens through the held
+    experts inside a shard_map. The load-balance statistics are averaged and
+    the counts summed over the axis, so the result is `moe_ffn`'s."""
+    first = experts_held[0] if experts_held else 0
+    if axis is not None and x.shape[0] % mesh.shape[axis]:
+        axis = None
+    given = [a is not None for a in (correction_bias, b1, b2, w3)]
+
+    def local(xs, gw, w1s, w2s, *rest):
+        rest = iter(rest)
+        cb, b1s, b2s, w3s = (next(rest) if g else None for g in given)
+        with unit_scope("router"):
+            r = route(xs, gw, k, scoring, cb, norm_topk, routed_scaling,
+                      axis=axis)
+        y, tokens, pairs = experts_ffn(xs, r, w1s, b1s, w2s, b2s, first, act,
+                                       w3=w3s)
+        if axis is not None:
+            tokens, pairs = lax.psum(tokens, axis), lax.psum(pairs, axis)
+        return y, r.aux_loss, tokens, pairs
+
+    whole = [a for a in (correction_bias, b1, b2, w3) if a is not None]
+    f = shard_map(local, mesh,
+                  in_specs=(P(axis),) + (P(),) * (3 + len(whole)),
+                  out_specs=(P(axis), P(), P(), P()))
+    return MoEOutput(*f(x, gate_w, w1, w2, *whole))
 
 
 def init_moe_params(rng, d_model: int, d_hidden: int, num_experts: int,

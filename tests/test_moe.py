@@ -4,14 +4,26 @@ reference's sparse story is pserver embeddings, parameter_prefetch.cc).
 Checks: the router's invariants, the dropless sort-and-segment layer against
 a plain loop over the experts (values and gradients, all experts or a held
 range, all tokens on one expert), single-device == expert-parallel outputs
-and gradients on the 8-device CPU mesh, the static-graph layer."""
+and gradients on the 8-device CPU mesh, the static-graph layer. Every test
+runs on both forms of the grouped product: the loops over tiles, and the
+Pallas kernels through the interpreter."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from paddle_tpu.ops.pallas_kernels import grouped_ffn
 from paddle_tpu.parallel import moe
+
+
+@pytest.fixture(autouse=True, params=["loop", "pallas"])
+def grouped_form(request, monkeypatch):
+    from paddle_tpu.ops import eager
+    monkeypatch.setattr(grouped_ffn, "FORCE_PALLAS_INTERPRET",
+                        request.param == "pallas")
+    eager._jit_cache.clear()      # an eager op lowered on the other form
+    return request.param
 
 
 def _params(d=16, h=32, e=8, seed=0):
@@ -153,6 +165,43 @@ def test_expert_parallel_grads_match_dense():
     g_ep = jax.grad(loss_ep)((x, p))
     g_dn = jax.grad(loss_dense)((x, p))
     for a, b in zip(jax.tree_util.tree_leaves(g_ep),
+                    jax.tree_util.tree_leaves(g_dn)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("axis", ["dp", None])
+def test_data_parallel_shards_match_the_unsharded_layer(axis):
+    """Each data shard's tokens through a held range of gated experts inside
+    a shard_map (where the kernels are the form and GSPMD cannot split
+    them): values, counts, the averaged load-balance loss and every
+    gradient are the unsharded layer's."""
+    d, h, e, held = 8, 16, 8, (2, 4)
+    n = 4 * 8
+    gw, w1, b1, w2, b2, w3 = moe.init_moe_params(
+        jax.random.PRNGKey(3), d, h, e, gated=True)
+    p = (gw, w1[2:6], b1[2:6] + 0.1, w2[2:6], b2[2:6] - 0.05)
+    x = jax.random.normal(jax.random.PRNGKey(6), (n, d))
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    kw = dict(k=2, act=jax.nn.silu, experts_held=held, scoring="sigmoid",
+              routed_scaling=2.5)
+
+    def loss(f, *extra):
+        def scalar(a):
+            out = f(a[0], *a[1], *extra, w3=a[2], **kw)
+            return jnp.sum(out.y ** 2) + 0.1 * out.aux_loss, out
+        return jax.value_and_grad(scalar, has_aux=True)((x, p, w3[2:6]))
+
+    (_, got), g_dp = loss(moe.moe_ffn_data_parallel, mesh, axis)
+    (_, ref), g_dn = loss(moe.moe_ffn)
+    np.testing.assert_allclose(np.asarray(got.y), np.asarray(ref.y),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(float(got.aux_loss), float(ref.aux_loss),
+                               rtol=1e-5)
+    assert np.array_equal(np.asarray(got.tokens_per_expert),
+                          np.asarray(ref.tokens_per_expert))
+    assert int(got.pairs_held) == int(ref.pairs_held)
+    for a, b in zip(jax.tree_util.tree_leaves(g_dp),
                     jax.tree_util.tree_leaves(g_dn)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-5)

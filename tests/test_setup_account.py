@@ -340,17 +340,32 @@ def test_a_second_process_reads_the_cache_and_lowers_to_the_same_text(
     assert runs[0]["text"] == runs[1]["text"]
 
 
-def test_the_walk_s_clock_is_exclusive():
+class _Clock:
+    """`time` as `setup_account` reads it, moved by the test alone: a loaded
+    machine stretches a real sleep past any bound."""
+
+    now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
+def test_the_walk_s_clock_is_exclusive(monkeypatch):
+    clock = _Clock()
+    monkeypatch.setattr(setup_account, "time", clock)
     account = Account()
     with setup_account.walk("test_outer_op"):
-        time.sleep(0.02)
+        clock.sleep(0.02)
         with setup_account.walk("test_inner_op"):
-            time.sleep(0.03)
+            clock.sleep(0.03)
     assert account.gained("setup/trace_op_calls", op="test_inner_op") == 1
     assert account.gained("setup/trace_op_calls", op="test_outer_op") == 1
     inner_s = account.gained("setup/trace_op_seconds", op="test_inner_op")
     outer_s = account.gained("setup/trace_op_seconds", op="test_outer_op")
-    assert 0.03 <= inner_s < 0.05 and 0.02 <= outer_s < 0.03
+    assert inner_s == pytest.approx(0.03) and outer_s == pytest.approx(0.02)
 
 
 def test_a_remat_unit_s_ops_and_the_autodiff_walk_sum_to_the_trace():
@@ -398,6 +413,79 @@ def test_a_kernel_is_traced_once_a_call_site():
                               kernel=kernel) > 0
     assert (account.gained("setup/kernel_trace_seconds")
             < account.gained("setup/seconds", phase="trace"))
+
+
+def test_the_experts_kernels_are_traced_once_a_shape_under_their_labels():
+    """Two expert layers of one shape, forward and backward: the grouped
+    product's kernels (ops/pallas_kernels/grouped_ffn.py) are bound inside a
+    jitted function a shape, so each body is traced once, not once a layer,
+    and the account counts it under the kernel's own label."""
+    from paddle_tpu.parallel import moe
+    kernels = importlib.import_module(
+        "paddle_tpu.ops.pallas_kernels.grouped_ffn")
+    gate, w1, _, w2, _, w3 = moe.init_moe_params(
+        jax.random.PRNGKey(0), 24, 40, 4, gated=True)
+    x = jax.random.normal(jax.random.PRNGKey(1), (32, 24))
+
+    def loss(x, w1, w2, w3):
+        for _ in range(2):
+            x = x + moe.moe_ffn(x, gate, w1, None, w2, None, k=2,
+                                act=jax.nn.silu, w3=w3).y
+        return jnp.sum(x ** 2)
+
+    for staged in (kernels._pack_rows, kernels._walk_forward,
+                   kernels._walk_backward):
+        staged.clear_cache()
+    account = Account()
+    kernels.FORCE_PALLAS_INTERPRET = True
+    try:
+        jax.jit(jax.grad(loss, (0, 1, 2, 3)))(x, w1, w2, w3)
+    finally:
+        kernels.FORCE_PALLAS_INTERPRET = False
+    # the rows laid out forward and, with the cotangent beside them, backward
+    for kernel, binds in (("grouped_ffn_fwd", 1), ("grouped_ffn_bwd", 1),
+                          ("grouped_ffn_rows", 2)):
+        assert account.gained("setup/kernel_traces", kernel=kernel) == binds
+        assert account.gained("setup/kernel_trace_seconds",
+                              kernel=kernel) > 0
+
+
+_NO_PALLAS = """
+import sys
+import numpy as np
+import jax
+jax.config.update("jax_platforms", "cpu")
+sys.path.insert(0, {repo!r})
+import paddle_tpu as fluid
+from paddle_tpu.models import deepfm
+main, startup, _, loss, _ = deepfm.build_train_program(
+    vocab_size=5000, is_sparse=True, fused_table=True, lr=0.05,
+    embedding_optimizer="adagrad", packed_rows={{"rows_per_step": 8 * 26}})
+exe = fluid.Executor()
+exe.run(startup)
+rng = np.random.RandomState(0)
+feed = {{"sparse_ids": rng.randint(0, 5000, (8, 26)).astype("int64"),
+        "dense": rng.rand(8, 13).astype("float32"),
+        "label": rng.randint(0, 2, (8, 1)).astype("float32")}}
+(value,) = exe.run(main, feed=feed, fetch_list=[loss])
+assert np.isfinite(np.asarray(value)).all()
+print("PALLAS", sorted(m for m in sys.modules if m.startswith(
+    ("jax.experimental.pallas", "paddle_tpu.ops.pallas_kernels"))))
+"""
+
+
+def test_a_program_without_a_kernel_loads_no_pallas():
+    """`import paddle_tpu` and a step of DeepFM, which has no Pallas kernel,
+    in a fresh interpreter: no Pallas module is loaded (1.3 s of `setup_s`,
+    which refused PR 42 in the two `deepfm_criteo` cells). A kernel's module
+    is imported where an op that takes it is lowered."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_PALLAS.format(repo=REPO)], env=env,
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "PALLAS []"
 
 
 def test_the_account_of_the_finite_probe_is_the_executor_s_own(monkeypatch):
