@@ -58,6 +58,9 @@ _CACHE_HITS = _OBS.counter("executor/cache_hits")
 _CACHE_MISSES = _OBS.counter("executor/cache_misses")
 _EXECUTE_MS = _OBS.histogram("executor/execute_ms")
 _UPDATE_FLUSHES = _OBS.counter("executor/update_flushes")
+# counted at lowering, once a barrier: the tape entries whose cotangents
+# the backward walk handed on behind one (_walk_tape)
+_GRAD_BARRIERS = _OBS.counter("executor/grad_barriers")
 _FUSED_GROUPS = _OBS.counter("executor/fused_update_groups")
 _FUSED_OPS = _OBS.counter("executor/fused_update_ops")
 _INFLIGHT = _OBS.gauge("executor/inflight_steps")
@@ -620,7 +623,19 @@ def _run_autodiff(op, env, ctx: ExecContext):
     (backward.py:558, accumulation rule _addup_repetitive_outputs_:135),
     executed functionally. The walk runs under the `autodiff` name scope
     (cotangent sums and custom gradients are backward work too); what the
-    block lowers after it is the optimizer's."""
+    block lowers after it is the optimizer's.
+
+    One rule decides what XLA may still fuse across that line: a tape entry
+    that reads a persistable parameter whose gradient is asked for hands its
+    cotangents on behind `optimization_barrier` when the parameter has two
+    or more dimensions, so the weight-gradient product runs alone and not
+    inside the update that reads it (`lfm2_24b_a2b.train8k` 42,378 ->
+    45,930 tokens/s; ledger, PR 44), or when several entries read it, so
+    each partial product is done where the walk meets it (PR 30). A mesh
+    whose data axis spans devices is excepted from the first: the gradient
+    all-reduce already separates product and update there, and the barrier
+    cost `ernie_base.dp4_seq512` 2.3% (112,074 -> 109,463; ledger, PR 44).
+    `executor/grad_barriers` counts the entries, once a lowering."""
     with _setup.walk(op.type), _scopes.autodiff_scope():
         _walk_tape(op, env, ctx)
     ctx.after_autodiff = True
@@ -668,16 +683,33 @@ def _walk_tape(op, env, ctx: ExecContext):
         else:
             cots[loss_name] = jnp.ones_like(env[loss_name])
 
-    # a parameter that several tape entries read (a layer applied more than
-    # once: models/ouro.py) gets its gradient as a sum of partial products.
-    # Left alone XLA puts those products off until the optimizer wants the
-    # sum and holds their operands, a block's activations and cotangents,
-    # meanwhile; so an entry that reads such a parameter hands on what it
-    # has summed so far together with its activation cotangents, in order
+    # Which entries hand their cotangents on behind a barrier. Left alone
+    # XLA puts a weight-gradient product off until the optimizer wants it
+    # and fuses it into the update, where it runs far under its own pace
+    # (LFM2 `matmul_ms` 181.3 -> 143.3 of a 386.6 ms step once it ran
+    # alone; ledger, PR 44), or, for a parameter that several entries read
+    # (a layer applied more than once: models/ouro.py), holds every partial
+    # product's operands until then. So an entry that reads a persistable
+    # parameter whose gradient is wanted ends its products where the walk
+    # meets it, if the parameter is a matrix or has more than one reader;
+    # vectors' reductions fuse into their neighbours, which is wanted.
+    # Under a mesh that sums gradients across devices the all-reduce
+    # already stands between a product and its update, and a barrier there
+    # only cost (dp4 `tokens_per_s` 112,074 -> 109,463, `step_hbm` 14.35
+    # -> 14.97; ledger, PR 44): there only the second reason counts.
     reads = collections.Counter(
         n for entry in ctx.tape for n in set(entry.in_names))
-    summed = {n for n, k in reads.items() if k > 1 and not _stop_grad(n)
-              and getattr(block._find_var_recursive(n), "persistable", False)}
+    mesh_sums = (ctx.mesh is not None and ctx.data_axis is not None
+                 and ctx.mesh.shape[ctx.data_axis] > 1)
+
+    def _wants_barrier(name: str) -> bool:
+        var = block._find_var_recursive(name)
+        if not getattr(var, "persistable", False) or _stop_grad(name):
+            return False
+        return reads[name] > 1 or (
+            not mesh_sums and name in target_set and len(var.shape) >= 2)
+
+    behind_barrier = {n for n in reads if _wants_barrier(n)}
 
     for entry in reversed(ctx.tape):
         if not any(n in cots for n in entry.out_names):
@@ -703,11 +735,13 @@ def _walk_tape(op, env, ctx: ExecContext):
                 cots[name] = cots[name] + g
             else:
                 cots[name] = g
-        if summed.intersection(entry.in_names):
+        if behind_barrier.intersection(entry.in_names):
             held = [n for n in dict.fromkeys(entry.in_names)
                     if isinstance(cots.get(n), jax.Array)]
-            cots.update(zip(held, jax.lax.optimization_barrier(
-                tuple(cots[n] for n in held))))
+            if held:
+                _GRAD_BARRIERS.inc()
+                cots.update(zip(held, jax.lax.optimization_barrier(
+                    tuple(cots[n] for n in held))))
 
     for t in targets:
         gname = grad_var_name(t)

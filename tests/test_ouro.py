@@ -653,17 +653,56 @@ def _lowered_barriers(layer_prefix):
     return text.count("optimization_barrier")
 
 
-def test_a_shared_weight_s_partial_sums_are_handed_on_in_order():
-    """Every tape entry that reads a parameter other entries read too (an
-    application of a layer, a pass's final norm) ends its backward behind
-    one barrier that holds its cotangents with the sums so far; the untied
-    model, whose every weight has one reader, gets none."""
+def test_a_shared_weight_s_partial_sums_are_handed_on_in_order(monkeypatch):
+    """On one device every tape entry that reads a matrix, or a parameter
+    other entries read too, ends its backward behind one barrier that holds
+    its cotangents with the sums so far: an application of a layer (a remat
+    block's entry holds all its weights), a pass's final norm (a vector, but
+    every pass reads it), the table's lookup, the head and the exit gate. So
+    the looped model and the untied one, whose every matrix has one reader,
+    lower the same barriers; the rest of the text's are jax.checkpoint's."""
     cfg = _cfg()
-    applications = cfg["total_ut_steps"] * cfg["num_hidden_layers"]
+    passes = cfg["total_ut_steps"]
+    applications = passes * cfg["num_hidden_layers"]
     looped = _lowered_barriers(ouro.shared)
     untied = _lowered_barriers(lambda t, i: f"u{t}.blk{i}")
-    # in the untied model the final norm's weight alone is shared
-    assert looped - untied == applications
+    assert looped == untied
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    assert looped - _lowered_barriers(ouro.shared) == applications + passes + 3
+
+
+def _mesh_barriers(layer_prefix, monkeypatch):
+    """Entries a backward walk hands on behind a barrier when the step is
+    lowered for a data-parallel mesh of two devices."""
+    from test_grad_barriers import barriers_a_walk
+
+    cfg = _cfg()
+    main, _, (loss, _, _), exe, scope = _program(cfg, 1e-3, layer_prefix)
+    program = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, places=jax.devices()[:2])
+    (batch,) = _batches(cfg, 1)
+
+    def run():
+        with fluid.scope_guard(scope):
+            exe.run(program, feed=batch, fetch_list=[loss])
+
+    return barriers_a_walk(run, 2, monkeypatch)
+
+
+def test_under_a_mesh_only_a_shared_weight_s_entries_are_behind_a_barrier(
+        monkeypatch):
+    """Where a mesh sums the gradients across devices the all-reduce stands
+    between a weight-gradient product and its update already: only the
+    entries that read a parameter several entries read keep the barrier,
+    which is there for the order of the partial sums. The looped model has
+    one an application and one a pass's final norm; the untied model the
+    final norm's alone."""
+    cfg = _cfg()
+    passes = cfg["total_ut_steps"]
+    applications = passes * cfg["num_hidden_layers"]
+    assert _mesh_barriers(ouro.shared, monkeypatch) == applications + passes
+    assert _mesh_barriers(
+        lambda t, i: f"u{t}.blk{i}", monkeypatch) == passes
 
 
 def test_a_remat_block_hands_out_unread_values_without_cotangents():
