@@ -23,14 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import jax.numpy as jnp
 
-from .executor import (_RNG_STATE, _CACHE_HITS, _CACHE_MISSES, _WATCHDOG,
-                       _record_dispatch, _sig_digest, _Step, ExecContext,
-                       _run_block)
-from .program import Program, Variable
-from ..observability import scopes as _scopes
-from ..observability.tracer import trace_span
-
-import weakref
+from .executor import (_RNG_STATE, _Step, _make_key, _make_step,
+                       convert_feed_value)
+from .program import Program
 
 
 class ShardingStrategy:
@@ -466,16 +461,15 @@ class CompiledProgram:
 
         return shard_grad
 
+    def _key_parts(self):
+        """What a mesh step's cache key holds beside the program's version
+        and the call's signature: everything here that changes the trace."""
+        return (id(self._mesh), self._data_axis, self._zero_stage(),
+                self._remat_spec().token, self._seq_axis)
+
     def _make_step(self, fetch_names, out_state_names):
-        """The pure (state, feed, key) -> (fetches, new_state, key) step —
-        shared by _build and Executor.run_batched's scan carry."""
-        block = self._program.global_block()
-        mesh = self._mesh
-        data_axis = self._data_axis
-        amp = getattr(self._program, "_amp", None)
-        remat_spec = self._remat_spec()
-        shard_grad = self._grad_shard_fn()
-        pads = self._zero_pad_map()
+        """The pure step (`executor._make_step`) with what the mesh adds to
+        it — shared by _build and Executor._run_scan's scan carry."""
         # stage3 (FSDP): re-assert each sharded parameter's dp layout INSIDE
         # the step. in_shardings only pins the boundary; the constraint keeps
         # the resident value sharded so every USE becomes an all-gather that
@@ -487,42 +481,12 @@ class CompiledProgram:
                 if v.persistable and self._fsdp_param(v):
                     pspec = self._zero_pspec(v)
                     if pspec is not None:
-                        fsdp_sh[v.name] = NamedSharding(mesh, pspec)
-
-        def step(state, feed, key):
-            env = dict(state)
-            # padded-boundary leaves: drop the pad rows before any op sees
-            # the value (ops run on the logical shape; GSPMD keeps the
-            # slice sharded — uneven tiles are legal INSIDE the program)
-            for n, (d, _dpad) in pads.items():
-                if n in env and env[n].shape[0] != d:
-                    env[n] = jax.lax.slice_in_dim(env[n], 0, d, axis=0)
-            for n, sh in fsdp_sh.items():
-                if n in env:
-                    env[n] = jax.lax.with_sharding_constraint(env[n], sh)
-            env.update(feed)
-            ctx = ExecContext(key, mesh=mesh, amp=amp,
-                              remat=remat_spec.op_set,
-                              remat_units=remat_spec,
-                              shard_grad=shard_grad,
-                              data_axis=data_axis)
-            _run_block(block, env, ctx)
-            fetches = [env[n] for n in fetch_names]
-            new_state = {}
-            for n in out_state_names:
-                if n not in env:
-                    continue
-                v = env[n]
-                pad = pads.get(n)
-                if pad is not None and v.shape[0] == pad[0]:
-                    v = jnp.pad(v, [(0, pad[1] - pad[0])]
-                                + [(0, 0)] * (v.ndim - 1))
-                new_state[n] = v
-            return fetches, new_state, ctx.final_key()
-
-        # as on the plain path: the compile cache hashes the name
-        step.__name__ = _scopes.scheme_name("step", self._program)
-        return step
+                        fsdp_sh[v.name] = NamedSharding(self._mesh, pspec)
+        return _make_step(
+            self._program, fetch_names, out_state_names, self._remat_spec(),
+            mesh=self._mesh, data_axis=self._data_axis,
+            shard_grad=self._grad_shard_fn(), pads=self._zero_pad_map(),
+            fsdp_sh=fsdp_sh)
 
     def _build(self, feed_names, fetch_names, state_names, out_state_names,
                feed_ndims=None):
@@ -545,88 +509,9 @@ class CompiledProgram:
             donate_argnums=(0,),
         ))
 
-    # -- execution (called by Executor.run) --------------------------------
-    def _run(self, exe, feed, fetch_list, scope, return_numpy):
-        """One step under the mesh. Called inside `Executor.run`'s
-        `executor/step` span, and records the same children as the plain
-        path: executor/feed, executor/state_in, compiled_program/run,
-        executor/telemetry, executor/state_out, executor/fetch."""
-        from .scope import _scope
-
-        with trace_span("executor/feed"):
-            if self._mesh is None:
-                self.with_data_parallel()
-            program = self._program
-            feed = feed or {}
-            fetch_list = list(fetch_list or [])
-            scope = scope or _scope()
-            fetch_names = [f.name if isinstance(f, Variable) else f
-                           for f in fetch_list]
-            multiproc = jax.process_count() > 1
-            feed_vals = self._convert_feeds(program.global_block(), feed,
-                                            multiproc)
-            feed_sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
-                                    for n, v in feed_vals.items()))
-            sig = _sig_digest(feed_sig)
-
-        with trace_span("executor/state_in"):
-            state_names = sorted(
-                v.name for v in program.list_vars()
-                if v.persistable and scope.has_var(v.name))
-            out_state_names = sorted({v.name for v in program.list_vars()
-                                      if v.persistable})
-            key_sig = (program._version, feed_sig, tuple(fetch_names),
-                       tuple(state_names),
-                       self._remat_spec().token,
-                       self._zero_stage(),
-                       id(self._mesh), self._data_axis,
-                       getattr(self, "_seq_axis", None))
-            fn = self._cache.get(key_sig)
-            compiling = fn is None
-            if compiling:
-                _CACHE_MISSES.inc()
-                wd_key = (id(self._program), program._version, "mesh",
-                          tuple(fetch_names))
-                if _WATCHDOG.record_compile(
-                        wd_key, feed_sig,
-                        label=f"CompiledProgram 0x{id(self._program):x}"):
-                    weakref.finalize(self._program, _WATCHDOG.forget, wd_key)
-                fn = self._build(
-                    sorted(feed_vals), fetch_names, state_names,
-                    out_state_names,
-                    {n: np.asarray(v).ndim if not isinstance(v, jax.Array)
-                     else v.ndim for n, v in feed_vals.items()})
-                self._cache[key_sig] = fn
-            else:
-                _CACHE_HITS.inc()
-            state, key = self._state_in(program, scope, state_names,
-                                        multiproc)
-
-        from ..observability.flight import get_flight_recorder
-        with get_flight_recorder().guard(
-                "CompiledProgram._run",
-                program=f"0x{id(self._program):x}",
-                sig=sig, compiling=compiling), \
-                trace_span("compiled_program/compile+run" if compiling
-                           else "compiled_program/run", sig=sig) as call:
-            fetches, new_state, new_key = fn(state, feed_vals, key)
-        dt_ms = call.dur_ms
-
-        with trace_span("executor/telemetry"):    # the instrument, timed
-            # on a compile also the state footprint, once per signature: the
-            # number ShardingStrategy shrinks
-            _record_dispatch(program, sig, fn, dt_ms, compiling,
-                             feed=feed_vals, new_state=new_state)
-        with trace_span("executor/state_out"):
-            for n, v in new_state.items():
-                scope.set_var(n, v)
-            scope.set_var(_RNG_STATE, new_key)
-        if return_numpy:
-            with trace_span("executor/fetch"):    # waits for the device
-                return [np.asarray(f) for f in fetches]
-        return list(fetches)
-
-    def _convert_feeds(self, block, feed, multiproc):
+    # -- what Executor.run asks of a mesh step's owner ----------------------
+    def _convert_feeds(self, block, feed):
+        multiproc = jax.process_count() > 1
         feed_vals = {}
         for name, val in feed.items():
             var = block._find_var_recursive(name)
@@ -649,12 +534,12 @@ class CompiledProgram:
                 feed_vals[name] = jax.make_array_from_process_local_data(
                     self._feed_sharding(local.ndim), local)
             else:
-                from .executor import convert_feed_value
                 feed_vals[name] = convert_feed_value(block, name, val)
         return feed_vals
 
-    def _state_in(self, program, scope, state_names, multiproc):
+    def _state_in(self, program, scope, state_names):
         """The state leaves in their compiled layout, and the RNG key."""
+        multiproc = jax.process_count() > 1
         pads = self._zero_pad_map()
         state = {}
         for n in state_names:
@@ -692,7 +577,6 @@ class CompiledProgram:
                 state[n] = v
         key = scope.find_var(_RNG_STATE)
         if key is None:
-            from .executor import _make_key
             key = _make_key(program.random_seed or 0)
         if multiproc and not (isinstance(key, jax.Array)
                               and len(key.sharding.device_set) > 1):

@@ -80,8 +80,8 @@ def _avals_of(args):
 
 def _record_dispatch(program, sig, fn, dt_ms, compiling, *, feed, avals=None,
                      steps=1, new_state=None):
-    """Record one dispatch of the jitted step `fn`: what `_run_program`,
-    `CompiledProgram._run` and `_run_scan` call inside `executor/telemetry`.
+    """Record one dispatch of the jitted step `fn`: what `Executor.run` and
+    `_run_scan` call inside `executor/telemetry`.
 
     `dt_ms` is the time of the jitted call, which on an accelerator returns
     when the step is enqueued: there `executor/execute_ms` and
@@ -1131,6 +1131,54 @@ def _run_block(block: Block, env: Dict[str, object], ctx: ExecContext):
     flush()
 
 
+def _make_step(program, fetch_names, out_state_names, remat_spec, mesh=None,
+               data_axis=None, shard_grad=None, pads=None, fsdp_sh=None):
+    """The pure (state, feed, key) -> (fetches, new_state, key) step of
+    `program`: what `Executor._build` and `CompiledProgram._build` jit and
+    what `_run_scan` carries through its scan. `remat_spec` is the resolved
+    RematSpec of whoever builds the step; the rest is the mesh's and stays
+    empty without one (`CompiledProgram._make_step` fills it in): `pads`
+    {name: (logical_dim0, padded_dim0)} and `fsdp_sh` {name: sharding}."""
+    block = program.global_block()
+    amp = getattr(program, "_amp", None)
+    pads = pads or {}
+    fsdp_sh = fsdp_sh or {}
+
+    def step(state, feed, key):
+        env = dict(state)
+        # padded-boundary leaves: drop the pad rows before any op sees
+        # the value (ops run on the logical shape; GSPMD keeps the
+        # slice sharded — uneven tiles are legal INSIDE the program)
+        for n, (d, _dpad) in pads.items():
+            if n in env and env[n].shape[0] != d:
+                env[n] = jax.lax.slice_in_dim(env[n], 0, d, axis=0)
+        for n, sh in fsdp_sh.items():
+            if n in env:
+                env[n] = jax.lax.with_sharding_constraint(env[n], sh)
+        env.update(feed)
+        ctx = ExecContext(key, mesh=mesh, amp=amp, remat=remat_spec.op_set,
+                          remat_units=remat_spec, shard_grad=shard_grad,
+                          data_axis=data_axis)
+        _run_block(block, env, ctx)
+        fetches = [env[n] for n in fetch_names]
+        new_state = {}
+        for n in out_state_names:
+            if n not in env:
+                continue
+            v = env[n]
+            pad = pads.get(n)
+            if pad is not None and v.shape[0] == pad[0]:
+                v = jnp.pad(v, [(0, pad[1] - pad[0])]
+                            + [(0, 0)] * (v.ndim - 1))
+            new_state[n] = v
+        return fetches, new_state, ctx.final_key()
+
+    # the compile cache hashes the step's name and not its metadata: the
+    # name carries the names the lowering will write
+    step.__name__ = _scopes.scheme_name("step", program)
+    return step
+
+
 class _Step:
     """What `Executor.compiled_step` and `scopes.hottest_step` need of a
     step: how often it was dispatched, and its executable. A jitted function
@@ -1427,39 +1475,44 @@ class Executor:
                                    chain_sizes, names)
         return names
 
+    # the plain path's answers to what `run` asks of whoever owns the step;
+    # a CompiledProgram gives the mesh's under the same names
+    def _convert_feeds(self, block, feed):
+        return {name: convert_feed_value(block, name, val)
+                for name, val in feed.items()}
+
+    def _state_in(self, program, scope, state_names):
+        """The state leaves as device arrays, and the RNG key."""
+        state = {n: scope.find_var(n) for n in state_names}
+        key = scope.find_var(_RNG_STATE)
+        if key is None:
+            key = _make_key(program.random_seed or 0)
+        # a scope that last ran through a ZeRO-padded CompiledProgram
+        # boundary holds some leaves padded past their declared shape —
+        # slice the pad off before tracing the unsharded step
+        zero_pads = getattr(program, "_zero_padded", None)
+        if zero_pads:
+            for n, shp in zero_pads.items():
+                v = state.get(n)
+                if (v is not None and shp and getattr(v, "shape", None)
+                        and tuple(v.shape) != tuple(shp)
+                        and v.shape[0] > shp[0]):
+                    state[n] = jnp.asarray(v)[:shp[0]]
+        state = {n: (v if isinstance(v, jax.Array) else jnp.asarray(v))
+                 for n, v in state.items()}
+        return state, key
+
+    def _make_step(self, program, fetch_names, out_state_names):
+        # without a CompiledProgram's strategies, what the program's builder
+        # asked for its own remat units (`program.remat_policy`)
+        from .compiler import resolve_remat
+        return _make_step(program, fetch_names, out_state_names,
+                          resolve_remat(program=program))
+
     def _build(self, program: Program, feed_names, fetch_names, state_names,
                out_state_names):
-        block = program.global_block()
-        amp = getattr(program, "_amp", None)
-        # PDTPU_REMAT_OPS="batch_norm,relu" — selective op-level
-        # jax.checkpoint on the plain-Executor path (the CompiledProgram
-        # path takes the same knob through BuildStrategy.remat);
-        # PDTPU_REMAT_POLICY="minimal"|"full" maps onto the policy surface
-        # (remat units included) for scripts without a CompiledProgram;
-        # without either, `program.remat_policy` is taken (resolve_remat)
-        import os as _os
-        from .compiler import resolve_remat
-        remat_env = _os.environ.get("PDTPU_REMAT_OPS", "")
-        legacy = (True if remat_env == "1"
-                  else frozenset(t for t in remat_env.split(",") if t)
-                  if remat_env else False)
-        spec = resolve_remat(_os.environ.get("PDTPU_REMAT_POLICY") or None,
-                             legacy, program=program)
-
-        def step(state, feed, key):
-            env = dict(state)
-            env.update(feed)
-            ctx = ExecContext(key, amp=amp, remat=spec.op_set,
-                              remat_units=spec)
-            _run_block(block, env, ctx)
-            fetches = [env[n] for n in fetch_names]
-            new_state = {n: env[n] for n in out_state_names if n in env}
-            return fetches, new_state, ctx.final_key()
-
-        # the compile cache hashes the step's name and not its metadata: the
-        # name carries the names the lowering will write
-        step.__name__ = _scopes.scheme_name("step", program)
-        return _AutoLayoutStep(step)
+        return _AutoLayoutStep(
+            self._make_step(program, fetch_names, out_state_names))
 
     def compiled_step(self, program=None):
         """The compiled executable (`jax.stages.Compiled`) behind
@@ -1499,143 +1552,125 @@ class Executor:
         from .compiler import CompiledProgram
 
         # one root span per call, carrying the step's ordinal; its children
-        # (the same names on the mesh path, in CompiledProgram._run) are the
-        # phases of the call: feed, state_in, run, telemetry, state_out,
-        # epilogue, fetch
+        # are the phases of the call: feed, state_in, run, telemetry,
+        # state_out, epilogue, fetch. One sequence for a Program and a
+        # CompiledProgram: `owner` answers for what a mesh changes (feed and
+        # state placement, the key, the jit and its cache), and the call's
+        # span and sites keep the name of the path
         with step_span("executor/step", next(_STEP_ORDINAL)):
-            if isinstance(program, CompiledProgram):
-                return self._run_compiled(program, feed, fetch_list, scope,
-                                          return_numpy, return_handle)
-            return self._run_program(program, feed, fetch_list, scope,
-                                     return_numpy, return_handle)
+            with trace_span("executor/feed"):
+                compiled = (program if isinstance(program, CompiledProgram)
+                            else None)
+                if compiled is None:
+                    owner, site, span = self, "Executor.run", "executor/"
+                    kind, wd_tag, key_parts = "Executor program", (), ()
+                    program = program or default_main_program()
+                else:
+                    owner, site, span = (compiled, "CompiledProgram._run",
+                                         "compiled_program/")
+                    kind, wd_tag = "CompiledProgram", ("mesh",)
+                    if compiled._mesh is None:
+                        compiled.with_data_parallel()
+                    key_parts = compiled._key_parts()
+                    program = compiled._program
+                feed = feed or {}
+                fetch_list = list(fetch_list or [])
+                scope = scope or _scope()
+                fetch_names = [f.name if isinstance(f, Variable) else f
+                               for f in fetch_list]
+                feed_vals = owner._convert_feeds(program.global_block(), feed)
+                feed_sig = feed_signature(feed_vals)
+                sig = _sig_digest(feed_sig)
 
-    def _run_compiled(self, program, feed, fetch_list, scope, return_numpy,
-                      return_handle):
-        # chaos probe: one hit per training-step dispatch — a spec
-        # like exec.dispatch:crash@7 kills exactly step 7's dispatch
-        fault_point("exec.dispatch")
-        out = program._run(self, feed, fetch_list, scope,
-                           return_numpy and not return_handle)
-        # maintenance epilogues must fire under the mesh too — the
-        # deferred-row fold is cadence-critical (the append log
-        # overflows silently if it never runs)
-        with trace_span("executor/epilogue"):
-            self._advance_epilogues(program._program, scope or _scope(), 1,
-                                    compiled=program)
-        if return_handle:
-            names = [f.name if isinstance(f, Variable) else f
-                     for f in (fetch_list or [])]
-            return FetchHandle(names, out)
-        return out
+            with trace_span("executor/state_in"):
+                state_names = self._state_names(program, scope)
+                out_state_names = sorted({v.name for v in program.list_vars()
+                                          if v.persistable})
+                key_sig = (id(program), program._version, feed_sig,
+                           tuple(fetch_names), tuple(state_names), *key_parts)
+                fn = owner._cache.get(key_sig)
+                compiling = fn is None
+                if compiling:
+                    _CACHE_MISSES.inc()
+                    # every cache miss is one XLA trace+compile: count it per
+                    # program and let the watchdog diagnose shape-churn storms
+                    wd_key = (id(program), program._version, *wd_tag,
+                              tuple(fetch_names))
+                    if _WATCHDOG.record_compile(
+                            wd_key, feed_sig,
+                            label=f"{kind} 0x{id(program):x}"):
+                        weakref.finalize(program, _WATCHDOG.forget, wd_key)
+                    if compiled is None:
+                        fn = self._build(program, sorted(feed_vals),
+                                         fetch_names, state_names,
+                                         out_state_names)
+                    else:
+                        fn = compiled._build(
+                            sorted(feed_vals), fetch_names, state_names,
+                            out_state_names,
+                            {n: np.ndim(v) for n, v in feed_vals.items()})
+                    owner._cache[key_sig] = fn
+                else:
+                    _CACHE_HITS.inc()
+                state, key = owner._state_in(program, scope, state_names)
 
-    def _run_program(self, program, feed, fetch_list, scope, return_numpy,
-                     return_handle):
-        with trace_span("executor/feed"):
-            program = program or default_main_program()
-            feed = feed or {}
-            fetch_list = list(fetch_list or [])
-            scope = scope or _scope()
-            fetch_names = [f.name if isinstance(f, Variable) else f
-                           for f in fetch_list]
-            block = program.global_block()
-            feed_vals = {name: convert_feed_value(block, name, val)
-                         for name, val in feed.items()}
-            feed_sig = feed_signature(feed_vals)
-            sig = _sig_digest(feed_sig)
+            with _FLIGHT.guard(site, program=f"0x{id(program):x}", sig=sig,
+                               compiling=compiling), \
+                    trace_span(span + ("compile+run" if compiling else "run"),
+                               sig=sig) as call:
+                # chaos probe: one hit per training-step dispatch
+                # (exec.dispatch:crash@7 kills exactly step 7). Inside the
+                # timed region on purpose — a delay_ms fault here IS a slow
+                # step, so the StepProfiler's straggler detector must see it
+                fault_point("exec.dispatch")
+                fetches, new_state, new_key = fn(state, feed_vals, key)
+            dt_ms = call.dur_ms
 
-        with trace_span("executor/state_in"):
-            state_names = self._state_names(program, scope)
-            out_state_names = sorted({v.name for v in program.list_vars()
-                                      if v.persistable})
-            key_sig = (id(program), program._version, feed_sig,
-                       tuple(fetch_names), tuple(state_names))
-            fn = self._cache.get(key_sig)
-            compiling = fn is None
-            if compiling:
-                _CACHE_MISSES.inc()
-                # every cache miss is one XLA trace+compile: count it per
-                # program and let the watchdog diagnose shape-churn storms
-                if _WATCHDOG.record_compile(
-                        (id(program), program._version, tuple(fetch_names)),
-                        feed_sig, label=f"Executor program 0x{id(program):x}"):
-                    weakref.finalize(
-                        program, _WATCHDOG.forget,
-                        (id(program), program._version, tuple(fetch_names)))
-                fn = self._build(program, sorted(feed_vals), fetch_names,
-                                 state_names, out_state_names)
-                self._cache[key_sig] = fn
-            else:
-                _CACHE_HITS.inc()
+            with trace_span("executor/telemetry"):    # the instrument, timed
+                # under a mesh, on a compile, also the state footprint, once
+                # per signature: the number ShardingStrategy shrinks
+                _record_dispatch(
+                    program, sig, fn, dt_ms, compiling, feed=feed_vals,
+                    new_state=new_state if compiled is not None else None)
 
-            state = {n: scope.find_var(n) for n in state_names}
-            key = scope.find_var(_RNG_STATE)
-            if key is None:
-                key = _make_key(program.random_seed or 0)
-            # a scope that last ran through a ZeRO-padded CompiledProgram
-            # boundary holds some leaves padded past their declared shape —
-            # slice the pad off before tracing the unsharded step
-            zero_pads = getattr(program, "_zero_padded", None)
-            if zero_pads:
-                for n, shp in zero_pads.items():
-                    v = state.get(n)
-                    if (v is not None and shp and getattr(v, "shape", None)
-                            and tuple(v.shape) != tuple(shp)
-                            and v.shape[0] > shp[0]):
-                        state[n] = jnp.asarray(v)[:shp[0]]
-            state = {n: (v if isinstance(v, jax.Array) else jnp.asarray(v))
-                     for n, v in state.items()}
+            with trace_span("executor/state_out"):
+                for n, v in new_state.items():
+                    scope.set_var(n, v)
+                scope.set_var(_RNG_STATE, new_key)
 
-        with _FLIGHT.guard("Executor.run", program=f"0x{id(program):x}",
-                           sig=sig, compiling=compiling), \
-                trace_span("executor/compile+run" if compiling
-                           else "executor/run", sig=sig) as call:
-            # chaos probe: one hit per training-step dispatch
-            # (exec.dispatch:crash@7 kills exactly step 7). Inside the
-            # timed region on purpose — a delay_ms fault here IS a slow
-            # step, so the StepProfiler's straggler detector must see it
-            fault_point("exec.dispatch")
-            fetches, new_state, new_key = fn(state, feed_vals, key)
-        dt_ms = call.dur_ms
+            with trace_span("executor/epilogue"):
+                # maintenance epilogues (e.g. the deferred-row fold program,
+                # optimizer.py _build_deferred_fold — pserver communicator-
+                # cadence analog): run attached programs every `every` runs
+                # of this program. Under the mesh too — the fold is cadence-
+                # critical (the append log overflows silently if it never
+                # runs)
+                self._advance_epilogues(program, scope, 1, compiled=compiled)
 
-        with trace_span("executor/telemetry"):    # the instrument, timed
-            _record_dispatch(program, sig, fn, dt_ms, compiling,
-                             feed=feed_vals)
+                from ..flags import flag
+                if flag("check_nan_inf"):
+                    # validate every fetched value and updated state var on
+                    # device; the host pays one scalar readback unless it
+                    # trips
+                    _check_finite(list(zip(fetch_names, fetches))
+                                  + list(new_state.items()))
 
-        with trace_span("executor/state_out"):
-            for n, v in new_state.items():
-                scope.set_var(n, v)
-            scope.set_var(_RNG_STATE, new_key)
-
-        with trace_span("executor/epilogue"):
-            # maintenance epilogues (e.g. the deferred-row fold program,
-            # optimizer.py _build_deferred_fold — pserver communicator-
-            # cadence analog): run attached programs every `every` runs of
-            # this program
-            self._advance_epilogues(program, scope, 1)
-
-            from ..flags import flag
-            if flag("check_nan_inf"):
-                # validate every fetched value and updated state var on
-                # device; the host pays one scalar readback unless it trips
-                _check_finite(list(zip(fetch_names, fetches))
-                              + list(new_state.items()))
-
-        if return_handle:
-            # fetch-less steps still need something to block on for
-            # in-flight bounding. Don't hold a new-state leaf directly:
-            # the NEXT step donates those buffers, which would invalidate
-            # the probe. A tiny dependent slice dispatched now lives in
-            # its own buffer and completes only after this step does.
-            probe = None
-            if not fetches:
-                leaf = next(iter(new_state.values()), None)
-                if leaf is not None:
-                    probe = jnp.ravel(leaf)[:1]
-            return FetchHandle(fetch_names, fetches, probe=probe)
-        if return_numpy:
-            with trace_span("executor/fetch"):    # waits for the device
-                return [np.asarray(f) for f in fetches]
-        return list(fetches)
+            if return_handle:
+                # fetch-less steps still need something to block on for
+                # in-flight bounding. Don't hold a new-state leaf directly:
+                # the NEXT step donates those buffers, which would invalidate
+                # the probe. A tiny dependent slice dispatched now lives in
+                # its own buffer and completes only after this step does.
+                probe = None
+                if not fetches:
+                    leaf = next(iter(new_state.values()), None)
+                    if leaf is not None:
+                        probe = jnp.ravel(leaf)[:1]
+                return FetchHandle(fetch_names, fetches, probe=probe)
+            if return_numpy:
+                with trace_span("executor/fetch"):    # waits for the device
+                    return [np.asarray(f) for f in fetches]
+            return list(fetches)
 
     def run_batched(
         self,
@@ -1745,11 +1780,7 @@ class Executor:
         stacked_sig = feed_signature(stacked)
         key_sig = (id(program), program._version, n,
                    stacked_sig, tuple(fetch_names),
-                   (id(compiled._mesh), compiled._data_axis,
-                    compiled._zero_stage(),
-                    compiled._remat_spec().token,
-                    getattr(compiled, "_seq_axis", None))
-                   if compiled is not None else None)
+                   compiled._key_parts() if compiled is not None else None)
         fn = self._cache.get(key_sig)
         compiling = fn is None
         if compiling:
@@ -1763,12 +1794,9 @@ class Executor:
                     program, _WATCHDOG.forget,
                     (id(program), program._version, "batched",
                      tuple(fetch_names)))
-            if compiled is not None:
-                raw_step = compiled._make_step(fetch_names, state_names)
-            else:
-                inner = self._build(program, keys, fetch_names,
-                                    state_names, state_names)
-                raw_step = inner._step
+            raw_step = (self._make_step(program, fetch_names, state_names)
+                        if compiled is None
+                        else compiled._make_step(fetch_names, state_names))
 
             def scan_fn(state, feeds, key):
                 def body(carry, feed):
@@ -1918,8 +1946,7 @@ class Executor:
                 # same id-reuse hazard as the fold counters: drop the
                 # compiled epilogue when its program dies
                 weakref.finalize(eprog, cache.pop, id(eprog), None)
-            cp._run(self, {}, [], scope, False)
-            return
+            eprog = cp
         self.run(eprog, scope=scope, return_numpy=False)
 
     def _advance_epilogues(self, program, scope, steps: int, compiled=None):

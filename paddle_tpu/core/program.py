@@ -268,8 +268,8 @@ class Program:
         self._appended_backward = False
         # what the builder asks for its `unit(..., remat=True)` blocks:
         # None | "none" | "minimal" | "full" | unit_name -> bool|"minimal"|
-        # "full". Taken where neither BuildStrategy.remat_policy nor
-        # PDTPU_REMAT_POLICY gives one (compiler.resolve_remat)
+        # "full". Taken where no BuildStrategy.remat_policy or
+        # DistributedStrategy.remat_policy gives one (compiler.resolve_remat)
         self.remat_policy = None
         # what those blocks keep for the backward pass under that policy
         # (`keep`): remat block path -> the names of the values kept. Taken

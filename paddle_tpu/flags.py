@@ -58,7 +58,7 @@ def flag(key: str):
 
 
 # runtime knobs also honored under their PDTPU_* spelling (the env names
-# documented alongside PDTPU_FUSE_UPDATES / PDTPU_REMAT_OPS)
+# documented alongside PDTPU_FUSE_UPDATES)
 _ENV_ALIASES = {
     "PDTPU_MAX_INFLIGHT_STEPS": "max_inflight_steps",
 }

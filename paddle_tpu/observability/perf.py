@@ -1,7 +1,7 @@
 """Perf-attribution ledger: per-(program, signature) cost accounting.
 
-At compile time the dispatch sites (`Executor.run`,
-`Executor.run_batched`/`train_scanned`, `CompiledProgram._run`, through
+At compile time the dispatch sites (`Executor.run`, with or without a
+mesh, and `Executor.run_batched`/`train_scanned`, through
 `core.executor._record_dispatch`; `Predictor.run`) register what one
 dispatch of the executable costs, in extraction-preference order:
 

@@ -28,7 +28,7 @@ Design (orbax-style, self-contained):
   the failure with the step and path;
 - bundles store plain host arrays, so `restore` works under ANY mesh: the
   compiler lifts host values into whatever sharding the new topology
-  declares (CompiledProgram._run), which is what makes checkpoints
+  declares (CompiledProgram._state_in), which is what makes checkpoints
   reshardable across dp/tp splits.
 
 Crash-consistency is testable, not aspirational: ``paddle_tpu.faults``

@@ -339,22 +339,29 @@ def test_a_span_whose_body_raises_still_closes_and_reads_its_duration():
     assert quiet.dur_ms >= 0 and len(tracer.spans()) == 2
 
 
-PHASES = ["executor/feed", "executor/state_in", "executor/telemetry",
-          "executor/state_out", "executor/epilogue"]
-
-
-@pytest.mark.parametrize("path,call", [
+# `Executor.run` is one sequence for a Program and a CompiledProgram: what
+# follows holds on both paths, the jitted call under its own span's name
+PATHS = pytest.mark.parametrize("path,call", [
     ("plain", "executor/run"), ("mesh", "compiled_program/run")])
-def test_a_run_records_its_phases_under_one_step_span(path, call):
+
+
+def _on_path(path, main):
     import jax
 
     import paddle_tpu as fluid
 
-    main, startup, y = _tiny_program()
-    program = main
     if path == "mesh":
-        program = fluid.CompiledProgram(main).with_data_parallel(
+        return fluid.CompiledProgram(main).with_data_parallel(
             places=jax.devices()[:2])
+    return main
+
+
+@PATHS
+def test_a_run_records_its_phases_under_one_step_span(path, call):
+    import paddle_tpu as fluid
+
+    main, startup, y = _tiny_program()
+    program = _on_path(path, main)
     exe = fluid.Executor(fluid.TPUPlace())
     exe.run(startup)
     feed = {"x": np.zeros((2, 3), np.float32)}
@@ -367,8 +374,10 @@ def test_a_run_records_its_phases_under_one_step_span(path, call):
     assert ordinals == list(range(ordinals[0], ordinals[0] + 4))
     last = roots[-1]
     children = [s for s in spans if s["parent"] == last]
-    # the same child names on both paths, the jitted call under its own
-    assert sorted(s["name"] for s in children) == sorted(PHASES + [call])
+    # the same children in the same order on both paths
+    assert [s["name"] for s in children] == [
+        "executor/feed", "executor/state_in", call, "executor/telemetry",
+        "executor/state_out", "executor/epilogue"]
     assert sum(s["dur"] for s in children) <= spans[last]["dur"]
     assert spans[last]["self"] == pytest.approx(
         spans[last]["dur"] - sum(s["dur"] for s in children))
@@ -381,6 +390,86 @@ def test_a_run_records_its_phases_under_one_step_span(path, call):
     assert spans[fetch["parent"]]["name"] == "executor/step"
     assert fetch["parent"] == max(i for i, s in enumerate(spans)
                                   if s["name"] == "executor/step")
+
+
+@PATHS
+def test_check_nan_inf_raises_on_a_fetch_that_is_not_finite(path, call):
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        y = fluid.layers.log(fluid.layers.data("x", [2]))   # log(-1) = nan
+    exe = fluid.Executor(fluid.TPUPlace())
+    fluid.set_flags({"FLAGS_check_nan_inf": True})
+    try:
+        with pytest.raises(FloatingPointError):
+            exe.run(_on_path(path, main), fetch_list=[y],
+                    feed={"x": -np.ones((2, 2), np.float32)})
+    finally:
+        fluid.set_flags({"FLAGS_check_nan_inf": False})
+
+
+@PATHS
+def test_a_fetchless_step_hands_back_a_handle_to_block_on(path, call):
+    import paddle_tpu as fluid
+
+    main, startup, _ = _tiny_program()
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(startup)
+    handle = exe.run(_on_path(path, main), return_handle=True,
+                     feed={"x": np.zeros((2, 3), np.float32)})
+    # nothing fetched: a probe cut from the new state is what the in-flight
+    # bound waits on
+    assert handle.names == [] and handle.numpy() == []
+    assert handle._probe is not None
+    handle.block_until_ready()
+
+
+@PATHS
+def test_a_delay_injected_at_dispatch_is_inside_the_call_s_span(path, call):
+    import paddle_tpu as fluid
+    from paddle_tpu import faults
+
+    main, startup, y = _tiny_program()
+    program = _on_path(path, main)
+    exe = fluid.Executor(fluid.TPUPlace())
+    exe.run(startup)
+    feed = {"x": np.zeros((2, 3), np.float32)}
+    exe.run(program, feed=feed, fetch_list=[y])
+    faults.install("exec.dispatch", "delay_ms", 60)
+    try:
+        exe.run(program, feed=feed, fetch_list=[y])
+    finally:
+        faults.clear()
+    # a slow dispatch is a slow step: the straggler detector reads this time
+    slow = [s for s in obs.get_tracer().spans() if s["name"] == call][-1]
+    assert slow["dur"] >= 60e3
+
+
+@PATHS
+def test_a_second_step_takes_its_state_names_from_the_cached_entry(
+        path, call, monkeypatch):
+    import paddle_tpu as fluid
+
+    main, startup, y = _tiny_program()
+    program = _on_path(path, main)
+    exe, scope = fluid.Executor(fluid.TPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = {"x": np.zeros((2, 3), np.float32)}
+    exe.run(program, feed=feed, fetch_list=[y], scope=scope)
+    cached = exe._state_names_cache
+    assert cached[0] is main and cached[2] is scope and cached[4]
+    asked = []
+    has_var = fluid.Scope.has_var
+    monkeypatch.setattr(fluid.Scope, "has_var", lambda self, name: (
+        asked.append(name), has_var(self, name))[1])
+    exe.run(program, feed=feed, fetch_list=[y], scope=scope)
+    # program and scope unchanged: no walk over the program's variables
+    assert asked == [] and exe._state_names_cache is cached
+    # a variable added to the scope's key set makes the entry anew
+    scope.set_var("another", np.zeros(1, np.float32))
+    exe.run(program, feed=feed, fetch_list=[y], scope=scope)
+    assert asked and exe._state_names_cache is not cached
 
 
 # -- timeline CLI ----------------------------------------------------------

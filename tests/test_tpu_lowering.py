@@ -8,9 +8,13 @@ sparse-Adagrad row kernels removed in PR 21 failed exactly this check from
 the day they were written). It is NOT a substitute for the chip: Mosaic
 itself does not run, so VMEM limits, tiling and numerics are only proven by
 ``chip_smoke.py``, whose kernel cases (cut to batch 2) this test reuses.
-"""
-import importlib
 
+The second half compiles for a described v5e with the TPU's own compiler
+(`one_chip`): the kernels at the cells' widths and the small steps here, the
+experts' grouped product in `test_tpu_lowering_experts.py` and the cells'
+whole steps in `test_tpu_lowering_steps.py`, each a file of its own because
+under `--dist loadfile` a file is one worker's.
+"""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,20 +22,8 @@ import pytest
 
 import chip_smoke
 import paddle_tpu as fluid
-from paddle_tpu.ops.pallas_kernels import fused_bn
-
-fa = importlib.import_module("paddle_tpu.ops.pallas_kernels.flash_attention")
-ssd = importlib.import_module("paddle_tpu.ops.pallas_kernels.ssd_scan")
-gffn = importlib.import_module("paddle_tpu.ops.pallas_kernels.grouped_ffn")
-
-
-@pytest.fixture(autouse=True)
-def _dispatch_as_on_tpu(monkeypatch):
-    """Take the dispatch decisions a TPU host would take."""
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(fused_bn, "_on_tpu", lambda: True)
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gffn, "_on_tpu", lambda: True)
+from tpu_lowering_base import (_dispatch_as_on_tpu, fa,  # noqa: F401
+                               one_chip, ssd)
 
 
 def _lower_for_tpu(fn, *args):
@@ -156,18 +148,6 @@ def test_data_parallel_ssd_scan_lowers_for_tpu():
     assert lowered() > before
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 @pytest.mark.parametrize(
     "n, h, v, x_dtype, bias, chunks",
     [(64 * 512, 768, 30522, jnp.bfloat16, True, (1024, 1024)),
@@ -233,51 +213,6 @@ def test_gqa_flash_attention_compiles_for_v5e_at_nemotron_width(one_chip):
     # nothing the size of k or v repeated for all 32 query heads is an
     # operand of a kernel: the kernels' k/v operands are [B*2, T, 128]
     assert f"bf16[{b * hkv},{t},{d}]" in text
-
-
-@pytest.mark.parametrize("d,h,e,held,k,gated,act", [
-    (2688, 1856, 128, 8, 6, False, "relu2"),
-    (2048, 1536, 64, 8, 4, True, "silu"),
-    (2048, 768, 256, 16, 8, True, "silu"),
-    (2048, 512, 256, 32, 8, True, "silu"),
-], ids=["nemotron", "lfm2", "joyai", "laguna"])
-def test_grouped_expert_product_compiles_for_v5e_at_the_cells_widths(
-        one_chip, d, h, e, held, k, gated, act):
-    """The routed experts of one block of each expert cell (16,384 tokens of
-    b2 x T8192, bf16 activations over float32 masters; Nemotron's plain
-    relu^2 experts of 2688 x 1856, 14.5 lane tiles wide, and the three gated
-    shapes), loss and gradients, through the TPU's own compiler: the two
-    kernels and no loop over tiles, nothing of the size of all the pairs'
-    rows, and the kernels' own buffers (a float32 row a token forward, two
-    backward) within 0.9 GiB."""
-    from paddle_tpu.ops.common import act_map
-    from paddle_tpu.parallel import moe
-
-    n = 16384
-    assert moe.grouped_path(d, h, gated, jnp.bfloat16, moe.TILE) == "pallas"
-
-    def loss(x, gate, w1, w2, w3=None):
-        out = moe.moe_ffn(x, gate, w1, None, w2, None, k=k,
-                          act=act_map()[act], experts_held=(0, held),
-                          scoring="sigmoid", routed_scaling=2.5, w3=w3)
-        return jnp.sum(out.y.astype(jnp.float32)), out.pairs_held
-
-    shapes = [((n, d), jnp.bfloat16), ((d, e), jnp.float32),
-              ((held, d, h), jnp.float32), ((held, h, d), jnp.float32)]
-    if gated:
-        shapes.append(((held, d, h), jnp.float32))
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-            for shape, dtype in shapes]
-    compiled = jax.jit(jax.value_and_grad(
-        loss, tuple(range(len(args))), has_aux=True)).trace(*args).lower(
-            lowering_platforms=("tpu",)).compile()
-    text = compiled.as_text()
-    # the rows laid out and the walk, forward and backward
-    assert text.count("tpu_custom_call") == 4
-    # the dispatch's search for each tile's expert is the one loop left
-    assert text.count(" while(") == 1 and "searchsorted" in text
-    assert f"[{n * k},{d}]" not in text and f"[{n * k},{h}]" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.9 * 2 ** 30
 
 
 def _scan_structs(one_chip, b, t, h, p, g, n, dtype):
@@ -438,92 +373,6 @@ def test_gqa_flash_attention_compiles_for_v5e_at_lfm2_width(one_chip):
     assert f"bf16[{b * hkv},{t},{d}]" in text
 
 
-def _grouped_kernel_traces():
-    """A function that gives the binds of the experts' kernels by label
-    (`setup/kernel_traces{kernel}`) since this call, which also forgets the
-    bodies an earlier test of this process has traced."""
-    from paddle_tpu.observability import get_registry
-
-    def traced():
-        n = {}
-        for s in get_registry().series():
-            label = s["labels"].get("kernel", "")
-            if (s["name"] == "setup/kernel_traces"
-                    and label.startswith("grouped_ffn")):
-                n[label] = n.get(label, 0) + s["value"]
-        return n
-
-    for staged in (gffn._pack_rows, gffn._walk_forward,
-                   gffn._walk_backward):
-        staged.clear_cache()
-    before = traced()
-    return lambda: {k: v - before.get(k, 0) for k, v in traced().items()
-                    if v - before.get(k, 0)}
-
-
-def test_lfm2_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
-    """The whole training step of `lfm2_24b_a2b.train8k` (the configuration's
-    file and the traffic file as the benchmark reads them: 7 layers at the
-    published widths, 8 of 64 gated experts held, b2 x T8192, bf16 AMP, Adam,
-    remat blocks with what they keep) through the TPU's own compiler: it
-    fits a v5e's 15.75 GiB (12.19 GiB on the ledger before the experts'
-    kernels, PR 41), holds 12 bytes a parameter of state, calls the
-    attention kernels twice a layer and the experts' kernels in place of
-    their loops, and traces each of those once for the six layers."""
-    import json
-    import os
-
-    from benchmark.configs import lfm2_24b_a2b as adapter
-    traced = _grouped_kernel_traces()
-
-    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-    with open(os.path.join(root, "configs", "lfm2_24b_a2b.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "traffic", "train8k.json")) as f:
-        traffic = json.load(f)
-    system = adapter.build(cfg, traffic, 1)
-    b, t = traffic["batch"], traffic["seq_len"]
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
-                                          sharding=one_chip)
-             for v in system.startup.list_vars() if v.persistable}
-    params = sum(int(np.prod(s.shape)) for n, s in state.items()
-                 if "Optimizer" not in n and "corr_bias" not in n
-                 and n.startswith(("blk", "embed", "final_norm")))
-    assert params == 647_819_904 - 6 * 64
-    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
-            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
-                                           sharding=one_chip)}
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    names = sorted(state)
-    step = system.exe._build(system.main, sorted(feed),
-                             [v.name for v in system._fetch], names, names)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
-            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
-    m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    gib = 2 ** 30
-    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.3
-    assert 11.9 < live / gib < 12.9
-    # a body a kernel and shape, whatever the layers and whether a remat
-    # block traces the forward a second time: the forward walk and its rows,
-    # the backward walk and its rows (the cotangent beside the activations);
-    # 18 and 6 binds a layer at a time would be
-    assert traced() == {
-        "grouped_ffn_rows": 2, "grouped_ffn_fwd": 1, "grouped_ffn_bwd": 1}
-    text = compiled.as_text()
-    # two attention layers' forward and backward; six expert layers' rows
-    # laid out and walked, forward and backward (the forward made again
-    # behind the remat block has no reader: its residuals are the block's
-    # own inputs)
-    assert text.count("tpu_custom_call") == 2 * 2 + 6 * 4
-    # the head's two loops stay; no expert layer's loop over tiles does
-    assert text.count(" while(") >= 2
-    assert not [line for line in text.splitlines()
-                if " while(" in line and "/moe/experts" in line]
-
-
 def test_latent_attention_kernels_compile_for_v5e_at_joyai_width(one_chip):
     """The attention call of `joyai_llm_flash.train8k`: 32 heads of 192-wide
     queries and keys on 128-wide values, causal, T 8,192, bf16, forward and
@@ -550,69 +399,6 @@ def test_latent_attention_kernels_compile_for_v5e_at_joyai_width(one_chip):
     # the kernels' operands, heads folded: q and k 192 wide, v and out 128
     assert f"bf16[{b * h},{t},{d}]" in text
     assert f"bf16[{b * h},{t},{dv}]" in text
-
-
-def test_joyai_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
-    """The whole training step of `joyai_llm_flash.train8k` (the
-    configuration's file and the traffic file as the benchmark reads them:
-    the dense layer, four expert layers and the prediction module at the
-    published widths, 16 of 256 gated experts held beside a shared expert,
-    b2 x T8192, bf16 AMP, Adam, remat blocks with what they keep) through the
-    TPU's own compiler: it fits a v5e's 15.75 GiB, holds 12 bytes a
-    parameter of state with one slot each for the table and the head matrix,
-    calls the attention kernels twice a layer in six layers and the experts'
-    kernels in place of their loops, one traced walk for both dtypes of
-    expert input, and keeps the two heads' loops of dynamic length."""
-    import json
-    import os
-
-    from benchmark.configs import joyai_llm_flash as adapter
-
-    traced = _grouped_kernel_traces()
-    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-    with open(os.path.join(root, "configs", "joyai_llm_flash.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "traffic", "train8k.json")) as f:
-        traffic = json.load(f)
-    system = adapter.build(cfg, traffic, 1)
-    b, t = traffic["batch"], traffic["seq_len"]
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
-                                          sharding=one_chip)
-             for v in system.startup.list_vars() if v.persistable}
-    params = sum(int(np.prod(s.shape)) for n, s in state.items()
-                 if "Optimizer" not in n and "corr_bias" not in n
-                 and n.startswith(("blk", "embed", "final_norm", "lm_head",
-                                   "mtp")))
-    assert params == 680_439_808
-    assert sum(n.startswith(("embed.w_", "lm_head.w_")) and "moment" in n
-               for n in state) == 4
-    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
-            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
-                                           sharding=one_chip)}
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    names = sorted(state)
-    step = system.exe._build(system.main, sorted(feed),
-                             [v.name for v in system._fetch], names, names)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
-            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
-    m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    gib = 2 ** 30
-    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.7
-    assert 12.5 < live / gib < 13.5        # 12.87 on the ledger, PR 41
-    # the float32 stream's four expert layers and the bfloat16 prediction
-    # module's one share the walks (a row is float32 whatever x is); the
-    # rows are laid out by dtype, forward and backward
-    assert traced() == {
-        "grouped_ffn_rows": 4, "grouped_ffn_fwd": 1, "grouped_ffn_bwd": 1}
-    text = compiled.as_text()
-    # six attention calls forward and backward; five expert layers' rows
-    # laid out and walked, forward and backward
-    assert text.count("tpu_custom_call") == 6 * 2 + 5 * 4
-    # the two heads' two loops each stay
-    assert text.count(" while(") >= 2 * 2
 
 
 @pytest.mark.parametrize("hq,window,tiles", [(64, 512, 31), (48, None, 36)],
@@ -648,98 +434,3 @@ def test_laguna_attention_kernels_compile_for_v5e(one_chip, hq, window,
                  if s["name"] == "flash_attention/tiles_scheduled"
                  and s["labels"]["call"] == call}
     assert scheduled["fwd"] == scheduled["bwd"] == tiles
-
-
-def test_laguna_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
-    """The whole training step of `laguna_xs2.train8k` (the configuration's
-    file and the traffic file as the benchmark reads them: the dense layer
-    and one period of four at the published widths, 32 of 256 gated experts
-    held beside a shared expert, b2 x T8192, bf16 AMP, Adam, remat blocks
-    with what they keep) through the TPU's own compiler: it fits a v5e's
-    15.75 GiB, holds 12 bytes a parameter of state, calls the attention
-    kernels twice a layer in five layers and keeps the experts' and the
-    head's loops of dynamic length."""
-    import json
-    import os
-
-    from benchmark.configs import laguna_xs2 as adapter
-
-    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-    with open(os.path.join(root, "configs", "laguna_xs2.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "traffic", "train8k.json")) as f:
-        traffic = json.load(f)
-    system = adapter.build(cfg, traffic, 1)
-    b, t = traffic["batch"], traffic["seq_len"]
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
-                                          sharding=one_chip)
-             for v in system.startup.list_vars() if v.persistable}
-    params = sum(int(np.prod(s.shape)) for n, s in state.items()
-                 if "Optimizer" not in n
-                 and n.startswith(("blk", "embed", "final_norm", "lm_head")))
-    assert params == 691_623_936
-    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
-            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
-                                           sharding=one_chip)}
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    names = sorted(state)
-    step = system.exe._build(system.main, sorted(feed),
-                             [v.name for v in system._fetch], names, names)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
-            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
-    m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    gib = 2 ** 30
-    assert 12 * params / gib < m.argument_size_in_bytes / gib < 7.8
-    assert 13.2 < live / gib < 14.2        # 13.51 on the ledger, PR 41
-    text = compiled.as_text()
-    # five attention calls forward and backward; four expert layers' rows
-    # laid out and walked, forward and backward
-    assert text.count("tpu_custom_call") == 5 * 2 + 4 * 4
-    # the head's two loops stay
-    assert text.count(" while(") >= 2
-
-
-def test_nemotron_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
-    """The whole training step of `nemotron3_nano.train8k` through the TPU's
-    own compiler: it fits a v5e's 15.75 GiB (12.84 on the ledger before the
-    experts' kernels, PR 41), and its four expert layers' plain relu^2
-    experts of 2688 x 1856 run the kernels (W1 held turned: 1,856 is 14.5
-    lane tiles) beside the scan's and attention's."""
-    import json
-    import os
-
-    from benchmark.configs import nemotron3_nano as adapter
-
-    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
-    with open(os.path.join(root, "configs", "nemotron3_nano.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "traffic", "train8k.json")) as f:
-        traffic = json.load(f)
-    system = adapter.build(cfg, traffic, 1)
-    b, t = traffic["batch"], traffic["seq_len"]
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
-                                          sharding=one_chip)
-             for v in system.startup.list_vars() if v.persistable}
-    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
-            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
-                                           sharding=one_chip)}
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    names = sorted(state)
-    step = system.exe._build(system.main, sorted(feed),
-                             [v.name for v in system._fetch], names, names)
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
-            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
-    m = compiled.memory_analysis()
-    live = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
-    assert 12.4 < live / 2 ** 30 < 13.4     # 12.85 on the chip, PR 42
-    text = compiled.as_text()
-    # four expert layers: the rows laid out and walked, forward and backward
-    assert text.count("grouped_ffn_fwd") >= 4
-    assert text.count("grouped_ffn_bwd") >= 4
-    assert not [line for line in text.splitlines()
-                if " while(" in line and "/moe/experts" in line]

@@ -1,0 +1,190 @@
+"""The whole training step of each decoder cell, built from the configuration's
+file and the traffic file as the benchmark reads them, through the TPU's own
+compiler on a CPU-only host: that it fits a v5e's 15.75 GiB, what state it
+holds, and which kernels it calls. A file of its own beside
+`test_tpu_lowering.py`: under `--dist loadfile` a file is one worker's, and
+each of these compiles takes most of a minute or two. A `model_config` PR adds
+its cell's whole-step case here."""
+import importlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tpu_lowering_base import (_dispatch_as_on_tpu, gffn,  # noqa: F401
+                               one_chip)
+
+GIB = 2 ** 30
+
+
+def _compiled_cell_step(config, one_chip):
+    """The state's shapes and the compiled step of cell `config`.train8k."""
+    adapter = importlib.import_module(f"benchmark.configs.{config}")
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", f"{config}.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "traffic", "train8k.json")) as f:
+        traffic = json.load(f)
+    system = adapter.build(cfg, traffic, 1)
+    b, t = traffic["batch"], traffic["seq_len"]
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype,
+                                          sharding=one_chip)
+             for v in system.startup.list_vars() if v.persistable}
+    feed = {"ids": jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip),
+            "labels": jax.ShapeDtypeStruct((b, t, 1), jnp.int32,
+                                           sharding=one_chip)}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    names = sorted(state)
+    step = system.exe._build(system.main, sorted(feed),
+                             [v.name for v in system._fetch], names, names)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step._step, donate_argnums=(0,)).trace(
+            state, feed, key).lower(lowering_platforms=("tpu",)).compile()
+    return state, compiled
+
+
+def _live_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def _grouped_kernel_traces():
+    """A function that gives the binds of the experts' kernels by label
+    (`setup/kernel_traces{kernel}`) since this call, which also forgets the
+    bodies an earlier test of this process has traced."""
+    from paddle_tpu.observability import get_registry
+
+    def traced():
+        n = {}
+        for s in get_registry().series():
+            label = s["labels"].get("kernel", "")
+            if (s["name"] == "setup/kernel_traces"
+                    and label.startswith("grouped_ffn")):
+                n[label] = n.get(label, 0) + s["value"]
+        return n
+
+    for staged in (gffn._pack_rows, gffn._walk_forward,
+                   gffn._walk_backward):
+        staged.clear_cache()
+    before = traced()
+    return lambda: {k: v - before.get(k, 0) for k, v in traced().items()
+                    if v - before.get(k, 0)}
+
+
+def test_lfm2_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `lfm2_24b_a2b.train8k` (the configuration's
+    file and the traffic file as the benchmark reads them: 7 layers at the
+    published widths, 8 of 64 gated experts held, b2 x T8192, bf16 AMP, Adam,
+    remat blocks with what they keep) through the TPU's own compiler: it
+    fits a v5e's 15.75 GiB (12.19 GiB on the ledger before the experts'
+    kernels, PR 41), holds 12 bytes a parameter of state, calls the
+    attention kernels twice a layer and the experts' kernels in place of
+    their loops, and traces each of those once for the six layers."""
+    traced = _grouped_kernel_traces()
+    state, compiled = _compiled_cell_step("lfm2_24b_a2b", one_chip)
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n and "corr_bias" not in n
+                 and n.startswith(("blk", "embed", "final_norm")))
+    assert params == 647_819_904 - 6 * 64
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 12 * params / GIB < args / GIB < 7.3
+    assert 11.9 < _live_bytes(compiled) / GIB < 12.9
+    # a body a kernel and shape, whatever the layers and whether a remat
+    # block traces the forward a second time: the forward walk and its rows,
+    # the backward walk and its rows (the cotangent beside the activations);
+    # 18 and 6 binds a layer at a time would be
+    assert traced() == {
+        "grouped_ffn_rows": 2, "grouped_ffn_fwd": 1, "grouped_ffn_bwd": 1}
+    text = compiled.as_text()
+    # two attention layers' forward and backward; six expert layers' rows
+    # laid out and walked, forward and backward (the forward made again
+    # behind the remat block has no reader: its residuals are the block's
+    # own inputs)
+    assert text.count("tpu_custom_call") == 2 * 2 + 6 * 4
+    # the head's two loops stay; no expert layer's loop over tiles does
+    assert text.count(" while(") >= 2
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "/moe/experts" in line]
+
+
+def test_joyai_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `joyai_llm_flash.train8k` (the
+    configuration's file and the traffic file as the benchmark reads them:
+    the dense layer, four expert layers and the prediction module at the
+    published widths, 16 of 256 gated experts held beside a shared expert,
+    b2 x T8192, bf16 AMP, Adam, remat blocks with what they keep) through the
+    TPU's own compiler: it fits a v5e's 15.75 GiB, holds 12 bytes a
+    parameter of state with one slot each for the table and the head matrix,
+    calls the attention kernels twice a layer in six layers and the experts'
+    kernels in place of their loops, one traced walk for both dtypes of
+    expert input, and keeps the two heads' loops of dynamic length."""
+    traced = _grouped_kernel_traces()
+    state, compiled = _compiled_cell_step("joyai_llm_flash", one_chip)
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n and "corr_bias" not in n
+                 and n.startswith(("blk", "embed", "final_norm", "lm_head",
+                                   "mtp")))
+    assert params == 680_439_808
+    assert sum(n.startswith(("embed.w_", "lm_head.w_")) and "moment" in n
+               for n in state) == 4
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 12 * params / GIB < args / GIB < 7.7
+    # 12.87 on the ledger, PR 41
+    assert 12.5 < _live_bytes(compiled) / GIB < 13.5
+    # the float32 stream's four expert layers and the bfloat16 prediction
+    # module's one share the walks (a row is float32 whatever x is); the
+    # rows are laid out by dtype, forward and backward
+    assert traced() == {
+        "grouped_ffn_rows": 4, "grouped_ffn_fwd": 1, "grouped_ffn_bwd": 1}
+    text = compiled.as_text()
+    # six attention calls forward and backward; five expert layers' rows
+    # laid out and walked, forward and backward
+    assert text.count("tpu_custom_call") == 6 * 2 + 5 * 4
+    # the two heads' two loops each stay
+    assert text.count(" while(") >= 2 * 2
+
+
+def test_laguna_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `laguna_xs2.train8k` (the configuration's
+    file and the traffic file as the benchmark reads them: the dense layer
+    and one period of four at the published widths, 32 of 256 gated experts
+    held beside a shared expert, b2 x T8192, bf16 AMP, Adam, remat blocks
+    with what they keep) through the TPU's own compiler: it fits a v5e's
+    15.75 GiB, holds 12 bytes a parameter of state, calls the attention
+    kernels twice a layer in five layers and keeps the experts' and the
+    head's loops of dynamic length."""
+    state, compiled = _compiled_cell_step("laguna_xs2", one_chip)
+    params = sum(int(np.prod(s.shape)) for n, s in state.items()
+                 if "Optimizer" not in n
+                 and n.startswith(("blk", "embed", "final_norm", "lm_head")))
+    assert params == 691_623_936
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 12 * params / GIB < args / GIB < 7.8
+    # 13.51 on the ledger, PR 41
+    assert 13.2 < _live_bytes(compiled) / GIB < 14.2
+    text = compiled.as_text()
+    # five attention calls forward and backward; four expert layers' rows
+    # laid out and walked, forward and backward
+    assert text.count("tpu_custom_call") == 5 * 2 + 4 * 4
+    # the head's two loops stay
+    assert text.count(" while(") >= 2
+
+
+def test_nemotron_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
+    """The whole training step of `nemotron3_nano.train8k` through the TPU's
+    own compiler: it fits a v5e's 15.75 GiB (12.84 on the ledger before the
+    experts' kernels, PR 41), and its four expert layers' plain relu^2
+    experts of 2688 x 1856 run the kernels (W1 held turned: 1,856 is 14.5
+    lane tiles) beside the scan's and attention's."""
+    _, compiled = _compiled_cell_step("nemotron3_nano", one_chip)
+    # 12.85 on the chip, PR 42
+    assert 12.4 < _live_bytes(compiled) / GIB < 13.4
+    text = compiled.as_text()
+    # four expert layers: the rows laid out and walked, forward and backward
+    assert text.count("grouped_ffn_fwd") >= 4
+    assert text.count("grouped_ffn_bwd") >= 4
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "/moe/experts" in line]
