@@ -45,6 +45,10 @@ Phases, one chip:
            experts held beside a shared expert, the per-head output gate in
            both, b1 x 1024, bf16 AMP Adam: the loss falls, every held pair
            is counted
+  kda      one Kimi Delta Attention layer's call of the chunked gated delta
+           rule (ops/linear_attn_ops.py) at the benchmark cell's shape, b2 x
+           T8192, 32 heads of 128, bf16 operands: its result and all five
+           gradients against the token-by-token recurrence in float32
 
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
@@ -1201,6 +1205,99 @@ def phase_laguna(args):
 
 
 # ---------------------------------------------------------------------------
+# kda: the chunked gated delta rule against the recurrence, one layer's call
+# ---------------------------------------------------------------------------
+
+KDA_BATCH, KDA_SEQ, KDA_HEADS, KDA_DIM, KDA_CHUNK = 2, 8192, 32, 128, 64
+KDA_TOL = 2e-2
+
+
+def _delta_rule_recurrence(q, k, v, g, beta, scale, segment=64):
+    """The rule position by position in float32, every sum written out (no
+    matrix unit): q, k, g [B, T, H, K], v [B, T, H, V], beta [B, T, H]. The
+    backward pass keeps the state entering each `segment` of positions."""
+    import jax
+    import jax.numpy as jnp
+
+    def step(state, inp):                   # state [B, H, K, V]
+        qt, kt, vt, gt, bt = inp
+        state = jnp.exp(gt)[..., None] * state
+        recalled = jnp.sum(state * kt[..., None], axis=-2)
+        state = state + (bt[..., None, None] * kt[..., None]
+                         * (vt - recalled)[..., None, :])
+        return state, scale * jnp.sum(state * qt[..., None], axis=-2)
+
+    @jax.checkpoint
+    def run(state, inputs):
+        return jax.lax.scan(step, state, inputs)
+
+    b, t, h, dk = k.shape
+    split = lambda x: jnp.moveaxis(x, 1, 0).reshape(
+        (t // segment, segment) + x.shape[:1] + x.shape[2:])
+    zero = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
+    _, out = jax.lax.scan(run, zero, tuple(map(split, (q, k, v, g, beta))))
+    return jnp.moveaxis(out.reshape((t,) + out.shape[2:]), 0, 1)
+
+
+def kda_case(batch=KDA_BATCH, seq=KDA_SEQ):
+    """(args, the op's and the oracle's (o, five gradients)): one KDA
+    layer's call of `ops/linear_attn_ops.py` at the benchmark cell's shape,
+    bf16 q, k, v (q and k unit vectors a head), a log-decay a channel over
+    the fresh draw's range (A in [1, 16] times steps in [1e-3, 1e-1]), beta
+    a sigmoid's draw. The oracle reads the same bf16 values in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import linear_attn_ops
+    rng = np.random.RandomState(0)
+    shape = (batch, seq, KDA_HEADS, KDA_DIM)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q, k = (jnp.asarray(unit(rng.standard_normal(shape)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    rate = rng.uniform(1.0, 16.0, (KDA_HEADS, 1))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
+    g = jnp.asarray(-rate * dt, jnp.float32)
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.standard_normal(shape[:3]))),
+                       jnp.float32)
+    scale = KDA_DIM ** -0.5
+    f32 = lambda x: x.astype(jnp.float32)
+    op = _with_grads(lambda *a: linear_attn_ops.gated_delta_rule(
+        *a, KDA_CHUNK, scale), 5)
+    oracle = _with_grads(lambda q, k, v, g, b: _delta_rule_recurrence(
+        f32(q), f32(k), f32(v), g, b, scale), 5)
+    return (q, k, v, g, beta), op, oracle
+
+
+def phase_kda(args):
+    import jax
+
+    case_args, op, oracle = kda_case()
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(jax.jit(op)(*case_args))
+    op_s = time.perf_counter() - t0
+    want = jax.jit(oracle)(*case_args)
+    errs = [_rel_err(g, w) for g, w in zip(got, want)]
+    finite = all(bool(np.isfinite(np.asarray(g, np.float32)).all())
+                 for g in got)
+    _require(_platforms(got[0]) == {"tpu"},
+             f"the rule's result lives on {_platforms(got[0])}, not tpu")
+    print(f"kda: the chunked gated delta rule b{KDA_BATCH} x T{KDA_SEQ}, "
+          f"{KDA_HEADS} heads of {KDA_DIM}, chunk {KDA_CHUNK}, against the "
+          f"recurrence: rel err o {errs[0]:.2e}, dq {errs[1]:.2e}, dk "
+          f"{errs[2]:.2e}, dv {errs[3]:.2e}, dg {errs[4]:.2e}, dbeta "
+          f"{errs[5]:.2e} (tol {KDA_TOL:.0e}; compile+run {op_s:.1f} s, "
+          f"set-up fact)", flush=True)
+    _require(finite and max(errs) <= KDA_TOL,
+             f"the rule differs from the recurrence: {errs}")
+    del got, want
+    gc.collect()
+    return {"shape": [KDA_BATCH, KDA_SEQ, KDA_HEADS, KDA_DIM],
+            "rel_err": [round(e, 5) for e in errs], "tol": KDA_TOL,
+            "setup": {"compile_and_run_s": round(op_s, 2)}}
+
+
+# ---------------------------------------------------------------------------
 # dp4: the same ERNIE program, data-parallel over four chips
 # ---------------------------------------------------------------------------
 
@@ -1313,7 +1410,8 @@ def phase_dp4(args):
 PHASES_ONE_CHIP = [("device", phase_device), ("trainer", phase_trainer),
                    ("kernels", phase_kernels), ("deepfm", phase_deepfm),
                    ("looped", phase_looped), ("lfm2", phase_lfm2),
-                   ("joyai", phase_joyai), ("laguna", phase_laguna)]
+                   ("joyai", phase_joyai), ("laguna", phase_laguna),
+                   ("kda", phase_kda)]
 PHASES_FOUR_CHIPS = [("device", phase_device), ("dp4", phase_dp4)]
 
 

@@ -43,6 +43,10 @@ WHITE_LIST = {"conv2d", "conv3d", "depthwise_conv2d", "conv2d_transpose",
 # cosines and signed sines and the multiply-adds `x C + partner(x) S` with
 # one rounding at the end (forward, and the op's own backward rule on the
 # bf16 cotangent: ops/nn_ops.py `_rope_turn`), the silu and the product.
+# gated_delta_rule is gray, as ssd_scan: bf16 q, k, v and raw gate values in
+# and bf16 out beside the bf16 products they sit between; inside, float32:
+# the l2 normalisation, the gate's softplus, the cumulative decays, the
+# triangular system and the states (ops/linear_attn_ops.py).
 # loop_exit_gate and loop_exit_loss are gray and float32 inside whatever
 # arrives: the gate is a full-precision product (its output weights the
 # loss) and black-listing them would only add a cast of the states they read.

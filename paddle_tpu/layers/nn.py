@@ -275,12 +275,15 @@ def layer_norm(input: Variable, scale: bool = True, shift: bool = True,
 
 
 def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
-             group_size: int = 0, param_attr=None, name=None) -> Variable:
+             group_size: int = 0, param_attr=None, name=None,
+             gate_after: str = "") -> Variable:
     """RMS normalisation over the last axis with a learned scale (TPU
     extension, no reference analog): `x / sqrt(mean(x^2) + eps) * scale`.
     With `gate` the input is `x * silu(gate)` first; with `group_size` each
     consecutive group of that many channels is normalised by itself
-    (Mamba-2's gated norm). Statistics in float32 under AMP."""
+    (Mamba-2's gated norm). With `gate` and `gate_after` (an activation's
+    name, "sigmoid") the gate multiplies the RESULT instead:
+    `norm(x) * scale * act(gate)`. Statistics in float32 under AMP."""
     helper = LayerHelper("rms_norm", name=name)
     s = helper.create_parameter(param_attr, shape=[int(input.shape[-1])],
                                 dtype=input.dtype,
@@ -289,8 +292,13 @@ def rms_norm(input: Variable, epsilon: float = 1e-5, gate: Variable = None,
     if gate is not None:
         ins["Gate"] = [gate.name]
     out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    attrs = {"epsilon": epsilon, "group_size": int(group_size)}
+    if gate_after:      # absent otherwise: a gate-first op is as it was
+        if gate is None:
+            raise ValueError("rms_norm: gate_after needs a gate")
+        attrs["gate_after"] = gate_after
     helper.append_op(type="rms_norm", inputs=ins, outputs={"Out": [out.name]},
-                     attrs={"epsilon": epsilon, "group_size": int(group_size)})
+                     attrs=attrs)
     return out
 
 
@@ -458,6 +466,46 @@ def ssd_scan(x: Variable, dt: Variable, b: Variable, c: Variable,
         attrs={"num_heads": int(num_heads), "n_groups": int(n_groups),
                "chunk": int(chunk)})
     return out
+
+
+def gated_delta_rule(q: Variable, k: Variable, v: Variable, g: Variable,
+                     beta: Variable, chunk: int = 64,
+                     return_decay_floor: bool = False, qk_l2norm: float = 0.0,
+                     a_log: Variable = None, dt_bias: Variable = None,
+                     name=None):
+    """The chunked gated delta rule with a decay per channel, Kimi Delta
+    Attention's state update (TPU extension; ops/linear_attn_ops.py has the
+    equations and the sub-block scheme that holds under strong decay). q, k
+    [B, T, H, K], v [B, T, H, V], g [B, T, H, K] the log-decay a channel
+    (<= 0), beta [B, T, H]; T whole chunks of `chunk`, a multiple of 16; the
+    output's scale is K^-1/2. Returns o [B, T, H, V] in q's dtype; with
+    `return_decay_floor` also the most negative cumulative log-decay a chunk
+    reaches, a float32 scalar without gradient. Decays, beta and the states
+    are float32 inside whatever arrives; the products take q's dtype.
+
+    Two parts of a KDA layer can be taken into the op, where they are made a
+    group of chunks at a time and never held for a whole layer: `qk_l2norm`
+    (an epsilon) divides q and k by max(their norm, epsilon) a head and
+    position; `a_log` [H] and `dt_bias` [H * K] (parameters) make the
+    log-decay inside, `-exp(a_log) softplus(g + dt_bias)`, from the decay
+    gate's raw values handed in as `g`."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, v.shape)
+    floor = helper.create_variable_for_type_inference(
+        "float32", shape=(), stop_gradient=True)
+    ins = {"Q": [q.name], "K": [k.name], "V": [v.name], "G": [g.name],
+           "Beta": [beta.name]}
+    if (a_log is None) != (dt_bias is None):
+        raise ValueError("gated_delta_rule: a_log and dt_bias come together")
+    if a_log is not None:
+        ins.update(ALog=[a_log.name], DtBias=[dt_bias.name])
+    attrs = {"chunk": int(chunk)}
+    if qk_l2norm:
+        attrs["qk_l2norm"] = float(qk_l2norm)
+    helper.append_op(
+        type="gated_delta_rule", inputs=ins,
+        outputs={"Out": [out.name], "DecayFloor": [floor.name]}, attrs=attrs)
+    return (out, floor) if return_decay_floor else out
 
 
 def group_norm(input, groups, epsilon=1e-5, param_attr=None, bias_attr=None,

@@ -95,6 +95,7 @@ class JoyaiFlashConfig:
     v_head_dim: int = 128
     rope_theta: float = 32e6
     rope_interleave: bool = True
+    mla_use_nope: bool = False      # True: nothing is rotated
     # experts
     n_routed_experts: int = 256
     num_experts_per_tok: int = 8
@@ -133,22 +134,33 @@ def _linear(cfg, x, size, name):
                      bias_attr=False)
 
 
-def latent_attention(cfg: JoyaiFlashConfig, x, pre: str):
+def latent_attention(cfg, x, pre: str):
     """x [B, T, D] -> [B, T, D]. The two low-rank products' results (1,536
     and 576 wide) stay for the backward pass; q, k and v (6,144, 6,144 and
-    4,096 wide) are made again from them."""
+    4,096 wide) are made again from them.
+
+    Two settings of other models that share the layer (`models/
+    kimi_linear.py`): `cfg.q_lora_rank` None takes q straight from x (one
+    product `<pre>.q.w` under the unit `q_b`; no latent, no `q_norm`), and
+    `cfg.mla_use_nope` leaves q's 64-wide part and the one shared key head
+    unrotated (no `rope` unit; k is still assembled to 192 channels a
+    head)."""
     t = x.shape[1]
     nh, nope, rope, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                           cfg.qk_rope_head_dim, cfg.v_head_dim)
+    rotate = not cfg.mla_use_nope
     with unit("attn"):
-        with unit("q_a"):
-            q_a = _linear(cfg, x, cfg.q_lora_rank, f"{pre}.q_a.w")
-            keep(q_a)
-        with unit("q_norm"):
-            c_q = _norm(cfg, q_a, f"{pre}.q_a_norm.w")
+        if cfg.q_lora_rank is None:
+            c_q, q_w = x, f"{pre}.q.w"
+        else:
+            with unit("q_a"):
+                q_a = _linear(cfg, x, cfg.q_lora_rank, f"{pre}.q_a.w")
+                keep(q_a)
+            with unit("q_norm"):
+                c_q, q_w = _norm(cfg, q_a, f"{pre}.q_a_norm.w"), f"{pre}.q_b.w"
         with unit("q_b"):
             q = layers.reshape(
-                _linear(cfg, c_q, nh * cfg.qk_head_dim, f"{pre}.q_b.w"),
+                _linear(cfg, c_q, nh * cfg.qk_head_dim, q_w),
                 [0, t, nh, cfg.qk_head_dim])
         with unit("kv_a"):
             kv_a = _linear(cfg, x, cfg.kv_lora_rank + rope, f"{pre}.kv_a.w")
@@ -161,19 +173,23 @@ def latent_attention(cfg: JoyaiFlashConfig, x, pre: str):
                 _linear(cfg, c_kv, nh * (nope + dv), f"{pre}.kv_b.w"),
                 [0, t, nh, nope + dv])
             k_nope, v = layers.split(kv, [nope, dv], dim=3)
-        with unit("rope"):
-            # q's rope parts and the one shared key head, nh + 1 heads of
-            # `rope` channels in one rotation
-            q_nope, q_rope = layers.split(q, [nope, rope], dim=3)
-            turned = layers.rotary_embedding(
-                layers.concat([layers.reshape(q_rope, [0, t, nh * rope]),
-                               k_rope], axis=2),
-                nh + 1, theta=cfg.rope_theta,
-                interleaved=cfg.rope_interleave)
-            q_rope, k_rope = layers.split(turned, [nh * rope, rope], dim=2)
+        if rotate:
+            with unit("rope"):
+                # q's rope parts and the one shared key head, nh + 1 heads
+                # of `rope` channels in one rotation
+                q_nope, q_rope = layers.split(q, [nope, rope], dim=3)
+                turned = layers.rotary_embedding(
+                    layers.concat([layers.reshape(q_rope, [0, t, nh * rope]),
+                                   k_rope], axis=2),
+                    nh + 1, theta=cfg.rope_theta,
+                    interleaved=cfg.rope_interleave)
+                q_rope, k_rope = layers.split(turned, [nh * rope, rope],
+                                              dim=2)
         with unit("assemble"):
-            q = layers.concat(
-                [q_nope, layers.reshape(q_rope, [0, t, nh, rope])], axis=3)
+            if rotate:
+                q = layers.concat(
+                    [q_nope, layers.reshape(q_rope, [0, t, nh, rope])],
+                    axis=3)
             k = layers.concat(
                 [k_nope, layers.expand(layers.reshape(k_rope,
                                                       [0, t, 1, rope]),

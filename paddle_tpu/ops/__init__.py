@@ -20,6 +20,7 @@ from . import (  # noqa: F401
     framework_ops,
     fused_ops,
     fusion_ops,
+    linear_attn_ops,
     math_ops,
     metric_ops,
     misc_ops,

@@ -21,7 +21,7 @@ import numpy as np
 from jax import lax
 
 from ..core.registry import register_op
-from .common import one, opt_input
+from .common import act_map, one, opt_input
 
 
 def _pair(v, n=2):
@@ -349,7 +349,10 @@ def _rms_norm(ctx, inputs, attrs):
     2019): y = x / sqrt(mean(x^2) + eps) * Scale. With a Gate input z (same
     shape as X) the input is x * silu(z) first, and with `group_size` the
     mean is taken over consecutive groups of that many channels, each
-    normalised by itself (Mamba-2's gated norm). Gray under AMP, like
+    normalised by itself (Mamba-2's gated norm). With `gate_after` (an
+    activation's name) the gate multiplies the normalised, scaled result
+    instead, as `act(z)` (Kimi Delta Attention's output gate: "sigmoid").
+    Gray under AMP, like
     layer_norm: activations in whatever dtype, statistics in float32, the
     input dtype back."""
     (x,) = inputs["X"]
@@ -357,14 +360,17 @@ def _rms_norm(ctx, inputs, attrs):
     gate = inputs.get("Gate", [None])[0]
     eps = attrs.get("epsilon", 1e-5)
     group = int(attrs.get("group_size", 0)) or x.shape[-1]
+    after = attrs.get("gate_after", "")
     xf = x.astype(jnp.float32)
-    if gate is not None:
+    if gate is not None and not after:
         xf = xf * jax.nn.silu(gate.astype(jnp.float32))
     grouped = xf.reshape(x.shape[:-1] + (x.shape[-1] // group, group))
     ms = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
     y = (grouped * lax.rsqrt(ms + eps)).reshape(x.shape)
     if scale is not None:
         y = y * scale.astype(jnp.float32)
+    if after:       # the normalised, scaled result times act(gate)
+        y = y * act_map()[after](gate.astype(jnp.float32))
     return one(y.astype(x.dtype))
 
 

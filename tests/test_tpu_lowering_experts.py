@@ -14,13 +14,15 @@ from tpu_lowering_base import _dispatch_as_on_tpu, one_chip  # noqa: F401
     (2048, 1536, 64, 8, 4, True, "silu"),
     (2048, 768, 256, 16, 8, True, "silu"),
     (2048, 512, 256, 32, 8, True, "silu"),
-], ids=["nemotron", "lfm2", "joyai", "laguna"])
+    (2304, 1024, 256, 8, 8, True, "silu"),
+], ids=["nemotron", "lfm2", "joyai", "laguna", "kimi_linear"])
 def test_grouped_expert_product_compiles_for_v5e_at_the_cells_widths(
         one_chip, d, h, e, held, k, gated, act):
     """The routed experts of one block of each expert cell (16,384 tokens of
     b2 x T8192, bf16 activations over float32 masters; Nemotron's plain
-    relu^2 experts of 2688 x 1856, 14.5 lane tiles wide, and the three gated
-    shapes), loss and gradients, through the TPU's own compiler: the two
+    relu^2 experts of 2688 x 1856, 14.5 lane tiles wide, and the four gated
+    shapes; Kimi Linear's d 2,304 is 18 lane tiles and no multiple of 512:
+    `grouped_path` says "pallas" there too), loss and gradients, through the TPU's own compiler: the two
     kernels and no loop over tiles, nothing of the size of all the pairs'
     rows, and the kernels' own buffers (a float32 row a token forward, two
     backward) within 0.9 GiB."""
