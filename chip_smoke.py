@@ -47,8 +47,12 @@ Phases, one chip:
            is counted
   kda      one Kimi Delta Attention layer's call of the chunked gated delta
            rule (ops/linear_attn_ops.py) at the benchmark cell's shape, b2 x
-           T8192, 32 heads of 128, bf16 operands: its result and all five
-           gradients against the token-by-token recurrence in float32
+           T8192, 32 heads of 128, bf16 operands, the l2 normalisation and
+           the decay gate inside, a decay floor past float32's e^-88: the op
+           takes its Pallas kernels (ops/pallas_kernels/kda_chunk.py), and
+           its result and all seven gradients (q, k, v, the gate's values,
+           beta, A_log, dt_bias) stand against the token-by-token recurrence
+           in float32
 
 `--chips 4` runs `device` and then `dp4`: the same ERNIE program under
 CompiledProgram.with_data_parallel at 64 per chip, checking the four-way feed
@@ -1239,62 +1243,88 @@ def _delta_rule_recurrence(q, k, v, g, beta, scale, segment=64):
     return jnp.moveaxis(out.reshape((t,) + out.shape[2:]), 0, 1)
 
 
+KDA_L2_EPS = 1e-6
+
+
 def kda_case(batch=KDA_BATCH, seq=KDA_SEQ):
-    """(args, the op's and the oracle's (o, five gradients)): one KDA
-    layer's call of `ops/linear_attn_ops.py` at the benchmark cell's shape,
-    bf16 q, k, v (q and k unit vectors a head), a log-decay a channel over
-    the fresh draw's range (A in [1, 16] times steps in [1e-3, 1e-1]), beta
-    a sigmoid's draw. The oracle reads the same bf16 values in float32."""
+    """(args, the op's and the oracle's (o, seven gradients), the op's decay
+    floor and the form it takes): one KDA layer's call of
+    `ops/linear_attn_ops.py` as `models/kimi_linear.py` makes it at the
+    benchmark cell's shape: bf16 q, k, v (q and k normalised inside), the
+    decay gate's raw values in bf16 with A_log and dt_bias (A in [1, 16]
+    times steps of softplus(raw + dt_bias) between 1e-3 and 0.4: the
+    strongest heads' chunks pass float32's e^-88), beta a sigmoid's draw.
+    The oracle normalises and gates the same bf16 values in float32 (q and k
+    rounded to bf16 again, as the op's are) and walks them position by
+    position."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops import linear_attn_ops
     rng = np.random.RandomState(0)
     shape = (batch, seq, KDA_HEADS, KDA_DIM)
-    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
-    q, k = (jnp.asarray(unit(rng.standard_normal(shape)), jnp.bfloat16)
-            for _ in range(2))
-    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    rate = rng.uniform(1.0, 16.0, (KDA_HEADS, 1))
-    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
-    g = jnp.asarray(-rate * dt, jnp.float32)
-    beta = jnp.asarray(1 / (1 + np.exp(-rng.standard_normal(shape[:3]))),
-                       jnp.float32)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), bf16)
+               for _ in range(3))
+    a_log = jnp.asarray(np.log(rng.uniform(1.0, 16.0, KDA_HEADS)), f32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.4), shape[2:]))
+    dt_bias = jnp.asarray(dt + np.log(-np.expm1(-dt)), f32)
+    raw = jnp.asarray(0.5 * rng.standard_normal(shape), bf16)
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.standard_normal(shape[:3]))), f32)
     scale = KDA_DIM ** -0.5
-    f32 = lambda x: x.astype(jnp.float32)
-    op = _with_grads(lambda *a: linear_attn_ops.gated_delta_rule(
-        *a, KDA_CHUNK, scale), 5)
-    oracle = _with_grads(lambda q, k, v, g, b: _delta_rule_recurrence(
-        f32(q), f32(k), f32(v), g, b, scale), 5)
-    return (q, k, v, g, beta), op, oracle
+    rule = lambda *a: linear_attn_ops.kda_rule(
+        *a[:5], (a[5], a[6]), KDA_CHUNK, scale, KDA_L2_EPS)
+
+    def recurrence(q, k, v, raw, beta, a_log, dt_bias):
+        unit = lambda x: (x.astype(f32) / jnp.maximum(jnp.linalg.norm(
+            x.astype(f32), axis=-1, keepdims=True), KDA_L2_EPS)).astype(bf16)
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            raw.astype(f32) + dt_bias)
+        return _delta_rule_recurrence(
+            unit(q).astype(f32), unit(k).astype(f32), v.astype(f32), g, beta,
+            scale)
+
+    args = (q, k, v, raw, beta, a_log, dt_bias)
+    return (args, _with_grads(lambda *a: rule(*a)[0], 7),
+            _with_grads(recurrence, 7), lambda: rule(*args)[1],
+            linear_attn_ops.rule_path(q, v, KDA_CHUNK))
 
 
 def phase_kda(args):
     import jax
 
-    case_args, op, oracle = kda_case()
+    case_args, op, oracle, floor, path = kda_case()
+    print(f"kda: the op takes the form {path!r}", flush=True)
+    _require(path == "pallas",
+             f"at the cell's shape on a TPU the rule took {path!r}, not its "
+             "kernels")
     t0 = time.perf_counter()
     got = jax.block_until_ready(jax.jit(op)(*case_args))
     op_s = time.perf_counter() - t0
+    floor = float(jax.jit(floor)())
     want = jax.jit(oracle)(*case_args)
     errs = [_rel_err(g, w) for g, w in zip(got, want)]
     finite = all(bool(np.isfinite(np.asarray(g, np.float32)).all())
                  for g in got)
     _require(_platforms(got[0]) == {"tpu"},
              f"the rule's result lives on {_platforms(got[0])}, not tpu")
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta", "dA_log", "ddt_bias")
     print(f"kda: the chunked gated delta rule b{KDA_BATCH} x T{KDA_SEQ}, "
-          f"{KDA_HEADS} heads of {KDA_DIM}, chunk {KDA_CHUNK}, against the "
-          f"recurrence: rel err o {errs[0]:.2e}, dq {errs[1]:.2e}, dk "
-          f"{errs[2]:.2e}, dv {errs[3]:.2e}, dg {errs[4]:.2e}, dbeta "
-          f"{errs[5]:.2e} (tol {KDA_TOL:.0e}; compile+run {op_s:.1f} s, "
-          f"set-up fact)", flush=True)
+          f"{KDA_HEADS} heads of {KDA_DIM}, chunk {KDA_CHUNK}, decay floor "
+          f"{floor:.1f} nats, against the recurrence: rel err "
+          + ", ".join(f"{n} {e:.2e}" for n, e in zip(names, errs))
+          + f" (tol {KDA_TOL:.0e}; compile+run {op_s:.1f} s, set-up fact)",
+          flush=True)
     _require(finite and max(errs) <= KDA_TOL,
              f"the rule differs from the recurrence: {errs}")
+    _require(floor < -88.0,
+             f"the case's decay floor {floor} is not past float32's e^-88")
     del got, want
     gc.collect()
-    return {"shape": [KDA_BATCH, KDA_SEQ, KDA_HEADS, KDA_DIM],
-            "rel_err": [round(e, 5) for e in errs], "tol": KDA_TOL,
-            "setup": {"compile_and_run_s": round(op_s, 2)}}
+    return {"shape": [KDA_BATCH, KDA_SEQ, KDA_HEADS, KDA_DIM], "path": path,
+            "decay_floor": round(floor, 2),
+            "rel_err": dict(zip(names, (round(e, 5) for e in errs))),
+            "tol": KDA_TOL, "setup": {"compile_and_run_s": round(op_s, 2)}}
 
 
 # ---------------------------------------------------------------------------

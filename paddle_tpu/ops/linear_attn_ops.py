@@ -58,11 +58,27 @@ k, the float32 log-decay and its cotangent are 1 GiB that a layer then never
 holds whole (the published kernel, as remembered and not held here, has the
 same two switches: `use_qk_l2norm_in_kernel`, `use_gate_in_kernel`).
 
-One form computes it: XLA einsums, on every backend (counter
-`ops/kda_lowered{path="einsum"}`). Kernels, where they come, go in
-ops/pallas_kernels/kda_chunk.py with this form as their oracle, are chosen by
-shapes and backend alone, and are imported where an op is lowered, never by
-`import paddle_tpu`.
+Two forms compute the same numbers:
+
+- *the kernels* (ops/pallas_kernels/kda_chunk.py): a Pallas forward and a
+  Pallas backward kernel that hold a tile of two chunks of a few heads in
+  VMEM, make its terms there (the triangles level by level, the inverse by
+  block forward substitution as ten masked products), walk the state through
+  it and keep the state entering each tile for the backward kernel, which
+  differentiates the same tile in VMEM. They run where `pallas_kernels.kda_chunk.takes` says: on a
+  TPU backend, for heads of 128 key and 128 value channels, a chunk of 64, T
+  whole tiles of 128 and bf16 or float32 operands, and not under a mesh (a
+  Mosaic call cannot be partitioned by GSPMD: there the einsum form runs,
+  which GSPMD partitions by batch).
+- *the einsum form* (here): XLA einsums inside a loop over groups of chunks
+  and a loop over a group's chunks. It runs everywhere else (off the TPU,
+  under a mesh, for the shapes the kernels do not take), and it is the
+  kernels' oracle in the tests.
+
+The shapes and the backend choose (`rule_path`); no flag, attribute or
+argument does. The counter `ops/kda_lowered{path="pallas"|"einsum"}` says
+which form an op was lowered to. The kernels are imported where an op is
+lowered, never by `import paddle_tpu`.
 """
 from __future__ import annotations
 
@@ -288,17 +304,32 @@ def _kda_rule(q, k, v, g, beta, gate, chunk, scale, l2_eps):
     return _rule_states(q, k, v, g, beta, gate, chunk, scale, l2_eps)[:2]
 
 
-def kda_rule(q, k, v, g, beta, gate, chunk, scale, l2_eps):
+def rule_path(q, v, chunk: int, under_mesh: bool = False) -> str:
+    """"pallas" where the kernels take a rule over q [B, T, H, K] and v
+    [B, T, H, V] at this chunk, else "einsum" (the module docstring has the
+    rule)."""
+    from .pallas_kernels import kda_chunk as kernels
+    return ("pallas" if not under_mesh and kernels.takes(
+        q.shape[1], q.shape[3], v.shape[3], chunk, q.dtype) else "einsum")
+
+
+def kda_rule(q, k, v, g, beta, gate, chunk, scale, l2_eps,
+             under_mesh: bool = False):
     """The chunked rule with what a KDA layer puts around it taken in: q and
     k l2-normalised inside where `l2_eps` is not 0, and with `gate` =
     (A_log [H], dt_bias [H, K]) the log-decay made inside from the decay
     gate's raw values g (`_prepared`); `gate` None: g is the log-decay.
     Returns (o [B, T, H, V] in q's dtype, the decay floor: the most negative
-    cumulative log-decay a chunk reaches, float32, no gradient). Inside, a
-    group of chunks at a time: the normalised q and k and the float32
+    cumulative log-decay a chunk reaches, float32, no gradient), by the
+    kernels where `rule_path` says so and by the einsum form elsewhere
+    (`under_mesh`: whether the op is lowered under a mesh). Inside, a tile or
+    a group of chunks at a time: the normalised q and k and the float32
     log-decay of a whole layer are never held."""
-    return _kda_rule(q, k.astype(q.dtype), v.astype(q.dtype), g,
-                     beta.astype(jnp.float32), gate, chunk, scale, l2_eps)
+    k, v, beta = k.astype(q.dtype), v.astype(q.dtype), beta.astype(jnp.float32)
+    if rule_path(q, v, chunk, under_mesh) == "pallas":
+        from .pallas_kernels import kda_chunk as kernels
+        return kernels.kda_rule(q, k, v, g, beta, gate, scale, l2_eps)
+    return _kda_rule(q, k, v, g, beta, gate, chunk, scale, l2_eps)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=64, scale=None):
@@ -381,6 +412,7 @@ def _gated_delta_rule(ctx, inputs, attrs):
     sub-block scheme is from float32's range."""
     from ..observability import get_registry
     from .common import opt_input
+    from .fused_ops import _under_mesh
 
     (q,), (k,), (v,) = inputs["Q"], inputs["K"], inputs["V"]
     (g,), (beta,) = inputs["G"], inputs["Beta"]
@@ -390,9 +422,11 @@ def _gated_delta_rule(ctx, inputs, attrs):
         raise ValueError(
             f"gated_delta_rule: sequence length {q.shape[1]} is not whole "
             f"chunks of {chunk}, or the chunk no multiple of {BLOCK}")
-    get_registry().counter("ops/kda_lowered", path="einsum").inc()
+    under_mesh = _under_mesh(ctx)
+    get_registry().counter(
+        "ops/kda_lowered", path=rule_path(q, v, chunk, under_mesh)).inc()
     gate = None if a_log is None else (
         a_log, dt_bias.reshape(k.shape[2], k.shape[3]))
     out, floor = kda_rule(q, k, v, g, beta, gate, chunk, _scale(None, k),
-                          float(attrs.get("qk_l2norm", 0.0)))
+                          float(attrs.get("qk_l2norm", 0.0)), under_mesh)
     return {"Out": [out], "DecayFloor": [floor]}

@@ -13,6 +13,9 @@ attention, forward and backward), `fused_bn` (batch norm + activation, 1x1
 conv + batch norm), `ssd_scan` (Mamba-2's chunked state-space scan, forward
 and backward; imported by ops/ssm_ops.py when an op is lowered, not here),
 `grouped_ffn` (the experts' grouped product of parallel/moe.py, forward and
-backward; imported there where the product's form is picked, not here).
+backward; imported there where the product's form is picked, not here),
+`kda_chunk` (Kimi Delta Attention's chunked gated delta rule, forward and
+backward; imported by ops/linear_attn_ops.py when an op is lowered, not
+here).
 """
 from .flash_attention import flash_attention  # noqa: F401

@@ -85,6 +85,13 @@ def _program(cfg, b=2, t=32, lr=None):
     return main, loss, counters, floors, exe, scope
 
 
+def _lowered(form):
+    from paddle_tpu.observability import get_registry
+    return sum(s["value"] for s in get_registry().series()
+               if s["name"] == "ops/kda_lowered"
+               and s["labels"].get("path") == form)
+
+
 def _reference_loss(cfg, weights, batch):
     b, t = batch["ids"].shape
     return sum(ref.sum_loss(weights, jnp.asarray(batch["ids"][r]),
@@ -105,24 +112,44 @@ def _moment_grad(scope, k):
 # the model against the reference
 # ---------------------------------------------------------------------------
 
-def test_loss_and_every_gradient_leaf_against_the_reference():
+@pytest.mark.parametrize("form", ["einsum", "pallas"])
+def test_loss_and_every_gradient_leaf_against_the_reference(form,
+                                                            monkeypatch):
     """float32 against float32: the chunked rule against the recurrence, the
     grouped product against a loop over experts, the flash form against the
     full softmax. 2e-6 on the loss and 2e-4 of a leaf's largest entry are
-    rounding's (the largest read here: 3e-5 of a leaf)."""
-    cfg = _cfg()
-    main, loss, _, _, exe, scope = _program(cfg, lr=1e-3)
+    rounding's (the largest read here: 3e-5 of a leaf). "pallas": the rule's
+    kernels (ops/pallas_kernels/kda_chunk.py) through the interpreter at a
+    shape they take, two heads of 128 over one tile of two chunks of 64: the
+    gate's parameters, the floor fetched with the loss and the remat block
+    making the kernel's forward again."""
+    from paddle_tpu.ops.pallas_kernels import kda_chunk
+
+    cfg, shape = _cfg(), {}
+    if form == "pallas":
+        monkeypatch.setattr(kda_chunk, "FORCE_PALLAS_INTERPRET", True)
+        cfg = _cfg(kda_chunk=64, linear_attn_config=dict(
+            _cfg()["linear_attn_config"], head_dim=128))
+        shape = {"b": 1, "t": 128}
+    before = _lowered(form)
+    main, loss, _, floors, exe, scope = _program(cfg, lr=1e-3, **shape)
     weights = ref.make_weights(cfg, 5)
     params = main.global_block().all_parameters()
     assert sorted(p.name for p in params) == sorted(weights)
     assert ([p.name for p in params if not p.trainable]
             == [k for k in weights if k.endswith(ref.FROZEN)])
     _set(scope, weights)
-    (batch,) = _batches(cfg, 1)
+    (batch,) = _batches(cfg, 1, **shape)
     want_loss, want_grads = jax.value_and_grad(
         lambda p: _reference_loss(cfg, p, batch))(weights)
-    (got_loss,) = exe.run(main, feed=batch, fetch_list=[loss], scope=scope)
+    got_loss, *got_floors = exe.run(
+        main, feed=batch, fetch_list=[loss] + [f for _, f in floors],
+        scope=scope)
     assert float(got_loss) == pytest.approx(float(want_loss), rel=2e-6)
+    assert all(float(f) < 0 for f in got_floors) and len(got_floors) == 3
+    # each KDA layer's op lowered by the form asked for, forward and again
+    # behind its remat block
+    assert _lowered(form) >= before + 3
     for k in weights:
         if k.endswith(ref.FROZEN):
             continue

@@ -436,13 +436,18 @@ def test_laguna_attention_kernels_compile_for_v5e(one_chip, hq, window,
     assert scheduled["fwd"] == scheduled["bwd"] == tiles
 
 
+@pytest.mark.parametrize("form", ["pallas", "einsum"])
 def test_the_gated_delta_rule_compiles_for_v5e_at_kimi_linear_s_width(
-        one_chip):
+        one_chip, form):
     """One KDA layer's call of the chunked gated delta rule as
     `kimi_linear_48b_a3b.train8k` makes it (b2 x T8192, 32 heads of 128,
     chunks of 64, bf16 q, k, v and raw decay gate, the l2 normalisation and
     the gate's softplus inside), forward and all seven gradients, through the
-    TPU's own compiler: XLA loops and no Mosaic call; beside the states
+    TPU's own compiler. "pallas", what the shapes pick on a TPU: the forward
+    and the backward kernel of ops/pallas_kernels/kda_chunk.py and no loop,
+    the state entering each tile of 128 positions (256 MiB) all that is kept
+    beside the inputs, under 1 GiB of temporaries. "einsum", what the op
+    takes under a mesh: XLA loops and no Mosaic call; beside the states
     entering the chunks (512 MiB) and the inputs and cotangents laid out
     heads first (bf16, 128 MiB each) nothing of a whole layer's size, and
     nothing of the size of the float32 log-decay, which a group of chunks
@@ -456,17 +461,22 @@ def test_the_gated_delta_rule_compiles_for_v5e_at_kimi_linear_s_width(
 
     def loss(q, kk, v, g, beta, a_log, dt_bias):
         out, _ = la.kda_rule(q, kk, v, g, beta, (a_log, dt_bias), 64,
-                             k ** -0.5, 1e-6)
+                             k ** -0.5, 1e-6, under_mesh=form == "einsum")
         return jnp.sum(out.astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(loss, tuple(range(7)))).trace(
         wide, wide, wide, wide, f32(b, t, h), f32(h), f32(h, k)).lower(
             lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert f"f32[{b},{t},{h},{k}]" not in text
+    if form == "pallas":
+        assert text.count("tpu_custom_call") == 2 and " while(" not in text
+        assert f"f32[{b},{h},{t // 128},{k},{k}]" in text
+        assert temp < 2 ** 30, temp / 2 ** 30
+        return
     assert "tpu_custom_call" not in text and text.count(" while(") >= 4
     groups = la._groups(t, b, h, 64)
     assert groups == 16
     assert f"f32[{groups},{t // 64 // groups},{b},{h},{k},{k}]" in text
-    assert f"f32[{b},{t},{h},{k}]" not in text
-    temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2.3 * 2 ** 30, temp / 2 ** 30

@@ -184,9 +184,10 @@ def test_kimi_linear_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
     12 bytes a parameter of state, calls the attention kernels twice in the
     one latent-attention layer and the experts' kernels at d 2,304 (18 lane
     tiles, no multiple of 512) in place of their loops, and runs the delta
-    rule as loops over groups of chunks and over chunks: no Mosaic call of
-    its own, and nothing of the size of a whole layer's float32 log-decay
-    outside them."""
+    rule as its two kernels (PR 48), three calls a KDA layer (forward, the
+    forward made again behind the remat block, backward) and no loop: with a
+    tile's terms in VMEM the step takes less than the einsum form's 13.87
+    GiB."""
     traced = _grouped_kernel_traces()
     state, compiled = _compiled_cell_step("kimi_linear_48b_a3b", one_chip)
     params = sum(int(np.prod(s.shape)) for n, s in state.items()
@@ -195,20 +196,21 @@ def test_kimi_linear_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):
     assert params == 602_433_408
     args = compiled.memory_analysis().argument_size_in_bytes
     assert 12 * params / GIB < args / GIB < 6.8
-    # 13.87 compiled here, PR 47
-    assert 13.4 < _live_bytes(compiled) / GIB < 14.5
+    # 13.87 compiled here with the einsum form, PR 47
+    print("kimi step live GiB", _live_bytes(compiled) / GIB)
+    assert 12.0 < _live_bytes(compiled) / GIB < 13.87
     assert traced() == {
         "grouped_ffn_rows": 2, "grouped_ffn_fwd": 1, "grouped_ffn_bwd": 1}
     text = compiled.as_text()
     # one attention layer's call forward and backward; four expert layers'
-    # rows laid out and walked, forward and backward
-    assert text.count("tpu_custom_call") == 1 * 2 + 4 * 4
-    # the head's two loops; each KDA layer's loops over groups and chunks,
+    # rows laid out and walked, forward and backward; four KDA layers' rule
     # forward, made again and backward
-    rule_loops = [line for line in text.splitlines()
-                  if " while(" in line and "u.kda/u.rule" in line]
-    assert len(rule_loops) >= 4 * 3 * 2
-    assert text.count(" while(") >= len(rule_loops) + 2
+    assert text.count("tpu_custom_call") == 1 * 2 + 4 * 4 + 4 * 3
+    rule = [line for line in text.splitlines() if "u.kda/u.rule" in line]
+    assert sum("tpu_custom_call" in line for line in rule) == 4 * 3
+    assert not [line for line in rule if " while(" in line]
+    # the head's two loops stay
+    assert text.count(" while(") >= 2
 
 
 def test_nemotron_step_compiles_for_v5e_at_the_cell_s_sizes(one_chip):

@@ -13,6 +13,7 @@ from paddle_tpu.ops.pallas_kernels import fused_bn
 fa = importlib.import_module("paddle_tpu.ops.pallas_kernels.flash_attention")
 ssd = importlib.import_module("paddle_tpu.ops.pallas_kernels.ssd_scan")
 gffn = importlib.import_module("paddle_tpu.ops.pallas_kernels.grouped_ffn")
+kda = importlib.import_module("paddle_tpu.ops.pallas_kernels.kda_chunk")
 
 
 @pytest.fixture(autouse=True)
@@ -22,6 +23,7 @@ def _dispatch_as_on_tpu(monkeypatch):
     monkeypatch.setattr(fused_bn, "_on_tpu", lambda: True)
     monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     monkeypatch.setattr(gffn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
 
 @pytest.fixture(scope="module")
